@@ -69,10 +69,14 @@ def main():
     args = parse_args()
     import jax
 
-    if args.cpu_devices:
-        from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+    from neuronx_distributed_llama3_2_tpu.utils.runtime import (
+        enable_compile_cache,
+        set_cpu_devices,
+    )
 
+    if args.cpu_devices:
         set_cpu_devices(args.cpu_devices)
+    enable_compile_cache()
 
     from neuronx_distributed_llama3_2_tpu.inference import (
         GenerationConfig,

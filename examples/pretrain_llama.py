@@ -101,10 +101,14 @@ def main():
     args = parse_args()
     import jax
 
-    if args.cpu_devices:
-        from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+    from neuronx_distributed_llama3_2_tpu.utils.runtime import (
+        enable_compile_cache,
+        set_cpu_devices,
+    )
 
+    if args.cpu_devices:
         set_cpu_devices(args.cpu_devices)
+    enable_compile_cache()
 
     import numpy as np
 
@@ -237,11 +241,13 @@ def main():
             native_available,
         )
 
-        if native_available():
-            dataset = NativeTokenDataset(data_path, args.seq_len)
-        else:
-            logger.warning("--native-loader requested but no C++ toolchain; "
-                           "using the numpy loader")
+        if not native_available():
+            raise SystemExit(
+                "--native-loader: native/libtoken_loader.so could not be "
+                "built (needs make and g++); drop the flag for the numpy "
+                "loader"
+            )
+        dataset = NativeTokenDataset(data_path, args.seq_len)
     if dataset is None:
         dataset = TokenDataset(data_path, args.seq_len)
     # train/eval holdout: eval owns the TAIL of the sample space and its own

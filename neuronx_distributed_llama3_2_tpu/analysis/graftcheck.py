@@ -155,11 +155,14 @@ _COLLECTIVE_PRIMS: FrozenSet[str] = frozenset(
 
 # host-transfer primitives (GC003): device_put is an explicit host->device
 # move smuggled into a trace; the callback family round-trips through the
-# host every step.
+# host every step. jax.debug.print is its own primitive (debug_print) on
+# jax 0.9, no longer a debug_callback; the rest of the family kept its
+# names (probed: debug.callback/breakpoint -> debug_callback,
+# pure_callback, io_callback, device_put).
 _HOST_TRANSFER_PRIMS: FrozenSet[str] = frozenset(
     {
         "device_put", "copy_to_host_async", "callback", "pure_callback",
-        "io_callback", "debug_callback",
+        "io_callback", "debug_callback", "debug_print",
     }
 )
 

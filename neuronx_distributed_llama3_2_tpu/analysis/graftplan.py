@@ -447,10 +447,9 @@ class Simulator:
 
     def _charge(self, key: tuple, pad: int, kind: str) -> None:
         f, byts, _src = analytic_cost(key, self.dims)
-        t = max(
-            f / flops_mod.PEAK_FLOPS_PER_CHIP,
-            byts / flops_mod.PEAK_HBM_BW_PER_CHIP,
-        ) * 1e3 + DISPATCH_OVERHEAD_MS
+        peaks = flops_mod.chip_peaks()
+        t = max(f / peaks.bf16_flops, byts / peaks.hbm_bw) * 1e3
+        t += DISPATCH_OVERHEAD_MS
         self._device_ms += t
         self._step_device_ms += t
         self._dispatches += 1

@@ -759,8 +759,7 @@ def _rule_sl005(ctx: _ModuleContext) -> List[Finding]:
                 "(the 0.4.x SPMD partitioner miscompiles mixed-manual "
                 "annotations; newer jax needs the ambient abstract mesh)",
                 "use parallel.layers.constrain — it targets the ambient "
-                "abstract mesh and no-ops in legacy full-manual regions — "
-                "or constrain outside the manual region",
+                "abstract mesh — or constrain outside the manual region",
             )
             if f:
                 out.append(f)

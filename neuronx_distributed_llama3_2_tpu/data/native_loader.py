@@ -8,9 +8,12 @@ the reference delegates to torch DataLoader's C++ workers
 :mod:`.dataset`; this module only accelerates sample gathering.
 
 The shared library builds on demand with ``g++`` (no pybind11 — plain C ABI
-via ctypes, per the environment constraints) and is cached next to the
-source. Everything degrades gracefully: :func:`native_available` is False
-when no compiler/library exists and callers fall back to the numpy path.
+via ctypes, per the environment constraints) next to the source, and
+rebuilds whenever it is missing or older than ``token_loader.cc`` — it is a
+git-ignored build product, so a copy that rode along from another checkout
+is never trusted over the source. :func:`native_available` is False when no
+toolchain exists; a caller that was *asked* for the native loader treats
+that as an error (``examples/pretrain_llama.py --native-loader``).
 """
 
 from __future__ import annotations
@@ -30,6 +33,7 @@ _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "..", "..", "native"
 )
 _SO_PATH = os.path.join(_NATIVE_DIR, "libtoken_loader.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "token_loader.cc")
 _LIB: Optional[ctypes.CDLL] = None
 _BUILD_FAILED = False
 
@@ -40,7 +44,9 @@ def _load_lib() -> Optional[ctypes.CDLL]:
         return _LIB
     if _BUILD_FAILED:
         return None
-    if not os.path.exists(_SO_PATH):
+    if not os.path.exists(_SO_PATH) or (
+        os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)
+    ):
         try:
             subprocess.run(
                 ["make", "-C", _NATIVE_DIR],
@@ -49,7 +55,7 @@ def _load_lib() -> Optional[ctypes.CDLL]:
                 timeout=120,
             )
         except (OSError, subprocess.SubprocessError) as e:
-            logger.info("native token loader unavailable (%s); using numpy", e)
+            logger.info("native token loader unavailable (%s)", e)
             _BUILD_FAILED = True
             return None
     lib = ctypes.CDLL(_SO_PATH)

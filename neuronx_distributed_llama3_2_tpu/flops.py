@@ -14,18 +14,67 @@ the backward pass, recovering the classic ``6·N + 12·L·H·S`` — exactly
 the expression ``trainer/metrics.py`` always used, verified drift-free
 when this module was factored out.
 
-Peak figures are the v5e reference chip (the BASELINE.md target
-hardware); callers may override per-chip peaks explicitly.
+Peak figures come from :data:`CHIP_PEAKS`, one row per ``device_kind``
+with its source; :func:`chip_peaks` is the only way to read one.
 """
 
 from __future__ import annotations
 
-# TPU v5e reference peaks: bf16 matmul throughput, HBM capacity and
-# bandwidth. bench.py's 45%-MFU north star and the serving roofline
-# both normalize by these.
-PEAK_FLOPS_PER_CHIP = 197e12        # bf16 FLOP/s
-HBM_BYTES_PER_CHIP = 16 * 2**30     # 16 GiB HBM
-PEAK_HBM_BW_PER_CHIP = 819e9        # bytes/s
+import dataclasses
+from typing import Optional
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks that MFU and the serving roofline divide by."""
+
+    bf16_flops: float      # FLOP/s, bf16 matmul
+    hbm_bytes: int         # HBM capacity
+    hbm_bw: float          # bytes/s
+    source: str
+
+
+# keyed by ``jax.devices()[0].device_kind``
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12,
+        hbm_bytes=16 * 2**30,
+        hbm_bw=819e9,
+        source='Google Cloud documentation, "TPU v5e" system architecture: '
+               "197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s per chip",
+    ),
+}
+
+# the row the CPU tier borrows: tests and analyzers price programs against a
+# fixed chip so their analytic cost tables stay deterministic on a host that
+# has no peaks of its own. Never the row of a run that produces device numbers.
+CPU_BORROWED_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: Optional[str] = None) -> ChipPeaks:
+    """The :data:`CHIP_PEAKS` row for ``device_kind`` (default: the first
+    device of this process). A TPU kind that is not in the table is an
+    error, not a default; a CPU process gets :data:`CPU_BORROWED_KIND`."""
+    if device_kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
+            device_kind = CPU_BORROWED_KIND
+        elif dev.platform == "tpu":
+            device_kind = dev.device_kind
+        else:
+            raise ValueError(
+                f"no peaks for platform {dev.platform!r} ({dev.device_kind})"
+            )
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"device kind {device_kind!r} is not in flops.CHIP_PEAKS "
+            f"(known: {sorted(CHIP_PEAKS)}); add its published peaks with "
+            "their source before normalising numbers by them"
+        ) from None
 
 
 def model_flops_per_token(

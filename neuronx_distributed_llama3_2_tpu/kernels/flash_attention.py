@@ -10,7 +10,8 @@ constraint :178). Two implementations behind one API:
   long-context memory is O(S·block); works on any backend; its backward is
   JAX autodiff through the scan (recomputes per-block, flash-style).
 - ``pallas_flash_attention``: the hand-written TPU kernel (fwd + dq + dkv
-  with custom VJP); :func:`flash_attention` dispatches to it on TPU.
+  with custom VJP); :func:`flash_attention` dispatches to it unless the
+  kernel mode is ``"reference"``.
 
 GQA is handled *inside* the kernel path by folding query-head groups into the
 batch rather than repeating K/V (the reference replicates KV heads instead,
@@ -25,6 +26,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from neuronx_distributed_llama3_2_tpu.kernels.mode import prefer_pallas
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_KV = 512
@@ -44,21 +48,96 @@ def flash_attention(
     attention across document boundaries (the segment-aware mode the NKI
     kernel lacks — long-context packing support).
 
-    Dispatch: the Pallas TPU kernel on TPU (incl. segment-ids masking
-    in-kernel; custom fwd+bwd kernels), else the pure-jax blockwise
-    implementation."""
-    if jax.default_backend() == "tpu":
-        from neuronx_distributed_llama3_2_tpu.kernels.pallas_flash_attention import (
-            pallas_flash_attention,
-        )
+    Dispatch follows the kernel mode (:mod:`.mode`): the Pallas kernel
+    (incl. segment-ids masking in-kernel; custom fwd+bwd kernels) unless
+    the caller asked for the ``"reference"`` mode, which takes the
+    pure-jax blockwise implementation.
 
+    On a multi-device mesh the kernel runs per device inside a manual
+    (``shard_map``) region — a Mosaic call is opaque to the SPMD
+    partitioner, which refuses it outright ("Mosaic kernels cannot be
+    automatically partitioned") — with the batch split over the data axes
+    and the heads over tp wherever they divide, and whole otherwise.
+    Attention mixes neither batch rows nor heads, so the region holds no
+    collective."""
+    if not prefer_pallas():
+        return flash_attention_reference(
+            q, k, v, causal=causal, segment_ids=segment_ids, block_kv=block_kv
+        )
+    from neuronx_distributed_llama3_2_tpu.kernels.pallas_flash_attention import (
+        pallas_flash_attention,
+    )
+    from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
+
+    def kernel(q, k, v, *seg):
         return pallas_flash_attention(
-            q, k, v, causal=causal, segment_ids=segment_ids,
+            q, k, v, causal=causal, segment_ids=seg[0] if seg else None,
             block_q=block_q, block_kv=block_kv,
         )
-    return flash_attention_reference(
-        q, k, v, causal=causal, segment_ids=segment_ids, block_kv=block_kv
+
+    seg = () if segment_ids is None else (segment_ids,)
+    if (
+        not parallel_state.model_parallel_is_initialized()
+        or parallel_state.get_parallel_state().mesh.size == 1
+    ):
+        return kernel(q, k, v, *seg)
+
+    mesh, axes, spec = manual_attention_region(
+        parallel_state.get_parallel_state().mesh, q.shape, k.shape[2]
     )
+    return jax.shard_map(
+        kernel,
+        mesh=mesh,
+        in_specs=(spec,) * 3 + (P(spec[0], None),) * len(seg),
+        out_specs=spec,
+        axis_names=axes,
+        check_vma=False,
+    )(q, k, v, *seg)
+
+
+def manual_attention_region(mesh, q_shape, num_kv_heads, seq_axis=None):
+    """``(mesh, axis_names, spec)`` for running an attention kernel per
+    device in a ``shard_map`` that leaves NO axis of ``mesh`` to the
+    partitioner — what a Mosaic call needs, since the partitioner will not
+    touch it. ``spec`` lays (B, S, heads, D) operands out with the batch
+    over the data axes and the heads over tp wherever they divide (whole
+    otherwise) and the sequence over ``seq_axis`` (the ring's cp axis; None
+    for plain flash).
+
+    Inside a region that is already manual over some axes — the pp
+    pipeline executors — the returned mesh is the ambient abstract mesh
+    and ``axis_names`` holds only the axes still auto: operands there
+    differ from stage to stage, and a nested region that listed pp again
+    without naming it in a spec would take them for replicated over pp and
+    sum their cotangents across stages in the backward pass."""
+    from neuronx_distributed_llama3_2_tpu.parallel.state import (
+        DP_AXIS,
+        EP_AXIS,
+        TP_AXIS,
+    )
+
+    tp = mesh.shape[TP_AXIS]
+    batch = (
+        (DP_AXIS, EP_AXIS)
+        if q_shape[0] % (mesh.shape[DP_AXIS] * mesh.shape[EP_AXIS]) == 0
+        else None
+    )
+    heads = TP_AXIS if q_shape[2] % tp == 0 and num_kv_heads % tp == 0 else None
+    axes = set(mesh.axis_names)
+    ambient, manual = ambient_manual_axes()
+    if manual:
+        mesh, axes = ambient, axes - manual
+    return mesh, axes, P(batch, seq_axis, heads, None)
+
+
+def ambient_manual_axes():
+    """``(abstract mesh, names of its axes that are already manual)`` at this
+    point of the trace — non-empty inside a ``shard_map`` region."""
+    ambient = jax.sharding.get_abstract_mesh()
+    return ambient, {
+        n for n, t in zip(ambient.axis_names, ambient.axis_types)
+        if t == jax.sharding.AxisType.Manual
+    }
 
 
 NEG = jnp.float32(-1e30)

@@ -12,27 +12,36 @@ gather-free read, done TPU-style through ``PrefetchScalarGridSpec``).
 
 Structure (flash-decoding, Dao et al. 2023 — split-K for a single query row):
 
-- grid ``(b, NKV, num_splits, blocks_per_split)``: one program instance per
-  (lane, kv head); the kv-length dimension is partitioned into
-  ``num_splits`` independent chunks so long contexts expose parallelism
-  beyond the (tiny) decode batch.
+- the pool ``(num_blocks, bs, NKV, D)`` is viewed as ``(num_blocks, bs,
+  NKV·D)`` — a free reshape — so one block is a lane-dense ``(bs, NKV·D)``
+  tile whose last two dims equal the array's: the only K/V block shape
+  Mosaic lowers for any (NKV, D) (a per-head ``(bs, D)`` block squeezes the
+  second-to-last pool axis, which the TPU lowering refuses outright).
+- grid ``(b, num_splits, blocks_per_split)``: one program instance per
+  lane; the kv-length dimension is partitioned into ``num_splits``
+  independent chunks so long contexts expose parallelism beyond the (tiny)
+  decode batch.
+- all kv heads of a block are scored by ONE dot: the query tile is laid
+  out block-diagonally, row ``h·t·G + ti·G + gi`` holding query token
+  ``ti`` / grouped head ``gi`` of kv head ``h`` in lanes ``[h·D, (h+1)·D)``
+  and zeros elsewhere, so ``q_bd · k_blkᵀ`` contracts each row against its
+  own head only. The p·v dot yields every head's lanes for every row; the
+  caller keeps the diagonal. The (NKV−1)/NKV wasted MXU work is far below
+  the per-grid-step cost at decode sizes, and the body stays whole-tile
+  2-D matmuls — no per-head lane slicing, no 4-row tiles.
 - within a split, the per-block online softmax carries the running max ``m``,
   denominator ``l`` and unnormalized accumulator in VMEM scratch — exactly
   the ``_fwd_kernel`` recurrence of :mod:`.pallas_flash_attention`.
 - each split emits ``(acc, m, l)``; the final combine outside the kernel
   rescales by ``exp(m_s - m*)`` (log-sum-exp merge) and normalizes once.
-- GQA is grouped: q arrives as ``(b, NKV, G, D)`` and each program attends
-  its G query heads against one shared kv head — no KV replication.
 - masking is per-lane by position (``row <= positions[lane]``), which also
   kills null-block garbage rows: the engine guarantees every row past a
   request's frontier is masked, whatever stale block the table points at.
 - multi-token queries (speculative verify / short suffix-prefill blocks,
   static ``t <= LlamaConfig.paged_kernel_max_t``) fold the t fresh tokens
-  into the query-tile rows — the tile grows from ``(G, D)`` to
-  ``(t*G, D)`` and the mask becomes block-causal per query row
+  into the query-tile rows and the mask becomes block-causal per query row
   (``row <= positions[lane] + ti``) — so each KV block is still DMA'd
-  exactly once per (lane, head, split) and serves all t queries, instead
-  of growing the grid a dimension and re-fetching the pool t times.
+  exactly once per (lane, split) and serves all t queries.
 - packed draft trees (tree speculation) generalize that mask: an optional
   per-lane ``(t,)`` int32 ancestor-bitmask operand (``tree_bits``) makes
   each query node attend the committed prefix plus exactly its ancestor
@@ -40,9 +49,9 @@ Structure (flash-decoding, Dao et al. 2023 — split-K for a single query row):
   forward while still sharing one KV DMA per block. A linear chain's
   bitmasks reproduce the block-causal mask bit for bit.
 
-Interpret mode (`jax.default_backend() != "tpu"`) runs the same kernel body
-through the Pallas interpreter so the tier-1 CPU suite exercises this exact
-code path; the real-chip numerics gate lives in scripts/tpu_kernel_gate.py.
+The kernel mode (:mod:`.mode`) decides whether the body runs through Mosaic
+or the Pallas interpreter; the real-chip numerics gate lives in
+scripts/tpu_kernel_gate.py.
 """
 
 from __future__ import annotations
@@ -55,11 +64,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from neuronx_distributed_llama3_2_tpu.kernels.mode import pallas_interpret
 from neuronx_distributed_llama3_2_tpu.kernels.pallas_flash_attention import (
     NEG_INF,
-    _interpret,
 )
-from neuronx_distributed_llama3_2_tpu.utils import compat
 
 # kv-length split count: enough to keep a megacore busy past small decode
 # batches without shrinking per-split work below a few blocks
@@ -70,6 +78,18 @@ def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def _head_columns(x, nkv: int, width: int):
+    """Widen per-head columns ``x (rows, NKV)`` to ``(rows, width)`` lanes,
+    lane ``c`` taking head ``c // (width // NKV)``'s column — NKV selects
+    over a lane-broadcast column, all VPU work on whole tiles."""
+    per = width // nkv
+    head = lax.broadcasted_iota(jnp.int32, (x.shape[0], width), 1) // per
+    out = jnp.zeros((x.shape[0], width), x.dtype)
+    for h in range(nkv):
+        out = jnp.where(head == h, x[:, h:h + 1], out)
+    return out
+
+
 def _decode_kernel(
     tbl_ref,   # scalar prefetch: (b, W) int32 block table (SMEM)
     pos_ref,   # scalar prefetch: (b,) int32 first-fresh-query positions (SMEM)
@@ -77,13 +97,14 @@ def _decode_kernel(
     #            when has_live),]
     #            [tree_ref (b, t) int32 per-node ancestor bitmasks (SMEM,
     #            only when has_tree),] then
-    #            q_ref (t*G, D) — this lane/kv-head's t fresh query groups,
-    #            k_ref / v_ref (bs, D) — one pool block via the table,
-    #            [ks_ref, vs_ref (bs, 1) — quantized scale tiles,] then
-    #            o_ref (t*G, D) f32 per-split UNNORMALIZED accumulator,
-    #            m_ref / l_ref (t*G, 1) f32 per-split running max / denom,
+    #            q_ref (R, NKV·D) — this lane's block-diagonal query tile,
+    #            R = NKV·t·G rows,
+    #            k_ref / v_ref (bs, NKV·D) — one pool block via the table,
+    #            [ks_ref, vs_ref (bs, NKV) f32 — quantized scale tiles,] then
+    #            o_ref (R, NKV·D) f32 per-split UNNORMALIZED accumulator,
+    #            m_ref / l_ref (R, 1) f32 per-split running max / denom,
     #            and the m/l/acc VMEM scratch
-    bs: int, bps: int, nblk: int, t: int, g: int, sm_scale: float,
+    bs: int, bps: int, nblk: int, t: int, g: int, nkv: int, sm_scale: float,
     quantized: bool = False, quant_mxu: bool = False, has_live: bool = False,
     has_tree: bool = False,
 ):
@@ -106,21 +127,22 @@ def _decode_kernel(
     refs = refs[3:]
     if quantized:
         # int8/fp8 pool: the block DMA moved low-bit payload + the block's
-        # (bs, 1) scale column for this kv head; dequant here in VMEM with
-        # the same f32-widen formula as quantization.kv_cache.kv_dequantize
+        # (bs, NKV) scale tile; dequant here in VMEM with the same
+        # f32-widen formula as quantization.kv_cache.kv_dequantize
         ks_ref, vs_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
     else:
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
     i = pl.program_id(0)          # lane
-    s = pl.program_id(2)          # kv split
-    j = pl.program_id(3)          # block within split
+    s = pl.program_id(1)          # kv split
+    j = pl.program_id(2)          # block within split
+    width = k_ref.shape[-1]       # NKV·D lanes
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     lb = s * bps + j              # logical block index into the sequence
     pos = pos_ref[i]
@@ -135,60 +157,69 @@ def _decode_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[:]                               # (t*G, D)
+        q = q_ref[...]                             # (R, NKV·D)
+        nt = (((1,), (1,)), ((), ()))              # contract the lane dims
         if ks_ref is not None and quant_mxu:
             # low-precision MXU q·k: keep the stored payload as a dot
             # operand instead of widening it first. Both absmax scales
             # factor algebraically out of the contraction —
-            # sc[r, c] = q_scale[r] * k_scale[c] * Σ_d q̂[r,d]·k̂[c,d] —
+            # sc[r, c] = q_scale[r] * k_scale[c, h(r)] * Σ q̂[r,·]·k̂[c,·] —
             # so they apply to the fp32 outputs the LSE combine consumes,
-            # never per-element before the dot.
-            ks_col = ks_ref[:, 0].astype(jnp.float32)          # (bs,)
+            # never per-element before the dot. The per-(row, head) k
+            # scale reaches the (R, bs) score tile through the same
+            # block-diagonal contraction as the payload: a 0/1 row-to-head
+            # selector against the lane-widened scale tile.
+            lane = lax.broadcasted_iota(jnp.int32, q.shape, 1)
+            row_head = lax.broadcasted_iota(jnp.int32, q.shape, 0) // (t * g)
+            sel = (lane == row_head * (width // nkv)).astype(jnp.float32)
+            ks_rows = lax.dot_general(
+                sel, _head_columns(ks_ref[...], nkv, width), nt,
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )                                                  # (R, bs)
             if k_ref.dtype == jnp.int8:
                 # int8 pool: quantize the query tile per row (symmetric
                 # absmax / 127, the kv_quantize formula) so the MXU runs
                 # int8 × int8 accumulating in int32
                 qf = q.astype(jnp.float32)
                 q_scl = jnp.maximum(
-                    jnp.max(jnp.abs(qf), axis=1), 1e-6
-                ) / 127.0                                      # (t*G,)
+                    jnp.max(jnp.abs(qf), axis=1, keepdims=True), 1e-6
+                ) / 127.0                                      # (R, 1)
                 q_i8 = jnp.clip(
-                    jnp.round(qf / q_scl[:, None]), -127.0, 127.0
+                    jnp.round(qf / q_scl), -127.0, 127.0
                 ).astype(jnp.int8)
                 acc = lax.dot_general(
-                    q_i8, k_ref[:], (((1,), (1,)), ((), ())),
+                    q_i8, k_ref[...], nt,
                     preferred_element_type=jnp.int32,
-                )                                              # (t*G, bs) i32
-                sc = (
-                    acc.astype(jnp.float32)
-                    * q_scl[:, None] * ks_col[None, :] * sm_scale
-                )
+                )                                              # (R, bs) i32
+                sc = acc.astype(jnp.float32) * q_scl * ks_rows * sm_scale
             else:
                 # fp8 pool: fp8 × fp8 operands with an fp32
                 # preferred_element_type — no query requantization needed,
                 # the cast is the same narrowing kv_quantize applied on
-                # write; only k's stored scale remains to factor out
+                # write (through f32, the one narrowing every Pallas TPU
+                # lowering accepts; the value rounds once either way);
+                # only k's stored scale remains to factor out
                 acc = lax.dot_general(
-                    q.astype(k_ref.dtype), k_ref[:],
-                    (((1,), (1,)), ((), ())),
+                    q.astype(jnp.float32).astype(k_ref.dtype), k_ref[...], nt,
                     preferred_element_type=jnp.float32,
-                )                                              # (t*G, bs) f32
-                sc = acc * ks_col[None, :] * sm_scale
+                )                                              # (R, bs) f32
+                sc = acc * ks_rows * sm_scale
         else:
             if ks_ref is not None:
                 k = (
-                    k_ref[:].astype(jnp.float32) * ks_ref[:].astype(jnp.float32)
-                ).astype(q.dtype)                  # (bs, D)
+                    k_ref[...].astype(jnp.float32)
+                    * _head_columns(ks_ref[...], nkv, width)
+                ).astype(q.dtype)                  # (bs, NKV·D)
             else:
-                k = k_ref[:].astype(q.dtype)       # (bs, D)
+                k = k_ref[...].astype(q.dtype)     # (bs, NKV·D)
             sc = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale                           # (t*G, bs) fp32
+                q, k, nt, preferred_element_type=jnp.float32,
+            ) * sm_scale                           # (R, bs) fp32
         rows = lb * bs + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         # block-causal across the fresh tokens: tile row r holds query
-        # token ti = r // g, which sits at sequence row pos + ti
-        ti = lax.broadcasted_iota(jnp.int32, sc.shape, 0) // g
+        # token ti = (r mod t·G) // G, which sits at sequence row pos + ti
+        ti = (lax.broadcasted_iota(jnp.int32, sc.shape, 0) % (t * g)) // g
         if tree_ref is None:
             mask = rows <= pos + ti
         else:
@@ -209,37 +240,38 @@ def _decode_kernel(
             mask = (rows < pos) | vis
         sc = jnp.where(mask, sc, NEG_INF)
 
-        m_prev = m_scr[:, 0]
+        m_prev = m_scr[...]                        # (R, 1)
         # every real query row keeps >= 1 valid key row (its own, written
         # this step), so after the final block m_new is finite; a tile row
         # fully masked within a `run` block (deeper query still ahead of
         # this shallower row) is safe: p zeroes under the mask and the
         # row's (m, l, acc) carry unchanged through alpha == 1
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.exp(sc - m_new[:, None])
+        p = jnp.exp(sc - m_new)
         p = jnp.where(mask, p, 0.0)
-        l_new = l_scr[:, 0] * alpha + jnp.sum(p, axis=1)
+        l_new = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         if vs_ref is not None:
             v = (
-                v_ref[:].astype(jnp.float32) * vs_ref[:].astype(jnp.float32)
-            ).astype(q.dtype)                      # (bs, D)
+                v_ref[...].astype(jnp.float32)
+                * _head_columns(vs_ref[...], nkv, width)
+            ).astype(q.dtype)                      # (bs, NKV·D)
         else:
-            v = v_ref[:].astype(q.dtype)           # (bs, D)
+            v = v_ref[...].astype(q.dtype)         # (bs, NKV·D)
         pv = lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                          # (G, D)
-        acc_scr[:] = acc_scr[:] * alpha[:, None] + pv
-        m_scr[:, 0] = m_new
-        l_scr[:, 0] = l_new
+        )                                          # (R, NKV·D)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = m_new
+        l_scr[...] = l_new
 
     @pl.when(j == bps - 1)
     def _finalize():
         # emit the split's raw (acc, m, l); the LSE combine happens outside
-        o_ref[:] = acc_scr[:]
-        m_ref[:] = m_scr[:]
-        l_ref[:] = l_scr[:]
+        o_ref[...] = acc_scr[...]
+        m_ref[...] = m_scr[...]
+        l_ref[...] = l_scr[...]
 
 
 def paged_flash_decode(
@@ -251,7 +283,6 @@ def paged_flash_decode(
     *,
     kv_limit: int | None = None,
     num_splits: int | None = None,
-    interpret: bool | None = None,
     k_scale: jax.Array | None = None,  # (num_blocks, bs, NKV) — quantized pool
     v_scale: jax.Array | None = None,
     quant_mxu: bool = False,
@@ -274,17 +305,17 @@ def paged_flash_decode(
 
     ``k_scale``/``v_scale`` mark a quantized pool (int8/fp8 payload with
     per-(row, head) absmax scales, docs/serving.md "Quantized KV pool"):
-    the scale columns ride through the *same* table-dereferencing index map
-    as the payload blocks — one extra tiny (bs, 1) DMA per block — and the
-    kernel dequantizes in VMEM, so HBM traffic stays low-bit.
+    the ``(bs, NKV)`` scale tiles ride through the *same* table-
+    dereferencing index map as the payload blocks — one extra tiny DMA per
+    block — and the kernel dequantizes in VMEM, so HBM traffic stays
+    low-bit.
 
     ``row_live`` marks a mixed-width tile (the serving engine's
     ``fused_step`` packing): lane ``i``'s query rows ``>= row_live[i]``
     are padding whose outputs the caller discards, and the lane's KV walk
     stops at ``positions[i] + row_live[i] - 1`` instead of the static
     ``positions[i] + t - 1``. It rides in as a third scalar-prefetch
-    operand; ``None`` (the default) lowers exactly the pre-existing
-    two-operand kernel, so unfused traces stay bitwise unchanged.
+    operand; ``None`` (the default) lowers exactly the two-operand kernel.
 
     ``tree_bits`` marks the fresh block as a packed draft *tree* (tree
     speculation, docs/serving.md "Tree speculation"): bit ``m`` of
@@ -298,8 +329,7 @@ def paged_flash_decode(
     scalar-prefetch operand — the per-block KV DMA is unchanged, so all
     candidate branches share one pool read per block. A chain tree
     (``tree_bits[i, q] = (1 << (q+1)) - 1``) is bitwise the block-causal
-    mask; ``None`` (the default) leaves every existing lowering
-    unchanged.
+    mask; ``None`` (the default) leaves every other lowering unchanged.
 
     ``quant_mxu`` (quantized pool only) keeps the q·k dot itself in low
     precision: int8 pools contract int8 × int8 operands accumulating in
@@ -328,30 +358,36 @@ def paged_flash_decode(
     splits = max(1, min(splits, nblk))
     bps = _ceil_div(nblk, splits)
     sm_scale = d ** -0.5
+    tg = t * g
+    rows, width = nkv * tg, nkv * d
 
-    # fold the t fresh tokens into the query-tile rows: row ti*g + gi is
-    # query token ti, grouped head gi — one KV DMA serves all t queries
+    # block-diagonal query tile: row h·tG + ti·G + gi carries query token
+    # ti, grouped head gi of kv head h in lanes [h·D, (h+1)·D), zeros in
+    # every other head's lanes — one (R, NKV·D) · (bs, NKV·D)ᵀ dot scores
+    # each row against its own kv head only
     qg = q.reshape(b, t, nkv, g, d).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(b, nkv, t * g, d)
-    grid = (b, nkv, splits, bps)
+    qg = qg.reshape(b, nkv, tg, d)
+    q_bd = jnp.einsum(
+        "bhrd,hk->bhrkd", qg, jnp.eye(nkv, dtype=q.dtype)
+    ).reshape(b, rows, width)
+    grid = (b, splits, bps)
 
     # index maps see every scalar-prefetch operand after the grid indices;
-    # *rest absorbs the optional row_live operand so one set of maps
-    # serves both lowerings
-    def q_idx(i, h, s, j, tbl, pos, *rest):
-        return (i, h, 0, 0)
+    # *rest absorbs the optional row_live / tree_bits operands so one set
+    # of maps serves every lowering
+    def q_idx(i, s, j, tbl, pos, *rest):
+        return (i, 0, 0)
 
-    def kv_idx(i, h, s, j, tbl, pos, *rest):
+    def kv_idx(i, s, j, tbl, pos, *rest):
         # the gather-free read: the table entry IS the pool block index the
         # pipeline DMAs next; clamp covers split padding (those iterations
         # are predicated off in the kernel body)
         lb = jnp.minimum(s * bps + j, nblk - 1)
-        return (tbl[i, lb], 0, h, 0)
+        return (tbl[i, lb], 0, 0)
 
-    def out_idx(i, h, s, j, tbl, pos, *rest):
-        return (i, h, s, 0, 0)
+    def out_idx(i, s, j, tbl, pos, *rest):
+        return (i, s, 0, 0)
 
-    tg = t * g
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     quantized = k_scale is not None
@@ -371,30 +407,35 @@ def paged_flash_decode(
                 f"tree_bits must be (b, t) = {(b, t)}, got {tree_bits.shape}"
             )
     kernel = functools.partial(
-        _decode_kernel, bs=bs, bps=bps, nblk=nblk, t=t, g=g,
+        _decode_kernel, bs=bs, bps=bps, nblk=nblk, t=t, g=g, nkv=nkv,
         sm_scale=sm_scale, quantized=quantized, quant_mxu=quant_mxu,
         has_live=row_live is not None, has_tree=tree_bits is not None,
     )
     in_specs = [
-        pl.BlockSpec((None, None, tg, d), q_idx),
-        pl.BlockSpec((None, bs, None, d), kv_idx),
-        pl.BlockSpec((None, bs, None, d), kv_idx),
+        pl.BlockSpec((None, rows, width), q_idx),
+        pl.BlockSpec((None, bs, width), kv_idx),
+        pl.BlockSpec((None, bs, width), kv_idx),
     ]
-    operands = [qg, k_pool, v_pool]
+    operands = [
+        q_bd, k_pool.reshape(nb, bs, width), v_pool.reshape(nb, bs, width),
+    ]
     if quantized:
         if k_scale.shape != (nb, bs, nkv) or v_scale.shape != (nb, bs, nkv):
             raise ValueError(
                 f"scale arrays must be (num_blocks, bs, NKV) = "
                 f"{(nb, bs, nkv)}, got {k_scale.shape} / {v_scale.shape}"
             )
-        # trailing singleton keeps the (bs, 1) scale tile 2-D; kv_idx's
-        # 4-tuple (table-deref, 0, head, 0) then serves payload and scale
-        # alike, so the scale column arrives with its block's DMA
+        # the (bs, NKV) scale tile of a block arrives with its payload
+        # through the same table dereference. Widened to f32 on the way
+        # in: the stored dtype is float16, which the v5e vector unit (and
+        # Mosaic) does not carry
         in_specs += [
-            pl.BlockSpec((None, bs, None, 1), kv_idx),
-            pl.BlockSpec((None, bs, None, 1), kv_idx),
+            pl.BlockSpec((None, bs, nkv), kv_idx),
+            pl.BlockSpec((None, bs, nkv), kv_idx),
         ]
-        operands += [k_scale[..., None], v_scale[..., None]]
+        operands += [
+            k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
+        ]
     prefetch = [block_tables.astype(jnp.int32), positions.astype(jnp.int32)]
     if row_live is not None:
         prefetch.append(row_live.astype(jnp.int32))
@@ -405,44 +446,49 @@ def paged_flash_decode(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, None, None, tg, d), out_idx),
+            pl.BlockSpec((None, None, rows, width), out_idx),
             # trailing singleton keeps the last-two-dims tiling legal
-            pl.BlockSpec((None, None, None, tg, 1), out_idx),
-            pl.BlockSpec((None, None, None, tg, 1), out_idx),
+            pl.BlockSpec((None, None, rows, 1), out_idx),
+            pl.BlockSpec((None, None, rows, 1), out_idx),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tg, 1), jnp.float32),
-            pltpu.VMEM((tg, 1), jnp.float32),
-            pltpu.VMEM((tg, d), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, width), jnp.float32),
         ],
     )
     o_parts, m_parts, l_parts = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, nkv, splits, tg, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, nkv, splits, tg, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, nkv, splits, tg, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, splits, rows, width), jnp.float32),
+            jax.ShapeDtypeStruct((b, splits, rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, splits, rows, 1), jnp.float32),
         ],
-        # lane/head/split all carry independent scratch epochs (re-inited at
+        # lane/split carry independent scratch epochs (re-inited at
         # j == 0); only the innermost block dim is a true reduction
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_interpret(),
+        name="paged_flash_decode",
     )(
         *prefetch,
         *operands,
     )
 
+    # keep each row's own-head lanes (the block diagonal), then the
     # flash-decoding combine: merge the per-split partial softmaxes by
-    # rescaling each to the global max (log-sum-exp), then normalize once.
-    m_star = jnp.max(m_parts, axis=2, keepdims=True)       # (b,NKV,1,tG,1)
+    # rescaling each to the global max (log-sum-exp), normalize once
+    o_parts = jnp.einsum(
+        "bshrhd->bshrd", o_parts.reshape(b, splits, nkv, tg, nkv, d)
+    ).reshape(b, splits, rows, d)
+    m_star = jnp.max(m_parts, axis=1, keepdims=True)       # (b,1,R,1)
     weight = jnp.where(
         m_parts == NEG_INF, 0.0, jnp.exp(m_parts - m_star)
-    )                                                      # (b,NKV,S,tG,1)
-    l_tot = jnp.sum(weight * l_parts, axis=2)              # (b,NKV,tG,1)
-    acc = jnp.sum(weight * o_parts, axis=2)                # (b,NKV,tG,D)
+    )                                                      # (b,S,R,1)
+    l_tot = jnp.sum(weight * l_parts, axis=1)              # (b,R,1)
+    acc = jnp.sum(weight * o_parts, axis=1)                # (b,R,D)
     out = acc / jnp.where(l_tot == 0.0, 1.0, l_tot)
     out = out.reshape(b, nkv, t, g, d).transpose(0, 2, 1, 3, 4)
     out = out.reshape(b, t, n, d).astype(q.dtype)
@@ -459,7 +505,6 @@ def paged_flash_decode_tp(
     mesh,
     kv_limit: int | None = None,
     num_splits: int | None = None,
-    interpret: bool | None = None,
     k_scale: jax.Array | None = None,  # (num_blocks, bs, NKV) — quantized pool
     v_scale: jax.Array | None = None,
     quant_mxu: bool = False,
@@ -556,15 +601,15 @@ def paged_flash_decode_tp(
         bits = next(it) if has_tree else None
         return paged_flash_decode(
             qs, ks, vs, tbl, pos,
-            kv_limit=kv_limit, num_splits=num_splits, interpret=interpret,
+            kv_limit=kv_limit, num_splits=num_splits,
             k_scale=kss, v_scale=vss, quant_mxu=quant_mxu,
             row_live=live, tree_bits=bits,
         )
 
-    # check_vma off: pallas_call carries no replication rule on either jax
-    # generation; the per-rank outputs are genuinely tp-varying anyway
-    return compat.shard_map(
-        local, mesh,
+    # check_vma off: pallas_call carries no replication rule; the per-rank
+    # outputs are genuinely tp-varying anyway
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=tuple(specs),
         out_specs=q_spec,
         check_vma=False,

@@ -30,7 +30,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from neuronx_distributed_llama3_2_tpu.utils import compat
+from neuronx_distributed_llama3_2_tpu.kernels.mode import pallas_interpret
 
 NEG_INF = float("-inf")
 DEFAULT_BLOCK_Q = 256
@@ -128,11 +128,6 @@ def _pad_to(x, size, axis):
     return jnp.pad(x, widths)
 
 
-def _interpret() -> bool:
-    # CPU (tests / virtual mesh): run kernels in the pallas interpreter
-    return jax.default_backend() != "tpu"
-
-
 def _seg_operands(segment_ids, sq, skv, block_q, block_kv):
     """(seg_q, seg_kv) padded to block multiples as (B, S_p, 1) int32; pad
     ids are -1 so padded keys can never match a real segment."""
@@ -215,10 +210,11 @@ def _flash_fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_kv):
         # (batch·head, q-block) iterations are independent; only the kv dim
         # carries the running-softmax scratch. Telling Mosaic unlocks
         # cross-iteration pipelining it must otherwise assume away.
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
+        name="flash_fwd",
     )(*operands)
     return o[:, :, :sq, :], lse[:, :, :sq, 0]
 
@@ -411,10 +407,11 @@ def _flash_bwd(q, k, v, o, lse, do, segment_ids, causal, sm_scale, block_q, bloc
         ),
         out_shape=jax.ShapeDtypeStruct((b, n, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap, *seg_operands)
 
     # dk/dv: grid (BN, nk, nq) — per q-head, then group-summed for GQA
@@ -457,10 +454,11 @@ def _flash_bwd(q, k, v, o, lse, do, segment_ids, causal, sm_scale, block_q, bloc
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap, *seg_operands)
 
     # GQA: sum q-head contributions within each kv group

@@ -16,10 +16,11 @@ sees the k/v chunk of device ``(i - r) mod cp``; chunks entirely in the
 future are masked (their compute is wasted — the classic contiguous-ring
 imbalance), the diagonal chunk is causal-masked, past chunks attend fully.
 
-This module holds the pure-jnp executor — the numerics oracle and the
-any-backend fallback. On TPU, :func:`ring_attention_sharded` dispatches to
-the Pallas-fused executors (``ring_attention_pallas.py``): the FA2 kernel
-per visiting chunk, a custom-VJP ring backward, and zigzag chunk
+This module holds the pure-jnp executor — the numerics oracle, taken in
+the ``"reference"`` kernel mode (:mod:`.mode`). Otherwise
+:func:`ring_attention_sharded` dispatches to the Pallas-fused executors
+(``ring_attention_pallas.py``): the FA2 kernel per visiting chunk, a
+custom-VJP ring backward, and zigzag chunk
 assignment that fixes the causal imbalance (each device holds half-chunks
 ``(i, 2cp-1-i)``, so every ring step does equal work everywhere).
 
@@ -43,11 +44,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-
 from neuronx_distributed_llama3_2_tpu.kernels.flash_attention import (
     DEFAULT_BLOCK_KV,
+    ambient_manual_axes,
     blockwise_attention_stats,
+    manual_attention_region,
 )
+from neuronx_distributed_llama3_2_tpu.kernels.mode import prefer_pallas
 
 
 def _chunk_attn_stats(
@@ -142,16 +145,13 @@ def resolve_cp_layout(seq: int, cp: int, causal: bool = True,
     ("auto"/"contiguous"/"zigzag") comes from the model config (tests
     force zigzag on CPU).
 
-    PROVISIONAL (VERDICT r4 weak #3): the zigzag-on-TPU choice rests on
-    the analytic critical path (~(cp+1)/2 vs cp full-chunk attentions)
-    and interpret-mode parity — no on-chip rotation timing has banked it
-    yet. The chip session's ``ring_ab`` stage (scripts/ab_stage.py
-    --which ring) times both critical paths from real pair kernels;
-    flip the auto rule if its record contradicts the analytics (check
-    CHIP_SESSION.jsonl)."""
+    PROVISIONAL: the zigzag-over-contiguous choice rests on the analytic
+    critical path (~(cp+1)/2 vs cp full-chunk attentions) and
+    interpret-mode parity — no on-chip rotation timing has been taken
+    (ROADMAP S5 decides it on the four-chip host)."""
     if force != "auto":
         return force
-    if causal and seq % (2 * cp) == 0 and jax.default_backend() == "tpu":
+    if causal and seq % (2 * cp) == 0 and prefer_pallas():
         return "zigzag"
     return "contiguous"
 
@@ -208,7 +208,8 @@ def ring_attention_sharded(
     ``"pallas"`` (Pallas FA2 kernel per visiting chunk,
     ring_attention_pallas.py), ``"zigzag"`` (pallas + zigzag-balanced
     chunk assignment — the causal-imbalance fix), or ``"auto"`` (zigzag
-    on TPU when the shapes allow, else jnp).
+    when the shapes allow, else pallas — the jnp ring throughout in the
+    ``"reference"`` kernel mode, :mod:`.mode`).
 
     ``pre_permuted``: the inputs are ALREADY in zigzag layout (the model
     permutes once outside the layer stack — the cheap path); without it
@@ -224,7 +225,7 @@ def ring_attention_sharded(
         if resolve_cp_layout(seq, cp, causal) == "zigzag":
             impl = "zigzag"
         else:
-            impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+            impl = "pallas" if prefer_pallas() else "jnp"
     if impl == "zigzag" and seq % (2 * cp):
         # validate here too: with pre_permuted=True the zigzag_permutation
         # check below never runs, and a bad shape would otherwise die as a
@@ -232,8 +233,6 @@ def ring_attention_sharded(
         raise ValueError(
             f"zigzag ring needs seq % (2*cp) == 0, got seq={seq} cp={cp}"
         )
-
-    spec = P(None, axis_name, None, None)
 
     if impl == "jnp":
         # kv_len=None: the sequence is exactly S with no padding; pass a
@@ -267,38 +266,25 @@ def ring_attention_sharded(
         perm, inv = zigzag_permutation(seq, cp)
         q, k, v = (x.take(perm, axis=1) for x in (q, k, v))
 
-    # nested-manual support (attention inside the pp-manual pipeline
-    # executors): the inner shard_map must be built on the CURRENT abstract
-    # mesh and list the union of the already-manual axes and ours
+    spec = P(None, axis_name, None, None)
     shard_mesh, manual_axes = mesh, {axis_name}
-    from neuronx_distributed_llama3_2_tpu.utils import compat
-
-    if axis_name in compat.legacy_manual_axes():
-        # old-jax full-manual region (compat.shard_map): cp is ALREADY
-        # manual and the inputs are replicated over it, so a nested
-        # shard_map is both impossible (0.4.x rejects re-manual axes) and
-        # unnecessary — slice this device's chunk, run the ring body
-        # directly, and restore cp-replication of the result
-        chunk = seq // cp
-        i0 = lax.axis_index(axis_name) * chunk
-        out = fn(*(lax.dynamic_slice_in_dim(x, i0, chunk, axis=1)
-                   for x in (q, k, v)))
-        out = lax.all_gather(out, axis_name, axis=1, tiled=True)
-        if inv is not None:
-            out = out.take(inv, axis=1)
-        return out
-
-    abs_mesh = compat.get_abstract_mesh()
-    if abs_mesh is not None and abs_mesh.axis_names:
-        already_manual = {
-            n for n, t in zip(abs_mesh.axis_names, abs_mesh.axis_types)
-            if t == jax.sharding.AxisType.Manual
-        }
+    if impl != "jnp":
+        # the Pallas executors hold Mosaic calls, which the partitioner
+        # refuses anywhere but a region that leaves it no axis: batch and
+        # heads split over the data and tp axes instead of staying auto
+        shard_mesh, manual_axes, spec = manual_attention_region(
+            mesh, q.shape, k.shape[2], seq_axis=axis_name
+        )
+    else:
+        # nested-manual support (attention inside the pp-manual pipeline
+        # executors): the inner shard_map is built on the CURRENT abstract
+        # mesh and lists the union of the already-manual axes and ours
+        abs_mesh, already_manual = ambient_manual_axes()
         if already_manual:
             shard_mesh = abs_mesh
             manual_axes = already_manual | {axis_name}
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         lambda q, k, v: fn(q, k, v),
         mesh=shard_mesh,
         in_specs=(spec, spec, spec),

@@ -114,9 +114,10 @@ class LlamaConfig:
     # clamp Q/K/V projections to [-clip_qkv, clip_qkv] (DBRX attn_config,
     # reference neuron_modeling_dbrx.py:171)
     clip_qkv: Optional[float] = None
-    # cp ring sequence layout: "auto" (zigzag on TPU when divisible —
-    # balances causal work across the ring, kernels/ring_attention_pallas),
-    # "contiguous", or "zigzag" (forced; tests use it on CPU). The model
+    # cp ring sequence layout: "auto" (zigzag when divisible and the kernel
+    # mode takes Pallas — balances causal work across the ring,
+    # kernels/ring_attention_pallas), "contiguous", or "zigzag" (forced;
+    # the CPU tests use it under the "reference" mode). The model
     # permutes hidden states once outside the layer stack; attention layers
     # must resolve the SAME value (kernels.ring_attention.resolve_cp_layout)
     cp_ring_layout: str = "auto"
@@ -464,6 +465,9 @@ class LlamaAttention:
             # context parallelism: sequence stays cp-sharded; attention runs
             # as a k/v ring over the cp axis (kernels/ring_attention.py) —
             # the only op in the block that mixes sequence positions
+            from neuronx_distributed_llama3_2_tpu.kernels.mode import (
+                prefer_pallas,
+            )
             from neuronx_distributed_llama3_2_tpu.kernels.ring_attention import (
                 active_cp_layout,
                 ring_attention_sharded,
@@ -473,12 +477,12 @@ class LlamaAttention:
             # layout via cp_layout(); reading it here (instead of
             # re-deriving) makes a layout/executor mismatch impossible.
             # zigzag ⇒ inputs are already permuted; contiguous ⇒ pallas
-            # ring on TPU, jnp oracle elsewhere
+            # ring, or the jnp oracle in the "reference" kernel mode
             layout = active_cp_layout()
             if layout == "zigzag":
                 impl = "zigzag"
             else:
-                impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+                impl = "pallas" if prefer_pallas() else "jnp"
             attn = ring_attention_sharded(
                 q, k, v,
                 parallel_state.get_parallel_state().mesh,

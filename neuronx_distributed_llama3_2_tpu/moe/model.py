@@ -153,10 +153,8 @@ class MoE:
         # inside a partial-manual region (the pp pipeline stage) the nested
         # shard_map must target the ambient abstract mesh (its manual axes
         # are marked) — same rule as layers.constrain / parallel CE
-        from neuronx_distributed_llama3_2_tpu.utils import compat
-
-        ambient = compat.get_abstract_mesh()
-        if ambient is not None and not ambient.empty:
+        ambient = jax.sharding.get_abstract_mesh()
+        if not ambient.empty:
             mesh = ambient
         t = x_flat.shape[0]
         dp_ep = mesh.shape[DP_AXIS] * mesh.shape[EP_AXIS]
@@ -204,7 +202,7 @@ class MoE:
             return out, logits, idx
 
         token_spec = P((DP_AXIS, EP_AXIS))
-        return compat.shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(

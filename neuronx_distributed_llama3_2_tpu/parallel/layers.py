@@ -75,15 +75,8 @@ def constrain(x: jax.Array, spec: P) -> jax.Array:
     if not parallel_state.model_parallel_is_initialized():
         return x
     mesh = parallel_state.get_parallel_state().mesh
-    from neuronx_distributed_llama3_2_tpu.utils import compat
-
-    if compat.legacy_manual_axes():
-        # old-jax shard_map regions run full-manual (compat.shard_map):
-        # every axis the spec could name is manual, so the constraint has
-        # nothing left to say — and the old partitioner CHECK-fails on it
-        return x
-    ambient = compat.get_abstract_mesh()
-    if ambient is not None and not ambient.empty:
+    ambient = jax.sharding.get_abstract_mesh()
+    if not ambient.empty:
         mesh = ambient
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 

@@ -40,9 +40,7 @@ def _vocab_parallel_xent_body(
     labels (...) int."""
     vl = logits.shape[-1]
     idx = lax.axis_index(TP_AXIS)
-    from neuronx_distributed_llama3_2_tpu.utils import compat
-
-    vocab_total = vl * compat.axis_size(TP_AXIS)
+    vocab_total = vl * lax.axis_size(TP_AXIS)
     valid = valid_token_mask(labels, vocab_total)
     labels = jnp.where(valid, labels, 0)
 
@@ -99,15 +97,8 @@ def parallel_cross_entropy(
     # inside a partial-manual region (e.g. the 1F1B executor, manual over pp)
     # the nested shard_map must be built against the ambient abstract mesh,
     # whose manual axes are marked (same rule as layers.constrain)
-    from neuronx_distributed_llama3_2_tpu.utils import compat
-
-    if TP_AXIS in compat.legacy_manual_axes():
-        # old-jax full-manual region: tp is already manual and the logits
-        # arrive tp-replicated (full vocab locally) — dense CE is exact
-        return cross_entropy(logits, labels, label_smoothing)
-
-    ambient = compat.get_abstract_mesh()
-    if ambient is not None and not ambient.empty:
+    ambient = jax.sharding.get_abstract_mesh()
+    if not ambient.empty:
         mesh = ambient
     nd = logits.ndim
     # leading dim rides the data-parallel axes so dp-sharded logits enter the
@@ -127,7 +118,7 @@ def parallel_cross_entropy(
         logits_spec = P(TP_AXIS)
         labels_spec = P()
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda lg, lb: _vocab_parallel_xent_body(lg, lb, label_smoothing),
         mesh=mesh,
         in_specs=(logits_spec, labels_spec),

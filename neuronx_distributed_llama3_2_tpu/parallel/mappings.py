@@ -97,9 +97,7 @@ def _reduce_scatter(x: jax.Array, axis_name: str, dim: int) -> jax.Array:
 
 def _split_local(x: jax.Array, axis_name: str, dim: int) -> jax.Array:
     dim = dim % x.ndim
-    from neuronx_distributed_llama3_2_tpu.utils import compat
-
-    size = compat.axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     if x.shape[dim] % size != 0:
         raise ValueError(
@@ -142,9 +140,7 @@ def enter_expert_parallel_region(x: jax.Array) -> jax.Array:
     for its local experts (reference enter_expert_parallel_region
     mappings.py:412)."""
     e, _, _ = x.shape
-    from neuronx_distributed_llama3_2_tpu.utils import compat
-
-    ep = compat.axis_size(EP_AXIS)
+    ep = lax.axis_size(EP_AXIS)
     if e % ep != 0:
         raise ValueError(f"num experts {e} not divisible by ep {ep}")
     return all_to_all_expert_parallel(x, 0, 1)

@@ -52,7 +52,6 @@ from neuronx_distributed_llama3_2_tpu.models.llama import (
 from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
 from neuronx_distributed_llama3_2_tpu.parallel.layers import BATCH_AXES, constrain
 from neuronx_distributed_llama3_2_tpu.parallel.state import PP_AXIS, TP_AXIS
-from neuronx_distributed_llama3_2_tpu.utils import compat
 
 Params = Dict[str, Any]
 
@@ -274,7 +273,7 @@ class PipelinedCausalLM:
             lambda _: P(PP_AXIS),
             stage_layers,
         )
-        return compat.shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(layer_specs, P(PP_AXIS), P(), P(), P()),
@@ -508,7 +507,7 @@ class PipelinedCausalLM:
             return out_buf[None], aux_sum[None]
 
         layer_specs = jax.tree.map(lambda _: P(None, PP_AXIS), params["layers"])
-        out_buf, aux_lanes = compat.shard_map(
+        out_buf, aux_lanes = jax.shard_map(
             lane_body,
             mesh=mesh,
             in_specs=(layer_specs, P()),
@@ -858,7 +857,7 @@ class PipelinedCausalLM:
 
         layer_specs = jax.tree.map(lambda _: P(PP_AXIS), params["layers"])
         rep = jax.tree.map(lambda _: P(), head_params)
-        layers_g, head_g, embed_g, loss = compat.shard_map(
+        layers_g, head_g, embed_g, loss = jax.shard_map(
             lane_body,
             mesh=mesh,
             in_specs=(layer_specs, rep, P(), P(), P()),
@@ -1195,7 +1194,7 @@ class PipelinedCausalLM:
                 restore_layers(layers_l), head_p, embed_p, ids_all, lab_all
             )
 
-        layers_g, head_g, embed_g, loss = compat.shard_map(
+        layers_g, head_g, embed_g, loss = jax.shard_map(
             lane_body_restored,
             mesh=mesh,
             in_specs=(layer_specs, rep, P(), P(), P()),

@@ -223,9 +223,7 @@ def _walk(tree: Any, fn, path: str = "") -> Any:
     family silently escaped quantization)."""
     if isinstance(tree, dict):
         return {k: _walk(v, fn, f"{path}/{k}" if path else k) for k, v in tree.items()}
-    # PartitionSpec subclasses tuple on the 0.4.x jax line — descending into
-    # it would shred spec trees entry-by-entry; a spec is always a leaf here
-    if isinstance(tree, (list, tuple)) and not isinstance(tree, P):
+    if isinstance(tree, (list, tuple)):
         out = [
             _walk(v, fn, f"{path}/{i}" if path else str(i))
             for i, v in enumerate(tree)

@@ -86,12 +86,17 @@ class CostProfile:
 
     def roofline_mfu(
         self,
-        peak_flops: float = flops_mod.PEAK_FLOPS_PER_CHIP,
-        peak_bw: float = flops_mod.PEAK_HBM_BW_PER_CHIP,
+        peak_flops: Optional[float] = None,
+        peak_bw: Optional[float] = None,
     ) -> float:
         """Bandwidth-roofline ceiling on achievable MFU at this program's
         arithmetic intensity: below the machine balance point the program
-        is bandwidth-bound and can reach at most AI/balance of peak."""
+        is bandwidth-bound and can reach at most AI/balance of peak.
+        Peaks default to this process's :func:`..flops.chip_peaks` row."""
+        if peak_flops is None or peak_bw is None:
+            peaks = flops_mod.chip_peaks()
+            peak_flops = peak_flops or peaks.bf16_flops
+            peak_bw = peak_bw or peaks.hbm_bw
         balance = peak_flops / peak_bw
         return min(1.0, self.arithmetic_intensity() / balance)
 
@@ -444,21 +449,25 @@ class HBMLedger:
         return dataclasses.asdict(self)
 
 
-def device_hbm_budget(
-    default: int = int(flops_mod.HBM_BYTES_PER_CHIP),
-) -> int:
-    """Per-device HBM budget: the backend's ``bytes_limit`` when it
-    reports one (TPU), else the v5e default — CPU test hosts report no
-    memory stats, and the ledger must stay deterministic there."""
-    try:
-        import jax
+def device_hbm_budget() -> int:
+    """Per-device HBM budget. On a TPU, what the runtime reports as
+    ``memory_stats()["bytes_limit"]`` — and an error when it reports none,
+    because a budget guessed for a chip nobody asked is how a ledger lies.
+    CPU hosts report no memory stats; they get the capacity of the
+    :mod:`..flops` row the CPU tier borrows, so the ledger stays
+    deterministic there."""
+    import jax
 
-        stats = jax.devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return int(default)
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        stats = dev.memory_stats() or {}
+        if not stats.get("bytes_limit"):
+            raise RuntimeError(
+                f"{dev.device_kind}: memory_stats() reports no bytes_limit "
+                f"(got {sorted(stats)}); pass PagedConfig.hbm_budget_bytes"
+            )
+        return int(stats["bytes_limit"])
+    return flops_mod.chip_peaks().hbm_bytes
 
 
 def hbm_ledger(

@@ -583,10 +583,12 @@ class PagedServingEngine:
                 self.model = type(self.model)(
                     dataclasses.replace(self.model.config, quant_mxu=True)
                 )
-        self.cache = self.model.init_paged_cache(
-            paged.num_blocks, bs, paged.cache_dtype,
-            kv_cache_dtype=paged.kv_cache_dtype,
-        )
+        def init_pool():
+            return self.model.init_paged_cache(
+                paged.num_blocks, bs, paged.cache_dtype,
+                kv_cache_dtype=paged.kv_cache_dtype,
+            )
+
         from neuronx_distributed_llama3_2_tpu.parallel import (
             state as parallel_state,
         )
@@ -598,19 +600,21 @@ class PagedServingEngine:
         # re-lower each program on its second call — graftcheck GC008)
         self._replicated_sharding = None
         if parallel_state.model_parallel_is_initialized():
-            from neuronx_distributed_llama3_2_tpu.parallel.layers import (
-                shard_pytree,
-            )
-
-            self.cache = shard_pytree(
-                self.cache,
+            # the pool is born sharded: each device zero-fills only its own
+            # kv-head slice, so a tp mesh can hold a pool tp× one chip's —
+            # building it whole on device 0 first would cap it at one HBM
+            mesh = parallel_state.get_parallel_state().mesh
+            shardings = jax.tree.map(
+                lambda s: jax.sharding.NamedSharding(mesh, s),
                 self.model.paged_cache_specs(quantized=self._kv_quantized),
             )
-            mesh = parallel_state.get_parallel_state().mesh
+            self.cache = jax.jit(init_pool, out_shardings=shardings)()
             if mesh.size > 1:
                 self._replicated_sharding = jax.sharding.NamedSharding(
                     mesh, jax.sharding.PartitionSpec()
                 )
+        else:
+            self.cache = init_pool()
         self.allocator = BlockAllocator(paged.num_blocks, bs)
         self.index = RadixPrefixIndex(self.allocator)
         # tiered KV storage (docs/serving.md "Tiered KV storage"): the
@@ -843,10 +847,11 @@ class PagedServingEngine:
         self.cost_profiles: Optional[Dict[tuple, Any]] = None
         self.hbm: Optional[Any] = None
         self._flops_by_key: Dict[tuple, tuple] = {}
-        from neuronx_distributed_llama3_2_tpu import flops as _flops_mod
+        from neuronx_distributed_llama3_2_tpu.flops import chip_peaks
 
-        self.metrics.peak_flops_per_chip = _flops_mod.PEAK_FLOPS_PER_CHIP
-        self.metrics.peak_hbm_bw_per_chip = _flops_mod.PEAK_HBM_BW_PER_CHIP
+        peaks = chip_peaks()
+        self.metrics.peak_flops_per_chip = peaks.bf16_flops
+        self.metrics.peak_hbm_bw_per_chip = peaks.hbm_bw
         # SLO burn-rate monitor (serving/slo.py): built only when an
         # objective is declared; otherwise the step hook is a None test
         slo_policy = SLOPolicy.from_paged(paged)

@@ -186,7 +186,9 @@ def main() -> None:
     import jax
 
     if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
+        from neuronx_distributed_llama3_2_tpu.utils.runtime import set_cpu_devices
+
+        set_cpu_devices(1)
 
     result = head_ab(args.quick, args.iters) if args.which == "head" else ring_ab(
         args.quick, args.iters

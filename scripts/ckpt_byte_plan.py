@@ -166,7 +166,7 @@ def main() -> None:
 
     import jax
 
-    from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+    from neuronx_distributed_llama3_2_tpu.utils.runtime import set_cpu_devices
 
     set_cpu_devices(TP * PP)
 

@@ -62,6 +62,7 @@ sys.path.insert(0, REPO_ROOT)
 # CPU-hosted like tests/conftest.py: 8 virtual devices (the tp=2 catalog
 # entries slice the first two), set before jax initializes its backend.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("NXDT_KERNEL_MODE", "reference")
 if "--xla_force_host_platform_device_count" not in os.environ.get(
     "XLA_FLAGS", ""
 ):
@@ -75,18 +76,15 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 jax.config.update("jax_threefry_partitionable", True)
-# own persistent compile cache so repeat gate runs skip XLA (the engine
-# entries are the only ones that compile). Deliberately NOT the test
-# suite's tests/.jax_cache: the gate runs as a subprocess inside tier-1,
-# and two processes hitting one cache dir concurrently has produced
-# corrupt entries (wrong executables, nondeterministic parity failures)
-_CACHE = os.path.join(REPO_ROOT, "tests", ".jax_cache_graftcheck")
-try:
-    jax.config.update("jax_compilation_cache_dir", _CACHE)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-except Exception:
-    pass
+# persistent compile cache so repeat gate runs skip XLA (the engine
+# entries are the only ones that compile)
+from neuronx_distributed_llama3_2_tpu.utils.runtime import (  # noqa: E402
+    enable_compile_cache,
+)
+
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 from neuronx_distributed_llama3_2_tpu.analysis.graftcheck import (  # noqa: E402
     GC_RULES,

@@ -60,19 +60,20 @@ GOLDEN_TABLE = os.path.join(
 
 
 def _configure_jax() -> None:
-    """Script-entry jax setup (CPU host, own persistent compile cache).
+    """Script-entry jax setup (CPU host, persistent compile cache).
     NOT called on the in-process tier-1 path — the test suite has already
     configured its backend and cache."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ.setdefault("NXDT_KERNEL_MODE", "reference")
     import jax
 
-    cache = os.path.join(REPO_ROOT, "tests", ".jax_cache_graftplan")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass
+    from neuronx_distributed_llama3_2_tpu.utils.runtime import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
 #: The recorded workload: three long ``batch`` prompts submitted FIRST

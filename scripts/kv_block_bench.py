@@ -170,7 +170,7 @@ def run_bench(args: argparse.Namespace) -> dict:
     import numpy as np
 
     if args.cpu_devices:
-        from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+        from neuronx_distributed_llama3_2_tpu.utils.runtime import set_cpu_devices
 
         set_cpu_devices(args.cpu_devices)
 

@@ -92,7 +92,7 @@ def cp_gate(seq: int) -> None:
 
     code = f"""
 import jax
-from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+from neuronx_distributed_llama3_2_tpu.utils.runtime import set_cpu_devices
 set_cpu_devices(8)
 import json, time
 import jax.numpy as jnp, numpy as np

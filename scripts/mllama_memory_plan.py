@@ -263,7 +263,7 @@ def main() -> None:
     # 64 virtual devices: the (tp=8, dp=8) mesh must EXIST for the ZeRO-1
     # spec generation to dp-shard exactly as v5e-64 would (dp=1 meshes
     # skip the dp dimension entirely)
-    from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+    from neuronx_distributed_llama3_2_tpu.utils.runtime import set_cpu_devices
 
     set_cpu_devices(64)
 

@@ -1059,7 +1059,7 @@ def main() -> None:
     if args.smoke:
         # the smoke tier is the CPU CI check; a 2-device virtual backend
         # lets the tp A/B run there too (must precede backend init)
-        from neuronx_distributed_llama3_2_tpu.utils.compat import (
+        from neuronx_distributed_llama3_2_tpu.utils.runtime import (
             set_cpu_devices,
         )
 

@@ -38,7 +38,7 @@ Usage:
     python scripts/serving_load.py --policy-table auto   # + table leg
 
 ``--smoke`` is what ``tests/test_server.py`` runs in-process; the full
-run is staged in ``scripts/chip_session.py``.
+run is one command through the chip tool.
 """
 
 from __future__ import annotations
@@ -57,15 +57,16 @@ TENANTS = ("acme", "globex", "initech")
 
 def _configure_jax() -> None:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ.setdefault("NXDT_KERNEL_MODE", "reference")
     import jax
 
-    cache = os.path.join(REPO_ROOT, "tests", ".jax_cache_serving_load")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass
+    from neuronx_distributed_llama3_2_tpu.utils.runtime import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
 _STATE = None

@@ -17,8 +17,7 @@ import time
 
 import jax
 
-from neuronx_distributed_llama3_2_tpu.utils import compat
-from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+from neuronx_distributed_llama3_2_tpu.utils.runtime import set_cpu_devices
 
 set_cpu_devices(8)
 
@@ -72,7 +71,7 @@ def main() -> None:
         pv = shard_pytree(pm.to_pipeline(params), pm.specs())
         lowered = jax.jit(grad_fn).lower(pv, ids, ids)
         compiled = lowered.compile()
-        flops = compat.cost_analysis(compiled).get("flops", float("nan"))
+        flops = compiled.cost_analysis().get("flops", float("nan"))
         t0 = time.perf_counter()
         out = compiled(pv, ids, ids)
         jax.block_until_ready(out)

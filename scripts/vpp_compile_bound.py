@@ -27,7 +27,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 import jax
 
-from neuronx_distributed_llama3_2_tpu.utils.compat import set_cpu_devices
+from neuronx_distributed_llama3_2_tpu.utils.runtime import set_cpu_devices
 
 set_cpu_devices(8)
 

@@ -1,0 +1,257 @@
+"""Cross-lowering: every ``pallas_call`` in the package, traced in the
+``"compiled"`` kernel mode on this CPU host and lowered for a TPU.
+
+Lowering only — no execution, no Mosaic — so it is cheap and runs in
+tier-1, and it is the earliest place a kernel that cannot reach the chip
+fails: the Pallas TPU lowering refuses illegal block shapes, unsupported
+primitives and bad index maps before any compiler is involved. The same
+case matrix, compiled all the way through Mosaic for a v5e without a chip,
+is ``python scripts/tpu_aot_compile.py``; numerics are the chip's
+``scripts/tpu_kernel_gate.py``.
+
+Geometry is Llama-3.2-1B's attention: 32 query heads over 8 kv heads of 64,
+pool blocks of 16 rows, bench sequence 2048.
+"""
+
+import itertools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (
+    KV_SCALE_DTYPE,
+    kv_cache_jax_dtype,
+)
+
+N, NKV, D, BS = 32, 8, 64, 16
+LANES, KV_LIMIT, POOL_BLOCKS = 8, 2048, 256
+
+
+def flash_case(block, segmented, backward):
+    """(fn, avals) for the flash kernels at bench geometry: forward, or the
+    dq + dkv backward pair through the custom VJP."""
+    from neuronx_distributed_llama3_2_tpu.kernels.pallas_flash_attention import (
+        pallas_flash_attention,
+    )
+
+    b, s = 2, 2048
+
+    def loss(q, k, v, *seg):
+        o = pallas_flash_attention(
+            q, k, v, causal=True, segment_ids=seg[0] if seg else None,
+            block_q=block, block_kv=block,
+        )
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    avals = [
+        jax.ShapeDtypeStruct((b, s, N, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((b, s, NKV, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((b, s, NKV, D), jnp.bfloat16),
+    ]
+    if segmented:
+        avals.append(jax.ShapeDtypeStruct((b, s), jnp.int32))
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else loss
+    return fn, avals
+
+
+def paged_case(kv_dtype, t, quant_mxu=False, row_live=False, tree=False,
+               mesh=None):
+    """(fn, avals) for the paged decode kernel; ``mesh`` wraps it in the tp
+    ``shard_map`` (``paged_flash_decode_tp``)."""
+    from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+        paged_flash_decode,
+        paged_flash_decode_tp,
+    )
+
+    quantized = kv_dtype != "bf16"
+    pool = jax.ShapeDtypeStruct(
+        (POOL_BLOCKS, BS, NKV, D), kv_cache_jax_dtype(kv_dtype)
+    )
+    scale = jax.ShapeDtypeStruct((POOL_BLOCKS, BS, NKV), KV_SCALE_DTYPE)
+    qshape = (LANES, N, D) if t == 1 else (LANES, t, N, D)
+    avals = [
+        jax.ShapeDtypeStruct(qshape, jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((LANES, KV_LIMIT // BS + 8), jnp.int32),
+        jax.ShapeDtypeStruct((LANES,), jnp.int32),
+    ]
+    avals += [scale, scale] if quantized else []
+    avals += [jax.ShapeDtypeStruct((LANES,), jnp.int32)] if row_live else []
+    avals += [jax.ShapeDtypeStruct((LANES, t), jnp.int32)] if tree else []
+
+    def fn(q, kp, vp, tables, positions, *rest):
+        rest = list(rest)
+        kw = dict(kv_limit=KV_LIMIT)
+        if quantized:
+            kw.update(k_scale=rest.pop(0), v_scale=rest.pop(0),
+                      quant_mxu=quant_mxu)
+        if row_live:
+            kw["row_live"] = rest.pop(0)
+        if tree:
+            kw["tree_bits"] = rest.pop(0)
+        if mesh is not None:
+            return paged_flash_decode_tp(
+                q, kp, vp, tables, positions, mesh=mesh, **kw
+            )
+        return paged_flash_decode(q, kp, vp, tables, positions, **kw)
+
+    return fn, avals
+
+
+def ring_case(mesh, impl):
+    """(fn, avals) for the Pallas ring executors (fwd + custom-VJP bwd) over
+    ``mesh``'s cp axis, 2048 tokens."""
+    from neuronx_distributed_llama3_2_tpu.kernels.ring_attention import (
+        ring_attention_sharded,
+    )
+
+    def loss(q, k, v):
+        o = ring_attention_sharded(q, k, v, mesh, "cp", causal=True, impl=impl)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    avals = [
+        jax.ShapeDtypeStruct((2, 2048, n, D), jnp.bfloat16)
+        for n in (N, NKV, NKV)
+    ]
+    return jax.grad(loss, argnums=(0, 1, 2)), avals
+
+
+QUANT = ("int8", "fp8_e4m3", "fp8_e5m2")
+
+FLASH_CASES = {
+    f"flash-{'bwd' if bwd else 'fwd'}-{blk}{'-seg' if seg else ''}":
+        (blk, seg, bwd)
+    for blk, seg, bwd in itertools.product(
+        (1024, 512), (False, True), (False, True)
+    )
+}
+# kwargs of paged_case, single chip
+PAGED_CASES = {
+    **{f"paged-{dt}-t{t}": dict(kv_dtype=dt, t=t)
+       for dt in ("bf16",) + QUANT for t in (1, 4, 8)},
+    **{f"paged-mxu-{dt}-t{t}": dict(kv_dtype=dt, t=t, quant_mxu=True)
+       for dt in QUANT for t in (1, 4, 8)},
+    **{f"paged-live-{dt}-t{t}": dict(kv_dtype=dt, t=t, row_live=True)
+       for dt in ("bf16", "int8") for t in (4, 8)},
+    **{f"paged-tree-{dt}-t{t}": dict(kv_dtype=dt, t=t, tree=True)
+       for dt in ("bf16", "int8") for t in (4, 8)},
+    "paged-tree-mxu-int8-t4": dict(
+        kv_dtype="int8", t=4, tree=True, quant_mxu=True),
+    "paged-tree-mxu-int8-t8": dict(
+        kv_dtype="int8", t=8, tree=True, quant_mxu=True),
+}
+# the same kernel under the tp=4 shard_map wrapper (NKV/tp = 2 heads a rank)
+TP_CASES = {
+    "tp4-bf16-t1": dict(kv_dtype="bf16", t=1),
+    "tp4-bf16-t4": dict(kv_dtype="bf16", t=4),
+    "tp4-int8-t1": dict(kv_dtype="int8", t=1),
+    "tp4-mxu-int8-t4": dict(kv_dtype="int8", t=4, quant_mxu=True),
+    "tp4-tree-bf16-t8": dict(kv_dtype="bf16", t=8, tree=True),
+    "tp4-live-bf16-t8": dict(kv_dtype="bf16", t=8, row_live=True),
+}
+# (initialize_model_parallel kwargs over four devices, ring impl)
+RING_CASES = {
+    "ring-pallas-cp4": (dict(context_parallel_size=4), "pallas"),
+    "ring-zigzag-cp4": (dict(context_parallel_size=4), "zigzag"),
+    "ring-zigzag-cp2-tp2": (
+        dict(context_parallel_size=2, tensor_model_parallel_size=2), "zigzag"),
+}
+
+
+def lower_for_tpu(fn, avals):
+    return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+
+def assert_mosaic_call(lowered, kernel_name):
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic custom call in the lowering"
+    assert kernel_name in text, f"kernel {kernel_name!r} not in the lowering"
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    monkeypatch.setenv(KERNEL_MODE_ENV, "compiled")
+
+
+@pytest.mark.parametrize("name", FLASH_CASES)
+def test_flash_kernels_lower_for_tpu(compiled_mode, name):
+    blk, seg, bwd = FLASH_CASES[name]
+    lowered = lower_for_tpu(*flash_case(blk, seg, bwd))
+    assert_mosaic_call(lowered, "flash_fwd")
+    if bwd:
+        assert_mosaic_call(lowered, "flash_bwd_dq")
+        assert_mosaic_call(lowered, "flash_bwd_dkv")
+
+
+@pytest.mark.parametrize("name", PAGED_CASES)
+def test_paged_kernel_lowers_for_tpu(compiled_mode, name):
+    lowered = lower_for_tpu(*paged_case(**PAGED_CASES[name]))
+    assert_mosaic_call(lowered, "paged_flash_decode")
+
+
+@pytest.mark.parametrize("name", TP_CASES)
+def test_tp_paged_kernel_lowers_for_tpu(compiled_mode, name):
+    from neuronx_distributed_llama3_2_tpu.parallel.state import (
+        initialize_model_parallel,
+    )
+
+    st = initialize_model_parallel(
+        tensor_model_parallel_size=4, devices=jax.devices()[:4]
+    )
+    lowered = lower_for_tpu(*paged_case(mesh=st.mesh, **TP_CASES[name]))
+    assert_mosaic_call(lowered, "paged_flash_decode")
+
+
+@pytest.mark.parametrize("name", RING_CASES)
+def test_ring_kernels_lower_for_tpu(compiled_mode, name):
+    from neuronx_distributed_llama3_2_tpu.parallel.state import (
+        initialize_model_parallel,
+    )
+
+    mesh_kw, impl = RING_CASES[name]
+    st = initialize_model_parallel(devices=jax.devices()[:4], **mesh_kw)
+    lowered = lower_for_tpu(*ring_case(st.mesh, impl))
+    assert_mosaic_call(lowered, "flash_fwd")
+
+
+@pytest.mark.parametrize("mesh_kw", [
+    dict(tensor_model_parallel_size=2),                       # tp2 x dp2
+    dict(tensor_model_parallel_size=2, pipeline_model_parallel_size=2),
+], ids=["tp2-dp2", "tp2-pp2"])
+def test_flash_dispatch_lowers_on_a_mesh(compiled_mode, mesh_kw):
+    """``flash_attention`` on a multi-device mesh: the partitioner refuses a
+    bare Mosaic call ("cannot be automatically partitioned"), so the
+    dispatcher must put the kernel in a manual region."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from neuronx_distributed_llama3_2_tpu.kernels.flash_attention import (
+        flash_attention,
+    )
+    from neuronx_distributed_llama3_2_tpu.parallel.state import (
+        initialize_model_parallel,
+    )
+
+    st = initialize_model_parallel(devices=jax.devices()[:4], **mesh_kw)
+    sharding = NamedSharding(st.mesh, P(("dp", "ep"), None, "tp", None))
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, block_q=512, block_kv=512)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    avals = [
+        jax.ShapeDtypeStruct((4, 1024, n, D), jnp.bfloat16, sharding=sharding)
+        for n in (N, NKV, NKV)
+    ]
+    lowered = lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), avals)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert_mosaic_call(lowered, kernel)
+
+
+def test_interpreted_kernels_never_reach_a_tpu_lowering(monkeypatch):
+    """The other two modes keep the kernel off Mosaic — which is why no
+    device-bound entry point may run in them unasked."""
+    for mode in ("interpret", "reference"):
+        monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+        lowered = lower_for_tpu(*paged_case("bf16", 1))
+        assert "tpu_custom_call" not in lowered.as_text()

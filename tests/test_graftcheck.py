@@ -36,7 +36,6 @@ from neuronx_distributed_llama3_2_tpu.serving import (
     PagedConfig,
     PagedServingEngine,
 )
-from neuronx_distributed_llama3_2_tpu.utils import compat
 
 TINY = LLAMA_CONFIGS["tiny"]
 TINY_KERNEL = dataclasses.replace(TINY, use_paged_kernel=True)
@@ -119,8 +118,18 @@ def test_gc003_fires_on_device_put_and_callback():
 
     closed = jax.make_jaxpr(cb)(jnp.ones(3))
     assert any(
-        "callback" in f.detail
+        "debug_print" in f.detail
         for f in gc.check_host_transfers(closed, "cb")
+    )
+
+    def dbg(x):
+        jax.debug.callback(lambda y: None, x)
+        return x * 2.0
+
+    closed = jax.make_jaxpr(dbg)(jnp.ones(3))
+    assert any(
+        "debug_callback" in f.detail
+        for f in gc.check_host_transfers(closed, "dbg")
     )
 
 
@@ -134,8 +143,8 @@ def test_gc003_quiet_on_pure_compute(params):
 
 def _psum_region_trace(axis="tp"):
     mesh = Mesh(np.array(jax.devices()[:1]), (axis,))
-    body = compat.shard_map(
-        lambda x: jax.lax.psum(x, axis), mesh,
+    body = jax.shard_map(
+        lambda x: jax.lax.psum(x, axis), mesh=mesh,
         in_specs=(P(),), out_specs=P(), check_vma=False,
     )
     return jax.make_jaxpr(body)(jnp.ones((4,)))

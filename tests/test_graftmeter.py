@@ -25,7 +25,6 @@ import numpy as np
 import pytest
 
 from neuronx_distributed_llama3_2_tpu.flops import (
-    PEAK_FLOPS_PER_CHIP,
     decode_flops_per_token,
     model_flops_per_token,
     train_flops_per_token,

@@ -10,7 +10,6 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from neuronx_distributed_llama3_2_tpu.parallel import layers, state as ps
-from neuronx_distributed_llama3_2_tpu.utils import compat
 
 
 @pytest.fixture
@@ -38,7 +37,7 @@ def test_column_row_mlp_parity(tp4):
     dense = loss(pc, pr, x)  # un-meshed path: constraints no-op'd via same fn
     pc_s = _shard_params(col, pc, mesh)
     pr_s = _shard_params(row, pr, mesh)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         sharded = jax.jit(loss)(pc_s, pr_s, x)
         gs = jax.jit(jax.grad(loss, argnums=(0, 1)))(pc_s, pr_s, x)
     gd = jax.grad(loss, argnums=(0, 1))(pc, pr, x)
@@ -55,7 +54,7 @@ def test_parallel_embedding_parity(tp4):
     ids = jax.random.randint(jax.random.fold_in(k, 1), (2, 8), 0, 128)
     ref = np.asarray(p["embedding"])[np.asarray(ids)]
     p_s = _shard_params(emb, p, mesh)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out = jax.jit(lambda p, i: emb(p, i))(p_s, ids)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-6)
 
@@ -74,7 +73,7 @@ def test_gqa_qkv_sharded_and_replicated_kv(tp4):
         assert p["k_kernel"].shape == (32, kvh * 4)
         x = jax.random.normal(k, (2, 8, 32))
         p_s = _shard_params(qkv, p, mesh)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             q, kk, v = jax.jit(lambda p, x: qkv(p, x))(p_s, x)
         np.testing.assert_allclose(
             np.asarray(q), np.asarray(x @ p["q_kernel"]), rtol=2e-5, atol=1e-6

@@ -8,7 +8,6 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from neuronx_distributed_llama3_2_tpu.parallel import loss as L, state as ps
-from neuronx_distributed_llama3_2_tpu.utils import compat
 
 
 @pytest.mark.parametrize("smoothing", [0.0, 0.1])
@@ -22,7 +21,7 @@ def test_parallel_xent_matches_dense(smoothing):
 
     dense = L.cross_entropy(logits, labels, smoothing)
     logits_s = jax.device_put(logits, NamedSharding(mesh, P(None, None, "tp")))
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         par = jax.jit(lambda lg, lb: L.parallel_cross_entropy(lg, lb, smoothing))(
             logits_s, labels
         )
@@ -39,7 +38,7 @@ def test_parallel_xent_grad_matches_dense():
 
     gd = jax.grad(lambda lg: L.cross_entropy(lg, labels).mean())(logits)
     logits_s = jax.device_put(logits, NamedSharding(mesh, P(None, "tp")))
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         gp = jax.jit(
             jax.grad(lambda lg: L.parallel_cross_entropy(lg, labels).mean())
         )(logits_s)

@@ -22,9 +22,7 @@ def _shard_map(f, mesh, in_specs, out_specs):
     # check_vma=False: axis_index-based slicing makes values look varying to
     # the static replication checker even when they are mathematically
     # replicated (e.g. after an all-gather); grads remain exact.
-    from neuronx_distributed_llama3_2_tpu.utils import compat
-
-    return compat.shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 

@@ -19,7 +19,6 @@ from neuronx_distributed_llama3_2_tpu.models.mllama import (
 )
 from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
 from neuronx_distributed_llama3_2_tpu.parallel.layers import shard_pytree
-from neuronx_distributed_llama3_2_tpu.utils import compat
 
 TINY = MllamaConfig(
     vision=MllamaVisionConfig(
@@ -330,26 +329,6 @@ def test_text_group_pattern_rejects_all_cross_layers():
     assert isinstance(params["layers"], list) and len(params["layers"]) == 2
 
 
-@pytest.mark.xfail(
-    compat.is_legacy_jax(),
-    # Triage (jax 0.4.x line only): tp=8 does not divide num_heads=4, so
-    # every flat tp layout in the attention stack lands mid-head, and the
-    # 0.4.x CPU SPMD partitioner resolves those boundaries with reduction
-    # reorderings that drift ~3e-3 in fp32 — patching
-    # model_parallel_is_initialized() to False makes the same sharded
-    # params match the reference EXACTLY, and forcing
-    # tensor_parallel_size_or()->1 (GQAQKV replicated-heads fallback off)
-    # halves the error, so the miscompile is in the partitioner's mid-head
-    # handling, not repo logic (same class as the kv_flat_sharded guard in
-    # parallel/layers.py). A compat.py shim that rounds activation
-    # constraints down to head-aligned layouts (replicate instead of
-    # mid-head shard) when is_legacy_jax() would close it; newer
-    # partitioners handle mid-head boundaries exactly, so the test must
-    # pass there — hence strict.
-    reason="0.4.x SPMD partitioner miscompiles mid-head tp layouts "
-    "(tp=8 > num_heads=4); see comment",
-    strict=True,
-)
 def test_mllama_tp_with_indivisible_vocab(hf_and_params):
     """When tp doesn't divide the vocab (tp=16 with the 128256+8-row
     embedding — the 11B fitting config's blocker), the embed falls back to

@@ -220,6 +220,44 @@ def test_1f1b_loss_and_grad_matches_autodiff(pp, M, tp):
         )
 
 
+@pytest.mark.parametrize("mesh", [
+    dict(tensor_model_parallel_size=2, pipeline_model_parallel_size=2),
+    dict(tensor_model_parallel_size=2),  # tp2 x dp4, no pipeline
+], ids=["pp2-tp2-1f1b", "tp2-dp4"])
+def test_flash_kernel_region_keeps_grads_exact_on_a_mesh(monkeypatch, mesh):
+    """``use_flash_attention`` with the Pallas kernel (interpreted here) on a
+    mesh: the kernel runs in a manual region (a Mosaic call cannot be
+    partitioned), nested inside the pp-manual executor under 1F1B. Loss and
+    grads must equal autodiff of the unsharded dense-attention model — a
+    region that re-lists pp sums cotangents across stages (grad norm ×100
+    here, inf at 1B on the chip)."""
+    monkeypatch.setenv("NXDT_KERNEL_MODE", "interpret")
+    cfg = dataclasses.replace(
+        TINY, use_flash_attention=True, flash_block_q=16, flash_block_kv=16
+    )
+    dense = LlamaForCausalLM(TINY)
+    params = dense.init(jax.random.key(4))
+    ids = _mk_batch(gbs=8, seq=32)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(dense.loss))(params, ids, ids)
+
+    parallel_state.initialize_model_parallel(**mesh)
+    model = LlamaForCausalLM(cfg)
+    if "pipeline_model_parallel_size" in mesh:
+        model = PipelinedCausalLM(model, num_microbatches=4, schedule="1f1b")
+        placed = shard_pytree(model.to_pipeline(params), model.specs())
+        loss, grads = jax.jit(model.loss_and_grad)(placed, ids, ids)
+        grads = model.from_pipeline(grads)
+    else:
+        placed = shard_pytree(params, model.specs())
+        loss, grads = jax.jit(jax.value_and_grad(model.loss))(placed, ids, ids)
+    assert abs(float(loss) - float(ref_loss)) < 1e-4
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=5e-4, rtol=1e-3,
+        )
+
+
 def test_1f1b_through_trainer():
     """schedule='1f1b' trains via the trainer facade (loss_and_grad path)."""
     cfg = TrainingConfig(

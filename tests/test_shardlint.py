@@ -253,7 +253,6 @@ def test_sl004_traced_callee_of_scan_and_shard_map():
         """
         import jax
         from jax import lax
-        from neuronx_distributed_llama3_2_tpu.utils import compat
 
         def body(c, x):
             print(x)
@@ -266,7 +265,7 @@ def test_sl004_traced_callee_of_scan_and_shard_map():
 
         def run(mesh, xs):
             lax.scan(body, 0, xs)
-            compat.shard_map(g, mesh, in_specs=None, out_specs=None)(xs)
+            jax.shard_map(g, mesh=mesh, in_specs=None, out_specs=None)(xs)
         """,
         "SL004",
     )
@@ -297,14 +296,13 @@ def test_sl005_raw_constraint_in_shard_map_fires():
         import jax
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from neuronx_distributed_llama3_2_tpu.utils import compat
 
         def body(x):
             return lax.with_sharding_constraint(x, P("tp"))
 
         def run(mesh, x):
-            return compat.shard_map(
-                body, mesh, in_specs=P("tp"), out_specs=P("tp")
+            return jax.shard_map(
+                body, mesh=mesh, in_specs=P("tp"), out_specs=P("tp")
             )(x)
         """,
         "SL005",
@@ -318,14 +316,13 @@ def test_sl005_blessed_constrain_ok():
         """
         from jax.sharding import PartitionSpec as P
         from neuronx_distributed_llama3_2_tpu.parallel.layers import constrain
-        from neuronx_distributed_llama3_2_tpu.utils import compat
 
         def body(x):
             return constrain(x, P("tp"))
 
         def run(mesh, x):
-            return compat.shard_map(
-                body, mesh, in_specs=P("tp"), out_specs=P("tp")
+            return jax.shard_map(
+                body, mesh=mesh, in_specs=P("tp"), out_specs=P("tp")
             )(x)
         """,
         "SL005",
@@ -354,14 +351,13 @@ def test_sl006_unbound_axis_fires():
     fs = _lint(
         """
         from jax import lax
-        from neuronx_distributed_llama3_2_tpu.utils import compat
 
         def body(x):
             return x + lax.axis_index("dp")
 
         def run(mesh, x):
-            return compat.shard_map(
-                body, mesh, in_specs=None, out_specs=None,
+            return jax.shard_map(
+                body, mesh=mesh, in_specs=None, out_specs=None,
                 axis_names={"tp"},
             )(x)
         """,
@@ -375,7 +371,6 @@ def test_sl006_bound_axis_and_unknown_axis_names_ok():
     fs = _lint(
         """
         from jax import lax
-        from neuronx_distributed_llama3_2_tpu.utils import compat
 
         def body(x):
             return x + lax.axis_index("tp")
@@ -384,13 +379,13 @@ def test_sl006_bound_axis_and_unknown_axis_names_ok():
             return x + lax.axis_index("dp")
 
         def run(mesh, x, names):
-            a = compat.shard_map(
-                body, mesh, in_specs=None, out_specs=None,
+            a = jax.shard_map(
+                body, mesh=mesh, in_specs=None, out_specs=None,
                 axis_names={"tp"},
             )(x)
             # axis_names not statically resolvable: rule must stay quiet
-            b = compat.shard_map(
-                dyn, mesh, in_specs=None, out_specs=None, axis_names=names
+            b = jax.shard_map(
+                dyn, mesh=mesh, in_specs=None, out_specs=None, axis_names=names
             )(x)
             return a, b
         """,
