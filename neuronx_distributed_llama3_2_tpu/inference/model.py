@@ -383,24 +383,31 @@ class LlamaDecode:
         b, t, _ = x.shape
 
         h = norm(lp["attn_norm"], x)
-        q, k, v = attn._qkv()(lp["attn"]["qkv"], h)
-        if c.clip_qkv is not None:
-            q = jnp.clip(q, -c.clip_qkv, c.clip_qkv)
-            k = jnp.clip(k, -c.clip_qkv, c.clip_qkv)
-            v = jnp.clip(v, -c.clip_qkv, c.clip_qkv)
-        q = q.reshape(b, t, c.num_heads, c.head_dim)
-        k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
-        v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, sin, cos, pos_block)
-        k = apply_rope(k, sin, cos, pos_block)
+        # the training block's scopes (models/llama.py LlamaAttention) plus
+        # attn/kv_write and attn/kv_read inside _attend_with_cache
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                q, k, v = attn._qkv()(lp["attn"]["qkv"], h)
+                if c.clip_qkv is not None:
+                    q = jnp.clip(q, -c.clip_qkv, c.clip_qkv)
+                    k = jnp.clip(k, -c.clip_qkv, c.clip_qkv)
+                    v = jnp.clip(v, -c.clip_qkv, c.clip_qkv)
+                q = q.reshape(b, t, c.num_heads, c.head_dim)
+                k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
+                v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
+            with jax.named_scope("rope"):
+                q = apply_rope(q, sin, cos, pos_block)
+                k = apply_rope(k, sin, cos, pos_block)
 
-        att, kc, vc = self._attend_with_cache(
-            q, k, v, kc, vc, slots, pos_block, positions,
-            context_encode=context_encode, tree=tree, kv_limit=kv_limit,
-            block_tables=block_tables, row_live=row_live,
-        )
-        att = att.reshape(b, t, c.num_heads * c.head_dim)
-        x = x + attn._o()(lp["attn"]["o"], att)
+            att, kc, vc = self._attend_with_cache(
+                q, k, v, kc, vc, slots, pos_block, positions,
+                context_encode=context_encode, tree=tree, kv_limit=kv_limit,
+                block_tables=block_tables, row_live=row_live,
+            )
+            att = att.reshape(b, t, c.num_heads * c.head_dim)
+            with jax.named_scope("o_proj"):
+                attn_out = attn._o()(lp["attn"]["o"], att)
+        x = x + attn_out
         h = norm(lp["mlp_norm"], x)
         x = x + self._mlp_block(lp, h)
         return x, kc, vc
@@ -438,8 +445,9 @@ class LlamaDecode:
                 "quantized (payload, scale) cache slices reach the dense "
                 "path only on a caller bug — forward() guards block_tables"
             )
-        kc = kc.at[slots[:, None], write_rows].set(k.astype(kc.dtype))
-        vc = vc.at[slots[:, None], write_rows].set(v.astype(vc.dtype))
+        with jax.named_scope("kv_write"):
+            kc = kc.at[slots[:, None], write_rows].set(k.astype(kc.dtype))
+            vc = vc.at[slots[:, None], write_rows].set(v.astype(vc.dtype))
 
         ha = _head_axis(c.num_heads)
         if context_encode:
@@ -451,18 +459,22 @@ class LlamaDecode:
                 core_attention,
             )
 
-            att = core_attention(q, k, v, causal=True)
+            with jax.named_scope("sdpa"):
+                att = core_attention(q, k, v, causal=True)
         else:
             # attend over the cache rows of the active slots, bounded to the
             # token-gen bucket when given (static slice — reads only
             # kv_limit rows from HBM instead of the whole S_max cache)
-            kr = kc if kv_limit is None else kc[:, :kv_limit]
-            vr = vc if kv_limit is None else vc[:, :kv_limit]
-            k_all = jnp.take(kr, slots, axis=0).astype(q.dtype)  # (b,S≤max,NKV,D)
-            v_all = jnp.take(vr, slots, axis=0).astype(q.dtype)
-            att = self._cache_attention(
-                q, k_all, v_all, pos_block, ha, positions=positions, tree=tree
-            )
+            with jax.named_scope("kv_read"):
+                kr = kc if kv_limit is None else kc[:, :kv_limit]
+                vr = vc if kv_limit is None else vc[:, :kv_limit]
+                k_all = jnp.take(kr, slots, axis=0).astype(q.dtype)  # (b,S≤max,NKV,D)
+                v_all = jnp.take(vr, slots, axis=0).astype(q.dtype)
+            with jax.named_scope("sdpa"):
+                att = self._cache_attention(
+                    q, k_all, v_all, pos_block, ha, positions=positions,
+                    tree=tree,
+                )
         return att, kc, vc
 
     def _attend_paged(
@@ -487,41 +499,42 @@ class LlamaDecode:
         if quantized:
             kc, ksc = kc
             vc, vsc = vc
-        nb, bs = kc.shape[0], kc.shape[1]
-        kflat = kc.reshape((nb * bs,) + kc.shape[2:])
-        vflat = vc.reshape((nb * bs,) + vc.shape[2:])
-        # logical row p of batch row i -> pool row table[i, p//bs]*bs + p%bs;
-        # rows past the allocated frontier map to the null block (id 0)
-        wr_phys = (
-            jnp.take_along_axis(block_tables, write_rows // bs, axis=1) * bs
-            + write_rows % bs
-        )
-        if quantized:
-            from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (
-                kv_dequantize,
-                kv_quantize,
+        with jax.named_scope("kv_write"):
+            nb, bs = kc.shape[0], kc.shape[1]
+            kflat = kc.reshape((nb * bs,) + kc.shape[2:])
+            vflat = vc.reshape((nb * bs,) + vc.shape[2:])
+            # logical row p of batch row i -> pool row table[i, p//bs]*bs + p%bs;
+            # rows past the allocated frontier map to the null block (id 0)
+            wr_phys = (
+                jnp.take_along_axis(block_tables, write_rows // bs, axis=1) * bs
+                + write_rows % bs
             )
+            if quantized:
+                from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (
+                    kv_dequantize,
+                    kv_quantize,
+                )
 
-            # quantize-on-write: payload + per-(row, head) scale land in the
-            # same scatter, so frontier overwrites (speculative rollback)
-            # replace both and stale rows can never poison a later read
-            kq, ks = kv_quantize(k, kflat.dtype)   # (b,t,NKV,D) / (b,t,NKV)
-            vq, vs = kv_quantize(v, vflat.dtype)
-            ksflat = ksc.reshape((nb * bs,) + ksc.shape[2:])
-            vsflat = vsc.reshape((nb * bs,) + vsc.shape[2:])
-            kflat = kflat.at[wr_phys].set(kq)
-            vflat = vflat.at[wr_phys].set(vq)
-            ksflat = ksflat.at[wr_phys].set(ks)
-            vsflat = vsflat.at[wr_phys].set(vs)
-            ksc, vsc = ksflat.reshape(ksc.shape), vsflat.reshape(vsc.shape)
-            # the fresh block the prefill softmax consumes is the same
-            # round-trip a later chunk will read back from the pool
-            k = kv_dequantize(kq, ks, q.dtype)
-            v = kv_dequantize(vq, vs, q.dtype)
-        else:
-            kflat = kflat.at[wr_phys].set(k.astype(kflat.dtype))
-            vflat = vflat.at[wr_phys].set(v.astype(vflat.dtype))
-        kc, vc = kflat.reshape(kc.shape), vflat.reshape(vc.shape)
+                # quantize-on-write: payload + per-(row, head) scale land in the
+                # same scatter, so frontier overwrites (speculative rollback)
+                # replace both and stale rows can never poison a later read
+                kq, ks = kv_quantize(k, kflat.dtype)   # (b,t,NKV,D) / (b,t,NKV)
+                vq, vs = kv_quantize(v, vflat.dtype)
+                ksflat = ksc.reshape((nb * bs,) + ksc.shape[2:])
+                vsflat = vsc.reshape((nb * bs,) + vsc.shape[2:])
+                kflat = kflat.at[wr_phys].set(kq)
+                vflat = vflat.at[wr_phys].set(vq)
+                ksflat = ksflat.at[wr_phys].set(ks)
+                vsflat = vsflat.at[wr_phys].set(vs)
+                ksc, vsc = ksflat.reshape(ksc.shape), vsflat.reshape(vsc.shape)
+                # the fresh block the prefill softmax consumes is the same
+                # round-trip a later chunk will read back from the pool
+                k = kv_dequantize(kq, ks, q.dtype)
+                v = kv_dequantize(vq, vs, q.dtype)
+            else:
+                kflat = kflat.at[wr_phys].set(k.astype(kflat.dtype))
+                vflat = vflat.at[wr_phys].set(v.astype(vflat.dtype))
+            kc, vc = kflat.reshape(kc.shape), vflat.reshape(vc.shape)
 
         ha = _head_axis(c.num_heads)
         if context_encode:
@@ -529,7 +542,8 @@ class LlamaDecode:
                 core_attention,
             )
 
-            att = core_attention(q, k, v, causal=True)
+            with jax.named_scope("sdpa"):
+                att = core_attention(q, k, v, causal=True)
         else:
             limit = (
                 kv_limit if kv_limit is not None
@@ -553,69 +567,72 @@ class LlamaDecode:
                     state as parallel_state,
                 )
 
-                tree_bits = None
-                if tree is not None:
-                    anc = tree[1]
-                    if anc.ndim == 2:
-                        anc = jnp.broadcast_to(
-                            anc[None], (q.shape[0],) + anc.shape
+                with jax.named_scope("sdpa"):
+                    tree_bits = None
+                    if tree is not None:
+                        anc = tree[1]
+                        if anc.ndim == 2:
+                            anc = jnp.broadcast_to(
+                                anc[None], (q.shape[0],) + anc.shape
+                            )
+                        t_nodes = anc.shape[-1]
+                        bits = jnp.zeros(anc.shape[:2], jnp.int32)
+                        for m_ in range(t_nodes):
+                            bits = bits | (
+                                anc[:, :, m_].astype(jnp.int32) << m_
+                            )
+                        tree_bits = bits
+                    if (
+                        parallel_state.model_parallel_is_initialized()
+                        and parallel_state.get_parallel_state().mesh.size > 1
+                    ):
+                        # multi-chip: the kernel runs per rank in a shard_map
+                        # region on its NKV head slice (eligibility guarantees
+                        # a pure-tp mesh with divisible heads); out spec = the
+                        # q head split, so the constrain below is a no-op
+                        # restatement, and the row-parallel o-projection right
+                        # after attention performs the tp reduction. Scale
+                        # arrays ride in on the same head split — no new
+                        # collective.
+                        att = paged_flash_decode_tp(
+                            q, kc, vc, block_tables, positions,
+                            mesh=parallel_state.get_parallel_state().mesh,
+                            kv_limit=limit, k_scale=ksc, v_scale=vsc,
+                            quant_mxu=c.quant_mxu and ksc is not None,
+                            row_live=row_live, tree_bits=tree_bits,
                         )
-                    t_nodes = anc.shape[-1]
-                    bits = jnp.zeros(anc.shape[:2], jnp.int32)
-                    for m_ in range(t_nodes):
-                        bits = bits | (
-                            anc[:, :, m_].astype(jnp.int32) << m_
+                    else:
+                        att = paged_flash_decode(
+                            q, kc, vc, block_tables, positions, kv_limit=limit,
+                            k_scale=ksc, v_scale=vsc,
+                            quant_mxu=c.quant_mxu and ksc is not None,
+                            row_live=row_live, tree_bits=tree_bits,
                         )
-                    tree_bits = bits
-                if (
-                    parallel_state.model_parallel_is_initialized()
-                    and parallel_state.get_parallel_state().mesh.size > 1
-                ):
-                    # multi-chip: the kernel runs per rank in a shard_map
-                    # region on its NKV head slice (eligibility guarantees
-                    # a pure-tp mesh with divisible heads); out spec = the
-                    # q head split, so the constrain below is a no-op
-                    # restatement, and the row-parallel o-projection right
-                    # after attention performs the tp reduction. Scale
-                    # arrays ride in on the same head split — no new
-                    # collective.
-                    att = paged_flash_decode_tp(
-                        q, kc, vc, block_tables, positions,
-                        mesh=parallel_state.get_parallel_state().mesh,
-                        kv_limit=limit, k_scale=ksc, v_scale=vsc,
-                        quant_mxu=c.quant_mxu and ksc is not None,
-                        row_live=row_live, tree_bits=tree_bits,
-                    )
-                else:
-                    att = paged_flash_decode(
-                        q, kc, vc, block_tables, positions, kv_limit=limit,
-                        k_scale=ksc, v_scale=vsc,
-                        quant_mxu=c.quant_mxu and ksc is not None,
-                        row_live=row_live, tree_bits=tree_bits,
-                    )
-                att = constrain(att, P(BATCH_AXES, None, ha, None))
+                    att = constrain(att, P(BATCH_AXES, None, ha, None))
             else:
-                jlog = jnp.arange(limit, dtype=jnp.int32)
-                rd_phys = block_tables[:, jlog // bs] * bs + (jlog % bs)[None, :]
-                if quantized:
-                    # dequant outside the kernel, same f32-widen formula the
-                    # kernel fuses after its block DMA — bit-identical
-                    # operands on every eligibility path
-                    from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (  # noqa: E501
-                        kv_dequantize,
-                    )
+                with jax.named_scope("kv_read"):
+                    jlog = jnp.arange(limit, dtype=jnp.int32)
+                    rd_phys = block_tables[:, jlog // bs] * bs + (jlog % bs)[None, :]
+                    if quantized:
+                        # dequant outside the kernel, same f32-widen formula the
+                        # kernel fuses after its block DMA — bit-identical
+                        # operands on every eligibility path
+                        from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (  # noqa: E501
+                            kv_dequantize,
+                        )
 
-                    k_all = kv_dequantize(
-                        kflat[rd_phys], ksflat[rd_phys], q.dtype
-                    )  # (b, limit, NKV, D)
-                    v_all = kv_dequantize(vflat[rd_phys], vsflat[rd_phys], q.dtype)
-                else:
-                    k_all = kflat[rd_phys].astype(q.dtype)  # (b, limit, NKV, D)
-                    v_all = vflat[rd_phys].astype(q.dtype)
-                att = self._cache_attention(
-                    q, k_all, v_all, pos_block, ha, positions=positions,
-                    tree=tree,
-                )
+                        k_all = kv_dequantize(
+                            kflat[rd_phys], ksflat[rd_phys], q.dtype
+                        )  # (b, limit, NKV, D)
+                        v_all = kv_dequantize(vflat[rd_phys], vsflat[rd_phys], q.dtype)
+                    else:
+                        k_all = kflat[rd_phys].astype(q.dtype)  # (b, limit, NKV, D)
+                        v_all = vflat[rd_phys].astype(q.dtype)
+                with jax.named_scope("sdpa"):
+                    att = self._cache_attention(
+                        q, k_all, v_all, pos_block, ha, positions=positions,
+                        tree=tree,
+                    )
         if quantized:
             return att, (kc, ksc), (vc, vsc)
         return att, kc, vc
@@ -1277,25 +1294,29 @@ class GPTNeoXDecode(LlamaDecode):
         b, t, _ = x.shape
 
         h1 = norm(lp["attn_norm"], x)
-        q, k, v = attn._qkv()(lp["attn"]["qkv"], h1)
-        if c.clip_qkv is not None:
-            # inherited LlamaConfig knob; the training forward clamps
-            # (llama.py LlamaAttention), so decode must too
-            q = jnp.clip(q, -c.clip_qkv, c.clip_qkv)
-            k = jnp.clip(k, -c.clip_qkv, c.clip_qkv)
-            v = jnp.clip(v, -c.clip_qkv, c.clip_qkv)
-        q = q.reshape(b, t, c.num_heads, c.head_dim)
-        k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
-        v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
-        q, k = attn._apply_rope(q, k, sin, cos, pos_block)
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                q, k, v = attn._qkv()(lp["attn"]["qkv"], h1)
+                if c.clip_qkv is not None:
+                    # inherited LlamaConfig knob; the training forward clamps
+                    # (llama.py LlamaAttention), so decode must too
+                    q = jnp.clip(q, -c.clip_qkv, c.clip_qkv)
+                    k = jnp.clip(k, -c.clip_qkv, c.clip_qkv)
+                    v = jnp.clip(v, -c.clip_qkv, c.clip_qkv)
+                q = q.reshape(b, t, c.num_heads, c.head_dim)
+                k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
+                v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
+            with jax.named_scope("rope"):
+                q, k = attn._apply_rope(q, k, sin, cos, pos_block)
 
-        att, kc, vc = self._attend_with_cache(
-            q, k, v, kc, vc, slots, pos_block, positions,
-            context_encode=context_encode, tree=tree, kv_limit=kv_limit,
-            block_tables=block_tables, row_live=row_live,
-        )
-        att = att.reshape(b, t, c.num_heads * c.head_dim)
-        attn_out = attn._o()(lp["attn"]["o"], att)
+            att, kc, vc = self._attend_with_cache(
+                q, k, v, kc, vc, slots, pos_block, positions,
+                context_encode=context_encode, tree=tree, kv_limit=kv_limit,
+                block_tables=block_tables, row_live=row_live,
+            )
+            att = att.reshape(b, t, c.num_heads * c.head_dim)
+            with jax.named_scope("o_proj"):
+                attn_out = attn._o()(lp["attn"]["o"], att)
 
         mlp = GPTNeoXMLP(c)
         if c.parallel_residual:

@@ -46,6 +46,7 @@ class SamplingConfig:
             raise ValueError(f"top_k must be >= 0, got {self.top_k}")
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jax.Array, key: jax.Array, config: SamplingConfig
 ) -> jax.Array:
@@ -93,6 +94,7 @@ def lane_keys(rng_data: jax.Array, index: jax.Array) -> jax.Array:
     return jax.vmap(jax.random.fold_in)(keys, index.astype(jnp.int32))
 
 
+@jax.named_scope("sample")
 def sample_lanes(
     logits: jax.Array,        # (B, V) or (B, T, V)
     rng_data: jax.Array,      # (B, 2) uint32 per-lane key data

@@ -210,6 +210,7 @@ class GPTNeoXMLP:
     def specs(self) -> Params:
         return {"up": self._up().specs(), "down": self._down().specs()}
 
+    @jax.named_scope("mlp")
     def __call__(self, params: Params, x: jax.Array) -> jax.Array:
         h = self._up()(params["up"], x)
         h = jax.nn.gelu(
@@ -278,7 +279,8 @@ class GPTNeoXForCausalLM(LlamaForCausalLM):
 
     def _logits(self, params: Params, hidden: jax.Array) -> jax.Array:
         if self.config.lm_head_bias:
-            return self._lm_head()(params["lm_head"], hidden)
+            with jax.named_scope("lm_head"):
+                return self._lm_head()(params["lm_head"], hidden)
         return super()._logits(params, hidden)
 
     def _rope(self, s: int):

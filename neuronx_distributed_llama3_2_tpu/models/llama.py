@@ -195,6 +195,7 @@ class RMSNorm:
     def specs(self) -> Params:
         return {"scale": P(None)}
 
+    @jax.named_scope("norm")
     def __call__(self, params: Params, x: jax.Array) -> jax.Array:
         h = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(h), axis=-1, keepdims=True)
@@ -227,6 +228,7 @@ class LayerNorm:
             s["bias"] = P(None)
         return s
 
+    @jax.named_scope("norm")
     def __call__(self, params: Params, x: jax.Array) -> jax.Array:
         h = x.astype(jnp.float32)
         mean = jnp.mean(h, axis=-1, keepdims=True)
@@ -399,6 +401,10 @@ class LlamaAttention:
         (GPT-NeoX/CodeGen) override."""
         return apply_rope(q, sin, cos, positions), apply_rope(k, sin, cos, positions)
 
+    # the device-trace scopes of an attention block (serving/tracing.py
+    # SCOPES): attn/qkv, attn/rope, attn/sdpa, attn/o_proj here;
+    # attn/kv_write and attn/kv_read in the cached forward (inference/model.py)
+    @jax.named_scope("attn")
     def __call__(
         self,
         params: Params,
@@ -410,16 +416,30 @@ class LlamaAttention:
         c = self.config
         b = x.shape[0]
         qkv_layer = self._qkv()
-        q, k, v = qkv_layer(params["qkv"], x)
-        if c.clip_qkv is not None:
-            q = jnp.clip(q, -c.clip_qkv, c.clip_qkv)
-            k = jnp.clip(k, -c.clip_qkv, c.clip_qkv)
-            v = jnp.clip(v, -c.clip_qkv, c.clip_qkv)
-        s = q.shape[1]  # global seq len (post SP all-gather under GSPMD)
-        q = q.reshape(b, s, c.num_heads, c.head_dim)
-        k = k.reshape(b, s, c.num_kv_heads, c.head_dim)
-        v = v.reshape(b, s, c.num_kv_heads, c.head_dim)
-        q, k = self._apply_rope(q, k, sin, cos, positions)
+        with jax.named_scope("qkv"):
+            q, k, v = qkv_layer(params["qkv"], x)
+            if c.clip_qkv is not None:
+                q = jnp.clip(q, -c.clip_qkv, c.clip_qkv)
+                k = jnp.clip(k, -c.clip_qkv, c.clip_qkv)
+                v = jnp.clip(v, -c.clip_qkv, c.clip_qkv)
+            s = q.shape[1]  # global seq len (post SP all-gather under GSPMD)
+            q = q.reshape(b, s, c.num_heads, c.head_dim)
+            k = k.reshape(b, s, c.num_kv_heads, c.head_dim)
+            v = v.reshape(b, s, c.num_kv_heads, c.head_dim)
+        with jax.named_scope("rope"):
+            q, k = self._apply_rope(q, k, sin, cos, positions)
+        with jax.named_scope("sdpa"):
+            attn = self._sdpa(qkv_layer, q, k, v)
+        attn = attn.reshape(b, s, c.num_heads * c.head_dim)
+        attn = checkpoint_name(attn, "attn_out")
+        with jax.named_scope("o_proj"):
+            return self._o()(params["o"], attn)
+
+    def _sdpa(self, qkv_layer, q, k, v) -> jax.Array:
+        """KV-head repeat, remat names, and the attention kernel the config
+        and the mesh select; (B, S, N, D) in and out."""
+        c = self.config
+        b = q.shape[0]
 
         # tp > kv_heads: repeat KV heads to tp granularity so the attention
         # activations shard 1 head/device instead of full replication — the
@@ -504,9 +524,7 @@ class LlamaAttention:
             )
         else:
             attn = core_attention(q, k, v, causal=True)
-        attn = attn.reshape(b, s, c.num_heads * c.head_dim)
-        attn = checkpoint_name(attn, "attn_out")
-        return self._o()(params["o"], attn)
+        return attn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -542,6 +560,7 @@ class LlamaMLP:
     def specs(self) -> Params:
         return {"gate_up": P(None, None, TP_AXIS), "down": self._down().specs()}
 
+    @jax.named_scope("mlp")
     def __call__(self, params: Params, x: jax.Array) -> jax.Array:
         y = jnp.einsum("bsh,hti->bsti", x, params["gate_up"])
         y = constrain(y, P(BATCH_AXES, None, None, TP_AXIS))
@@ -768,6 +787,7 @@ class LlamaForCausalLM:
             x = constrain(x, P(BATCH_AXES, None, None))
         return x
 
+    @jax.named_scope("lm_head")
     def _logits(self, params: Params, hidden: jax.Array) -> jax.Array:
         c = self.config
         if c.tie_word_embeddings:

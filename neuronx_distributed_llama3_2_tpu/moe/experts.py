@@ -199,6 +199,7 @@ class ExpertMLPs:
         y = jnp.einsum("tki,tkih->tkh", act, w_dn)  # (T,k,H)
         return jnp.sum(y * gates[:, :, None].astype(y.dtype), axis=1)
 
+    @jax.named_scope("experts")
     def __call__(
         self, params: Params, x: jax.Array, gates: jax.Array, idx: jax.Array
     ) -> jax.Array:

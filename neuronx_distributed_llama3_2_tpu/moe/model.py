@@ -111,6 +111,7 @@ class MoE:
             "experts": self._experts().specs(),
         }
 
+    @jax.named_scope("router")
     def _route(self, router_params: Params, x_flat: jax.Array):
         c = self.config
         logits = self._router()(router_params, x_flat)
@@ -127,6 +128,8 @@ class MoE:
             return 1
         return parallel_state.get_expert_model_parallel_size()
 
+    # device-trace scopes (serving/tracing.py SCOPES): moe/router, moe/experts
+    @jax.named_scope("moe")
     def __call__(
         self, params: Params, x: jax.Array
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -192,13 +195,14 @@ class MoE:
             expert_p = restore_experts(expert_p)
             logits, gates, idx = self._route(router_p, xl)
             cap = experts.capacity(xl.shape[0], c.top_k)
-            buf, slot, keep = experts.dispatch(xl, gates, idx, cap)
-            # (E, C, H) -> (E/ep, ep·C, H): tokens travel to expert owners
-            buf = enter_expert_parallel_region(buf)
-            y = experts._mlp(expert_p, buf)
-            # (E/ep, ep·C, H) -> (E, C, H): outputs return to token owners
-            y = exit_expert_parallel_region(y)
-            out = experts.combine(y, slot, keep, gates, xl.shape[0])
+            with jax.named_scope("experts"):
+                buf, slot, keep = experts.dispatch(xl, gates, idx, cap)
+                # (E, C, H) -> (E/ep, ep·C, H): tokens travel to expert owners
+                buf = enter_expert_parallel_region(buf)
+                y = experts._mlp(expert_p, buf)
+                # (E/ep, ep·C, H) -> (E, C, H): outputs return to token owners
+                y = exit_expert_parallel_region(y)
+                out = experts.combine(y, slot, keep, gates, xl.shape[0])
             return out, logits, idx
 
         token_spec = P((DP_AXIS, EP_AXIS))

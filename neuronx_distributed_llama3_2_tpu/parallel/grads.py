@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("grad_clip")
 def global_norm(tree: Any) -> jax.Array:
     """L2 norm over a gradient pytree (reference get_grad_norm grads.py:33,
     minus the duplicate-grad bookkeeping GSPMD makes unnecessary)."""
@@ -38,6 +39,7 @@ def global_norm(tree: Any) -> jax.Array:
     )
 
 
+@jax.named_scope("grad_clip")
 def clip_grad_norm(tree: Any, max_norm: float) -> Tuple[Any, jax.Array]:
     """Scale the pytree so its global norm is at most ``max_norm``
     (reference clip_grad_norm grads.py:180). Returns (clipped, norm)."""

@@ -220,6 +220,7 @@ class ParallelEmbedding:
             return {"embedding": P(TP_AXIS, None)}
         return {"embedding": P(None, TP_AXIS)}
 
+    @jax.named_scope("embed")
     def __call__(self, params: Params, ids: jax.Array) -> jax.Array:
         y = jnp.take(params["embedding"], ids, axis=0)
         # vocab-sharded: output replicated over tp (post-all-reduce, reference

@@ -71,6 +71,7 @@ def _vocab_parallel_xent_body(
     return jnp.where(valid, loss, 0.0)
 
 
+@jax.named_scope("ce")
 def parallel_cross_entropy(
     logits: jax.Array,
     labels: jax.Array,
@@ -129,6 +130,7 @@ def parallel_cross_entropy(
     return f(logits, labels)
 
 
+@jax.named_scope("ce")
 def fused_linear_cross_entropy(
     hidden: jax.Array,
     logits_fn,

@@ -57,6 +57,13 @@ Params = Dict[str, Any]
 
 SCHEDULES = ("gpipe", "1f1b", "interleaved")
 
+# One entry per schedule an executor below has traced, in order:
+# ``PipelinedCausalLM.schedule_counters()`` of that trace (Python ints, written
+# at trace time — no device work). The benchmark's ``pipeline_bubble_share``
+# reads the last one; ``make_train_step`` puts the same numbers into the
+# step's metrics.
+COMPILED_SCHEDULES: list = []
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _seq_slice(x, start, chunk: int):
@@ -170,6 +177,42 @@ class PipelinedCausalLM:
 
     def _pp(self) -> int:
         return parallel_state.get_pipeline_model_parallel_size()
+
+    def schedule_counters(self) -> Dict[str, int]:
+        """What the schedule's rotation count buys, per lane: every one of
+        ``rotations`` offers a lane one forward and one backward slot (the
+        1F1B executors run both in one rotation; under autodiff the backward
+        slots are the forward scan's rotations run in reverse), and
+        ``useful_lane_rotations`` of those ``2 * rotations`` slots hold a
+        real micro-batch — the others compute on masked data, which no
+        device trace can tell from work. Bubble share =
+        ``1 - useful_lane_rotations / (2 * rotations)``."""
+        pp, M, V = self._pp(), self.num_microbatches, self.num_model_chunks
+        if self.schedule == "1f1b":
+            rotations = M + 2 * (pp - 1)
+        elif self.schedule == "gpipe":
+            rotations = M + pp - 1
+        else:
+            from neuronx_distributed_llama3_2_tpu.pipeline import scheduler
+
+            plan = (
+                scheduler.Interleaved1F1BPlan if self.memory_bounded_backward
+                else scheduler.InterleavedRotationPlan
+            )
+            rotations = plan(M, V, pp).num_rotations
+        return {
+            "rotations": rotations, "useful_lane_rotations": 2 * M * V,
+        }
+
+    def _note_compiled(self, rotations: int) -> None:
+        """An executor traced ``rotations`` rotations: record the counters,
+        which have to be the ones the scan really runs."""
+        counters = self.schedule_counters()
+        assert counters["rotations"] == rotations, (counters, rotations)
+        COMPILED_SCHEDULES.append({
+            "schedule": self.schedule, "pp": self._pp(),
+            "num_microbatches": self.num_microbatches, **counters,
+        })
 
     def _layers_per_stage(self) -> int:
         L, pp = self.config.num_layers, self._pp()
@@ -347,6 +390,10 @@ class PipelinedCausalLM:
             cp_layout_from_inv,
         )
 
+        if self.schedule == "gpipe":
+            # (a 1F1B model's forward-only loss also scans this: not its
+            # training schedule)
+            self._note_compiled(M + pp - 1)
         with cp_layout_from_inv(zz_inv):
             (stream, out_buf, aux_sum), _ = lax.scan(
                 rotate, (stream, out_buf, jnp.float32(0.0)),
@@ -390,6 +437,8 @@ class PipelinedCausalLM:
         )
 
         plan = InterleavedRotationPlan(M, V, pp)
+        if not self.uses_manual_vjp:
+            self._note_compiled(plan.num_rotations)
 
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (mbs, S))
         sin, cos = self.model._rope(S)
@@ -557,6 +606,7 @@ class PipelinedCausalLM:
             hp["lm_head"] = params["lm_head"]
         return hp
 
+    @jax.named_scope("ce")
     def _head_loss_sum(self, head_params: Params, h: jax.Array, labels_m):
         """Un-normalized CE sum for one microbatch's final hidden states."""
         cfg = self.config
@@ -574,6 +624,7 @@ class PipelinedCausalLM:
         )
         return loss_sum
 
+    @jax.named_scope("ce")
     def _head_loss_sum_slice(
         self, head_params: Params, h: jax.Array, labels_m, lane, pp: int
     ):
@@ -644,6 +695,7 @@ class PipelinedCausalLM:
         H = cfg.hidden_size
         D = 2 * pp - 1  # stash ring depth ≥ max in-flight (2(pp-1)) + 1
         T = M + 2 * (pp - 1)
+        self._note_compiled(T)
         mesh = parallel_state.get_parallel_state().mesh
 
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (mbs, S))
@@ -926,6 +978,7 @@ class PipelinedCausalLM:
         plan = Interleaved1F1BPlan(M, V, pp)
         D = plan.stash_depth
         T = plan.num_rotations
+        self._note_compiled(T)
         split_head = self.head_sequence_split and pp > 1
 
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (mbs, S))

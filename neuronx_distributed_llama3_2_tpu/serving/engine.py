@@ -36,6 +36,7 @@ than the dense engine, so sampled streams are valid, not bit-matching.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
@@ -96,6 +97,21 @@ logger = get_logger()
 
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _in_scope(name: str, fn):
+    """``fn`` traced under ``jax.named_scope(name)``: every HLO instruction
+    of the program carries ``name`` as the first segment of its ``op_name``
+    path, which is how a device trace tells the serving programs apart
+    (serving/tracing.py ``PROGRAM_SCOPES``). ``functools.wraps`` keeps the
+    callable's ``__name__``, so the XLA module is called what it was."""
+
+    @functools.wraps(fn)
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    return scoped
 
 
 def _aval_of(x):
@@ -896,10 +912,14 @@ class PagedServingEngine:
         cached in the ``_programs`` registry, so ``graftcheck``'s
         ``audit_programs`` can see (and re-lower / retrace) the complete
         compiled-program population. shardlint SL007 flags any donated
-        jit in ``serving/`` created anywhere else."""
+        jit in ``serving/`` created anywhere else. The program is traced
+        inside a named scope of its kind (``pctx``, ``psfx``, ``pdecode``,
+        ...), here and wherever graftcheck retraces ``rec.fn``."""
+        kind = kind if kind is not None else str(key_[0])
+        fn = _in_scope(kind, fn)
         rec = ProgramRecord(
             key=key_,
-            kind=kind if kind is not None else str(key_[0]),
+            kind=kind,
             fn=fn,
             donate_argnums=tuple(donate_argnums),
             gather=gather,
@@ -1714,6 +1734,7 @@ class PagedServingEngine:
             ms = (req.first_token_at - req.submitted_at) * 1e3
             self.metrics.hist_ttft_ms.observe(ms)
             self.metrics.observe_class_latency("ttft", req.service_class, ms)
+            self.tracer.mark("first_token", req.rid, step=self._step_index)
 
     def _note_terminal(self, req: _PagedRequest) -> None:
         """Terminal transition (finished or failed): stamp the end time and
@@ -3872,15 +3893,6 @@ class PagedServingEngine:
             self._last_log_step = steps
             self.metrics.log(logger, self.allocator, self.index)
         self._check_stall()
-        if self.tracer.enabled:
-            m = self.metrics
-            self.tracer.counter(
-                "graftmeter",
-                decode_pad_tokens=m.decode_pad_tokens,
-                prefill_pad_tokens=m.prefill_pad_tokens,
-                dispatched_flops=m.dispatched_flops,
-                mfu_est=round(m.mfu_estimate(), 6),
-            )
         self.tracer.end_step(
             queue=len(self._queue), active=len(self._active),
             wait_ms=round(self._wait_ms, 3),

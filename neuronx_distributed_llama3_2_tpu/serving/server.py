@@ -110,15 +110,27 @@ class GraftServer:
 
     async def _drive(self) -> None:
         assert self._wake is not None
+        tr = self.engine.tracer
         try:
             while not self._closed:
+                # graftscope (serving/tracing.py): the three parts of a turn
+                # — drive.step, drive.pump, drive.yield — tile the loop's
+                # time; off, the tracer costs this one attribute test a turn
+                traced = tr.enabled
+                t0 = tr.now() if traced else 0.0
                 if self.engine._queue or self.engine._active:
                     self.engine.step()
+                    t1 = tr.now() if traced else 0.0
                     self._pump()
+                    t2 = tr.now() if traced else 0.0
                     # yield between steps: submits, cancels, and stream
                     # consumers run here, honoring the engine's
                     # between-steps mutation contract
                     await asyncio.sleep(0)
+                    if traced:
+                        tr.drive_turn(
+                            self.engine._step_index, t0, t1, t2, tr.now()
+                        )
                 else:
                     self._pump()
                     self._wake.clear()
@@ -128,6 +140,8 @@ class GraftServer:
                         )
                     except asyncio.TimeoutError:
                         pass
+                    if traced:
+                        tr.drive_idle(t0, tr.now())
         except Exception:
             logger.exception("graftserve driver crashed")
             raise
@@ -135,9 +149,12 @@ class GraftServer:
     def _pump(self) -> None:
         """Push newly committed tokens into every open stream; close the
         stream (sentinel) once its request is terminal."""
+        tr = self.engine.tracer
         for rid in list(self._streams):
             q, sent = self._streams[rid]
             toks = self.engine.request_tokens(rid)
+            if tr.enabled and not sent and toks:
+                tr.note_first_pump(rid)
             for t in toks[sent:]:
                 q.put_nowait(t)
             self._streams[rid] = (q, len(toks))
@@ -174,6 +191,8 @@ class GraftServer:
             raise RuntimeError(f"request {rid} already has an open stream")
         q: asyncio.Queue = asyncio.Queue()
         toks = self.engine.request_tokens(rid)
+        if toks and self.engine.tracer.enabled:
+            self.engine.tracer.note_first_pump(rid)
         for t in toks:
             q.put_nowait(t)
         if self.engine.request_info(rid)["done"]:
@@ -300,6 +319,10 @@ class GraftServer:
         reader: asyncio.StreamReader,
         writer: asyncio.StreamWriter,
     ) -> None:
+        # graftscope: the `request` root of this connection (None when
+        # tracing is off — every later hook tests the record)
+        tr = self.engine.tracer
+        door = tr.open_request()
         try:
             request_line = (await reader.readline()).decode("latin-1")
             if not request_line.strip():
@@ -316,7 +339,9 @@ class GraftServer:
             length = int(headers.get("content-length", 0) or 0)
             if length:
                 body = await reader.readexactly(length)
-            await self._route(writer, method.upper(), target, body)
+            if door is not None:
+                tr.door_span(door, "door.read", door["t0"], tr.now())
+            await self._route(writer, method.upper(), target, body, door)
         except (asyncio.IncompleteReadError, ConnectionError):
             pass
         except Exception as exc:  # malformed request: answer, don't die
@@ -329,6 +354,8 @@ class GraftServer:
             except ConnectionError:
                 pass
         finally:
+            if door is not None:
+                tr.close_request(door)
             try:
                 writer.close()
                 await writer.wait_closed()
@@ -341,6 +368,7 @@ class GraftServer:
         method: str,
         target: str,
         body: bytes,
+        door: Optional[dict] = None,
     ) -> None:
         if method == "GET" and target == "/metrics":
             await self._send(
@@ -364,8 +392,13 @@ class GraftServer:
                 service_class=req.get("service_class", "batch"),
                 tenant=req.get("tenant", "default"),
             )
+            if door is not None:
+                tr = self.engine.tracer
+                parsed = door["spans"][-1][2]      # where door.read ended
+                tr.door_span(door, "door.submit", parsed, tr.now())
+                tr.bind_request(door, rid)
             if req.get("stream"):
-                await self._send_stream(writer, rid)
+                await self._send_stream(writer, rid, door)
             else:
                 async for _ in self.stream(rid):
                     pass
@@ -412,7 +445,8 @@ class GraftServer:
         )
 
     async def _send_stream(
-        self, writer: asyncio.StreamWriter, rid: int
+        self, writer: asyncio.StreamWriter, rid: int,
+        door: Optional[dict] = None,
     ) -> None:
         writer.write(
             b"HTTP/1.1 200 OK\r\n"
@@ -426,6 +460,13 @@ class GraftServer:
                 f"data: {json.dumps({'token': tok})}\n\n".encode()
             )
             await writer.drain()
+            if door is not None and door["first_pump"] is not None:
+                # the first SSE chunk is out: door.first_write, once
+                tr = self.engine.tracer
+                tr.door_span(
+                    door, "door.first_write", door["first_pump"], tr.now()
+                )
+                door = None
         final = json.dumps(self.response(rid))
         writer.write(f"data: {final}\n\ndata: [DONE]\n\n".encode())
         await writer.drain()

@@ -1,7 +1,8 @@
 """graftscope: the serving engine's flight recorder and span tracer.
 
-Two recorders behind one object, both pure host-side python at the
-engine's existing funnels (the same choke points the chaos layer hooks):
+Three recorders behind one object, all pure host-side python at the
+engine's and the front door's existing funnels (the same choke points the
+chaos layer hooks):
 
 - a **ring-buffer step flight recorder** — each ``step()`` owns a list
   of phase events (admit wave, prefill chunk, decode/verify dispatch
@@ -11,11 +12,30 @@ engine's existing funnels (the same choke points the chaos layer hooks):
   steps are retained, so memory is bounded however long the engine runs;
 - a **per-request span recorder** — monotonic ``(timestamp, state)``
   transitions through ``queued → prefilling → active → preempted →
-  finished/failed``; terminal requests move to a bounded deque.
+  finished/failed``; terminal requests move to a bounded deque. The
+  engine also leaves a ``first_token`` mark (rid, step index) when it
+  commits a request's first generated token;
+- a **front-door recorder** (``GraftServer`` writes it) — one ``request``
+  root per accepted connection, from socket accepted to the last byte of
+  the response, with the children ``door.read``, ``door.submit`` (which
+  binds the connection to its ``rid``, so the lifecycle states above are
+  the same request's children) and ``door.first_write``; and the three
+  parts of every turn of the server's driver loop, ``drive.step`` (the
+  parent of the engine's step record of the same index), ``drive.pump``
+  and ``drive.yield`` (``drive.idle`` while the loop is parked).
+
+One clock: while enabled, every step is also a
+``jax.profiler.TraceAnnotation("graft.step", step=<index>)``. It costs next
+to nothing while no profile is being taken; while one is, it lands on the
+host plane of the ``.xplane.pb`` with its ``step`` stat, and a reader joins
+it to the step record of the same index — the offset between
+``time.perf_counter()`` and the profile's clock puts every span above on
+the device trace's timeline (``benchmarks/program_trace.py``).
 
 Everything exports as Chrome trace-event JSON (``chrome://tracing`` /
 https://ui.perfetto.dev — pid 0 is the engine step timeline, pid 1 is
-one thread per request) or as jsonl for ad-hoc grepping.
+one thread per request, pid 2 the server's driver loop) or as jsonl for
+ad-hoc grepping.
 
 Zero-interference contract (asserted in tests/test_tracing.py and the
 graftcheck gate): tracing records around device work, never in it — no
@@ -33,6 +53,31 @@ from typing import Any, Dict, List, Optional, Tuple
 
 # request states that end a span and retire it to the done-deque
 TERMINAL_STATES = ("finished", "failed")
+
+# The vocabulary of ``jax.named_scope`` names on the device work. A scope is
+# a segment of an HLO instruction's ``op_name`` path (``tf_op`` in a device
+# trace), so the readers in ``benchmarks/`` match whole segments of it.
+# PROGRAM_SCOPES open a jitted program (``_register_program`` enters the
+# program's kind, ``make_train_step`` enters ``train_step``; the jitted
+# callable keeps its name); BLOCK_SCOPES sit where the blocks are defined, so
+# training and serving share them; CHILD_SCOPES only ever appear under their
+# parent (``attn/qkv``, ``moe/router``).
+PROGRAM_SCOPES = ("pctx", "psfx", "pdecode", "train_step")
+BLOCK_SCOPES = (
+    "embed", "norm", "attn", "mlp", "moe", "lm_head", "ce", "sample",
+    "grad_clip", "optimizer",
+)
+CHILD_SCOPES = {
+    "attn": ("qkv", "rope", "kv_write", "kv_read", "sdpa", "o_proj"),
+    "moe": ("router", "experts"),
+}
+SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
+    f"{parent}/{child}"
+    for parent, children in CHILD_SCOPES.items() for child in children
+)
+
+# the annotation every traced step opens on the profiler's host timeline
+STEP_ANNOTATION = "graft.step"
 
 # event tuple layout inside a step record: (ph, name, t0, t1, args)
 # ph "X" = duration slice (t1 = end), ph "i" = instant (t1 unused)
@@ -99,6 +144,20 @@ class EngineTracer:
         # retire to _done so memory stays bounded under churn
         self._spans: Dict[int, List[Tuple[float, str]]] = {}
         self._done: deque = deque(maxlen=max(int(max_requests), 1))
+        # front door: one record per accepted connection (open ones by rid
+        # once bound), marks (name, ts, rid, args), driver-loop turns
+        self._conn = 0
+        self._doors: Dict[int, dict] = {}
+        self._doors_done: deque = deque(maxlen=max(int(max_requests), 1))
+        self._marks: deque = deque(maxlen=max(int(max_requests), 1))
+        self._drive: deque = deque(maxlen=self.buffer_steps)
+        # the open step's jax.profiler.TraceAnnotation, if any
+        self._annotation: Any = None
+        self._annotate = None
+        if self.enabled:
+            from jax.profiler import TraceAnnotation
+
+            self._annotate = TraceAnnotation
 
     # ------------------------------------------------------------------
     # recording hooks (every one is a no-op unless enabled)
@@ -111,13 +170,21 @@ class EngineTracer:
     def begin_step(self, index: int) -> None:
         if not self.enabled:
             return
+        if self._annotation is not None:   # a step that raised never ended
+            self._annotation.__exit__(None, None, None)
         self._cur = []
         self._step_idx = index
+        # t0 and the annotation's start are the two ends of the clock join:
+        # nothing runs between them
+        self._annotation = self._annotate(STEP_ANNOTATION, step=index)
         self._step_t0 = time.perf_counter()
+        self._annotation.__enter__()
 
     def end_step(self, **args: Any) -> None:
         if not self.enabled or self._cur is None:
             return
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
         self._steps.append({
             "step": self._step_idx,
             "t0": self._step_t0,
@@ -150,21 +217,81 @@ class EngineTracer:
             return
         self._cur.append(("i", name, time.perf_counter(), None, args))
 
-    def counter(self, name: str, **values: Any) -> None:
-        """Chrome counter sample (ph "C"): a named set of numeric series
-        the trace viewer plots as stacked graphs over the step timeline —
-        graftmeter emits its cumulative pad/FLOP counters here once per
-        traced step. Same drop rule as :meth:`instant`."""
-        if not self.enabled or self._cur is None:
-            return
-        self._cur.append(("C", name, time.perf_counter(), None, values))
-
     def request_state(self, rid: int, state: str) -> None:
         if not self.enabled:
             return
         self._spans.setdefault(rid, []).append((time.perf_counter(), state))
         if state in TERMINAL_STATES:
             self._done.append((rid, self._spans.pop(rid)))
+
+    def mark(self, name: str, rid: int, **args: Any) -> None:
+        """Point event of one request (``first_token``)."""
+        if not self.enabled:
+            return
+        self._marks.append((name, time.perf_counter(), rid, args))
+
+    # -- front door (GraftServer) ------------------------------------------
+
+    def open_request(self) -> Optional[dict]:
+        """Root ``request`` record of a connection just accepted, or None
+        when tracing is off — the server keeps the record while it handles
+        the connection and tests it, not ``enabled``, at every later hook."""
+        if not self.enabled:
+            return None
+        self._conn += 1
+        return {"conn": self._conn, "rid": None, "t0": time.perf_counter(),
+                "t1": None, "spans": [], "first_pump": None}
+
+    @staticmethod
+    def door_span(door: dict, name: str, t0: float, t1: float) -> None:
+        """Child span of a ``request`` root."""
+        door["spans"].append((name, t0, t1))
+
+    def bind_request(self, door: dict, rid: int) -> None:
+        """``engine.submit`` returned: the connection is request ``rid``."""
+        door["rid"] = rid
+        self._doors[rid] = door
+
+    def note_first_pump(self, rid: int) -> None:
+        """The server queued ``rid``'s first token for its stream: where
+        ``door.first_write`` starts."""
+        door = self._doors.get(rid)
+        if door is not None and door["first_pump"] is None:
+            door["first_pump"] = time.perf_counter()
+
+    def close_request(self, door: dict) -> None:
+        """Last byte of the response written."""
+        door["t1"] = time.perf_counter()
+        if door["rid"] is not None:
+            self._doors.pop(door["rid"], None)
+        self._doors_done.append(door)
+
+    def drive_turn(self, step: int, t0: float, t1: float, t2: float,
+                   t3: float) -> None:
+        """One turn of the server's driver loop: ``drive.step`` [t0, t1)
+        around ``engine.step()`` number ``step``, ``drive.pump`` [t1, t2),
+        ``drive.yield`` [t2, t3) in which the loop ran its other tasks."""
+        self._drive.append((step, t0, t1, t2, t3))
+
+    def drive_idle(self, t0: float, t1: float) -> None:
+        """The driver parked with no work: ``drive.idle``."""
+        self._drive.append((None, t0, t1, t1, t1))
+
+    def timeline(self) -> dict:
+        """Everything recorded, as plain lists on ``time.perf_counter()``'s
+        clock — what the benchmark's readers take: ``steps`` (the flight
+        recorder's records), ``requests`` (front-door roots, finished and
+        open), ``states`` (rid -> [(ts, state)]), ``marks`` [(name, ts,
+        rid, args)], ``drive`` [(step, t0, t1, t2, t3)]."""
+        states = {rid: list(trans) for rid, trans in self._done}
+        states.update({rid: list(t) for rid, t in self._spans.items()})
+        return {
+            "steps": list(self._steps),
+            "requests": list(self._doors_done) + list(self._doors.values()),
+            "states": states,
+            "marks": list(self._marks),
+            "drive": list(self._drive),
+        }
 
     # ------------------------------------------------------------------
     # export
@@ -175,15 +302,21 @@ class EngineTracer:
         return round(t * 1e6, 1)
 
     def chrome_events(self) -> List[dict]:
-        """Flatten both recorders into Chrome trace-event dicts: pid 0 =
+        """Flatten the recorders into Chrome trace-event dicts: pid 0 =
         engine step timeline (one outer slice per step, phase slices and
         instants nested inside), pid 1 = requests (tid = rid, one slice
-        per lifecycle state, instants at terminal transitions)."""
+        per lifecycle state, instants at terminal transitions, the front
+        door's ``request`` root and ``door.*`` children and the
+        ``first_token`` mark on the same thread; a connection that never
+        became a request sits on thread ``-conn``), pid 2 = the server's
+        driver loop (``drive.step`` / ``drive.pump`` / ``drive.yield``)."""
         evs: List[dict] = [
             {"ph": "M", "name": "process_name", "pid": 0, "tid": 0,
              "args": {"name": "engine steps"}},
             {"ph": "M", "name": "process_name", "pid": 1, "tid": 0,
              "args": {"name": "requests"}},
+            {"ph": "M", "name": "process_name", "pid": 2, "tid": 0,
+             "args": {"name": "server driver loop"}},
         ]
         for rec in self._steps:
             evs.append({
@@ -197,8 +330,6 @@ class EngineTracer:
                       "tid": 0, "ts": self._us(t0), "args": args}
                 if ph == "X":
                     ev["dur"] = self._us(t1 - t0)
-                elif ph == "C":
-                    ev["cat"] = "counter"
                 else:
                     ev["cat"] = "event"
                     ev["s"] = "p"       # process-scoped instant
@@ -218,7 +349,35 @@ class EngineTracer:
                 end = trans[i + 1][0] if i + 1 < len(trans) else ts
                 evs.append({"ph": "X", "name": state, "cat": "request",
                             "pid": 1, "tid": rid, "ts": self._us(ts),
-                            "dur": self._us(end - ts), "args": {"rid": rid}})
+                            "dur": self._us(end - ts),
+                            "args": {"rid": rid, "parent": "request"}})
+        for door in list(self._doors_done) + list(self._doors.values()):
+            rid, conn = door["rid"], door["conn"]
+            tid = rid if rid is not None else -conn
+            ids = {"rid": rid, "conn": conn}
+            end = door["t1"] if door["t1"] is not None else door["t0"]
+            evs.append({"ph": "X", "name": "request", "cat": "door",
+                        "pid": 1, "tid": tid, "ts": self._us(door["t0"]),
+                        "dur": self._us(end - door["t0"]), "args": ids})
+            for name, t0, t1 in door["spans"]:
+                evs.append({"ph": "X", "name": name, "cat": "door",
+                            "pid": 1, "tid": tid, "ts": self._us(t0),
+                            "dur": self._us(t1 - t0),
+                            "args": {**ids, "parent": "request"}})
+        for name, ts, rid, args in self._marks:
+            evs.append({"ph": "i", "name": name, "cat": "request", "pid": 1,
+                        "tid": rid, "ts": self._us(ts), "s": "t",
+                        "args": {"rid": rid, **args}})
+        for step, t0, t1, t2, t3 in self._drive:
+            if step is None:
+                parts = (("drive.idle", t0, t1),)
+            else:
+                parts = (("drive.step", t0, t1), ("drive.pump", t1, t2),
+                         ("drive.yield", t2, t3))
+            for name, a, b in parts:
+                evs.append({"ph": "X", "name": name, "cat": "drive",
+                            "pid": 2, "tid": 0, "ts": self._us(a),
+                            "dur": self._us(b - a), "args": {"step": step}})
         return evs
 
     def export(self, path: str, fmt: str = "chrome") -> str:

@@ -107,6 +107,7 @@ def optimizer_state_specs(
     )
 
 
+@jax.named_scope("optimizer")
 def apply_gradients(
     state: OptimizerState,
     grads: Any,

@@ -161,6 +161,10 @@ def make_train_step(
     else:
         grad_fn = jax.value_and_grad(loss_fn)
 
+    # every instruction of the program carries `train_step` as the first
+    # segment of its op_name path (serving/tracing.py PROGRAM_SCOPES); the
+    # blocks' own scopes (attn, mlp, ce, optimizer, ...) nest under it
+    @jax.named_scope("train_step")
     def train_step(state: TrainState, batch) -> Tuple[TrainState, dict]:
         input_ids, labels = batch["input_ids"], batch["labels"]
         input_ids = jax.lax.with_sharding_constraint(
@@ -254,7 +258,30 @@ def make_train_step(
         }
         return TrainState(params=new_params, opt=new_opt), metrics
 
-    return jax.jit(train_step, donate_argnums=0)
+    step = jax.jit(train_step, donate_argnums=0)
+    counters = getattr(model, "schedule_counters", None)
+    return step if counters is None else _StepWithCounters(step, counters)
+
+
+class _StepWithCounters:
+    """The jitted step of a pipelined model, with the schedule's counters
+    (``rotations``, ``useful_lane_rotations``: Python ints, no device work)
+    merged into the metrics it returns. Everything else — ``lower``,
+    ``trace``, ``_cache_size`` — is the jitted function's own."""
+
+    def __init__(self, step, counters: Callable[[], dict]):
+        self._step = step
+        self._read = counters   # reads the mesh, so not before the first step
+        self._counters: Optional[dict] = None
+
+    def __call__(self, state, batch):
+        state, metrics = self._step(state, batch)
+        if self._counters is None:
+            self._counters = self._read()
+        return state, {**metrics, **self._counters}
+
+    def __getattr__(self, name):
+        return getattr(self._step, name)
 
 
 def make_eval_step(model, config: TrainingConfig) -> Callable:

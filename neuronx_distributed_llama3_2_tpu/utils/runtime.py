@@ -25,7 +25,13 @@ def enable_compile_cache() -> str:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
     nothing is touched — the cache can be placed from outside. Otherwise the
     cache goes to the fixed ``<checkout>/.jax_cache``. This is the only
-    place in the repo that sets ``jax_compilation_cache_dir``."""
+    place in the repo that sets ``jax_compilation_cache_dir``.
+
+    The cache key includes the HLO metadata: ``jax.named_scope`` names live
+    there (``op_name``), and with JAX's default key a program cached before a
+    scope was added is served in place of the scoped one — same instructions,
+    old names, and a device trace that cannot be told apart by scope."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(COMPILE_CACHE_ENV)
     if placed:
         return placed

@@ -18,12 +18,16 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # -- compile cache ----------------------------------------------------------
 
+# named scopes live in HLO metadata, which JAX's default cache key ignores
+METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
 def test_compile_cache_placed_from_outside_is_left_alone(monkeypatch):
     monkeypatch.setenv(runtime.COMPILE_CACHE_ENV, "/x")
     updates = []
     monkeypatch.setattr(jax.config, "update", lambda *a: updates.append(a))
     assert runtime.enable_compile_cache() == "/x"
-    assert updates == []
+    assert updates == [METADATA_IN_KEY]       # the directory is never touched
 
 
 def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(monkeypatch):
@@ -33,7 +37,7 @@ def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(monkeypatch):
     want = os.path.join(REPO, ".jax_cache")
     assert runtime.enable_compile_cache() == want
     assert runtime.enable_compile_cache() == want  # no pid, no timestamp
-    assert updates == [("jax_compilation_cache_dir", want)] * 2
+    assert updates == [METADATA_IN_KEY, ("jax_compilation_cache_dir", want)] * 2
 
 
 # -- peaks ------------------------------------------------------------------
