@@ -39,6 +39,10 @@ from neuronx_distributed_llama3_2_tpu.inference.model import (
     LlamaDecode,
     decode_model_for,
 )
+from neuronx_distributed_llama3_2_tpu.inference.placement import (
+    committed_home,
+    rest_fused_weights,
+)
 from neuronx_distributed_llama3_2_tpu.inference.sampling import (
     SamplingConfig,
     sample,
@@ -130,7 +134,10 @@ class InferenceEngine:
     ) -> None:
         self.config = config
         self.model = decode_model_for(config)
-        self.params = params
+        # fused (..., in, 2, out) leaves rest in the layout their matmul
+        # reads (inference/placement.py), placed before the cache exists:
+        # the transient is one leaf beside the weights
+        self.params, self.placement = rest_fused_weights(params)
         self.max_batch = max_batch
         self.max_seq_len = max_seq_len
         self.buckets = list(buckets) if buckets else default_buckets(max_seq_len)
@@ -152,6 +159,10 @@ class InferenceEngine:
             self.cache = shard_pytree(
                 self.cache, self.model.cache_specs(max_batch)
             )
+        elif (home := committed_home(self.params)) is not None:
+            # born committed beside committed weights, as every program
+            # returns it: one lowering a program, not two
+            self.cache = jax.device_put(self.cache, home)
         self._programs: Dict[Tuple, Callable] = {}
 
     def _live_params(self, params):
@@ -297,8 +308,10 @@ class InferenceEngine:
 
     @staticmethod
     def _abstract(tree):
+        # the format, not the sharding alone: a compiled program refuses an
+        # argument whose layout is not the one it was lowered for
         return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.format),
             tree,
         )
 

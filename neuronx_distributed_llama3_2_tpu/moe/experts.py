@@ -20,6 +20,16 @@ reference's dispatch (:298-357):
   scale by gates.
 - EP execution lives in :mod:`.model` (shard_map + all-to-all); the math here
   is mesh-agnostic global code usable inside or outside shard_map.
+
+``gate_up`` is logically ``(E, H, 2, I)`` (stacked ``(L, E, H, 2, I)``) here,
+in checkpoints and in training. The serving engines give it another
+*physical* layout — major-to-minor ``(L, E, 2, H, I)``, the size-2 axis
+ahead of the contraction axis (``inference/placement.py``): tiled by
+default on a TPU, a second-minor axis of extent 2 makes the parameter
+``T(2,128)``, and the einsum in :meth:`ExpertMLPs._mlp`, which wants ``(H, I)`` minor in
+``T(8,128)``, re-tiles a whole layer (1.88 GB at Mixtral's widths) before
+every matmul. At rest in the order the dot converts to, the layer scan's
+slice fuses into the dot. Indexing (``gate_up[..., 0, :]``) is unchanged.
 """
 
 from __future__ import annotations

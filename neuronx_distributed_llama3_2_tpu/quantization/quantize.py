@@ -208,7 +208,7 @@ def _match(path_key: str, patterns) -> bool:
     return any(re.search(p, path_key) for p in patterns)
 
 
-def _reduce_axes_for(path: str, ndim: int) -> Optional[Tuple[int, ...]]:
+def fused_reduce_axes(path: str, ndim: int) -> Optional[Tuple[int, ...]]:
     """Fused gate_up tensors (..., in, 2, out) carry their contraction axis
     third-from-last; everything else uses the (..., in, out) default."""
     if path.endswith("gate_up") and ndim >= 3:
@@ -216,16 +216,16 @@ def _reduce_axes_for(path: str, ndim: int) -> Optional[Tuple[int, ...]]:
     return None
 
 
-def _walk(tree: Any, fn, path: str = "") -> Any:
+def walk_tree(tree: Any, fn, path: str = "") -> Any:
     """Recurse dict/list/tuple pytrees applying fn(path, leaf) at leaves.
     List indices become path segments (Mllama keeps its text layers as a
     per-layer list, not a stacked array — without list recursion the whole
     family silently escaped quantization)."""
     if isinstance(tree, dict):
-        return {k: _walk(v, fn, f"{path}/{k}" if path else k) for k, v in tree.items()}
+        return {k: walk_tree(v, fn, f"{path}/{k}" if path else k) for k, v in tree.items()}
     if isinstance(tree, (list, tuple)):
         out = [
-            _walk(v, fn, f"{path}/{i}" if path else str(i))
+            walk_tree(v, fn, f"{path}/{i}" if path else str(i))
             for i, v in enumerate(tree)
         ]
         return type(tree)(out)
@@ -245,11 +245,11 @@ def quantize_params(
     def visit(path, leaf):
         if isinstance(leaf, jax.Array) and leaf.ndim >= 2 and _match(path, targets):
             return quantize_array(
-                leaf, config, reduce_axes=_reduce_axes_for(path, leaf.ndim)
+                leaf, config, reduce_axes=fused_reduce_axes(path, leaf.ndim)
             )
         return leaf
 
-    return _walk(params, visit)
+    return walk_tree(params, visit)
 
 
 def quantize_specs(
@@ -262,7 +262,7 @@ def quantize_specs(
     become QuantizedTensor(kernel_spec, scale_spec)."""
 
     flat_p: Dict[str, Any] = {}
-    _walk(params, lambda p, l: flat_p.setdefault(p, l))
+    walk_tree(params, lambda p, l: flat_p.setdefault(p, l))
 
     def visit(path, spec):
         leaf = flat_p.get(path)
@@ -271,12 +271,12 @@ def quantize_specs(
                 spec,
                 scale_spec(
                     spec, config, leaf.ndim,
-                    reduce_axes=_reduce_axes_for(path, leaf.ndim),
+                    reduce_axes=fused_reduce_axes(path, leaf.ndim),
                 ),
             )
         return spec
 
-    return _walk(specs, visit)
+    return walk_tree(specs, visit)
 
 
 def dequantize_params(params: Params, dtype=jnp.bfloat16) -> Params:

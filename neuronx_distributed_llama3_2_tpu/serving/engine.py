@@ -61,6 +61,9 @@ from neuronx_distributed_llama3_2_tpu.serving.faults import (
     FaultInjector,
     InjectedFault,
 )
+from neuronx_distributed_llama3_2_tpu.inference.placement import (
+    committed_home,
+)
 from neuronx_distributed_llama3_2_tpu.inference.sampling import (
     GREEDY_TEMPERATURE,
     SamplingConfig,
@@ -119,6 +122,12 @@ def _aval_of(x):
     what a :class:`ProgramRecord` remembers about its first dispatch so
     the auditor can re-lower/retrace without holding live buffers."""
     if hasattr(x, "shape") and hasattr(x, "dtype"):
+        if getattr(x, "committed", False):
+            # a committed argument keeps its placement, sharding and layout
+            # (a weight may rest in a layout of its own, inference/
+            # placement.py): re-lowering is then the dispatch's own lowering,
+            # a cache hit, and not a second program
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.format)
         return jax.ShapeDtypeStruct(x.shape, x.dtype)
     return x
 
@@ -614,7 +623,7 @@ class PagedServingEngine:
         # so constructing the residents on the SAME sharding keeps every
         # dispatch on one lowering (uncommitted single-device inputs would
         # re-lower each program on its second call — graftcheck GC008)
-        self._replicated_sharding = None
+        self._resident_sharding = None
         if parallel_state.model_parallel_is_initialized():
             # the pool is born sharded: each device zero-fills only its own
             # kv-head slice, so a tp mesh can hold a pool tp× one chip's —
@@ -626,9 +635,15 @@ class PagedServingEngine:
             )
             self.cache = jax.jit(init_pool, out_shardings=shardings)()
             if mesh.size > 1:
-                self._replicated_sharding = jax.sharding.NamedSharding(
+                self._resident_sharding = jax.sharding.NamedSharding(
                     mesh, jax.sharding.PartitionSpec()
                 )
+        elif (home := committed_home(engine.params)) is not None:
+            # committed weights on one device (a re-placed leaf is): the
+            # pool and the residents are born committed beside them, for
+            # the same reason as on a mesh
+            self.cache = jax.jit(init_pool, out_shardings=home)()
+            self._resident_sharding = home
         else:
             self.cache = init_pool()
         self.allocator = BlockAllocator(paged.num_blocks, bs)
@@ -1002,6 +1017,28 @@ class PagedServingEngine:
             }
         m.mfu_by_rung = by_rung
         return profiles
+
+    def _setup_facts(self) -> Dict[str, int]:
+        """What construction did, for the tracer's ``setup`` record: the
+        fused weight leaves the engine re-placed and their bytes
+        (inference/placement.py), and the largest ``temp_size_in_bytes``
+        among the dispatched programs — which is where a per-layer copy of
+        a weight shows. Each record is lowered as it was dispatched and
+        compiled for its memory analysis (a persistent-cache hit where
+        there is a cache, a compile where there is none), so traced
+        engines only."""
+        from neuronx_distributed_llama3_2_tpu.serving.accounting import (
+            harvest_cost_profiles,
+        )
+
+        profiles = harvest_cost_profiles(self, deep=True)
+        return {
+            "relaid_leaves": self.engine.placement["leaves"],
+            "relaid_bytes": self.engine.placement["bytes"],
+            "program_temp_bytes_max": max(
+                (p.temp_bytes for p in profiles.values()), default=0
+            ),
+        }
 
     def _kv_bucket(self, needed: int) -> int:
         """kv_limit rung covering ``needed`` rows over the serving kv
@@ -1488,9 +1525,9 @@ class PagedServingEngine:
         explorer audits. The copy severs the alias so donation can only
         ever recycle device-owned storage."""
         pinned = jnp.array(x, copy=True)
-        if self._replicated_sharding is None:
+        if self._resident_sharding is None:
             return pinned
-        return jax.device_put(pinned, self._replicated_sharding)
+        return jax.device_put(pinned, self._resident_sharding)
 
     def _upload(self, x, dtype=jnp.int32):
         """Every host→device transfer on the serving path funnels through
@@ -2110,6 +2147,8 @@ class PagedServingEngine:
             # graftmeter: every catalog key just compiled — harvest the
             # device-cost ledger while the lowerings are trace-cache warm
             self.ensure_cost_profiles()
+        if self.tracer.enabled:
+            self.tracer.setup = self._setup_facts()
 
     # -- request lifecycle -------------------------------------------------
 

@@ -151,6 +151,9 @@ class EngineTracer:
         self._doors_done: deque = deque(maxlen=max(int(max_requests), 1))
         self._marks: deque = deque(maxlen=max(int(max_requests), 1))
         self._drive: deque = deque(maxlen=self.buffer_steps)
+        # what construction did, written once by the engine's prewarm:
+        # relaid_leaves, relaid_bytes, program_temp_bytes_max
+        self.setup: Dict[str, int] = {}
         # the open step's jax.profiler.TraceAnnotation, if any
         self._annotation: Any = None
         self._annotate = None
@@ -282,7 +285,8 @@ class EngineTracer:
         clock — what the benchmark's readers take: ``steps`` (the flight
         recorder's records), ``requests`` (front-door roots, finished and
         open), ``states`` (rid -> [(ts, state)]), ``marks`` [(name, ts,
-        rid, args)], ``drive`` [(step, t0, t1, t2, t3)]."""
+        rid, args)], ``drive`` [(step, t0, t1, t2, t3)], ``setup`` (the
+        engine's construction counts)."""
         states = {rid: list(trans) for rid, trans in self._done}
         states.update({rid: list(t) for rid, t in self._spans.items()})
         return {
@@ -291,6 +295,7 @@ class EngineTracer:
             "states": states,
             "marks": list(self._marks),
             "drive": list(self._drive),
+            "setup": dict(self.setup),
         }
 
     # ------------------------------------------------------------------

@@ -149,10 +149,10 @@ def test_quantized_tree_serves_in_jit(setup):
     # coverage: text self+cross attention, vision attention/MLP and the
     # projector all quantize (review finding: only o-projections matched
     # before the Mllama patterns were added to DEFAULT_TARGETS)
-    from neuronx_distributed_llama3_2_tpu.quantization.quantize import _walk
+    from neuronx_distributed_llama3_2_tpu.quantization.quantize import walk_tree
 
     q_paths = []
-    _walk(qparams, lambda p, l: q_paths.append(p)
+    walk_tree(qparams, lambda p, l: q_paths.append(p)
           if isinstance(l, QuantizedTensor) else l)
     assert any("cross_attn/q/kernel" in p for p in q_paths), q_paths[:10]
     assert any("vision_model" in p and "self_attn/q/kernel" in p for p in q_paths)

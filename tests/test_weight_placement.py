@@ -1,0 +1,329 @@
+"""Fused ``gate_up`` weights rest on the device in the layout the matmul reads
+(``inference/placement.py``).
+
+Two tiers. On the CPU: an engine over re-placed weights is the engine over
+default-placed weights, bit for bit, and nothing but the physical layout of
+the fused leaves changed. For a described v5e (libtpu compiles for a topology
+without a chip — sizes, never a time): the paged programs over re-placed
+weights hold no copy of a layer's ``gate_up``, and the same programs over
+default-placed weights — the control — do.
+"""
+
+import dataclasses
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.layout import Format
+
+from neuronx_distributed_llama3_2_tpu.inference import (
+    GenerationConfig,
+    InferenceEngine,
+    SamplingConfig,
+    decode_model_for,
+)
+from neuronx_distributed_llama3_2_tpu.analysis import graftcheck
+from neuronx_distributed_llama3_2_tpu.inference import engine as engine_mod
+from neuronx_distributed_llama3_2_tpu.inference.placement import (
+    fused_rest_layout,
+    rest_fused_weights,
+)
+from neuronx_distributed_llama3_2_tpu.models import (
+    LLAMA_CONFIGS,
+    MIXTRAL_CONFIGS,
+    LlamaForCausalLM,
+    MixtralForCausalLM,
+)
+from neuronx_distributed_llama3_2_tpu.models.llama import params_to_hf
+from neuronx_distributed_llama3_2_tpu.models.mixtral import params_to_hf_mixtral
+from neuronx_distributed_llama3_2_tpu.quantization import (
+    QuantizationConfig,
+    quantize_params,
+)
+from neuronx_distributed_llama3_2_tpu.quantization.quantize import walk_tree
+from neuronx_distributed_llama3_2_tpu.serving import (
+    PagedConfig,
+    PagedServingEngine,
+)
+
+FAMILIES = {
+    # preset, model, to_hf, path of the fused leaf, its rest order
+    "mixtral": (MIXTRAL_CONFIGS["tiny-moe"], MixtralForCausalLM, params_to_hf_mixtral,
+                ("layers", "moe", "experts", "gate_up"), (0, 1, 3, 2, 4)),
+    "llama": (LLAMA_CONFIGS["tiny"], LlamaForCausalLM, params_to_hf,
+              ("layers", "mlp", "gate_up"), (0, 2, 1, 3)),
+}
+
+
+def leaf_at(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
+def order_of(leaf):
+    return tuple(leaf.format.layout.major_to_minor)
+
+
+def flat(tree):
+    out = {}
+    walk_tree(tree, lambda path, leaf: out.setdefault(path, leaf))
+    return out
+
+
+def serve(engine, prompts, new_tokens):
+    """Tokens of a prewarmed paged run that dispatches pctx, psfx (the second
+    prompt extends the first, so its prefix is cached) and pdecode; no
+    program may have lowered twice (GC008: state born uncommitted beside
+    committed weights would)."""
+    paged = PagedServingEngine(
+        engine, GenerationConfig(max_new_tokens=new_tokens),
+        PagedConfig(block_size=8, num_blocks=32, prewarm=True),
+    )
+    out = []
+    for prompt in prompts:
+        paged.submit(prompt)
+        out.append(paged.run_to_completion())
+    assert graftcheck.audit_programs(paged) == []
+    return out
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_engine_over_rested_weights_is_the_engine_over_default_weights(family, monkeypatch):
+    cfg, model_cls, to_hf, fused_path, rest_order = FAMILIES[family]
+    params = model_cls(cfg).init(jax.random.key(0))
+    kw = dict(max_batch=2, max_seq_len=64, buckets=[16, 32])
+    rested = InferenceEngine(cfg, params, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(engine_mod, "rest_fused_weights", lambda p: (p, {"leaves": 0, "bytes": 0}))
+        plain = InferenceEngine(cfg, params, **kw)
+
+    # the tree the engine holds: the same keys, shapes, dtypes and shardings;
+    # one leaf in another physical order, every other leaf the caller's own
+    fused = leaf_at(params, fused_path)
+    assert rested.placement == {"leaves": 1, "bytes": fused.nbytes}
+    assert plain.params is params and order_of(leaf_at(plain.params, fused_path)) == tuple(range(fused.ndim))
+    before, after = flat(params), flat(rested.params)
+    assert list(before) == list(after)
+    for path, old in before.items():
+        new = after[path]
+        assert (new.shape, new.dtype, new.sharding) == (old.shape, old.dtype, old.sharding), path
+        if path == "/".join(fused_path):
+            assert order_of(new) == rest_order
+            np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+        else:
+            assert new is old, path
+    # the caller's arrays are its own: nothing was deleted under it
+    assert not fused.is_deleted()
+    # a second engine over the rested tree copies nothing
+    again = InferenceEngine(cfg, rested.params, **kw)
+    assert again.placement == {"leaves": 0, "bytes": 0}
+    assert leaf_at(again.params, fused_path) is leaf_at(rested.params, fused_path)
+
+    # checkpoints leave from the rested tree as from the caller's
+    want_sd, got_sd = to_hf(params, cfg), to_hf(rested.params, cfg)
+    assert list(want_sd) == list(got_sd)
+    for name in want_sd:
+        np.testing.assert_array_equal(np.asarray(got_sd[name]), np.asarray(want_sd[name]), err_msg=name)
+
+    # the same logits and tokens, bit for bit: dense prefill, bucketed
+    # generate (lazily jitted), AOT-compiled generate, and the paged programs
+    rng = np.random.default_rng(5)
+    prompt = rng.integers(1, cfg.vocab_size, size=20).tolist()
+    ids = jnp.asarray([prompt], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(rested.prefill_logits(ids)), np.asarray(plain.prefill_logits(ids))
+    )
+    for precompile in (False, True):
+        gen = GenerationConfig(max_new_tokens=6, sampling=SamplingConfig(greedy=True),
+                               precompile=precompile)
+        assert rested.generate([prompt, prompt[:7]], gen).sequences == \
+            plain.generate([prompt, prompt[:7]], gen).sequences
+    prompts = [prompt, prompt + [3, 4]]
+    assert serve(rested, prompts, 4) == serve(plain, prompts, 4)
+
+
+def test_traced_engine_reports_what_construction_placed():
+    cfg, model_cls, _, fused_path, _ = FAMILIES["mixtral"]
+    params = model_cls(cfg).init(jax.random.key(0))
+    engine = InferenceEngine(cfg, params, max_batch=2, max_seq_len=64, buckets=[16, 32])
+
+    def paged(trace):
+        return PagedServingEngine(
+            engine, GenerationConfig(max_new_tokens=3),
+            PagedConfig(block_size=8, num_blocks=32, prewarm=True, trace_enabled=trace),
+        )
+
+    traced = paged(True)
+    setup = traced.tracer.timeline()["setup"]
+    assert setup["relaid_leaves"] == 1
+    assert setup["relaid_bytes"] == leaf_at(params, fused_path).nbytes
+    assert setup["program_temp_bytes_max"] >= 0
+    # a record re-lowers as it was dispatched: for the layout the weights rest in
+    rec = next(r for r in traced.program_registry().values() if r.kind == "pdecode")
+    assert order_of(leaf_at(rec.example_args[0], fused_path)) == FAMILIES["mixtral"][4]
+    # off unless tracing is
+    assert paged(False).tracer.timeline()["setup"] == {}
+
+
+def test_placement_follows_the_leaf_and_not_the_model():
+    f32 = jnp.float32
+    stacked = jnp.zeros((2, 4, 16, 2, 32), f32)
+    assert fused_rest_layout("layers/moe/experts/gate_up", stacked).major_to_minor == (0, 1, 3, 2, 4)
+    assert fused_rest_layout("3/mlp/gate_up", jnp.zeros((16, 2, 32), f32)).major_to_minor == (1, 0, 2)
+    # a second-minor axis as wide as a tile is a layout the matmul reads
+    assert fused_rest_layout("layers/mlp/gate_up", jnp.zeros((2, 16, 8, 32), f32)) is None
+    # not fused, not a float
+    assert fused_rest_layout("layers/moe/experts/down", jnp.zeros((2, 4, 2, 16), f32)) is None
+    assert fused_rest_layout("layers/mlp/gate_up", jnp.zeros((2, 16, 2, 32), jnp.int8)) is None
+
+
+def test_the_relayout_program_never_meets_the_persistent_compile_cache(monkeypatch):
+    """An executable loaded back from the cache has lost its output layout
+    (``placement._place``), so the relayout is compiled afresh and not
+    written, whatever the threshold the process runs with."""
+    from jax._src import compilation_cache
+
+    written = []
+    real = compilation_cache.put_executable_and_time
+    monkeypatch.setattr(
+        compilation_cache, "put_executable_and_time",
+        lambda key, name, *rest: (written.append(name), real(key, name, *rest)),
+    )
+    before = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        leaf = jax.random.normal(jax.random.key(0), (2, 4, 16, 2, 32), jnp.float32)
+        tree = {"layers": {"moe": {"experts": {"gate_up": leaf}}}}
+        rested, placed = rest_fused_weights(tree)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        # the spy sees a write: a program no earlier run can have cached
+        nonce = float(time.time_ns() % 1_000_003)
+        jax.block_until_ready(jax.jit(lambda a: a + nonce)(leaf))
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", before)
+    assert placed["leaves"] == 1 and written and not any("rest_fused" in n for n in written)
+    new = leaf_at(rested, FAMILIES["mixtral"][3])
+    assert order_of(new) == (0, 1, 3, 2, 4)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(leaf))
+
+
+def test_quantized_payloads_stay_where_quantization_put_them():
+    cfg = LLAMA_CONFIGS["tiny"]
+    qparams = quantize_params(LlamaForCausalLM(cfg).init(jax.random.key(0)), QuantizationConfig())
+    rested, placed = rest_fused_weights(qparams)
+    assert placed == {"leaves": 0, "bytes": 0}
+    assert jax.tree.leaves(rested)[0] is jax.tree.leaves(qparams)[0]
+
+
+def test_rested_weights_keep_their_sharding_on_a_mesh():
+    from jax.sharding import NamedSharding
+
+    from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
+    from neuronx_distributed_llama3_2_tpu.parallel.layers import shard_pytree
+
+    cfg = MIXTRAL_CONFIGS["tiny-moe"]
+    parallel_state.initialize_model_parallel(tensor_model_parallel_size=2)
+    model = MixtralForCausalLM(cfg)
+    params = shard_pytree(model.init(jax.random.key(0)), model.specs())
+    path = FAMILIES["mixtral"][3]
+    engine = InferenceEngine(cfg, params, max_batch=2, max_seq_len=64, buckets=[16, 32])
+    old, new = leaf_at(params, path), leaf_at(engine.params, path)
+    assert isinstance(new.sharding, NamedSharding) and new.sharding == old.sharding
+    assert order_of(new) == (0, 1, 3, 2, 4)
+    # a leaf that jit would spread over the mesh itself is not pinned to one device
+    loose = model.init(jax.random.key(0))
+    assert leaf_at(rest_fused_weights(loose)[0], path) is leaf_at(loose, path)
+    prompt = list(range(1, 13))
+    gen = GenerationConfig(max_new_tokens=4, sampling=SamplingConfig(greedy=True))
+    got = engine.generate([prompt], gen).sequences
+    parallel_state.destroy_model_parallel()
+    want = InferenceEngine(
+        cfg, model.init(jax.random.key(0)), max_batch=2, max_seq_len=64, buckets=[16, 32]
+    ).generate([prompt], gen).sequences
+    assert got == want
+
+
+# ---------------------------------------------------------------------------
+# compiled for a described v5e: the copy is gone, and the control has it
+# ---------------------------------------------------------------------------
+
+# widths at which one layer's gate_up (470 MB) cannot hide in a smaller memory
+# space: at H 1024, I 3584 the control's copy of the 512-token program is
+# placed outside HBM and the temporaries do not show it
+AOT = dataclasses.replace(
+    MIXTRAL_CONFIGS["tiny-moe"], hidden_size=2048, intermediate_size=7168, num_experts=8,
+    num_layers=2, num_heads=8, num_kv_heads=2, head_dim=128, vocab_size=2048,
+    max_seq_len=1024, dtype=jnp.bfloat16,
+)
+LAYER_SHAPE = (AOT.num_experts, AOT.hidden_size, 2, AOT.intermediate_size)
+LAYER_BYTES = 2 * int(np.prod(LAYER_SHAPE))
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described v5e, or a clean skip where libtpu cannot
+    describe one."""
+    for name, value in (("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                        ("TPU_WORKER_HOSTNAMES", "localhost"),
+                        ("TPU_SKIP_MDS_QUERY", "1")):
+        os.environ.setdefault(name, value)
+    try:
+        from jax.experimental import topologies
+
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0]
+    except Exception as exc:  # no libtpu, or one that cannot describe a topology
+        pytest.skip(f"libtpu cannot describe a v5e here: {exc}")
+
+
+def compile_paged(device, program, rested):
+    """The paged decode step at 16 lanes, or a 512-token suffix step, over a
+    2-layer stack, with the weights placed as the engine places them
+    (``rested``) or as ``model.init`` leaves them."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(device)
+    model = decode_model_for(AOT)
+
+    def place(path, a):
+        layout = fused_rest_layout(path, a) if rested else None
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one if layout is None else Format(layout, one)
+        )
+
+    params = walk_tree(jax.eval_shape(MixtralForCausalLM(AOT).init, jax.random.key(0)), place)
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: model.init_paged_cache(256, 16)),
+    )
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)  # noqa: E731
+    if program == "pdecode":
+        def fn(params, cache, tokens, positions, tables):
+            return model.decode_step(params, cache, tokens, positions, tables, kv_limit=1024)
+        args = (i32(16), i32(16), i32(16, 64))
+    else:
+        def fn(params, cache, ids, start, table):
+            return model.forward(params, cache, ids, start, None, return_hidden=True,
+                                 block_tables=table, kv_limit=1024)
+        args = (i32(1, 512), i32(1), i32(1, 64))
+    return jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args).compile()
+
+
+@pytest.mark.parametrize("program", ["pdecode", "psfx"])
+def test_paged_program_over_rested_weights_copies_no_layer_of_gate_up(v5e, program):
+    one_layer = r"(\S*dynamic-slice\S*) = bf16\[1,%s\]" % ",".join(map(str, LAYER_SHAPE))
+    control = compile_paged(v5e, program, rested=False)
+    rested = compile_paged(v5e, program, rested=True)
+    # the control copies a layer's gate_up out of the stack (and re-tiles it)
+    assert re.search(one_layer, control.as_text())
+    assert not re.search(one_layer, rested.as_text())
+    saved = (control.memory_analysis().temp_size_in_bytes
+             - rested.memory_analysis().temp_size_in_bytes)
+    # one layer's bytes, less the few MB by which other temporaries move
+    assert saved >= 0.95 * LAYER_BYTES, (saved, LAYER_BYTES)
+    # the parameter rests tiled as the dot reads it
+    assert "bf16[2,%s]{4,2,3,1,0:T(8,128)(2,1)}" % ",".join(map(str, LAYER_SHAPE)) in rested.as_text()
