@@ -48,6 +48,7 @@ from neuronx_distributed_llama3_2_tpu.models.llama import (
     apply_rope,
     make_norm,
 )
+from neuronx_distributed_llama3_2_tpu.moe import tap as routing_tap
 from neuronx_distributed_llama3_2_tpu.parallel.layers import (
     BATCH_AXES,
     constrain,
@@ -320,6 +321,11 @@ class LlamaDecode:
         x = constrain(x, P(BATCH_AXES, None, None))
         norm = make_norm(c)
 
+        # a traced serving engine's routing tap (moe/tap.py), None otherwise:
+        # each layer's expert counts leave the body that traced them as a
+        # third per-layer output (nothing for a dense model)
+        tap = routing_tap.current()
+
         def layer_body(x, layer_in):
             lp, kc, vc = layer_in
             x, kc, vc = self._decode_layer(
@@ -327,7 +333,9 @@ class LlamaDecode:
                 context_encode=context_encode, tree=tree, kv_limit=kv_limit,
                 block_tables=block_tables, row_live=row_live,
             )
-            return x, (kc, vc)
+            if tap is None:
+                return x, (kc, vc)
+            return x, (kc, vc, tap.take_layer())
 
         if quantized:
             k_stk: Any = (cache.k, cache.k_scale)
@@ -335,20 +343,21 @@ class LlamaDecode:
         else:
             k_stk, v_stk = cache.k, cache.v
         if c.scan_layers:
-            x, (k_new, v_new) = jax.lax.scan(
+            x, per_layer = jax.lax.scan(
                 layer_body, x, (params["layers"], k_stk, v_stk)
             )
         else:
-            ks, vs = [], []
+            outs = []
             for i in range(c.num_layers):
-                lp = jax.tree.map(lambda p: p[i], params["layers"])
-                kc_i = jax.tree.map(lambda a: a[i], k_stk)
-                vc_i = jax.tree.map(lambda a: a[i], v_stk)
-                x, (kc, vc) = layer_body(x, (lp, kc_i, vc_i))
-                ks.append(kc)
-                vs.append(vc)
-            k_new = jax.tree.map(lambda *a: jnp.stack(a), *ks)
-            v_new = jax.tree.map(lambda *a: jnp.stack(a), *vs)
+                layer_in = jax.tree.map(
+                    lambda a: a[i], (params["layers"], k_stk, v_stk)
+                )
+                x, out = layer_body(x, layer_in)
+                outs.append(out)
+            per_layer = jax.tree.map(lambda *a: jnp.stack(a), *outs)
+        k_new, v_new = per_layer[:2]
+        if tap is not None:
+            tap.commit(per_layer[2], c.num_layers)
 
         x = norm(params["final_norm"], x)
         if quantized:
@@ -395,6 +404,8 @@ class LlamaDecode:
                 q = q.reshape(b, t, c.num_heads, c.head_dim)
                 k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
                 v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
+            if c.qk_norm:
+                q, k = attn._qk_norm(lp["attn"], q, k)
             with jax.named_scope("rope"):
                 q = apply_rope(q, sin, cos, pos_block)
                 k = apply_rope(k, sin, cos, pos_block)

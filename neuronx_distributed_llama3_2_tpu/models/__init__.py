@@ -10,6 +10,13 @@ from neuronx_distributed_llama3_2_tpu.models.mixtral import (  # noqa: F401
     params_from_hf_mixtral,
     params_to_hf_mixtral,
 )
+from neuronx_distributed_llama3_2_tpu.models.olmoe import (  # noqa: F401
+    OLMOE_CONFIGS,
+    OlmoeConfig,
+    OlmoeForCausalLM,
+    params_from_hf_olmoe,
+    params_to_hf_olmoe,
+)
 from neuronx_distributed_llama3_2_tpu.models.dbrx import (  # noqa: F401
     DBRX_CONFIGS,
     DbrxConfig,
@@ -63,6 +70,11 @@ def model_registry():
         reg[name] = {
             "config": cfg, "model_cls": MixtralForCausalLM,
             "from_hf": params_from_hf_mixtral, "to_hf": params_to_hf_mixtral,
+        }
+    for name, cfg in OLMOE_CONFIGS.items():
+        reg[name] = {
+            "config": cfg, "model_cls": OlmoeForCausalLM,
+            "from_hf": params_from_hf_olmoe, "to_hf": params_to_hf_olmoe,
         }
     for name, cfg in DBRX_CONFIGS.items():
         reg[name] = {

@@ -114,6 +114,12 @@ class LlamaConfig:
     # clamp Q/K/V projections to [-clip_qkv, clip_qkv] (DBRX attn_config,
     # reference neuron_modeling_dbrx.py:171)
     clip_qkv: Optional[float] = None
+    # OLMoE's QK-norm (arXiv:2409.02060; HF OlmoeAttention q_norm/k_norm): an
+    # RMSNorm with a learned scale over the whole projected query and the
+    # whole projected key — every head jointly — after the projection and
+    # before the split's rotary embedding. What the cache holds is the
+    # normed, rotated key.
+    qk_norm: bool = False
     # cp ring sequence layout: "auto" (zigzag when divisible and the kernel
     # mode takes Pallas — balances causal work across the ring,
     # kernels/ring_attention_pallas), "contiguous", or "zigzag" (forced;
@@ -135,6 +141,10 @@ class LlamaConfig:
             raise ValueError(
                 f"norm_type must be rmsnorm|layernorm, got {self.norm_type!r}"
             )
+        if self.qk_norm and self.clip_qkv is not None:
+            # HF OlmoeAttention clamps *after* its q/k norms, this block
+            # before them; no published config sets both
+            raise ValueError("qk_norm with clip_qkv is not implemented")
 
 
 # Published Llama-3.x architectures (HF config.json values).
@@ -390,11 +400,37 @@ class LlamaAttention:
         )
 
     def init(self, key: jax.Array) -> Params:
+        c = self.config
         kq, ko = jax.random.split(key)
-        return {"qkv": self._qkv().init(kq), "o": self._o().init(ko)}
+        params = {"qkv": self._qkv().init(kq), "o": self._o().init(ko)}
+        if c.qk_norm:
+            for name, heads in (("q_norm", c.num_heads), ("k_norm", c.num_kv_heads)):
+                params[name] = {"scale": jnp.ones((heads * c.head_dim,), jnp.float32)}
+        return params
 
     def specs(self) -> Params:
-        return {"qkv": self._qkv().specs(), "o": self._o().specs()}
+        specs = {"qkv": self._qkv().specs(), "o": self._o().specs()}
+        if self.config.qk_norm:
+            # replicated like every norm scale: 2 x hidden floats a layer
+            specs["q_norm"] = specs["k_norm"] = {"scale": P(None)}
+        return specs
+
+    @jax.named_scope("qk_norm")
+    def _qk_norm(self, params: Params, q: jax.Array, k: jax.Array):
+        """RMSNorm of q (b, s, N, D) and k (b, s, NKV, D), each over all of
+        its heads jointly, fp32 accumulation, with the learned scale. The
+        mean runs over the head axis, which tensor parallelism shards: the
+        block is global GSPMD math, so under tp > 1 the partitioner supplies
+        the cross-shard sum (one scalar per token for q, one for k)."""
+        eps = self.config.rms_norm_eps
+
+        def norm(x, scale):
+            h = x.astype(jnp.float32)
+            var = jnp.mean(jnp.square(h), axis=(-2, -1), keepdims=True)
+            h = h * lax.rsqrt(var + eps) * scale.reshape(x.shape[-2:])
+            return h.astype(x.dtype)
+
+        return norm(q, params["q_norm"]["scale"]), norm(k, params["k_norm"]["scale"])
 
     def _apply_rope(self, q, k, sin, cos, positions):
         """Full-head-dim rotate-half RoPE; partial-rotary families
@@ -426,6 +462,8 @@ class LlamaAttention:
             q = q.reshape(b, s, c.num_heads, c.head_dim)
             k = k.reshape(b, s, c.num_kv_heads, c.head_dim)
             v = v.reshape(b, s, c.num_kv_heads, c.head_dim)
+        if c.qk_norm:
+            q, k = self._qk_norm(params, q, k)
         with jax.named_scope("rope"):
             q, k = self._apply_rope(q, k, sin, cos, positions)
         with jax.named_scope("sdpa"):

@@ -251,14 +251,22 @@ class MixtralForCausalLM:
         return ce + self.config.router_aux_loss_coef * aux
 
 
+# HF module names of a Mixtral-shaped sparse block: (the MoE module, and
+# inside ``experts.<e>.``: gate, up and down projections). OLMoE's differ
+# (models/olmoe.py); the tensors and their layouts do not.
+MIXTRAL_HF_NAMES = ("block_sparse_moe", "w1", "w3", "w2")
+
+
 def params_from_hf_mixtral(
-    state_dict: Dict[str, Any], config: MixtralConfig
+    state_dict: Dict[str, Any], config: MixtralConfig,
+    hf_names: Tuple[str, str, str, str] = MIXTRAL_HF_NAMES,
 ) -> Params:
     """Convert an HF Mixtral ``state_dict`` to the stacked pytree.
 
     HF ``MixtralSparseMoeBlock``: per-expert w1 (gate, (I,H)), w3 (up, (I,H)),
     w2 (down, (H,I)); router ``gate.weight`` (E,H). Attention maps exactly as
-    Llama (same GQA block)."""
+    Llama (same GQA block); with ``config.qk_norm`` the block's
+    ``q_norm``/``k_norm`` weights come along."""
     import numpy as np
 
     def t(name):
@@ -269,6 +277,7 @@ def params_from_hf_mixtral(
 
     c = config
     L, E = c.num_layers, c.num_experts
+    moe_name, gate_name, up_name, down_name = hf_names
 
     def stack(fmt, transform=lambda w: w.T, dtype=None):
         return jnp.asarray(
@@ -276,42 +285,39 @@ def params_from_hf_mixtral(
             dtype or c.dtype,
         )
 
+    def scale(fmt):
+        return {"scale": stack(fmt, transform=lambda w: w, dtype=jnp.float32)}
+
     gate_ups, downs, routers = [], [], []
     for i in range(L):
-        moe = f"model.layers.{i}.block_sparse_moe"
+        moe = f"model.layers.{i}.{moe_name}"
         routers.append(t(f"{moe}.gate.weight").T)  # (H, E)
-        gate = np.stack([t(f"{moe}.experts.{e}.w1.weight").T for e in range(E)])
-        up = np.stack([t(f"{moe}.experts.{e}.w3.weight").T for e in range(E)])
+        gate = np.stack([t(f"{moe}.experts.{e}.{gate_name}.weight").T for e in range(E)])
+        up = np.stack([t(f"{moe}.experts.{e}.{up_name}.weight").T for e in range(E)])
         gate_ups.append(np.stack([gate, up], axis=2))  # (E, H, 2, I)
         downs.append(
-            np.stack([t(f"{moe}.experts.{e}.w2.weight").T for e in range(E)])
+            np.stack([t(f"{moe}.experts.{e}.{down_name}.weight").T for e in range(E)])
         )  # (E, I, H)
 
+    attn = {
+        "qkv": {
+            "q_kernel": stack("model.layers.{}.self_attn.q_proj.weight"),
+            "k_kernel": stack("model.layers.{}.self_attn.k_proj.weight"),
+            "v_kernel": stack("model.layers.{}.self_attn.v_proj.weight"),
+        },
+        "o": {"kernel": stack("model.layers.{}.self_attn.o_proj.weight")},
+    }
+    if c.qk_norm:
+        attn["q_norm"] = scale("model.layers.{}.self_attn.q_norm.weight")
+        attn["k_norm"] = scale("model.layers.{}.self_attn.k_norm.weight")
     params: Params = {
         "embed": {
             "embedding": jnp.asarray(t("model.embed_tokens.weight"), c.dtype)
         },
         "layers": {
-            "attn_norm": {
-                "scale": stack(
-                    "model.layers.{}.input_layernorm.weight",
-                    transform=lambda w: w, dtype=jnp.float32,
-                )
-            },
-            "attn": {
-                "qkv": {
-                    "q_kernel": stack("model.layers.{}.self_attn.q_proj.weight"),
-                    "k_kernel": stack("model.layers.{}.self_attn.k_proj.weight"),
-                    "v_kernel": stack("model.layers.{}.self_attn.v_proj.weight"),
-                },
-                "o": {"kernel": stack("model.layers.{}.self_attn.o_proj.weight")},
-            },
-            "mlp_norm": {
-                "scale": stack(
-                    "model.layers.{}.post_attention_layernorm.weight",
-                    transform=lambda w: w, dtype=jnp.float32,
-                )
-            },
+            "attn_norm": scale("model.layers.{}.input_layernorm.weight"),
+            "attn": attn,
+            "mlp_norm": scale("model.layers.{}.post_attention_layernorm.weight"),
             "moe": {
                 "router": {
                     "kernel": jnp.asarray(np.stack(routers), jnp.float32)
@@ -334,7 +340,8 @@ def params_from_hf_mixtral(
 
 
 def params_to_hf_mixtral(
-    params: Params, config: MixtralConfig
+    params: Params, config: MixtralConfig,
+    hf_names: Tuple[str, str, str, str] = MIXTRAL_HF_NAMES,
 ) -> Dict[str, Any]:
     """Inverse of :func:`params_from_hf_mixtral`: stacked pytree → HF Mixtral
     ``state_dict`` (numpy fp32, torch (out, in) Linear layout). The
@@ -344,6 +351,7 @@ def params_to_hf_mixtral(
 
     c = config
     L, E = c.num_layers, c.num_experts
+    moe_name, gate_name, up_name, down_name = hf_names
 
     def np32(x):
         return np.asarray(x, dtype=np.float32)
@@ -370,12 +378,15 @@ def params_to_hf_mixtral(
         sd[p + "self_attn.k_proj.weight"] = k_k[i].T
         sd[p + "self_attn.v_proj.weight"] = v_k[i].T
         sd[p + "self_attn.o_proj.weight"] = o_k[i].T
-        moe = p + "block_sparse_moe."
+        if c.qk_norm:
+            sd[p + "self_attn.q_norm.weight"] = np32(lyr["attn"]["q_norm"]["scale"][i])
+            sd[p + "self_attn.k_norm.weight"] = np32(lyr["attn"]["k_norm"]["scale"][i])
+        moe = p + moe_name + "."
         sd[moe + "gate.weight"] = router[i].T
         for e in range(E):
-            sd[moe + f"experts.{e}.w1.weight"] = gate_up[i, e, :, 0, :].T
-            sd[moe + f"experts.{e}.w3.weight"] = gate_up[i, e, :, 1, :].T
-            sd[moe + f"experts.{e}.w2.weight"] = down[i, e].T
+            sd[moe + f"experts.{e}.{gate_name}.weight"] = gate_up[i, e, :, 0, :].T
+            sd[moe + f"experts.{e}.{up_name}.weight"] = gate_up[i, e, :, 1, :].T
+            sd[moe + f"experts.{e}.{down_name}.weight"] = down[i, e].T
     if not c.tie_word_embeddings:
         sd["lm_head.weight"] = np32(params["lm_head"]["kernel"]).T
     return sd

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_llama3_2_tpu.moe import tap as routing_tap
 from neuronx_distributed_llama3_2_tpu.parallel.state import EP_AXIS, TP_AXIS
 
 Params = Dict[str, Any]
@@ -213,11 +214,23 @@ class ExpertMLPs:
     def __call__(
         self, params: Params, x: jax.Array, gates: jax.Array, idx: jax.Array
     ) -> jax.Array:
+        # the no-drop path taken (all that serving runs) is a scope of its own
+        # under moe/experts, so a device trace says which one a program ran; a
+        # traced serving engine's tap (moe/tap.py) is told what was routed and
+        # how many pairs are computed
+        t, k = idx.shape
+        tap = routing_tap.current()
         if self.capacity_factor is None:
             # selective wins exactly when it gathers fewer expert-weight
             # bytes than streaming all E experts (the role of the reference's
             # SELECTIVE_LOADING_THRESHOLD dispatch, expert_mlps.py:298-357)
-            if x.shape[0] * idx.shape[1] <= self.num_experts:
-                return self.forward_selective(params, x, gates, idx)
-            return self.forward_all_experts(params, x, gates, idx)
+            if t * k <= self.num_experts:
+                if tap is not None:
+                    tap.record(idx, self.num_experts, "selective", t * k)
+                with jax.named_scope("selective"):
+                    return self.forward_selective(params, x, gates, idx)
+            if tap is not None:
+                tap.record(idx, self.num_experts, "all", t * self.num_experts)
+            with jax.named_scope("all"):
+                return self.forward_all_experts(params, x, gates, idx)
         return self.forward_capacity_factor(params, x, gates, idx)

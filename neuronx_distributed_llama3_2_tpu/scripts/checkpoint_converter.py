@@ -286,6 +286,16 @@ def _hf_config_dict(config) -> Dict[str, Any]:
         rms_norm_eps=config.rms_norm_eps,
         rope_theta=config.rope_theta,
     )
+    if name == "OlmoeConfig":
+        cfg.update(
+            architectures=["OlmoeForCausalLM"],
+            model_type="olmoe",
+            num_experts=config.num_experts,
+            num_experts_per_tok=config.top_k,
+            norm_topk_prob=config.normalize_top_k,
+            router_aux_loss_coef=config.router_aux_loss_coef,
+        )
+        return cfg
     if name == "MixtralConfig":
         cfg.update(
             architectures=["MixtralForCausalLM"],

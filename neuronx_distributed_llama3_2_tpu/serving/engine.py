@@ -70,6 +70,7 @@ from neuronx_distributed_llama3_2_tpu.inference.sampling import (
     sample,
     sample_lanes,
 )
+from neuronx_distributed_llama3_2_tpu.moe import tap as routing_tap
 from neuronx_distributed_llama3_2_tpu.serving.block_allocator import (
     NULL_BLOCK,
     BlockAllocator,
@@ -117,6 +118,41 @@ def _in_scope(name: str, fn):
     return scoped
 
 
+# The programs a traced engine taps for routing counters (moe/tap.py), and
+# which (lane, row) slots of a call carry a request's token: a prefill bucket's
+# rows up to the chunk's length, a decode step's lanes that hold a table. The
+# rest is padding, which routes like any row and is not counted.
+_LIVE_ROWS = {
+    "pctx": lambda params, cache, ids, length, *_: (
+        jnp.arange(ids.shape[1]) < length[:, None]),
+    "psfx": lambda params, cache, ids, start, length, *_: (
+        jnp.arange(ids.shape[1]) < length[:, None]),
+    "pdecode": lambda params, cache, tokens, positions, tables, *_: (
+        tables[:, :1] != NULL_BLOCK),
+}
+
+
+def _with_routing_tap(fn, record: "ProgramRecord"):
+    """``fn`` traced with a routing tap open: returns ``(fn's outputs, live
+    tokens routed to each expert over all layers)`` — ``None`` for a model
+    without experts — and leaves on ``record.routing`` what the trace showed
+    of the expert block: the dispatch paths taken and the (token, expert)
+    pairs they compute per call."""
+    live_rows = _LIVE_ROWS[record.kind]
+
+    @functools.wraps(fn)
+    def tapped(*args):
+        with routing_tap.open_tap(live_rows(*args)) as tap:
+            out = fn(*args)
+        record.routing = {
+            "paths": tuple(sorted(tap.paths)),
+            "pairs_computed": tap.pairs_computed,
+        }
+        return out, tap.tokens_per_expert
+
+    return tapped
+
+
 def _aval_of(x):
     """ShapeDtypeStruct twin of an array leaf (non-arrays pass through) —
     what a :class:`ProgramRecord` remembers about its first dispatch so
@@ -154,13 +190,23 @@ class ProgramRecord:
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
     jitted: Any = None
     example_args: Optional[tuple] = None  # avals of the first dispatch
+    # traced engines only (``_with_routing_tap``): the program also returns
+    # its per-expert token counts, handed to ``on_routed`` at every dispatch
+    # as the device array they are — nothing waits for them
+    routing: Optional[Dict[str, Any]] = None
+    on_routed: Any = None
 
     def __call__(self, *args):
         if self.example_args is None:
             self.example_args = tuple(
                 jax.tree.map(_aval_of, a) for a in args
             )
-        return self.jitted(*args)
+        if self.on_routed is None:
+            return self.jitted(*args)
+        out, counts = self.jitted(*args)
+        if counts is not None:
+            self.on_routed(self, counts)
+        return out
 
     def lower(self):
         """Re-lower at the recorded example avals (trace-cache hit — the
@@ -676,6 +722,9 @@ class PagedServingEngine:
             self.allocator.spill_hook = self._spill_block
             self.index.on_spill_drop = self._drop_spill_payload
         self.metrics = ServingMetrics()
+        # a snapshot taken with no arguments (the front door's, the
+        # benchmark's) carries the pool's and the radix index's counts too
+        self.metrics.bind(self.allocator, self.index)
         # graftscope flight recorder (serving/tracing.py): always
         # constructed — every hook is a no-op attribute test when
         # trace_enabled is off, so the fault-free/trace-free path pays
@@ -931,17 +980,19 @@ class PagedServingEngine:
         inside a named scope of its kind (``pctx``, ``psfx``, ``pdecode``,
         ...), here and wherever graftcheck retraces ``rec.fn``."""
         kind = kind if kind is not None else str(key_[0])
-        fn = _in_scope(kind, fn)
         rec = ProgramRecord(
             key=key_,
             kind=kind,
-            fn=fn,
+            fn=_in_scope(kind, fn),
             donate_argnums=tuple(donate_argnums),
             gather=gather,
             checked=checked,
             meta=meta,
-            jitted=jax.jit(fn, donate_argnums=donate_argnums),
         )
+        if self.tracer.enabled and kind in _LIVE_ROWS:
+            rec.fn = _with_routing_tap(rec.fn, rec)
+            rec.on_routed = self._note_routed
+        rec.jitted = jax.jit(rec.fn, donate_argnums=donate_argnums)
         self._programs[key_] = rec
         self.metrics.programs_compiled += 1
         if self._prewarming:
@@ -953,6 +1004,12 @@ class PagedServingEngine:
             # kernel-shed rung mints them deliberately on first climb.
             self.metrics.steadystate_compiles += 1
         return rec
+
+    def _note_routed(self, rec: ProgramRecord, counts: jax.Array) -> None:
+        self.tracer.routed(
+            self._step_index, rec.kind, rec.routing["paths"],
+            rec.routing["pairs_computed"], counts,
+        )
 
     def program_registry(self) -> Dict[tuple, ProgramRecord]:
         """key -> :class:`ProgramRecord` for every program this engine has

@@ -343,11 +343,21 @@ class ServingMetrics:
         d["accepted"] += accept
         d["by_len"][accept] = d["by_len"].get(accept, 0) + 1
 
+    # the engine's pool and prefix index (``bind``): what a snapshot merges
+    # in when it is given none. Not dataclass fields: they are not counters.
+    _allocator = None
+    _index = None
+
+    def bind(self, allocator: BlockAllocator, index: Optional[RadixPrefixIndex]) -> None:
+        self._allocator, self._index = allocator, index
+
     def snapshot(
         self,
         allocator: Optional[BlockAllocator] = None,
         index: Optional[RadixPrefixIndex] = None,
     ) -> dict:
+        allocator = allocator if allocator is not None else self._allocator
+        index = index if index is not None else self._index
         # built by hand rather than dataclasses.asdict: asdict would
         # deep-copy the Histogram objects into the record and break JSON
         # serialization; the hist_* fields export as summary dicts under

@@ -76,6 +76,17 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
     for parent, children in CHILD_SCOPES.items() for child in children
 )
 
+# Finer scopes below the vocabulary, for one cell's own readers: the shared
+# readers (``benchmarks/program_trace.py`` keeps a copy of SCOPES) do not know
+# them and book their ops to the parent. ``attn/qk_norm`` is OLMoE's joint
+# RMSNorm of q and k (models/llama.py); ``moe/experts/selective`` and
+# ``moe/experts/all`` say which no-drop dispatch path a program took
+# (moe/experts.py).
+DETAIL_SCOPES = {
+    "attn": ("qk_norm",),
+    "moe/experts": ("selective", "all"),
+}
+
 # the annotation every traced step opens on the profiler's host timeline
 STEP_ANNOTATION = "graft.step"
 
@@ -154,6 +165,11 @@ class EngineTracer:
         # what construction did, written once by the engine's prewarm:
         # relaid_leaves, relaid_bytes, program_temp_bytes_max
         self.setup: Dict[str, int] = {}
+        # routing counters, one entry per dispatch of a tapped program
+        # (moe/tap.py): (step, kind, dispatch paths, pairs computed, live
+        # tokens per expert). The counts stay the device arrays the program
+        # returned until ``timeline()`` is asked for them.
+        self._routed: deque = deque(maxlen=4 * self.buffer_steps)
         # the open step's jax.profiler.TraceAnnotation, if any
         self._annotation: Any = None
         self._annotate = None
@@ -227,6 +243,11 @@ class EngineTracer:
         if state in TERMINAL_STATES:
             self._done.append((rid, self._spans.pop(rid)))
 
+    def routed(self, step: int, kind: str, paths: Tuple[str, ...],
+               pairs_computed: int, tokens_per_expert: Any) -> None:
+        """One dispatch of a tapped program in engine step ``step``."""
+        self._routed.append((step, kind, paths, pairs_computed, tokens_per_expert))
+
     def mark(self, name: str, rid: int, **args: Any) -> None:
         """Point event of one request (``first_token``)."""
         if not self.enabled:
@@ -286,9 +307,19 @@ class EngineTracer:
         recorder's records), ``requests`` (front-door roots, finished and
         open), ``states`` (rid -> [(ts, state)]), ``marks`` [(name, ts,
         rid, args)], ``drive`` [(step, t0, t1, t2, t3)], ``setup`` (the
-        engine's construction counts)."""
+        engine's construction counts), ``routed`` [(step, program kind,
+        dispatch paths, (token, expert) pairs computed, [live tokens — no
+        bucket padding, no idle lane — routed to each expert, summed over
+        layers])] per dispatch of a program with experts (reading it waits
+        for the device)."""
         states = {rid: list(trans) for rid, trans in self._done}
         states.update({rid: list(t) for rid, t in self._spans.items()})
+        routed = list(self._routed)
+        counts = []
+        if routed:
+            import jax
+
+            counts = jax.device_get([row[4] for row in routed])    # one batched fetch
         return {
             "steps": list(self._steps),
             "requests": list(self._doors_done) + list(self._doors.values()),
@@ -296,6 +327,10 @@ class EngineTracer:
             "marks": list(self._marks),
             "drive": list(self._drive),
             "setup": dict(self.setup),
+            "routed": [
+                (step, kind, paths, pairs, [int(n) for n in per_expert])
+                for (step, kind, paths, pairs, _), per_expert in zip(routed, counts)
+            ],
         }
 
     # ------------------------------------------------------------------

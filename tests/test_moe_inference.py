@@ -12,6 +12,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from neuronx_distributed_llama3_2_tpu.inference import (
     InferenceEngine,
@@ -24,13 +25,21 @@ from neuronx_distributed_llama3_2_tpu.inference import (
 from neuronx_distributed_llama3_2_tpu.models import (
     LLAMA_CONFIGS,
     MIXTRAL_CONFIGS,
+    OLMOE_CONFIGS,
     LlamaForCausalLM,
     MixtralForCausalLM,
+    OlmoeForCausalLM,
 )
 from neuronx_distributed_llama3_2_tpu.moe.experts import ExpertMLPs
 from neuronx_distributed_llama3_2_tpu.moe.routing import top_k_routing
 
 TINY_MOE = MIXTRAL_CONFIGS["tiny-moe"]
+# the families MixtralDecode serves: (config, training model); OLMoE adds the
+# joint QK-norm and gates that are not renormalised
+FAMILIES = {
+    "mixtral": (TINY_MOE, MixtralForCausalLM),
+    "olmoe": (OLMOE_CONFIGS["tiny-olmoe"], OlmoeForCausalLM),
+}
 
 
 def _params():
@@ -87,13 +96,15 @@ def test_decode_model_dispatch():
     assert not isinstance(llama, MixtralDecode)
 
 
-def test_mixtral_incremental_decode_matches_recompute():
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_mixtral_incremental_decode_matches_recompute(family):
     """Prefill + per-token decode logits == full-model forward on the
     growing prefix (the MoE analogue of the Llama decode-parity gate)."""
-    cfg = TINY_MOE
-    model = MixtralForCausalLM(cfg)
-    params = _params()
-    decode = MixtralDecode(cfg)
+    cfg, model_cls = FAMILIES[family]
+    model = model_cls(cfg)
+    params = model.init(jax.random.key(0))
+    decode = decode_model_for(cfg)
+    assert type(decode) is MixtralDecode
     rng = np.random.default_rng(5)
     prompt = rng.integers(0, cfg.vocab_size, size=(1, 8)).astype(np.int32)
     n_extra = 4
@@ -125,12 +136,14 @@ def test_mixtral_incremental_decode_matches_recompute():
         )
 
 
-def test_mixtral_engine_greedy_generate():
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_mixtral_engine_greedy_generate(family):
     """End-to-end: the bucketed engine generates the same greedy tokens as
     an argmax loop over the training model's full forward."""
-    cfg = dataclasses.replace(TINY_MOE, max_seq_len=128)
-    model = MixtralForCausalLM(cfg)
-    params = _params()
+    cfg, model_cls = FAMILIES[family]
+    cfg = dataclasses.replace(cfg, max_seq_len=128)
+    model = model_cls(cfg)
+    params = model.init(jax.random.key(0))
     rng = np.random.default_rng(11)
     prompt = rng.integers(0, cfg.vocab_size, size=(6,)).tolist()
     n_new = 5
