@@ -1235,14 +1235,15 @@ class MixtralDecode(LlamaDecode):
     ``examples/inference/mixtral/neuron_modeling_mixtral.py``, whose attention
     is the Llama base + MoE feed-forward).
 
-    Token-gen dispatches through :meth:`..moe.ExpertMLPs.forward_selective`
-    (the reference's selective expert loading, expert_mlps.py:267) whenever
-    the fresh block is small enough that gathering the chosen experts reads
-    less HBM than streaming all of them; larger (prefill) blocks run the
-    batched all-experts path. Inference never drops tokens — the training
-    config's capacity factor is ignored here, so big-bucket MoE prefill pays
-    all-experts FLOPs (reference token-gen/context dispatch,
-    expert_mlps.py:298-357). Routing is per-token, so decode routing is
+    Inference never drops tokens — the training config's capacity factor is
+    ignored here — and every block, token-gen and prefill alike, runs the
+    batched all-experts path (:meth:`..moe.ExpertMLPs.forward_all_experts`):
+    decode streams each layer's expert stack once, fused with the layer
+    scan's slice, and big-bucket MoE prefill pays all-experts FLOPs. The
+    reference's selective expert loading for token-gen (expert_mlps.py:267,
+    dispatch :298-357) is taken at no shape: over the layer scan its gather
+    copies the whole stack before it reads a slice (``ExpertMLPs.__call__``
+    carries the measured table). Routing is per-token, so decode routing is
     identical to the training model's. Expert parallelism is not supported
     in decode (the reference's Mixtral inference is TP-only as well).
     """
@@ -1265,8 +1266,8 @@ class MixtralDecode(LlamaDecode):
                 "under an ep>1 mesh would allgather every EP-sharded expert "
                 "weight per token. Serve MoE models with tp/dp sharding."
             )
-        # capacity_factor=None routes through the selective/all-experts
-        # no-drop dispatch in ExpertMLPs.__call__ (single dispatch site)
+        # capacity_factor=None routes through the no-drop (all-experts)
+        # dispatch in ExpertMLPs.__call__ (single dispatch site)
         cfg = dataclasses.replace(self.config.moe_config(), capacity_factor=None)
         y, _, _ = MoE(cfg)(lp["moe"], h)
         return y

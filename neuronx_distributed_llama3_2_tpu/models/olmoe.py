@@ -60,8 +60,8 @@ OLMOE_CONFIGS: Dict[str, OlmoeConfig] = {
         num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
         max_seq_len=4096, rope_theta=10000.0, capacity_factor=8.0,
     ),
-    # E > k > 1 and E >= 8: with T tokens the no-drop dispatch takes the
-    # selective path for T * k <= E (T <= 4) and all-experts above it
+    # E > k > 1 and E >= 8: T tokens sit below, at and above T * k = E
+    # (T < 4, = 4, > 4); the no-drop dispatch is all-experts at each
     "tiny-olmoe": OlmoeConfig(
         vocab_size=256, hidden_size=64, intermediate_size=32,
         num_layers=2, num_heads=4, num_kv_heads=4, head_dim=16,

@@ -197,6 +197,10 @@ class ExpertMLPs:
         bandwidth-bound, and for T tokens this reads T·k experts' weights
         instead of all E (a k·T/E reduction; at Mixtral's T=1, k=2, E=8 that
         is 4× less weight traffic per MoE layer). x (T,H), gates/idx (T,k).
+
+        Not dispatched to by :meth:`__call__`: compiled over a layer scan the
+        gather moves more bytes than all-experts at every T (the table
+        there). Kept as the plain reference of the routed-experts-only read.
         """
         t, k = idx.shape
         # (T,k,H,n_up,I) / (T,k,I,H) dynamic gathers of whole-expert slices
@@ -218,17 +222,24 @@ class ExpertMLPs:
         # under moe/experts, so a device trace says which one a program ran; a
         # traced serving engine's tap (moe/tap.py) is told what was routed and
         # how many pairs are computed
-        t, k = idx.shape
+        t = idx.shape[0]
         tap = routing_tap.current()
         if self.capacity_factor is None:
-            # selective wins exactly when it gathers fewer expert-weight
-            # bytes than streaming all E experts (the role of the reference's
-            # SELECTIVE_LOADING_THRESHOLD dispatch, expert_mlps.py:298-357)
-            if t * k <= self.num_experts:
-                if tap is not None:
-                    tap.record(idx, self.num_experts, "selective", t * k)
-                with jax.named_scope("selective"):
-                    return self.forward_selective(params, x, gates, idx)
+            # all experts at every shape. A gather of the T·k routed experts
+            # asks for fewer bytes where T·k < E, but as compiled over the
+            # serving engines' layer scan it cannot fuse with the scan's
+            # slice: the layer's whole stack is copied out first (read E,
+            # write E), the gather scans it again and writes T·k slices to a
+            # buffer the einsum reads back — against one fused read of E
+            # here. Device ms a layer, weights as a scan's xs in
+            # placement.py's layout, selective / all (v5e, chip run, PR 27):
+            #   E 64, k 8, H 2048, I 1024   T 1: 4.89 / 1.08   2: 5.29 / 1.07
+            #                               T 4: 6.20 / 1.07   8: 9.02 / 1.07
+            #   E 8, k 2, H 4096, I 14336   T 1: 25.5 / 3.74   2: 30.2 / 3.73
+            #                               T 4: 39.8 / 3.73
+            # (the reference dispatches on SELECTIVE_LOADING_THRESHOLD here,
+            # expert_mlps.py:298-357; forward_selective stays as the plain
+            # reference for a kernel that reads only the routed experts)
             if tap is not None:
                 tap.record(idx, self.num_experts, "all", t * self.num_experts)
             with jax.named_scope("all"):

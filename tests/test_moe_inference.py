@@ -1,8 +1,9 @@
-"""MoE inference tests: selective expert loading + Mixtral KV-cache decode.
+"""MoE inference tests: the no-drop expert dispatch + Mixtral KV-cache decode.
 
 Mirrors the reference's Mixtral inference model
-(examples/inference/mixtral/neuron_modeling_mixtral.py) and the selective
-expert-loading token-gen path (modules/moe/expert_mlps.py:267,298-357):
+(examples/inference/mixtral/neuron_modeling_mixtral.py) and its token-gen
+dispatch (modules/moe/expert_mlps.py:267,298-357; the selective
+expert-loading path is kept as a reference, ``__call__`` streams all experts):
 decode must route/compute identically to the training model so incremental
 generation equals full recompute.
 """
@@ -32,6 +33,8 @@ from neuronx_distributed_llama3_2_tpu.models import (
 )
 from neuronx_distributed_llama3_2_tpu.moe.experts import ExpertMLPs
 from neuronx_distributed_llama3_2_tpu.moe.routing import top_k_routing
+
+from tests.test_moe import _dense_reference
 
 TINY_MOE = MIXTRAL_CONFIGS["tiny-moe"]
 # the families MixtralDecode serves: (config, training model); OLMoE adds the
@@ -63,8 +66,9 @@ def test_selective_matches_all_experts():
 
 
 def test_selective_dispatch_threshold(monkeypatch):
-    """__call__ picks selective exactly when T·k <= E (the HBM-traffic
-    crossover; role of the reference SELECTIVE_LOADING_THRESHOLD)."""
+    """The no-drop branch of __call__ streams all experts at every shape —
+    below T·k = E, at it and above it (the table above the rule in
+    moe/experts.py says why). forward_selective stays as the reference."""
     ex = ExpertMLPs(
         num_experts=4, hidden_size=8, intermediate_size=16, dtype=jnp.float32
     )
@@ -76,17 +80,38 @@ def test_selective_dispatch_threshold(monkeypatch):
         "forward_selective",
         lambda self, *a, **k: (calls.append("sel"), real_selective(self, *a, **k))[1],
     )
-    for t, expect_selective in ((1, True), (2, True), (5, False)):
+    for t in (1, 2, 5):  # T·k = 2 < E, 4 = E, 10 > E
         x = jax.random.normal(jax.random.key(t), (t, 8), jnp.float32)
         logits = jax.random.normal(jax.random.key(t + 10), (t, 4), jnp.float32)
         gates, idx = top_k_routing(logits, 2, normalize=True)
         calls.clear()
         y = ex(params, x, gates, idx)
-        assert (len(calls) > 0) == expect_selective, (t, calls)
-        y_ref = ex.forward_all_experts(params, x, gates, idx)
+        assert not calls, t
+        y_ref = real_selective(ex, params, x, gates, idx)
         np.testing.assert_allclose(
             np.asarray(y), np.asarray(y_ref), atol=1e-5, rtol=1e-5
         )
+
+
+@pytest.mark.parametrize("side", ["below", "at", "above"])
+@pytest.mark.parametrize("experts,k", [(8, 2), (64, 8)])
+def test_no_drop_dispatch_equals_selective_and_dense(experts, k, side):
+    """__call__ on each side of T·k = E, at Mixtral's and OLMoE's expert
+    counts, against forward_selective and the dense reference; gates are not
+    renormalised for the second, as OLMoE's are not."""
+    t = experts // k + {"below": -1, "at": 0, "above": 3}[side]
+    ex = ExpertMLPs(
+        num_experts=experts, hidden_size=16, intermediate_size=24, dtype=jnp.float32
+    )
+    params = ex.init(jax.random.key(experts))
+    x = jax.random.normal(jax.random.key(t), (t, 16), jnp.float32)
+    logits = jax.random.normal(jax.random.key(t + 1), (t, experts), jnp.float32)
+    gates, idx = top_k_routing(logits, k, normalize=experts == 8)
+    y = np.asarray(ex(params, x, gates, idx))
+    np.testing.assert_allclose(
+        y, np.asarray(ex.forward_selective(params, x, gates, idx)), atol=1e-5, rtol=1e-5
+    )
+    np.testing.assert_allclose(y, _dense_reference(params, x, gates, idx), atol=1e-5, rtol=1e-5)
 
 
 def test_decode_model_dispatch():
@@ -169,7 +194,7 @@ def test_mixtral_engine_greedy_generate(family):
 
 def test_mixtral_capacity_config_decode_never_drops():
     """A capacity-factor training config still decodes through the no-drop
-    selective/all-experts paths (capacity dispatch is training-only)."""
+    all-experts path (capacity dispatch is training-only)."""
     cfg = dataclasses.replace(TINY_MOE, capacity_factor=1.0)
     params = MixtralForCausalLM(cfg).init(jax.random.key(0))
     decode = MixtralDecode(cfg)

@@ -190,7 +190,10 @@ def test_the_vocabulary_is_covered_by_the_two_suites_above_and_nothing_else_is_u
                         and isinstance(node.args[0], ast.Constant)):
                     used.add(node.args[0].value)
     assert used and used <= names, used - names
-    assert names - used == {"pctx", "psfx", "pdecode"}      # entered by kind, not by literal
+    # pctx/psfx/pdecode are entered by kind, not by literal; "selective" names
+    # the reference path no program enters since the no-drop dispatch is
+    # all-experts at every shape (moe/experts.py)
+    assert names - used == {"pctx", "psfx", "pdecode", "selective"}
 
 
 @pytest.mark.parametrize("M,pp,bubble", [(8, 2, 0.2), (4, 4, 0.6)])

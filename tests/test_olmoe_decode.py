@@ -1,6 +1,7 @@
 """OLMoE through the paged engine: prefill then decode through the pool
 (``pctx``, ``psfx``, ``pdecode``) against the plain float32 reference's full
-forward, on both sides of the expert dispatch rule ``T·k <= E``; the serving
+forward, at and above ``T·k = E`` (where the expert dispatch once changed
+paths; it is all-experts at every shape now); the serving
 check failing what it has to fail; the finer device scopes; the routing
 counters of a traced engine; HF names; weight placement; the joint QK-norm
 under tensor parallelism."""
@@ -79,13 +80,13 @@ def test_olmoe_is_served_by_the_mixtral_decode_model():
         dataclasses.replace(TINY, clip_qkv=8.0)
 
 
-@pytest.mark.parametrize("lanes,path", [(4, "selective"), (8, "all")])
+@pytest.mark.parametrize("lanes,path", [(4, "all"), (8, "all")])
 def test_paged_prefill_then_decode_equals_the_reference(params, traced, lanes, path):
     """Parts A, B and C of the benchmark's check on a tiny engine: the tokens
     the engine emits, and teacher-forced logits through ``pctx`` (first chunk),
-    ``psfx`` (later chunks) and ``pdecode`` over the pool. 4 lanes x 2 = 8 = E
-    decodes on the selective path, 8 lanes on all-experts; a 16-token chunk is
-    always all-experts."""
+    ``psfx`` (later chunks) and ``pdecode`` over the pool. 4 lanes x 2 = 8 = E,
+    where the dispatch rule was once wrong, and 8 lanes above it both decode
+    on all-experts, as a 16-token chunk always did."""
     serving = traced if lanes == 4 else build(params, lanes, trace=True)
     got = run_check(serving, lanes)
     assert got["ok"], got
@@ -117,7 +118,7 @@ def scope_paths(rec):
 
 def test_the_finer_scopes_say_which_path_a_program_took(traced):
     by_kind = {rec.kind: rec for rec in traced.program_registry().values()}
-    want = {"pctx": "all", "psfx": "all", "pdecode": "selective"}
+    want = {"pctx": "all", "psfx": "all", "pdecode": "all"}
     for kind, path in want.items():
         parts = [[inner for _, inner in program_trace.segments(p)] for p in scope_paths(by_kind[kind])]
         assert any("attn" in p and "qk_norm" in p[p.index("attn"):] for p in parts), kind
@@ -150,7 +151,7 @@ def test_routing_counters_ride_the_traced_programs_only(params, traced):
     for step, kind, paths, computed, counts in rows:
         tokens = 4 if kind == "pdecode" else 16
         assert len(counts) == E
-        assert computed == (tokens * k * L if paths == ("selective",) else tokens * E * L)
+        assert paths == ("all",) and computed == tokens * E * L
     # the untraced programs are the ones an engine without a tracer builds:
     # no extra output, and no trace of the tap in their HLO
     for key, rec in plain.program_registry().items():

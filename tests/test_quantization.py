@@ -255,7 +255,7 @@ def test_quantized_mixtral_logits_track_fp():
 
 
 def test_quantized_moe_decode_generates():
-    """int8 weights drive the MoE selective-loading decode end to end."""
+    """int8 weights drive the MoE no-drop decode dispatch end to end."""
     from neuronx_distributed_llama3_2_tpu.inference import (
         GenerationConfig,
         InferenceEngine,
