@@ -166,10 +166,13 @@ class MllamaDecoder:
                 )
                 ci += 1
             else:
-                x, new_k[si], new_v[si] = self._decode._decode_layer(
-                    lp, x, new_k[si], new_v[si], sin, cos, pos_block,
-                    positions, slots, context_encode=False,
+                # the shared layer takes a stack of layers and an index into
+                # it: this cache is a list, so a stack of one and index 0
+                x, kc, vc = self._decode._decode_layer(
+                    lp, x, new_k[si][None], new_v[si][None], 0, sin, cos,
+                    pos_block, positions, slots, context_encode=False,
                 )
+                new_k[si], new_v[si] = kc[0], vc[0]
                 si += 1
 
         x = RMSNorm(t.hidden_size, t.rms_norm_eps, t.dtype)(

@@ -58,7 +58,9 @@ Params = Dict[str, Any]
 
 
 class KVCache(NamedTuple):
-    """Stacked-layer KV cache: k/v (L, B, S_max, n_kv, head_dim)."""
+    """Stacked-layer KV cache: k/v (L, B, S_max, n_kv, head_dim). The whole
+    stack is the carry of ``forward``'s layer loop, written and read at
+    ``[layer, slot, row]``."""
 
     k: jax.Array
     v: jax.Array
@@ -82,6 +84,12 @@ class PagedKVCache(NamedTuple):
     as the null block: block-table entries past a request's allocated
     frontier point at it, so bucket-padding writes land in garbage rows
     that no masked read ever sees.
+
+    ``forward``'s layer loop carries the whole pool and folds the layer into
+    the row index (layer ``l``'s rows start at ``l · num_blocks ·
+    block_size`` of the pool seen as one run of rows), so a donated pool is
+    updated in place: a call moves the rows it writes and the rows it
+    attends over, never a layer of the pool.
 
     Quantized mode (``PagedConfig.kv_cache_dtype`` int8/fp8): ``k``/``v``
     hold the low-bit payloads and ``k_scale``/``v_scale`` carry the
@@ -298,10 +306,10 @@ class LlamaDecode:
             pos_block = positions[:, None] + (
                 depths if depths.ndim == 2 else depths[None, :]
             )
-        # quantized paged pool: each layer's cache slice travels as a
-        # (payload, scale) pair through the scan, so _decode_layer and the
-        # per-family overrides stay signature-stable (they only hand the
-        # slices through to _attend_with_cache, which unpacks)
+        # a quantized paged pool rides the loop as (payload, scale) pairs, so
+        # _decode_layer and the per-family overrides stay signature-stable
+        # (they only hand the pairs through to _attend_with_cache, which
+        # unpacks)
         quantized = getattr(cache, "k_scale", None) is not None
         if quantized and block_tables is None:
             raise ValueError(
@@ -322,42 +330,44 @@ class LlamaDecode:
         norm = make_norm(c)
 
         # a traced serving engine's routing tap (moe/tap.py), None otherwise:
-        # each layer's expert counts leave the body that traced them as a
-        # third per-layer output (nothing for a dense model)
+        # each layer's expert counts leave the body that traced them as the
+        # loop's one per-layer output (nothing for a dense model)
         tap = routing_tap.current()
 
-        def layer_body(x, layer_in):
-            lp, kc, vc = layer_in
+        # the cache is the layer loop's carry, whole, and each layer writes
+        # and reads its rows at [layer, ...]: a loop updates its carry in
+        # place, so with the cache donated only the written rows move. As
+        # the loop's xs and ys — which cannot alias — every call copied the
+        # whole cache three times (PERF.md §6, PR 31)
+        def layer_body(carry, layer_in):
+            x, kc, vc = carry
+            lp, layer = layer_in
             x, kc, vc = self._decode_layer(
-                lp, x, kc, vc, sin, cos, pos_block, positions, slots,
+                lp, x, kc, vc, layer, sin, cos, pos_block, positions, slots,
                 context_encode=context_encode, tree=tree, kv_limit=kv_limit,
                 block_tables=block_tables, row_live=row_live,
             )
-            if tap is None:
-                return x, (kc, vc)
-            return x, (kc, vc, tap.take_layer())
+            return (x, kc, vc), (None if tap is None else tap.take_layer())
 
         if quantized:
-            k_stk: Any = (cache.k, cache.k_scale)
-            v_stk: Any = (cache.v, cache.v_scale)
+            carry: Any = (x, (cache.k, cache.k_scale), (cache.v, cache.v_scale))
         else:
-            k_stk, v_stk = cache.k, cache.v
+            carry = (x, cache.k, cache.v)
         if c.scan_layers:
-            x, per_layer = jax.lax.scan(
-                layer_body, x, (params["layers"], k_stk, v_stk)
+            carry, counts = jax.lax.scan(
+                layer_body, carry,
+                (params["layers"], jnp.arange(c.num_layers, dtype=jnp.int32)),
             )
         else:
-            outs = []
+            per_layer = []
             for i in range(c.num_layers):
-                layer_in = jax.tree.map(
-                    lambda a: a[i], (params["layers"], k_stk, v_stk)
-                )
-                x, out = layer_body(x, layer_in)
-                outs.append(out)
-            per_layer = jax.tree.map(lambda *a: jnp.stack(a), *outs)
-        k_new, v_new = per_layer[:2]
+                lp = jax.tree.map(lambda a: a[i], params["layers"])
+                carry, out = layer_body(carry, (lp, i))
+                per_layer.append(out)
+            counts = None if per_layer[0] is None else jnp.stack(per_layer)
+        x, k_new, v_new = carry
         if tap is not None:
-            tap.commit(per_layer[2], c.num_layers)
+            tap.commit(counts, c.num_layers)
 
         x = norm(params["final_norm"], x)
         if quantized:
@@ -372,15 +382,17 @@ class LlamaDecode:
         return logits, new_cache
 
     def _decode_layer(
-        self, lp, x, kc, vc, sin, cos, pos_block, positions, slots,
+        self, lp, x, kc, vc, layer, sin, cos, pos_block, positions, slots,
         *, context_encode: bool, tree=None, kv_limit=None, block_tables=None,
         row_live=None,
     ):
         """One decoder layer with cache read/write.
 
-        kc/vc: (B, S_max, NKV, D) full cache rows for this layer — or, under
-        ``block_tables``, the (num_blocks, block_size, NKV, D) pool slice;
-        x: (b, T, H). Writes fresh K/V at (slots, pos_block) then attends.
+        kc/vc: the whole cache of every layer, (L, B, S_max, NKV, D) — or,
+        under ``block_tables``, the (L, num_blocks, block_size, NKV, D) pool —
+        and ``layer`` (a traced or a Python int) this layer's index into it;
+        x: (b, T, H). Writes fresh K/V at (layer, slots, pos_block) then
+        attends, and returns the same arrays updated.
         """
         c = self.config
         from neuronx_distributed_llama3_2_tpu.models.llama import (
@@ -411,7 +423,7 @@ class LlamaDecode:
                 k = apply_rope(k, sin, cos, pos_block)
 
             att, kc, vc = self._attend_with_cache(
-                q, k, v, kc, vc, slots, pos_block, positions,
+                q, k, v, kc, vc, layer, slots, pos_block, positions,
                 context_encode=context_encode, tree=tree, kv_limit=kv_limit,
                 block_tables=block_tables, row_live=row_live,
             )
@@ -424,14 +436,16 @@ class LlamaDecode:
         return x, kc, vc
 
     def _attend_with_cache(
-        self, q, k, v, kc, vc, slots, pos_block, positions,
+        self, q, k, v, kc, vc, layer, slots, pos_block, positions,
         *, context_encode: bool, tree=None, kv_limit=None, block_tables=None,
         row_live=None,
     ):
         """Cache write + attention, shared by every decode family (Llama,
-        MoE, GPT-NeoX): scatter the fresh roped K/V into the cache, then
-        bucket-causal (prefill) or cache attention (token-gen). Returns
-        (att (b,T,N,D), kc, vc)."""
+        MoE, GPT-NeoX): scatter the fresh roped K/V into layer ``layer`` of
+        the cache, then bucket-causal (prefill) or cache attention
+        (token-gen). kc/vc are the whole (L, ...) cache; the layer is part
+        of the scatter's and the gather's index, never sliced out first — a
+        ``kc[layer]`` would copy that layer. Returns (att (b,T,N,D), kc, vc)."""
         c = self.config
 
         # scatter-write the fresh block into the cache at (slot, position) —
@@ -447,18 +461,18 @@ class LlamaDecode:
         )
         if block_tables is not None:
             return self._attend_paged(
-                q, k, v, kc, vc, block_tables, write_rows, pos_block,
+                q, k, v, kc, vc, layer, block_tables, write_rows, pos_block,
                 positions, context_encode=context_encode, tree=tree,
                 kv_limit=kv_limit, row_live=row_live,
             )
         if isinstance(kc, tuple):
             raise ValueError(
-                "quantized (payload, scale) cache slices reach the dense "
+                "a quantized (payload, scale) cache reaches the dense "
                 "path only on a caller bug — forward() guards block_tables"
             )
         with jax.named_scope("kv_write"):
-            kc = kc.at[slots[:, None], write_rows].set(k.astype(kc.dtype))
-            vc = vc.at[slots[:, None], write_rows].set(v.astype(vc.dtype))
+            kc = kc.at[layer, slots[:, None], write_rows].set(k.astype(kc.dtype))
+            vc = vc.at[layer, slots[:, None], write_rows].set(v.astype(vc.dtype))
 
         ha = _head_axis(c.num_heads)
         if context_encode:
@@ -474,13 +488,11 @@ class LlamaDecode:
                 att = core_attention(q, k, v, causal=True)
         else:
             # attend over the cache rows of the active slots, bounded to the
-            # token-gen bucket when given (static slice — reads only
+            # token-gen bucket when given (static bound — reads only
             # kv_limit rows from HBM instead of the whole S_max cache)
             with jax.named_scope("kv_read"):
-                kr = kc if kv_limit is None else kc[:, :kv_limit]
-                vr = vc if kv_limit is None else vc[:, :kv_limit]
-                k_all = jnp.take(kr, slots, axis=0).astype(q.dtype)  # (b,S≤max,NKV,D)
-                v_all = jnp.take(vr, slots, axis=0).astype(q.dtype)
+                k_all = kc[layer, slots, :kv_limit].astype(q.dtype)  # (b,S≤max,NKV,D)
+                v_all = vc[layer, slots, :kv_limit].astype(q.dtype)
             with jax.named_scope("sdpa"):
                 att = self._cache_attention(
                     q, k_all, v_all, pos_block, ha, positions=positions,
@@ -489,14 +501,18 @@ class LlamaDecode:
         return att, kc, vc
 
     def _attend_paged(
-        self, q, k, v, kc, vc, block_tables, write_rows, pos_block, positions,
-        *, context_encode: bool, tree=None, kv_limit=None, row_live=None,
+        self, q, k, v, kc, vc, layer, block_tables, write_rows, pos_block,
+        positions, *, context_encode: bool, tree=None, kv_limit=None,
+        row_live=None,
     ):
         """Paged cache write + attention: the block table translates logical
         sequence rows to pool rows for both the fresh-block scatter and the
-        attention gather. kc/vc: (num_blocks, block_size, NKV, D) per-layer
-        pool slice — or, quantized, the ((num_blocks, block_size, NKV, D)
-        payload, (num_blocks, block_size, NKV) scale) pair. Numerically
+        attention gather. kc/vc: the whole (L, num_blocks, block_size, NKV, D)
+        pool — or, quantized, the ((L, num_blocks, block_size, NKV, D)
+        payload, (L, num_blocks, block_size, NKV) scale) pair — seen as one
+        run of ``L · num_blocks · block_size`` rows (a reshape of leading,
+        unsharded axes), in which layer ``layer``'s rows start at
+        ``layer · num_blocks · block_size``. Numerically
         identical to the dense path — the gathered K/V rows carry the same
         values in the same logical order, and garbage rows (stale blocks,
         null-block padding) are removed by the same ``j <= position + t``
@@ -511,13 +527,19 @@ class LlamaDecode:
             kc, ksc = kc
             vc, vsc = vc
         with jax.named_scope("kv_write"):
-            nb, bs = kc.shape[0], kc.shape[1]
-            kflat = kc.reshape((nb * bs,) + kc.shape[2:])
-            vflat = vc.reshape((nb * bs,) + vc.shape[2:])
-            # logical row p of batch row i -> pool row table[i, p//bs]*bs + p%bs;
-            # rows past the allocated frontier map to the null block (id 0)
+            nl, nb, bs = kc.shape[:3]
+
+            def rows(a):  # every layer's rows in one run
+                return a.reshape((nl * nb * bs,) + a.shape[3:])
+
+            kflat, vflat = rows(kc), rows(vc)
+            # logical row p of batch row i -> this layer's pool row
+            # table[i, p//bs]*bs + p%bs; rows past the allocated frontier map
+            # to the layer's null block (id 0)
+            base = layer * (nb * bs)
             wr_phys = (
-                jnp.take_along_axis(block_tables, write_rows // bs, axis=1) * bs
+                base
+                + jnp.take_along_axis(block_tables, write_rows // bs, axis=1) * bs
                 + write_rows % bs
             )
             if quantized:
@@ -531,8 +553,7 @@ class LlamaDecode:
                 # replace both and stale rows can never poison a later read
                 kq, ks = kv_quantize(k, kflat.dtype)   # (b,t,NKV,D) / (b,t,NKV)
                 vq, vs = kv_quantize(v, vflat.dtype)
-                ksflat = ksc.reshape((nb * bs,) + ksc.shape[2:])
-                vsflat = vsc.reshape((nb * bs,) + vsc.shape[2:])
+                ksflat, vsflat = rows(ksc), rows(vsc)
                 kflat = kflat.at[wr_phys].set(kq)
                 vflat = vflat.at[wr_phys].set(vq)
                 ksflat = ksflat.at[wr_phys].set(ks)
@@ -579,6 +600,16 @@ class LlamaDecode:
                 )
 
                 with jax.named_scope("sdpa"):
+                    # the kernel sees every layer's blocks as one pool of
+                    # L · num_blocks, and this layer's table points into it
+                    def blocks(a):
+                        return a.reshape((nl * nb,) + a.shape[2:])
+
+                    kpool, vpool = blocks(kc), blocks(vc)
+                    kspool = vspool = None
+                    if quantized:
+                        kspool, vspool = blocks(ksc), blocks(vsc)
+                    layer_tables = block_tables + layer * nb
                     tree_bits = None
                     if tree is not None:
                         anc = tree[1]
@@ -606,16 +637,16 @@ class LlamaDecode:
                         # arrays ride in on the same head split — no new
                         # collective.
                         att = paged_flash_decode_tp(
-                            q, kc, vc, block_tables, positions,
+                            q, kpool, vpool, layer_tables, positions,
                             mesh=parallel_state.get_parallel_state().mesh,
-                            kv_limit=limit, k_scale=ksc, v_scale=vsc,
+                            kv_limit=limit, k_scale=kspool, v_scale=vspool,
                             quant_mxu=c.quant_mxu and ksc is not None,
                             row_live=row_live, tree_bits=tree_bits,
                         )
                     else:
                         att = paged_flash_decode(
-                            q, kc, vc, block_tables, positions, kv_limit=limit,
-                            k_scale=ksc, v_scale=vsc,
+                            q, kpool, vpool, layer_tables, positions,
+                            kv_limit=limit, k_scale=kspool, v_scale=vspool,
                             quant_mxu=c.quant_mxu and ksc is not None,
                             row_live=row_live, tree_bits=tree_bits,
                         )
@@ -623,7 +654,11 @@ class LlamaDecode:
             else:
                 with jax.named_scope("kv_read"):
                     jlog = jnp.arange(limit, dtype=jnp.int32)
-                    rd_phys = block_tables[:, jlog // bs] * bs + (jlog % bs)[None, :]
+                    rd_phys = (
+                        base
+                        + block_tables[:, jlog // bs] * bs
+                        + (jlog % bs)[None, :]
+                    )
                     if quantized:
                         # dequant outside the kernel, same f32-widen formula the
                         # kernel fuses after its block DMA — bit-identical
@@ -1291,7 +1326,7 @@ class GPTNeoXDecode(LlamaDecode):
         return GPTNeoXForCausalLM(self.config)
 
     def _decode_layer(
-        self, lp, x, kc, vc, sin, cos, pos_block, positions, slots,
+        self, lp, x, kc, vc, layer, sin, cos, pos_block, positions, slots,
         *, context_encode: bool, tree=None, kv_limit=None, block_tables=None,
         row_live=None,
     ):
@@ -1322,7 +1357,7 @@ class GPTNeoXDecode(LlamaDecode):
                 q, k = attn._apply_rope(q, k, sin, cos, pos_block)
 
             att, kc, vc = self._attend_with_cache(
-                q, k, v, kc, vc, slots, pos_block, positions,
+                q, k, v, kc, vc, layer, slots, pos_block, positions,
                 context_encode=context_encode, tree=tree, kv_limit=kv_limit,
                 block_tables=block_tables, row_live=row_live,
             )

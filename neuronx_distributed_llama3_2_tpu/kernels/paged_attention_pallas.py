@@ -276,7 +276,7 @@ def _decode_kernel(
 
 def paged_flash_decode(
     q: jax.Array,             # (b, N, D) single query — or (b, t, N, D)
-    k_pool: jax.Array,        # (num_blocks, bs, NKV, D) pool slice
+    k_pool: jax.Array,        # (num_blocks, bs, NKV, D): every layer's blocks in one run
     v_pool: jax.Array,        # (num_blocks, bs, NKV, D)
     block_tables: jax.Array,  # (b, W) int32; entries must be < num_blocks
     positions: jax.Array,     # (b,) int32 — row of the FIRST fresh query
@@ -497,7 +497,7 @@ def paged_flash_decode(
 
 def paged_flash_decode_tp(
     q: jax.Array,             # (b, N, D) single query — or (b, t, N, D)
-    k_pool: jax.Array,        # (num_blocks, bs, NKV, D) pool slice
+    k_pool: jax.Array,        # (num_blocks, bs, NKV, D): every layer's blocks in one run
     v_pool: jax.Array,        # (num_blocks, bs, NKV, D)
     block_tables: jax.Array,  # (b, W) int32 — REPLICATED per rank
     positions: jax.Array,     # (b,) int32 — REPLICATED per rank

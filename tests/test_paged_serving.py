@@ -9,6 +9,7 @@ tests already establish for the dense programs)."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -427,3 +428,141 @@ def test_admit_blocked_counter(params):
     assert paged.metrics.admit_blocked > 0
     snap = paged.metrics.snapshot(paged.allocator, paged.index)
     assert "admit_blocked" in snap and "prefill_chunks" in snap
+
+
+# ---------------------------------------------------------------------------
+# the layer loop carries the whole pool and indexes it by layer
+# ---------------------------------------------------------------------------
+
+
+def _loop_families():
+    from neuronx_distributed_llama3_2_tpu.models import (
+        MIXTRAL_CONFIGS,
+        MixtralForCausalLM,
+    )
+    from neuronx_distributed_llama3_2_tpu.models.gptneox import (
+        GPTNEOX_CONFIGS,
+        GPTNeoXForCausalLM,
+    )
+    from neuronx_distributed_llama3_2_tpu.models.olmoe import (
+        OLMOE_CONFIGS,
+        OlmoeForCausalLM,
+    )
+
+    return {
+        "llama": (dataclasses.replace(TINY, num_layers=3), LlamaForCausalLM),
+        "mixtral": (MIXTRAL_CONFIGS["tiny-moe"], MixtralForCausalLM),
+        "olmoe": (OLMOE_CONFIGS["tiny-olmoe"], OlmoeForCausalLM),
+        "gptneox": (dataclasses.replace(GPTNEOX_CONFIGS["tiny-neox"], num_layers=3),
+                    GPTNeoXForCausalLM),
+    }
+
+
+def _noisy_pool(model, kv):
+    """A pool of 12 blocks of 4 rows with something in every row, so that a
+    row the program should not have written shows when it has."""
+    pool = model.init_paged_cache(12, 4, kv_cache_dtype=kv)
+    keys = iter(jax.random.split(jax.random.key(7), 4))
+
+    def noise(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jax.random.randint(next(keys), a.shape, -127, 128, jnp.int32).astype(a.dtype)
+        return (0.1 + jax.random.uniform(next(keys), a.shape)).astype(a.dtype)
+
+    return jax.tree.map(noise, pool)
+
+
+def _per_layer_loop(model, params, pool, tokens, positions, tables, **kw):
+    """``forward`` as a plain loop over ``pool[l]``: each layer is handed a
+    pool of its one layer and index 0, and the layers' pools are stacked
+    again — a ``lax.scan`` with the pool as its ``xs`` and ``ys`` where the
+    model scans its layers (the form ``forward`` had), a Python loop where
+    it unrolls them."""
+    from neuronx_distributed_llama3_2_tpu.models.llama import make_norm
+
+    c = model.config
+    pos_block = positions[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+    sin, cos = model._rope_tables(tables.shape[1] * pool.block_size)
+
+    def layer(x, layer_in):
+        lp, one = layer_in
+        one = jax.tree.map(lambda a: a[None], one)
+        kc, vc = (
+            ((one.k, one.k_scale), (one.v, one.v_scale)) if pool.quantized
+            else (one.k, one.v)
+        )
+        x, kc, vc = model._decode_layer(
+            lp, x, kc, vc, 0, sin, cos, pos_block, positions, None,
+            block_tables=tables, **kw,
+        )
+        one = (
+            type(pool)(k=kc[0], v=vc[0], k_scale=kc[1], v_scale=vc[1])
+            if pool.quantized else type(pool)(k=kc, v=vc)
+        )
+        return x, jax.tree.map(lambda a: a[0], one)
+
+    x = model._model()._embed()(params["embed"], tokens)
+    if c.scan_layers:
+        x, new_pool = jax.lax.scan(layer, x, (params["layers"], pool))
+    else:
+        layers = []
+        for l in range(c.num_layers):
+            x, one = layer(x, jax.tree.map(lambda a: a[l], (params["layers"], pool)))
+            layers.append(one)
+        new_pool = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    x = make_norm(c)(params["final_norm"], x)
+    return model._model()._logits(params, x), new_pool
+
+
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("program", ["pctx", "psfx", "pdecode"])
+@pytest.mark.parametrize("family", ["llama", "mixtral", "olmoe", "gptneox"])
+def test_layer_loop_over_the_whole_pool_is_a_loop_over_its_layers(family, program, scan, kv):
+    """Logits and the *whole* returned pool — the rows of other layers and of
+    unwritten blocks with them — are bit-equal to the per-layer loop."""
+    from neuronx_distributed_llama3_2_tpu.inference import decode_model_for
+
+    cfg, train = _loop_families()[family]
+    cfg = dataclasses.replace(cfg, scan_layers=scan)
+    model = decode_model_for(cfg)
+    params = train(cfg).init(jax.random.key(0))
+    pool = _noisy_pool(model, kv)
+    # two lanes of four blocks; lane 1's last entry is the null block
+    tables = jnp.asarray([[3, 9, 1, 5], [7, 2, 11, 0]], jnp.int32)
+    rng = np.random.default_rng(3)
+    if program == "pctx":
+        tokens, positions = rng.integers(0, cfg.vocab_size, (2, 8)), [0, 0]
+        kw = dict(context_encode=True)
+    elif program == "psfx":
+        tokens, positions = rng.integers(0, cfg.vocab_size, (2, 4)), [5, 8]
+        kw = dict(context_encode=False, kv_limit=16)
+    else:
+        tokens, positions = rng.integers(0, cfg.vocab_size, (2, 1)), [6, 11]
+        kw = dict(context_encode=False, kv_limit=16)
+    tokens = jnp.asarray(tokens, jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
+
+    want_logits, want_pool = jax.jit(
+        lambda p, ch: _per_layer_loop(model, p, ch, tokens, positions, tables, **kw)
+    )(params, pool)
+    got_logits, got_pool = jax.jit(
+        lambda p, ch: model.forward(p, ch, tokens, positions, None, block_tables=tables, **kw),
+        donate_argnums=(1,),
+    )(params, jax.tree.map(jnp.copy, pool))
+
+    np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(want_logits))
+    written = 0
+    for got, want, before in zip(
+        jax.tree.leaves(got_pool), jax.tree.leaves(want_pool), jax.tree.leaves(pool)
+    ):
+        assert got.shape == before.shape and got.dtype == before.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        written += int(np.sum(np.any(
+            np.asarray(got) != np.asarray(before), axis=tuple(range(3, got.ndim))
+        )))
+    # the rows the program wrote, and no other, differ from what was there:
+    # T rows a lane in every layer of every leaf (lane 1's rows 12.. of psfx
+    # and pdecode do not exist: positions stay inside the table)
+    leaves = len(jax.tree.leaves(pool))
+    assert written == leaves * cfg.num_layers * tokens.size

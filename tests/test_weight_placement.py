@@ -280,14 +280,15 @@ def v5e():
         pytest.skip(f"libtpu cannot describe a v5e here: {exc}")
 
 
-def compile_paged(device, program, rested):
-    """The paged decode step at 16 lanes, or a 512-token suffix step, over a
-    2-layer stack, with the weights placed as the engine places them
-    (``rested``) or as ``model.init`` leaves them."""
+def compile_paged(device, program, rested, cfg=AOT, train=MixtralForCausalLM, blocks=256, kv=None):
+    """The paged decode step at 16 lanes, or a 512-token suffix step, over
+    ``cfg``'s layer stack (the 2-layer MoE where nothing else is asked) and a
+    donated pool of ``blocks`` blocks, with the weights placed as the engine
+    places them (``rested``) or as ``model.init`` leaves them."""
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(device)
-    model = decode_model_for(AOT)
+    model = decode_model_for(cfg)
 
     def place(path, a):
         layout = fused_rest_layout(path, a) if rested else None
@@ -295,10 +296,10 @@ def compile_paged(device, program, rested):
             a.shape, a.dtype, sharding=one if layout is None else Format(layout, one)
         )
 
-    params = walk_tree(jax.eval_shape(MixtralForCausalLM(AOT).init, jax.random.key(0)), place)
+    params = walk_tree(jax.eval_shape(train(cfg).init, jax.random.key(0)), place)
     cache = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
-        jax.eval_shape(lambda: model.init_paged_cache(256, 16)),
+        jax.eval_shape(lambda: model.init_paged_cache(blocks, 16, kv_cache_dtype=kv)),
     )
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)  # noqa: E731
     if program == "pdecode":
@@ -327,3 +328,82 @@ def test_paged_program_over_rested_weights_copies_no_layer_of_gate_up(v5e, progr
     assert saved >= 0.95 * LAYER_BYTES, (saved, LAYER_BYTES)
     # the parameter rests tiled as the dot reads it
     assert "bf16[2,%s]{4,2,3,1,0:T(8,128)(2,1)}" % ",".join(map(str, LAYER_SHAPE)) in rested.as_text()
+
+
+# ---------------------------------------------------------------------------
+# compiled for a described v5e: the pool is updated in place
+# ---------------------------------------------------------------------------
+
+# a Llama-shaped stack whose pool — (4, 1024, 16, 8, 128) twice, 268 MB in
+# bf16 — is far larger than anything else a step keeps: a pool, or one layer
+# of it, among the temporaries cannot hide
+POOL_AOT = dataclasses.replace(
+    LLAMA_CONFIGS["tiny"], hidden_size=1024, intermediate_size=2816, num_layers=4,
+    num_heads=8, num_kv_heads=8, head_dim=128, vocab_size=2048, max_seq_len=1024,
+    dtype=jnp.bfloat16,
+)
+POOL_BLOCKS = 1024
+MOVES_A_POOL = ("copy", "dynamic-slice", "dynamic-update-slice", "dynamic_slice", "dynamic_update_slice")
+
+
+def pool_sized_moves(text, shapes):
+    """Instructions of the compiled text that copy, slice or update in bulk a
+    result of one of ``shapes``: (name, type) pairs."""
+    found = []
+    for name, result, op in re.findall(r"^\s*(?:ROOT )?%?(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(", text, re.M):
+        dims = result[result.index("[") + 1:-1]
+        if dims in shapes and any(m in name or m == op for m in MOVES_A_POOL):
+            found.append((name, result))
+    return found
+
+
+def test_a_pool_that_is_the_scans_xs_and_ys_is_copied_whole(v5e):
+    """The control: the form the layer loop had. A loop cannot alias its
+    ``xs`` with its ``ys``, so a donated pool is copied, and the search below
+    finds that copy."""
+    from jax.sharding import SingleDeviceSharding
+
+    shape = (4, 256, 16, 8, 128)
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=SingleDeviceSharding(v5e))
+
+    def fn(pool):
+        def body(x, layer):
+            return x + 1, layer.at[x, 0].set(1)
+        return jax.lax.scan(body, jnp.int32(0), pool)[1]
+
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(pool).compile()
+    dims = {",".join(map(str, shape)), ",".join(map(str, (1,) + shape[1:]))}
+    assert pool_sized_moves(compiled.as_text(), dims)
+
+
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("program", ["pdecode", "psfx"])
+def test_paged_program_updates_the_donated_pool_in_place(v5e, program, kv):
+    compiled = compile_paged(
+        v5e, program, rested=True, cfg=POOL_AOT, train=LlamaForCausalLM,
+        blocks=POOL_BLOCKS, kv=kv,
+    )
+    text = compiled.as_text()
+    model = decode_model_for(POOL_AOT)
+    pool = jax.eval_shape(lambda: model.init_paged_cache(POOL_BLOCKS, 16, kv_cache_dtype=kv))
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    # no bulk copy, slice or update of the pool's shape or of one layer's
+    # (with or without its leading 1). A quantized pool's *scales* — a 64th
+    # of its bytes — are re-tiled once on the way in and once on the way
+    # out: their default layout is not the one the row scatter wants
+    shapes = [pool.k.shape, (1,) + pool.k.shape[1:], pool.k.shape[1:]]
+    if kv is not None:
+        shapes += [(1,) + pool.k_scale.shape[1:], pool.k_scale.shape[1:]]
+    dims = {",".join(map(str, s)) for s in shapes}
+    assert not pool_sized_moves(text, dims), pool_sized_moves(text, dims)
+    # every pool array is a parameter the program may write its output over
+    n_params = len(jax.tree.leaves(jax.eval_shape(LlamaForCausalLM(POOL_AOT).init, jax.random.key(0))))
+    aliased = re.search(r"input_output_alias=\{(.*?) \}, entry_computation_layout", text).group(1)
+    assert (
+        sorted(int(n) for n in re.findall(r"\((\d+), \{\}", aliased))
+        == [n_params + i for i in range(len(jax.tree.leaves(pool)))]
+    ), aliased
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == pool_bytes
+    # as the scan's xs and ys the temporaries held a whole pool and more
+    assert memory.temp_size_in_bytes < 0.2 * pool_bytes, (memory.temp_size_in_bytes, pool_bytes)
