@@ -26,9 +26,11 @@ from neuronx_distributed_llama3_2_tpu.inference.engine import (
 )
 from neuronx_distributed_llama3_2_tpu.inference.model import (
     KVCache,
+    LatentCache,
     LlamaDecode,
     MixtralDecode,
     PagedKVCache,
+    SarvamDecode,
     decode_model_for,
 )
 from neuronx_distributed_llama3_2_tpu.inference.sampling import (
@@ -62,6 +64,7 @@ __all__ = [
     "GenerationConfig",
     "InferenceEngine",
     "KVCache",
+    "LatentCache",
     "LatencyCollector",
     "LlamaDecode",
     "MedusaBuffers",
@@ -72,6 +75,7 @@ __all__ = [
     "MllamaCache",
     "MllamaDecoder",
     "PagedKVCache",
+    "SarvamDecode",
     "SamplingConfig",
     "decode_model_for",
     "SpeculativeDecoder",

@@ -35,6 +35,7 @@ b <= B active requests addresses its rows explicitly.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -119,6 +120,42 @@ class PagedKVCache(NamedTuple):
         return self.k_scale is not None
 
 
+class LatentCache(NamedTuple):
+    """Latent-attention cache (MLA, models/sarvam.py): one array of rows
+    ``[c ‖ k_r]`` — the normed latent and the rotated shared rotary key — and
+    nothing by head. Dense: ``kv`` (L, B, S_max, W); paged: (L, num_blocks,
+    block_size, W), block 0 the null block, with the layer folded into the
+    row index exactly as :class:`PagedKVCache`'s. ``W`` is the row's width
+    rounded up to whole 128-lanes (``SarvamDecode.pool_row_width``): the
+    values sit first, zeros after. There is no quantized form."""
+
+    kv: jax.Array
+
+    @property
+    def max_batch(self) -> int:
+        return self.kv.shape[1]
+
+    @property
+    def max_len(self) -> int:
+        return self.kv.shape[2]
+
+    num_blocks = max_batch
+    block_size = max_len
+    quantized = False
+
+
+def cache_row_bytes(cache: Any) -> int:
+    """Bytes a token leaves in ``cache`` a layer *as the device lays the
+    arrays out*: the argument bytes of a compiled program that takes the
+    cache — tile padding included: on a TPU a bf16 array's two minor axes
+    are tiled (16, 128), so a 576-wide row occupies 640 — over its
+    (layer, block or slot, row) positions. Compiles an identity; for a
+    traced engine's ``setup`` record."""
+    compiled = jax.jit(lambda c: c).lower(cache).compile()
+    rows = math.prod(jax.tree.leaves(cache)[0].shape[:3])
+    return compiled.memory_analysis().argument_size_in_bytes // rows
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaDecode:
     """Decode-mode Llama sharing the training model's parameter pytree.
@@ -192,6 +229,13 @@ class LlamaDecode:
             k_scale=jnp.zeros(sshape, KV_SCALE_DTYPE),
             v_scale=jnp.zeros(sshape, KV_SCALE_DTYPE),
         )
+
+    def cache_row_dims(self) -> Tuple[int, int, int]:
+        """(arrays, heads, width) of what a token leaves in the cache, a
+        layer — here k and v by kv head. The serving layer's byte arithmetic
+        (pool size, a block's bytes, a program's cost) reads this and nothing
+        else of the cache's shape."""
+        return 2, self.config.num_kv_heads, self.config.head_dim
 
     def paged_cache_specs(self, quantized: bool = False) -> PagedKVCache:
         """Paged-pool sharding: kv heads over tp (same GQA rule as the dense
@@ -329,6 +373,25 @@ class LlamaDecode:
         x = constrain(x, P(BATCH_AXES, None, None))
         norm = make_norm(c)
 
+        x, new_cache = self._run_layers(
+            params, cache, x, sin, cos, pos_block, positions, slots,
+            context_encode=context_encode, tree=tree, kv_limit=kv_limit,
+            block_tables=block_tables, row_live=row_live,
+        )
+        x = norm(params["final_norm"], x)
+        if return_hidden:
+            return x, new_cache
+        logits = model._logits(params, x)
+        return logits, new_cache
+
+    def _run_layers(
+        self, params, cache, x, sin, cos, pos_block, positions, slots,
+        *, context_encode: bool, tree=None, kv_limit=None, block_tables=None,
+        row_live=None,
+    ):
+        """The layer loop of :meth:`forward`: x (b, T, H) through every
+        decoder layer over ``cache``; returns (x, the cache updated)."""
+        c = self.config
         # a traced serving engine's routing tap (moe/tap.py), None otherwise:
         # each layer's expert counts leave the body that traced them as the
         # loop's one per-layer output (nothing for a dense model)
@@ -349,6 +412,7 @@ class LlamaDecode:
             )
             return (x, kc, vc), (None if tap is None else tap.take_layer())
 
+        quantized = getattr(cache, "k_scale", None) is not None
         if quantized:
             carry: Any = (x, (cache.k, cache.k_scale), (cache.v, cache.v_scale))
         else:
@@ -369,17 +433,11 @@ class LlamaDecode:
         if tap is not None:
             tap.commit(counts, c.num_layers)
 
-        x = norm(params["final_norm"], x)
         if quantized:
-            new_cache = type(cache)(
+            return x, type(cache)(
                 k=k_new[0], v=v_new[0], k_scale=k_new[1], v_scale=v_new[1]
             )
-        else:
-            new_cache = type(cache)(k=k_new, v=v_new)
-        if return_hidden:
-            return x, new_cache
-        logits = model._logits(params, x)
-        return logits, new_cache
+        return x, type(cache)(k=k_new, v=v_new)
 
     def _decode_layer(
         self, lp, x, kc, vc, layer, sin, cos, pos_block, positions, slots,
@@ -1309,6 +1367,186 @@ class MixtralDecode(LlamaDecode):
 
 
 @dataclasses.dataclass(frozen=True)
+class SarvamDecode(MixtralDecode):
+    """Decode-mode latent attention (MLA, :mod:`..models.sarvam`) over a
+    :class:`LatentCache`: what a token leaves in the cache is the one row
+    ``[c ‖ k_r]``, written after the norm and the rotation, and every program
+    reads those rows — never keys or values by head.
+
+    Which form of the attention a program runs follows from its shape alone:
+    ``pctx`` (``context_encode``) attends over the fresh block expanded;
+    a block over cached rows (``psfx``, ``pdecode``) runs absorbed where that
+    costs fewer FLOPs (:func:`..models.sarvam.absorbed_is_cheaper`: one
+    decode row, a short chunk) and expanded otherwise (a 512-row chunk
+    up-projects the cached rows through ``W_UKV``). Prefill attention runs
+    over query blocks of at most 512 rows, so no program holds a
+    (heads, S, S) tensor.
+
+    The leading dense layers are a stack of their own ahead of the expert
+    layers' scan, the pool's layer index running through both. The Pallas
+    paged kernel reads k/v by head and is never eligible; tree (speculative)
+    blocks and a quantized pool are refused."""
+
+    def _model(self):
+        from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamForCausalLM
+
+        return SarvamForCausalLM(self.config)
+
+    # -- cache ------------------------------------------------------------
+
+    @property
+    def pool_row_width(self) -> int:
+        """The cache's minor axis: the row's 576 values in whole lanes of 128
+        (640). A TPU tiles a bf16 array's two minor axes (16, 128); at 576 the
+        device's default layout, which avoids padding, makes the *block* axis
+        minor — every program then re-tiled the whole pool on its way in and
+        out (two pool-sized copies a call, AOT for a v5e, PR 33). At 640 the
+        default is row-major, the pool is a donated carry written in place,
+        and the bytes are those the row-major tiling would have padded to."""
+        return -(-self.config.cache_row_width // 128) * 128
+
+    def init_cache(self, max_batch: int, max_len: int, dtype: Any = None) -> LatentCache:
+        c = self.config
+        shape = (c.num_layers, max_batch, max_len, self.pool_row_width)
+        return LatentCache(kv=jnp.zeros(shape, dtype or c.dtype))
+
+    def init_paged_cache(
+        self, num_blocks: int, block_size: int, dtype: Any = None,
+        kv_cache_dtype: Optional[str] = None,
+    ) -> LatentCache:
+        if kv_cache_dtype not in (None, "bf16"):
+            raise NotImplementedError(
+                f"kv_cache_dtype={kv_cache_dtype!r}: a latent (MLA) pool has no "
+                "quantized form — its row is one vector shared by every head, "
+                "and the per-(row, head) scale tiles of quantization/kv_cache "
+                "do not describe it"
+            )
+        return self.init_cache(num_blocks, block_size, dtype)
+
+    def cache_row_dims(self) -> Tuple[int, int, int]:
+        return 1, 1, self.pool_row_width
+
+    def paged_cache_specs(self, quantized: bool = False) -> LatentCache:
+        """The latent row is shared by every head: replicated over tp."""
+        return LatentCache(kv=P())
+
+    def cache_specs(self, max_batch: Optional[int] = None) -> LatentCache:
+        return LatentCache(kv=P())
+
+    def forbidden_gather_shapes(self, batch: int, kv_limit: int):
+        return {(batch, kv_limit, self.pool_row_width)}
+
+    def _paged_kernel_eligible(self, t: int, tree) -> bool:
+        return False
+
+    # -- forward ----------------------------------------------------------
+
+    def _run_layers(
+        self, params, cache, x, sin, cos, pos_block, positions, slots,
+        *, context_encode: bool, tree=None, kv_limit=None, block_tables=None,
+        row_live=None,
+    ):
+        if tree is not None:
+            raise NotImplementedError("tree verification over a latent cache")
+        c = self.config
+        tap = routing_tap.current()
+
+        def layer_body(carry, layer_in):
+            x, pool = carry
+            lp, layer = layer_in
+            x, pool = self._latent_layer(
+                lp, x, pool, layer, sin, cos, pos_block, slots,
+                context_encode=context_encode, kv_limit=kv_limit,
+                block_tables=block_tables,
+            )
+            return (x, pool), (None if tap is None else tap.take_layer())
+
+        # the dense layers' stack, then the expert layers', one carry and one
+        # run of layer indices through both
+        carry, first = (x, cache.kv), 0
+        for name in ("dense_layers", "layers"):
+            if name not in params:
+                continue
+            count = jax.tree.leaves(params[name])[0].shape[0]
+            carry, counts = jax.lax.scan(
+                layer_body, carry,
+                (params[name], first + jnp.arange(count, dtype=jnp.int32)),
+            )
+            if tap is not None:
+                tap.commit(counts, count)
+            first += count
+        return carry[0], LatentCache(kv=carry[1])
+
+    def _latent_layer(
+        self, lp, x, pool, layer, sin, cos, pos_block, slots,
+        *, context_encode: bool, kv_limit=None, block_tables=None,
+    ):
+        """One decoder layer over the latent cache: pool (L, num_blocks,
+        block_size, W) under ``block_tables``, else (L, B, S_max, W); writes
+        the fresh rows at layer ``layer`` and attends (see the class)."""
+        from neuronx_distributed_llama3_2_tpu.models.sarvam import (
+            LatentAttention,
+            absorbed_is_cheaper,
+            latent_attention,
+        )
+
+        c = self.config
+        attn = LatentAttention(c)
+        norm = make_norm(c)
+        t = x.shape[1]
+        h = norm(lp["attn_norm"], x)
+        with jax.named_scope("attn"):
+            q, rows = attn.project(lp["attn"], h, sin, cos, pos_block)
+            with jax.named_scope("kv_write"):
+                # the row's values, then zeros up to the pool's whole lanes
+                stored = jnp.pad(
+                    rows.astype(pool.dtype),
+                    ((0, 0), (0, 0), (0, pool.shape[-1] - rows.shape[-1])),
+                )
+                if block_tables is None:
+                    pool = pool.at[layer, slots[:, None], pos_block].set(stored)
+                else:
+                    # every layer's rows as one run: never pool[layer]
+                    nl, nb, bs, w = pool.shape
+                    flat = pool.reshape(nl * nb * bs, w)
+                    base = layer * (nb * bs)
+                    wr_phys = (
+                        base
+                        + jnp.take_along_axis(block_tables, pos_block // bs, axis=1) * bs
+                        + pos_block % bs
+                    )
+                    flat = flat.at[wr_phys].set(stored)
+                    pool = flat.reshape(pool.shape)
+            if context_encode:
+                # the fresh block alone, expanded (what the training model runs)
+                seen, absorbed = rows, False
+            else:
+                with jax.named_scope("kv_read"):
+                    if block_tables is None:
+                        seen = pool[layer, slots, :kv_limit]
+                    else:
+                        # gathered a block at a time: a block's rows lie together
+                        # (block_size · W values), so the gather moves
+                        # limit / block_size slices a lane and not ``limit`` rows
+                        # (row by row it ran at a tenth of the bandwidth: 15 of a
+                        # decode step's 32 ms; chip run, PR 33)
+                        limit = kv_limit if kv_limit is not None else block_tables.shape[1] * bs
+                        nblk = -(-limit // bs)
+                        blocks = flat.reshape(nl * nb, bs, w)[layer * nb + block_tables[:, :nblk]]
+                        seen = blocks.reshape(blocks.shape[0], nblk * bs, w)[:, :limit]   # (b, limit, W)
+                    seen = seen[..., :c.cache_row_width]
+                absorbed = absorbed_is_cheaper(c, t)
+            att = latent_attention(
+                c, lp["attn"]["kv_b"]["kernel"], q, seen, pos_block, absorbed=absorbed
+            )
+            attn_out = attn.output(lp["attn"], att)
+        x = x + attn_out
+        h = norm(lp["mlp_norm"], x)
+        ffn = MixtralDecode._mlp_block if "moe" in lp else LlamaDecode._mlp_block
+        return x + ffn(self, lp, h), pool
+
+
+@dataclasses.dataclass(frozen=True)
 class GPTNeoXDecode(LlamaDecode):
     """Decode-mode GPT-NeoX/Pythia/CodeGen: the shared KV-cache machinery
     (:meth:`LlamaDecode._attend_with_cache`) under the family's block
@@ -1382,6 +1620,7 @@ def decode_model_for(config) -> LlamaDecode:
     from neuronx_distributed_llama3_2_tpu.models.bert import BertConfig
     from neuronx_distributed_llama3_2_tpu.models.gptneox import GPTNeoXConfig
     from neuronx_distributed_llama3_2_tpu.models.mixtral import MixtralConfig
+    from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamConfig
 
     if isinstance(config, BertConfig):
         raise NotImplementedError(
@@ -1390,6 +1629,8 @@ def decode_model_for(config) -> LlamaDecode:
         )
     if isinstance(config, GPTNeoXConfig):
         return GPTNeoXDecode(config)
+    if isinstance(config, SarvamConfig):
+        return SarvamDecode(config)
     if isinstance(config, MixtralConfig):
         return MixtralDecode(config)
     return LlamaDecode(config)

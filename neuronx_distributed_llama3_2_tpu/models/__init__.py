@@ -17,6 +17,11 @@ from neuronx_distributed_llama3_2_tpu.models.olmoe import (  # noqa: F401
     params_from_hf_olmoe,
     params_to_hf_olmoe,
 )
+from neuronx_distributed_llama3_2_tpu.models.sarvam import (  # noqa: F401
+    SARVAM_CONFIGS,
+    SarvamConfig,
+    SarvamForCausalLM,
+)
 from neuronx_distributed_llama3_2_tpu.models.dbrx import (  # noqa: F401
     DBRX_CONFIGS,
     DbrxConfig,
@@ -75,6 +80,12 @@ def model_registry():
         reg[name] = {
             "config": cfg, "model_cls": OlmoeForCausalLM,
             "from_hf": params_from_hf_olmoe, "to_hf": params_to_hf_olmoe,
+        }
+    for name, cfg in SARVAM_CONFIGS.items():
+        # the catalog publishes no tensor names for sarvam_mla: no HF map
+        reg[name] = {
+            "config": cfg, "model_cls": SarvamForCausalLM,
+            "from_hf": None, "to_hf": None,
         }
     for name, cfg in DBRX_CONFIGS.items():
         reg[name] = {

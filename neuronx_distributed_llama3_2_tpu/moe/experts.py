@@ -58,6 +58,13 @@ class ExpertMLPs:
     capacity_factor: Optional[float] = None  # None => all-experts path
     glu: bool = True
     dtype: Any = jnp.bfloat16
+    # a share of a wider router's experts (one rank of an expert-parallel
+    # deployment run alone): the stack holds ids ``first_expert ..
+    # first_expert + num_experts - 1`` of ``routed_experts``; a pair routed
+    # to any other id contributes nothing here. None / 0: the stack is all
+    # the router has.
+    routed_experts: Optional[int] = None
+    first_expert: int = 0
 
     def init(self, key: jax.Array) -> Params:
         e, h, i = self.num_experts, self.hidden_size, self.intermediate_size
@@ -113,9 +120,10 @@ class ExpertMLPs:
         # docs/moe_1f1b_tp.md for the minimal repro), and dense one-hot
         # contractions are the MXU-friendly formulation anyway (same trick
         # as the reference's top-k one-hot in moe/loss_function.py:5).
-        onehot = (
-            idx[:, :, None] == jnp.arange(self.num_experts, dtype=idx.dtype)
-        ).astype(jnp.float32)  # (T, k, E)
+        held = jnp.arange(
+            self.first_expert, self.first_expert + self.num_experts, dtype=idx.dtype
+        )
+        onehot = (idx[:, :, None] == held).astype(jnp.float32)  # (T, k, E)
         combine = jnp.einsum("tke,tk->te", onehot, gates)  # (T, E)
         return jnp.einsum(
             "te,eth->th", combine.astype(x.dtype), y_all
@@ -241,7 +249,16 @@ class ExpertMLPs:
             # expert_mlps.py:298-357; forward_selective stays as the plain
             # reference for a kernel that reads only the routed experts)
             if tap is not None:
-                tap.record(idx, self.num_experts, "all", t * self.num_experts)
+                tap.record(
+                    idx, self.routed_experts or self.num_experts, "all",
+                    t * self.num_experts, held=(self.first_expert, self.num_experts),
+                )
             with jax.named_scope("all"):
                 return self.forward_all_experts(params, x, gates, idx)
+        if self.routed_experts not in (None, self.num_experts):
+            raise NotImplementedError(
+                "a held share of the experts runs the no-drop path only "
+                "(capacity_factor=None): the capacity dispatch buffers every "
+                "routed id"
+            )
         return self.forward_capacity_factor(params, x, gates, idx)

@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 from neuronx_distributed_llama3_2_tpu.moe.experts import ExpertMLPs
 from neuronx_distributed_llama3_2_tpu.moe.routing import (
     Router,
+    sigmoid_bias_routing,
     sinkhorn_routing,
     top_k_routing,
 )
@@ -56,17 +57,43 @@ class MoEConfig:
     # None => all-experts path (no dropping); reference SELECTIVE_LOADING /
     # forward_all_experts dispatch (expert_mlps.py:298-357)
     capacity_factor: Optional[float] = None
-    routing: str = "topk"  # "topk" | "sinkhorn"
+    # "topk" (softmax, then the k largest) | "sinkhorn" | "sigmoid_bias"
+    # (sigmoid scores chosen by score + a learned bias, gates renormalised
+    # and scaled by ``routed_scale``: routing.sigmoid_bias_routing)
+    routing: str = "topk"
     normalize_top_k: bool = True
     sinkhorn_iterations: int = 3
+    routed_scale: float = 1.0
     glu: bool = True
     dtype: Any = jnp.bfloat16
+    # width of the shared expert(s) every token passes through beside its
+    # routed ones (n shared experts of width w are one MLP of width n·w);
+    # 0 = none
+    shared_intermediate_size: int = 0
+    # which of the router's ``num_experts`` this block holds: ids
+    # ``first_held .. first_held + experts_held - 1`` (one rank's share of an
+    # expert-parallel deployment, run alone). The router stays ``num_experts``
+    # wide; a pair routed to an expert held elsewhere contributes nothing
+    # here. None = all of them (every model but a cut deployment).
+    experts_held: Optional[int] = None
+    first_held: int = 0
 
     def __post_init__(self):
-        if self.routing not in ("topk", "sinkhorn"):
-            raise ValueError(f"routing must be topk|sinkhorn, got {self.routing!r}")
+        if self.routing not in ("topk", "sinkhorn", "sigmoid_bias"):
+            raise ValueError(
+                f"routing must be topk|sinkhorn|sigmoid_bias, got {self.routing!r}"
+            )
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError("need 1 <= top_k <= num_experts")
+        if not (self.held >= 1 and 0 <= self.first_held <= self.num_experts - self.held):
+            raise ValueError(
+                f"held experts {self.first_held}..{self.first_held + self.held - 1} "
+                f"are not among the router's {self.num_experts}"
+            )
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None else self.experts_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,31 +112,54 @@ class MoE:
 
     def _router(self) -> Router:
         c = self.config
-        return Router(c.hidden_size, c.num_experts, c.dtype)
+        return Router(
+            c.hidden_size, c.num_experts, c.dtype,
+            selection_bias=c.routing == "sigmoid_bias",
+        )
 
     def _experts(self) -> ExpertMLPs:
         c = self.config
         return ExpertMLPs(
-            num_experts=c.num_experts,
+            num_experts=c.held,
             hidden_size=c.hidden_size,
             intermediate_size=c.intermediate_size,
             capacity_factor=c.capacity_factor,
             glu=c.glu,
             dtype=c.dtype,
+            routed_experts=c.num_experts,
+            first_expert=c.first_held,
         )
 
     def init(self, key: jax.Array) -> Params:
+        c = self.config
         kr, ke = jax.random.split(key)
-        return {
+        params = {
             "router": self._router().init(kr),
             "experts": self._experts().init(ke),
         }
+        if c.shared_intermediate_size:
+            kg, kd = jax.random.split(jax.random.fold_in(key, 2))
+            h, i = c.hidden_size, c.shared_intermediate_size
+            params["shared"] = {
+                "gate_up": (jax.random.normal(kg, (h, 2, i), jnp.float32) * 0.02).astype(c.dtype),
+                "down": (jax.random.normal(kd, (i, h), jnp.float32) * 0.02).astype(c.dtype),
+            }
+        return params
 
     def specs(self) -> Params:
-        return {
+        specs = {
             "router": self._router().specs(),
             "experts": self._experts().specs(),
         }
+        if self.config.shared_intermediate_size:
+            specs["shared"] = {"gate_up": P(None, None, TP_AXIS), "down": P(TP_AXIS, None)}
+        return specs
+
+    @jax.named_scope("shared")
+    def _shared(self, params: Params, x_flat: jax.Array) -> jax.Array:
+        """The shared expert: a SwiGLU MLP over every token, x (T, H)."""
+        h1 = jnp.einsum("th,hui->tui", x_flat, params["gate_up"])
+        return (jax.nn.silu(h1[:, 0]) * h1[:, 1]) @ params["down"]
 
     @jax.named_scope("router")
     def _route(self, router_params: Params, x_flat: jax.Array):
@@ -118,6 +168,11 @@ class MoE:
         if c.routing == "sinkhorn":
             gates, idx = sinkhorn_routing(
                 logits, c.top_k, c.sinkhorn_iterations, c.normalize_top_k
+            )
+        elif c.routing == "sigmoid_bias":
+            gates, idx = sigmoid_bias_routing(
+                logits, router_params["bias"], c.top_k, c.routed_scale,
+                c.normalize_top_k,
             )
         else:
             gates, idx = top_k_routing(logits, c.top_k, c.normalize_top_k)
@@ -140,6 +195,8 @@ class MoE:
         else:
             logits, gates, idx = self._route(params["router"], x_flat)
             y = self._experts()(params["experts"], x_flat, gates, idx)
+        if self.config.shared_intermediate_size:
+            y = y + self._shared(params["shared"], x_flat)
         return y.reshape(b, s, h), logits, idx
 
     # -- EP execution ------------------------------------------------------
@@ -151,6 +208,11 @@ class MoE:
         Experts EP entry/exit, experts.py:121-152), run the local experts,
         all-to-all back, combine."""
         c = self.config
+        if c.held != c.num_experts:
+            raise NotImplementedError(
+                "a held share of the experts is one rank run alone; under an "
+                "ep > 1 mesh the mesh holds them all"
+            )
         experts = self._experts()
         mesh = parallel_state.get_parallel_state().mesh
         # inside a partial-manual region (the pp pipeline stage) the nested

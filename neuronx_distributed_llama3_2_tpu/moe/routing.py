@@ -31,18 +31,30 @@ class Router:
     hidden_size: int
     num_experts: int
     dtype: Any = jnp.float32
+    # a learned per-expert selection bias beside the kernel
+    # (:func:`sigmoid_bias_routing`); seeded small and non-zero, so that a
+    # seeded model's choice of experts depends on it
+    selection_bias: bool = False
 
     def init(self, key: jax.Array) -> Params:
         scale = self.hidden_size ** -0.5
         kernel = jax.random.normal(
             key, (self.hidden_size, self.num_experts), jnp.float32
         ) * scale
-        return {"kernel": kernel}
+        if not self.selection_bias:
+            return {"kernel": kernel}
+        bias = 0.1 * jax.random.normal(
+            jax.random.fold_in(key, 1), (self.num_experts,), jnp.float32
+        )
+        return {"kernel": kernel, "bias": bias}
 
     def specs(self) -> Params:
         from jax.sharding import PartitionSpec as P
 
-        return {"kernel": P(None, None)}
+        specs = {"kernel": P(None, None)}
+        if self.selection_bias:
+            specs["bias"] = P(None)
+        return specs
 
     def __call__(self, params: Params, x: jax.Array) -> jax.Array:
         """x (T, H) -> logits (T, E) fp32 (router math always fp32;
@@ -63,6 +75,25 @@ def top_k_routing(
     if normalize:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     return gates.astype(jnp.float32), idx.astype(jnp.int32)
+
+
+def sigmoid_bias_routing(
+    logits: jax.Array, bias: jax.Array, top_k: int, scale: float = 1.0,
+    normalize: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores with a selection bias (the aux-loss-free router of the
+    DeepSeek-V3 lineage): ``s = sigmoid(logits)``; the ``top_k`` experts are
+    the largest of ``s + bias`` — the learned per-expert ``bias`` chooses and
+    never weighs — and the gates are the chosen experts' *unbiased* scores,
+    renormalised to sum to one (``normalize``, HF ``norm_topk_prob``) and
+    multiplied by ``scale`` (``routed_scaling_factor``). Returns (gates
+    (T, k) fp32, idx (T, k))."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return (scale * gates).astype(jnp.float32), idx.astype(jnp.int32)
 
 
 def sinkhorn(cost: jax.Array, n_iters: int = 3) -> jax.Array:

@@ -21,7 +21,7 @@ layer's counts out of the ``lax.scan`` body that traced them).
 from __future__ import annotations
 
 import contextlib
-from typing import List, Optional, Set
+from typing import List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,12 +36,18 @@ class RoutingTap:
         self._pending_pairs = 0
         self._layer_pairs = 0
         self.tokens_per_expert: Optional[jax.Array] = None   # (E,) int32 over all layers
+        # (first id, count) of the experts the blocks hold: all of the
+        # router's, unless the model holds a share of them (MoEConfig.experts_held)
+        self.held: Optional[Tuple[int, int]] = None
         self.pairs_computed = 0
         self.paths: Set[str] = set()
 
-    def record(self, idx: jax.Array, num_experts: int, path: str, pairs_computed: int) -> None:
-        """One expert block routed ``idx`` (T, k) and computes
-        ``pairs_computed`` (token, expert) pairs on ``path``."""
+    def record(self, idx: jax.Array, num_experts: int, path: str, pairs_computed: int,
+               held: Optional[Tuple[int, int]] = None) -> None:
+        """One expert block routed ``idx`` (T, k) over ``num_experts`` and
+        computes ``pairs_computed`` (token, expert) pairs on ``path``;
+        ``held`` = (first id, count) of the experts it holds (all, if None)."""
+        self.held = held or (0, num_experts)
         # compare-to-iota, not a scatter-add: see ExpertMLPs.forward_all_experts
         hit = idx[:, :, None] == jnp.arange(num_experts, dtype=idx.dtype)
         hit = hit & self.live[:, None, None]

@@ -332,6 +332,10 @@ def hf_to_native(args) -> None:
     from neuronx_distributed_llama3_2_tpu.checkpoint import save_checkpoint
 
     entry = _resolve_model(args.model)
+    if entry["from_hf"] is None:
+        raise NotImplementedError(
+            f"{args.model!r} has no from_hf converter in the model registry"
+        )
     sd = load_hf_state_dict(args.input)
     params = entry["from_hf"](sd, entry["config"])
     save_checkpoint(args.output, tag=args.tag, model=params)

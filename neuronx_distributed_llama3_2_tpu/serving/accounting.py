@@ -136,6 +136,9 @@ class EngineDims:
     tp_size: int
     quant_mxu: bool = False      # int8 q·k dot on the MXU (config.quant_mxu)
     fused_sampling: bool = False  # per-lane sampling residents in lane_set
+    # arrays a cache row is spread over: k and v, or 1 for a latent pool
+    # (then num_kv_heads = 1 and head_dim = the row's width)
+    kv_arrays: int = 2
 
     @classmethod
     def from_engine(cls, engine: Any) -> "EngineDims":
@@ -143,6 +146,7 @@ class EngineDims:
         import numpy as np
 
         mc = engine.model.config
+        kv_arrays, kv_heads, kv_width = engine.model.cache_row_dims()
         leaves = jax.tree.leaves(engine.engine.params)
         num_params = sum(int(np.prod(l.shape)) for l in leaves)
         param_bytes = sum(
@@ -157,18 +161,19 @@ class EngineDims:
             param_bytes=param_bytes,
             num_layers=mc.num_layers,
             hidden_size=mc.hidden_size,
-            num_kv_heads=mc.num_kv_heads,
-            head_dim=mc.head_dim,
+            num_kv_heads=kv_heads,
+            head_dim=kv_width,
             vocab_size=mc.vocab_size,
             max_batch=engine.engine.max_batch,
             table_width=engine.table_width,
             block_size=engine.paged.block_size,
             num_blocks=engine.paged.num_blocks,
-            kv_bytes_per_elem=engine.cache.k.dtype.itemsize,
+            kv_bytes_per_elem=jax.tree.leaves(engine.cache)[0].dtype.itemsize,
             scale_bytes=kv_scale_itemsize(engine.paged.kv_cache_dtype),
             tp_size=max(int(engine.metrics.tp_size), 1),
             quant_mxu=bool(getattr(engine.model.config, "quant_mxu", False)),
             fused_sampling=bool(getattr(engine, "_fused", False)),
+            kv_arrays=kv_arrays,
         )
 
     @property
@@ -184,10 +189,10 @@ class EngineDims:
         return self.param_bytes // self.tp_size
 
     def kv_row_bytes(self) -> int:
-        """HBM bytes one KV row (all layers, K and V, local heads) holds,
+        """HBM bytes one KV row (all layers, every array, local heads) holds,
         scale arrays included when the pool is quantized."""
         per_head = self.head_dim * self.kv_bytes_per_elem + self.scale_bytes
-        return 2 * self.num_layers * self.kv_heads_local * per_head
+        return self.kv_arrays * self.num_layers * self.kv_heads_local * per_head
 
 
 def _flops_per_token(
@@ -258,7 +263,7 @@ def analytic_cost(key: tuple, dims: EngineDims) -> Tuple[float, float, str]:
         rows = dims.max_batch * (kv + t - 1)
         tokens = dims.max_batch * t
     elif kind == "copy_block":
-        elems = 2 * dims.num_layers * dims.block_size \
+        elems = dims.kv_arrays * dims.num_layers * dims.block_size \
             * dims.kv_heads_local * dims.head_dim
         return float(elems), float(2 * elems * dims.kv_bytes_per_elem), \
             "analytic-move"
@@ -278,7 +283,7 @@ def analytic_cost(key: tuple, dims: EngineDims) -> Tuple[float, float, str]:
         # payload under quantized storage, so rows are priced at
         # kv_row_bytes — these are the figures the restore-vs-recompute
         # crossover divides by HOST_LINK_BW_BYTES_PER_S.
-        elems = 2 * dims.num_layers * dims.block_size \
+        elems = dims.kv_arrays * dims.num_layers * dims.block_size \
             * dims.kv_heads_local * dims.head_dim
         byts = 2 * dims.block_size * dims.kv_row_bytes()
         return float(elems), float(byts), "analytic-move"
@@ -310,6 +315,7 @@ def analytic_profile(key: tuple, dims: EngineDims) -> CostProfile:
             dtype_bytes=dims.kv_bytes_per_elem,
             tp_size=dims.tp_size,
             scale_bytes=dims.scale_bytes,
+            arrays=dims.kv_arrays,
         )
         arg = dims.param_bytes_local + pool
         out = dims.max_batch * 4

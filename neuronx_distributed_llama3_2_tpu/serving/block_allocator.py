@@ -387,8 +387,11 @@ def kv_pool_bytes_per_rank(
     dtype_bytes: int,
     tp_size: int = 1,
     scale_bytes: int = 0,
+    arrays: int = 2,
 ) -> int:
-    """Bytes of paged KV pool (K and V) resident on ONE chip.
+    """Bytes of paged KV pool resident on ONE chip: ``arrays`` arrays (K and
+    V; one for a latent pool, whose row is 1 "head" of its own width —
+    ``LlamaDecode.cache_row_dims``).
 
     The pool shards its kv-head dim over the tensor-parallel mesh when
     divisible (``LlamaDecode.paged_cache_specs`` — the same GQA rule as the
@@ -412,4 +415,4 @@ def kv_pool_bytes_per_rank(
         else num_kv_heads
     )
     row_bytes = head_dim * dtype_bytes + scale_bytes
-    return 2 * num_layers * num_blocks * block_size * heads * row_bytes
+    return arrays * num_layers * num_blocks * block_size * heads * row_bytes

@@ -61,6 +61,7 @@ from neuronx_distributed_llama3_2_tpu.serving.faults import (
     FaultInjector,
     InjectedFault,
 )
+from neuronx_distributed_llama3_2_tpu.inference.model import cache_row_bytes
 from neuronx_distributed_llama3_2_tpu.inference.placement import (
     committed_home,
 )
@@ -136,8 +137,9 @@ def _with_routing_tap(fn, record: "ProgramRecord"):
     """``fn`` traced with a routing tap open: returns ``(fn's outputs, live
     tokens routed to each expert over all layers)`` — ``None`` for a model
     without experts — and leaves on ``record.routing`` what the trace showed
-    of the expert block: the dispatch paths taken and the (token, expert)
-    pairs they compute per call."""
+    of the expert block: the dispatch paths taken, the (token, expert)
+    pairs they compute per call, and which of the router's experts the
+    model holds."""
     live_rows = _LIVE_ROWS[record.kind]
 
     @functools.wraps(fn)
@@ -147,6 +149,7 @@ def _with_routing_tap(fn, record: "ProgramRecord"):
         record.routing = {
             "paths": tuple(sorted(tap.paths)),
             "pairs_computed": tap.pairs_computed,
+            "held": tap.held,
         }
         return out, tap.tokens_per_expert
 
@@ -783,11 +786,12 @@ class PagedServingEngine:
 
         mc = self.model.config
         tp = parallel_state.tensor_parallel_size_or(1)
+        arrays, row_heads, row_width = self.model.cache_row_dims()
         pool_dims = dict(
             num_layers=mc.num_layers, num_blocks=paged.num_blocks,
-            block_size=bs, num_kv_heads=mc.num_kv_heads,
-            head_dim=mc.head_dim, dtype_bytes=self.cache.k.dtype.itemsize,
-            scale_bytes=kv_scale_itemsize(paged.kv_cache_dtype),
+            block_size=bs, num_kv_heads=row_heads, head_dim=row_width,
+            dtype_bytes=jax.tree.leaves(self.cache)[0].dtype.itemsize,
+            scale_bytes=kv_scale_itemsize(paged.kv_cache_dtype), arrays=arrays,
         )
         self.metrics.tp_size = tp
         self.metrics.kv_dtype = paged.kv_cache_dtype
@@ -856,23 +860,15 @@ class PagedServingEngine:
         self._wait_ms = 0.0          # per-step readback wait scratch
         self._last_log_step = 0      # dedupe periodic metrics logging
         self._last_prefill_bucket = 0  # bucket of the most recent prefill
+        self._last_prefill_kv = 0      # its kv_limit rung; 0 = pctx, no cache read
         self._programs: Dict[tuple, ProgramRecord] = {}
-        if self._kv_quantized:
-            # COW copies the block's scale tile with its payload — the scale
-            # IS part of the block's value under quantized storage
-            def _copy_block(c, s, d):
-                return type(c)(
-                    k=c.k.at[:, d].set(c.k[:, s]),
-                    v=c.v.at[:, d].set(c.v[:, s]),
-                    k_scale=c.k_scale.at[:, d].set(c.k_scale[:, s]),
-                    v_scale=c.v_scale.at[:, d].set(c.v_scale[:, s]),
-                )
-        else:
-            def _copy_block(c, s, d):
-                return type(c)(
-                    k=c.k.at[:, d].set(c.k[:, s]),
-                    v=c.v.at[:, d].set(c.v[:, s]),
-                )
+        # the block programs move whatever arrays the decode model's cache
+        # is made of — k and v, their scale tiles under quantized storage
+        # (the scale IS part of a block's value), or one latent row array —
+        # at [:, block] of each: (L, num_blocks, block_size, ...)
+        def _copy_block(c, s, d):
+            return jax.tree.map(lambda a: a.at[:, d].set(a[:, s]), c)
+
         self._copy_block_fn = self._register_program(
             ("copy_block", self._kv_quantized), _copy_block,
             donate_argnums=(0,), kind="copy_block",
@@ -887,29 +883,15 @@ class PagedServingEngine:
         self._block_save_fn = None
         self._block_restore_fn = None
         if self._spill:
-            if self._kv_quantized:
-                # scale tiles ARE part of the block's value under quantized
-                # storage — they spill and restore with the payload
-                def _block_save(c, b):
-                    return (c.k[:, b], c.v[:, b],
-                            c.k_scale[:, b], c.v_scale[:, b])
+            def _block_save(c, b):
+                return tuple(a[:, b] for a in jax.tree.leaves(c))
 
-                def _block_restore(c, b, k, v, ks, vs):
-                    return type(c)(
-                        k=c.k.at[:, b].set(k),
-                        v=c.v.at[:, b].set(v),
-                        k_scale=c.k_scale.at[:, b].set(ks),
-                        v_scale=c.v_scale.at[:, b].set(vs),
-                    )
-            else:
-                def _block_save(c, b):
-                    return (c.k[:, b], c.v[:, b])
+            def _block_restore(c, b, *payload):
+                leaves, treedef = jax.tree.flatten(c)
+                return jax.tree.unflatten(
+                    treedef, [a.at[:, b].set(x) for a, x in zip(leaves, payload)]
+                )
 
-                def _block_restore(c, b, k, v):
-                    return type(c)(
-                        k=c.k.at[:, b].set(k),
-                        v=c.v.at[:, b].set(v),
-                    )
             self._block_save_fn = self._register_program(
                 ("block_save", self._kv_quantized), _block_save,
                 kind="block_save",
@@ -1008,7 +990,7 @@ class PagedServingEngine:
     def _note_routed(self, rec: ProgramRecord, counts: jax.Array) -> None:
         self.tracer.routed(
             self._step_index, rec.kind, rec.routing["paths"],
-            rec.routing["pairs_computed"], counts,
+            rec.routing["pairs_computed"], counts, rec.routing["held"],
         )
 
     def program_registry(self) -> Dict[tuple, ProgramRecord]:
@@ -1080,7 +1062,8 @@ class PagedServingEngine:
         fused weight leaves the engine re-placed and their bytes
         (inference/placement.py), and the largest ``temp_size_in_bytes``
         among the dispatched programs — which is where a per-layer copy of
-        a weight shows. Each record is lowered as it was dispatched and
+        a weight shows — and the bytes a token leaves in the pool a layer
+        (``inference.model.cache_row_bytes``). Each record is lowered as it was dispatched and
         compiled for its memory analysis (a persistent-cache hit where
         there is a cache, a compile where there is none), so traced
         engines only."""
@@ -1095,6 +1078,7 @@ class PagedServingEngine:
             "program_temp_bytes_max": max(
                 (p.temp_bytes for p in profiles.values()), default=0
             ),
+            "cache_row_bytes": cache_row_bytes(self.cache),
         }
 
     def _kv_bucket(self, needed: int) -> int:
@@ -2423,19 +2407,13 @@ class PagedServingEngine:
 
     def _null_block_payload(self) -> tuple:
         """Aval twins of a restore's uploaded payload arrays (one block's
-        k/v slices, plus scale tiles when quantized): plain ``jnp`` zeros,
+        slice of every cache array: k/v, plus scale tiles when quantized): plain ``jnp`` zeros,
         so prewarm's ``block_restore`` dispatch traces at exactly traffic's
         shapes/dtypes without touching the ``h2d_uploads`` counter."""
-        c = self.cache
-        ks = c.k.shape  # (L, num_blocks, block_size, NKV_local, D)
-        shape = (ks[0], ks[2], ks[3], ks[4])
-        out = [jnp.zeros(shape, c.k.dtype), jnp.zeros(shape, c.v.dtype)]
-        if self._kv_quantized:
-            ss = c.k_scale.shape  # (L, num_blocks, block_size, NKV_local)
-            sshape = (ss[0], ss[2], ss[3])
-            out.append(jnp.zeros(sshape, c.k_scale.dtype))
-            out.append(jnp.zeros(sshape, c.v_scale.dtype))
-        return tuple(out)
+        return tuple(
+            jnp.zeros(a.shape[:1] + a.shape[2:], a.dtype)
+            for a in jax.tree.leaves(self.cache)
+        )
 
     def _spill_block(self, bid: int) -> bool:
         """``BlockAllocator.spill_hook``: move the eviction victim's
@@ -2752,6 +2730,7 @@ class PagedServingEngine:
                     "prefill", t_p, t_p1, rid=req.rid,
                     tokens=len(suffix), cached=cached,
                     bucket=self._last_prefill_bucket,
+                    kv_bucket=self._last_prefill_kv,
                     pad=self._last_prefill_bucket - max(len(suffix), 1),
                 )
             req.out.append(first)
@@ -2785,6 +2764,7 @@ class PagedServingEngine:
         eng = self.engine
         bucket = pick_bucket(self._prefill_buckets, max(len(suffix), 1))
         self._last_prefill_bucket = bucket  # tracer pad-waste tag
+        self._last_prefill_kv = 0
         ids = np.zeros((1, bucket), np.int32)
         ids[0, : len(suffix)] = suffix
         length = np.asarray([max(len(suffix), 1)], np.int32)
@@ -2801,6 +2781,7 @@ class PagedServingEngine:
             )
         else:
             kv_limit = self._kv_bucket(min(cached + bucket, eng.max_seq_len))
+            self._last_prefill_kv = kv_limit
             fn = self._prefill_suffix_program(
                 bucket, kv_limit, self._decode_cfg()
             )
@@ -2877,6 +2858,7 @@ class PagedServingEngine:
                     "prefill_chunk", t_p, t_p1, rid=req.rid,
                     tokens=len(piece), final=final,
                     bucket=self._last_prefill_bucket,
+                    kv_bucket=self._last_prefill_kv,
                     pad=self._last_prefill_bucket - max(len(piece), 1),
                 )
             req.prefill_pos = start + len(piece)
@@ -3235,6 +3217,8 @@ class PagedServingEngine:
                 "dispatch", t_d, program=program_label(fn), mode="async",
                 sampling=smode, lanes=len(decode_lanes), kv_bucket=kv_limit,
                 kv_pad=kv_limit - kv_need,
+                # cache rows the live lanes attend over, this step's included
+                rows=int(sum(self._positions[l] for l in decode_lanes)) + len(decode_lanes),
             )
         self._d_tokens = toks
         self._dispatch_count += 1
@@ -3304,6 +3288,8 @@ class PagedServingEngine:
                 "dispatch", t_d, program=program_label(fn), mode="sync",
                 sampling=smode, lanes=len(decode_lanes), kv_bucket=kv_limit,
                 kv_pad=kv_limit - kv_need,
+                # cache rows the live lanes attend over, this step's included
+                rows=int(sum(self._positions[l] for l in decode_lanes)) + len(decode_lanes),
             )
         self._d_tokens = toks
         self._dispatch_count += 1

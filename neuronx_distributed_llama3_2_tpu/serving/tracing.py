@@ -81,9 +81,14 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
 # them and book their ops to the parent. ``attn/qk_norm`` is OLMoE's joint
 # RMSNorm of q and k (models/llama.py); ``moe/experts/selective`` and
 # ``moe/experts/all`` say which no-drop dispatch path a program took
-# (moe/experts.py).
+# (moe/experts.py); ``attn/latent_down`` (``W_DKV`` and the latent's norm),
+# ``attn/latent_up`` (``W_UKV`` over the rows attended) and ``attn/absorb``
+# (``W_UK`` / ``W_UV`` folded into the query and the output) are latent
+# attention's (models/sarvam.py); ``moe/shared`` the shared expert
+# (moe/model.py).
 DETAIL_SCOPES = {
-    "attn": ("qk_norm",),
+    "attn": ("qk_norm", "latent_down", "latent_up", "absorb"),
+    "moe": ("shared",),
     "moe/experts": ("selective", "all"),
 }
 
@@ -163,7 +168,7 @@ class EngineTracer:
         self._marks: deque = deque(maxlen=max(int(max_requests), 1))
         self._drive: deque = deque(maxlen=self.buffer_steps)
         # what construction did, written once by the engine's prewarm:
-        # relaid_leaves, relaid_bytes, program_temp_bytes_max
+        # relaid_leaves, relaid_bytes, program_temp_bytes_max, cache_row_bytes
         self.setup: Dict[str, int] = {}
         # routing counters, one entry per dispatch of a tapped program
         # (moe/tap.py): (step, kind, dispatch paths, pairs computed, live
@@ -244,9 +249,11 @@ class EngineTracer:
             self._done.append((rid, self._spans.pop(rid)))
 
     def routed(self, step: int, kind: str, paths: Tuple[str, ...],
-               pairs_computed: int, tokens_per_expert: Any) -> None:
-        """One dispatch of a tapped program in engine step ``step``."""
-        self._routed.append((step, kind, paths, pairs_computed, tokens_per_expert))
+               pairs_computed: int, tokens_per_expert: Any,
+               held: Optional[Tuple[int, int]] = None) -> None:
+        """One dispatch of a tapped program in engine step ``step``; ``held``
+        = (first id, count) of the experts the model holds (all, if None)."""
+        self._routed.append((step, kind, paths, pairs_computed, tokens_per_expert, held))
 
     def mark(self, name: str, rid: int, **args: Any) -> None:
         """Point event of one request (``first_token``)."""
@@ -310,8 +317,10 @@ class EngineTracer:
         engine's construction counts), ``routed`` [(step, program kind,
         dispatch paths, (token, expert) pairs computed, [live tokens — no
         bucket padding, no idle lane — routed to each expert, summed over
-        layers])] per dispatch of a program with experts (reading it waits
-        for the device)."""
+        layers])] per dispatch of a program with experts, ``routed_local``
+        [of those live pairs, the ones routed to an expert the model holds —
+        all of them unless it holds a share of the router's] beside it
+        (reading them waits for the device)."""
         states = {rid: list(trans) for rid, trans in self._done}
         states.update({rid: list(t) for rid, t in self._spans.items()})
         routed = list(self._routed)
@@ -329,7 +338,11 @@ class EngineTracer:
             "setup": dict(self.setup),
             "routed": [
                 (step, kind, paths, pairs, [int(n) for n in per_expert])
-                for (step, kind, paths, pairs, _), per_expert in zip(routed, counts)
+                for (step, kind, paths, pairs, *_), per_expert in zip(routed, counts)
+            ],
+            "routed_local": [
+                int(sum(per_expert[slice(held[0], held[0] + held[1]) if held else slice(None)]))
+                for (*_, held), per_expert in zip(routed, counts)
             ],
         }
 

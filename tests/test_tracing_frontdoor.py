@@ -196,7 +196,7 @@ def test_tracing_off_records_nothing_and_enters_no_annotation(params, monkeypatc
     assert _CountingAnnotation.entered == 0
     tl = eng.tracer.timeline()
     assert tl == {"steps": [], "requests": [], "states": {}, "marks": [], "drive": [],
-                  "setup": {}, "routed": []}
+                  "setup": {}, "routed": [], "routed_local": []}
     assert eng.tracer.open_request() is None and eng.tracer._conn == 0
     # on: one annotation a step, each closed again
     eng_on = _paged(params, GenerationConfig(max_new_tokens=4), trace_enabled=True)
