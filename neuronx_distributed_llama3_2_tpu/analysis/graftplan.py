@@ -193,7 +193,6 @@ class Workload:
     kv_buckets: Tuple[int, ...]
     dims: EngineDims
     requests: List[WorkloadRequest]
-    async_loop: bool = False
     slo_ttft_p99_ms: Optional[float] = None
     slo_tpot_p99_ms: Optional[float] = None
     #: summary of the recorded action trace (graftscope/graftsched side
@@ -212,7 +211,6 @@ class Workload:
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,
                 "prefill_buckets": list(self.prefill_buckets),
                 "kv_buckets": list(self.kv_buckets),
-                "async_loop": self.async_loop,
                 "slo_ttft_p99_ms": self.slo_ttft_p99_ms,
                 "slo_tpot_p99_ms": self.slo_tpot_p99_ms,
                 "dims": dataclasses.asdict(self.dims),
@@ -223,6 +221,10 @@ class Workload:
 
     @classmethod
     def from_dict(cls, d: Mapping[str, Any]) -> "Workload":
+        """An artifact written while the look-ahead was an option carries
+        that option's flag in its ``config`` block: the key is read past —
+        the request spans and the geometry are what a replay needs, and
+        every engine now steps the way the flag's ``true`` did."""
         cfg = d["config"]
         return cls(
             block_size=int(cfg["block_size"]),
@@ -233,7 +235,6 @@ class Workload:
             prefill_chunk_tokens=cfg.get("prefill_chunk_tokens"),
             prefill_buckets=tuple(cfg["prefill_buckets"]),
             kv_buckets=tuple(cfg["kv_buckets"]),
-            async_loop=bool(cfg.get("async_loop", False)),
             slo_ttft_p99_ms=cfg.get("slo_ttft_p99_ms"),
             slo_tpot_p99_ms=cfg.get("slo_tpot_p99_ms"),
             dims=EngineDims(**cfg["dims"]),
@@ -278,7 +279,7 @@ class PolicyVector:
     #: attempt a VERIFY (speculative) arm every N steps (spec engines
     #: only; 1 = every step, the FIFO default)
     verify_cadence: int = 1
-    #: take the async lookahead arm when eligible (async engines only)
+    #: take the async lookahead arm when eligible
     prefer_async: bool = True
 
     def to_dict(self) -> dict:
@@ -782,9 +783,21 @@ class Simulator:
             self._maybe_finish(req)
 
     def _async_eligible(self) -> bool:
-        if self._queue or not self._active:
+        """Sim twin of the live eligibility: no lane to admit into, no
+        lane mid-prefill, and the token in flight is no lane's last by
+        count (a queue with no free lane leaves the step eligible)."""
+        if not self._active or (self._queue and self._free_lanes):
             return False
-        return not any(r.prefilling for r in self._active.values())
+        if any(r.prefilling for r in self._active.values()):
+            return False
+        for lane in self._pending or ():
+            req = self._active.get(lane)
+            if req is not None and (
+                req.out + 1 >= req.spec.max_new_tokens
+                or req.position + 1 >= self.w.max_seq_len - 1
+            ):
+                return False
+        return True
 
     def _ensure_decode_blocks_async(self) -> bool:
         bs = self.w.block_size
@@ -868,10 +881,8 @@ class Simulator:
         self._step_host_ms = 0.0
         self._step_device_ms = 0.0
         self._step_async = False
-        async_on = self.w.async_loop
         if (
-            async_on
-            and self.vec.prefer_async
+            self.vec.prefer_async
             and self._async_eligible()
             and self._step_async_arm()
         ):

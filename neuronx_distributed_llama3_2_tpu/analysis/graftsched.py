@@ -518,8 +518,7 @@ class SeededSchedulePolicy(StepPolicy):
             if self._step < len(self._vector) else _Choices()
         )
         self._step += 1
-        cfg = view.config
-        async_on = cfg.async_loop and view.degrade_level < 2
+        async_on = view.degrade_level < 2
         if async_on and view.async_eligible and not c.force_sync:
             yield StepAction(ActionType.DECODE_DISPATCH, mode="async")
             if not view.last_async_fell_back:

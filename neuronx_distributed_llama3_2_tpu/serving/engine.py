@@ -297,13 +297,6 @@ class PagedConfig:
     # sampling must be greedy (on_device_sampling lifts that, exactly as
     # it does for speculation).
     fused_step: bool = False
-    # async double-buffered decode (docs/serving.md "Async step pipeline"):
-    # when no scheduler event is pending, dispatch step N+1 from the
-    # device-resident state before reading step N's tokens back, so host
-    # scheduling overlaps device compute. Token-identical to the sync loop
-    # for greedy sampling; EOS/max-len detection lags one step and the
-    # extra "lame-duck" token is discarded.
-    async_loop: bool = False
     # speculative decoding (docs/serving.md "Speculative decoding"): draft
     # up to this many tokens per lane per step and verify them in ONE
     # multi-token forward — accepted drafts multiply tokens/step. 0 = off.
@@ -332,11 +325,11 @@ class PagedConfig:
     spec_min_accept_rate: float = 0.2
     spec_probation_tokens: int = 32
     # verify steps need same-step readback (the accept length decides how
-    # far each lane advanced), so drafting runs in the synchronous loop;
-    # when the drafter abstains for every lane, the async lookahead runs
-    # instead and drafting is re-tried after this many steps. 0 = re-try
-    # every step (the async pipeline only runs when speculation is off or
-    # every active request is spec-disabled).
+    # far each lane advanced), so a drafting step is a drained one; when
+    # the drafter abstains for every lane the look-ahead runs instead and
+    # drafting is re-tried after this many steps. 0 = re-try every step
+    # (the look-ahead then runs only while every active request is
+    # spec-disabled).
     spec_retry_steps: int = 4
     # -- fault tolerance (docs/serving.md "Failure handling & degradation") --
     # on-device finite-logit check: decode/verify programs grow a (B,) bool
@@ -592,6 +585,8 @@ class PagedServingEngine:
         self._last_verify_drafted = False
         self._last_async_fell_back = False
         self._last_mixed_dispatched = False
+        # why this step's decode was not dispatched ahead (_note_declined)
+        self._declined: Optional[str] = None
         # graftsched action trace: per-step (step_index, pending_at_start,
         # [StepAction...]) records, ring-bounded like the flight recorder;
         # analysis/graftsched.py replays it against the legality automaton
@@ -2309,7 +2304,6 @@ class PagedServingEngine:
             kv_buckets=tuple(self._kv_buckets),
             dims=EngineDims.from_engine(self),
             requests=requests,
-            async_loop=self.paged.async_loop,
             slo_ttft_p99_ms=self.paged.slo_ttft_p99_ms,
             slo_tpot_p99_ms=self.paged.slo_tpot_p99_ms,
             trace=trace,
@@ -2717,7 +2711,9 @@ class PagedServingEngine:
             t_p = time.perf_counter()
             try:
                 self._chaos_device("prefill", (lane,))
-                first = self._prefill(suffix, cached, table, k, lane=lane)
+                first = int(self._read_tokens(
+                    self._prefill(suffix, cached, table, k, lane=lane)
+                )[0])
             except InjectedFault as fault:
                 # admission prefill fault: only this request dies — its
                 # lane/table teardown leaves the admission wave consistent
@@ -2754,13 +2750,16 @@ class PagedServingEngine:
     def _prefill(
         self, suffix: List[int], cached: int, table: List[int], key,
         table_dev=None, lane: Optional[int] = None,
-    ) -> int:
-        """Run one (whole or chunk) prefill and read its sampled token back.
-        ``table_dev`` short-circuits the per-call block-table upload —
-        chunked prefill passes the same (1, W) device array for every chunk
-        of an admission instead of re-uploading it each time. Under fused
-        sampling ``key`` is None and ``lane`` selects the installed
-        sampling mirrors that ride in as the (1,·) trailing uploads."""
+    ):
+        """Dispatch one (whole or chunk) prefill and return its sampled token
+        as the device array it is: the caller reads it back where the token
+        is a request's first, and never for a non-final chunk's, which
+        nobody uses. ``table_dev`` short-circuits the per-call block-table
+        upload — chunked prefill passes the same (1, W) device array for
+        every chunk of an admission instead of re-uploading it each time.
+        Under fused sampling ``key`` is None and ``lane`` selects the
+        installed sampling mirrors that ride in as the (1,·) trailing
+        uploads."""
         eng = self.engine
         bucket = pick_bucket(self._prefill_buckets, max(len(suffix), 1))
         self._last_prefill_bucket = bucket  # tracer pad-waste tag
@@ -2796,14 +2795,14 @@ class PagedServingEngine:
             bucket, max(len(suffix), 1),
             *(self._flops_by_key.get(fn.key) or (0.0, 0.0)),
         )
-        return int(self._read_tokens(tok)[0])
+        return tok
 
     def _advance_prefills(self, budget_tokens: Optional[int] = None) -> None:
         """One fixed-budget chunk per prefilling lane per step (Sarathi-Serve
         chunked prefill): each chunk runs through the existing suffix-prefill
         program starting at ``prefill_pos``, so all non-final chunks of a
-        given chunk size reuse ONE compiled (bucket, kv_limit) family. The
-        sampled token is discarded on non-final chunks — only the final
+        given chunk size reuse ONE compiled (bucket, kv_limit) family. A
+        non-final chunk's sampled token is never read back — only the final
         chunk's logits are the real next-token distribution — and bucket
         padding is safe for the same reason it always was: padded writes
         land at rows a later chunk overwrites before any mask admits them.
@@ -2846,6 +2845,10 @@ class PagedServingEngine:
                 tok = self._prefill(
                     piece, start, req.table, k, req.table_dev, lane=lane
                 )
+                if final:
+                    # the one token of a chunk walk anybody uses; the step
+                    # goes on to its decode dispatch behind the others
+                    tok = int(self._read_tokens(tok)[0])
             except InjectedFault as fault:
                 # chunk fault: this lane's prefill walk dies, the other
                 # prefilling/decoding lanes are untouched
@@ -3163,12 +3166,41 @@ class PagedServingEngine:
         pending, self._pending = self._pending, None
         self._read_and_apply(pending)
 
+    def _lookahead_blocker(self) -> Optional[str]:
+        """The scheduler event that makes this step a drained one, or None
+        when its admission and prefill phases could do nothing and no
+        finish is due that the host can count. A queue with no free lane
+        is no event: it is the test :meth:`_admit` opens with. The names
+        are the ``ServingMetrics.lookahead_declined_*`` suffixes."""
+        if self._queue and self._free_lanes:
+            return "admit"
+        if any(r.prefilling for r in self._active.values()):
+            return "prefill"
+        if self._pending is not None:
+            # the token in flight is some lane's last by count: drain, so
+            # the lane is released and the queue's head admitted in the
+            # step the synchronous sequence would do it. Only EOS is learnt
+            # a step late (the lame-duck step of _read_and_apply).
+            for lane in self._pending[1]:
+                req = self._active.get(lane)
+                if req is not None and (
+                    len(req.out) + 1 >= self.gen.max_new_tokens
+                    or req.position + 1 >= self.engine.max_seq_len - 1
+                ):
+                    return "finish"
+        return None
+
     def _async_eligible(self) -> bool:
-        """Steady state: nothing for the scheduler to do this step except
-        advance decode lanes — no waiting queue, no prefill chunks."""
-        if self._queue or not self._active:
-            return False
-        return not any(r.prefilling for r in self._active.values())
+        """Nothing for the scheduler to do this step except advance decode
+        lanes: the look-ahead dispatch may run."""
+        return bool(self._active) and self._lookahead_blocker() is None
+
+    def _note_declined(self) -> None:
+        """Book a decode step that was not dispatched ahead to the rule
+        that drained it (none when a policy chose the drained sequence on
+        an eligible step)."""
+        if self._declined is not None:
+            self.metrics.note_lookahead_declined(self._declined)
 
     def _step_async(self) -> bool:
         """One lookahead decode step: dispatch step N+1 entirely from
@@ -3300,6 +3332,7 @@ class PagedServingEngine:
         for lane in decode_lanes:
             self._positions[lane] += 1
         self.metrics.decode_steps += 1
+        self._note_declined()
         self._read_and_apply((toks, decode_lanes, self._dispatch_count, finite))
         return bool(self._active or self._queue)
 
@@ -3853,9 +3886,16 @@ class PagedServingEngine:
                 budget = act.meta.get("budget_tokens") if act.meta else None
                 self._advance_prefills(budget_tokens=budget)
         elif t is ActionType.VERIFY:
+            # drafting needs same-step readback: whatever decodes in this
+            # step, the verify program or the plain tail, was held by it
+            self._declined = "spec"
             self._last_verify_drafted = self._verify_phase()
+            if self._last_verify_drafted:
+                self._note_declined()
         elif t is ActionType.MIXED_DISPATCH:
             self._last_mixed_dispatched = self._mixed_phase()
+            if self._last_mixed_dispatched:
+                self._note_declined()
         elif t is ActionType.DECODE_DISPATCH:
             if act.mode == "async":
                 if self._ensure_decode_blocks_async():
@@ -3866,7 +3906,7 @@ class PagedServingEngine:
                     # lane state — the policy reads this outcome and drops
                     # to the synchronous sequence for this step.
                     self._last_async_fell_back = True
-                    self.metrics.sync_fallbacks += 1
+                    self._declined = "pool"
             else:
                 self._dispatch_sync_decode()
         elif t is ActionType.AUDIT:
@@ -3886,6 +3926,9 @@ class PagedServingEngine:
         # shedding is a policy decision too (FifoPolicy reads
         # view.degrade_level); rung 3 — the paged kernel — is applied at
         # program selection, rung 4 at _update_ladder.
+        self._declined = (
+            "ladder" if self._degrade_level >= 2 else self._lookahead_blocker()
+        )
         n = 0
         for act in self.policy.actions(self._view):
             n += 1
@@ -3910,11 +3953,11 @@ class PagedServingEngine:
         lane, then advance every decode-ready lane one token — so a long
         prompt's chunks interleave with the existing streams' decode
         steps. Pool exhaustion preempts-and-requeues instead of raising.
-        With ``PagedConfig.async_loop`` the steady-state decode path runs
-        a depth-1 lookahead pipeline (docs/serving.md "Async step
-        pipeline"); per-request state then trails the device by one step
-        until the pipeline drains. Returns False when nothing is left to
-        do.
+        Wherever the scheduler has nothing to do the decode path runs one
+        program ahead of the device (docs/serving.md "How the engine
+        steps"); per-request state then trails the device by one step
+        until a scheduler event drains the pipeline. Returns False when
+        nothing is left to do.
 
         Failure domains: an injected device fault aborts only its victim
         lanes (terminal ``failed`` status, blocks released, survivors

@@ -75,10 +75,17 @@ class ServingMetrics:
     prefill_tokens: int = 0   # prompt tokens actually pushed through prefill
     prefill_chunks: int = 0   # chunked-prefill program invocations
     cached_tokens: int = 0    # prompt tokens admitted by prefix reference
-    # -- async double-buffered loop (docs/serving.md "Async step pipeline") --
+    # -- the look-ahead step loop (docs/serving.md "How the engine steps") --
     decode_steps_async: int = 0  # of decode_steps, dispatched with lookahead
     lame_duck_tokens: int = 0    # post-finish lookahead tokens discarded
-    sync_fallbacks: int = 0      # async-eligible steps dropped to sync mode
+    # why a decode step was NOT dispatched ahead of the device, by the rule
+    # that drained it (the step policy tests them in this order)
+    lookahead_declined_spec: int = 0     # drafting needs same-step readback
+    lookahead_declined_ladder: int = 0   # degradation rung >= 2 sheds it
+    lookahead_declined_admit: int = 0    # a lane was free and the queue not empty
+    lookahead_declined_prefill: int = 0  # a lane was mid-prefill
+    lookahead_declined_finish: int = 0   # the token in flight was a lane's last by count
+    lookahead_declined_pool: int = 0     # backing the write rows needed a preemption
     # -- resident decode state (device-side tokens/positions/tables) --
     lane_syncs: int = 0          # full-lane host→device resident-state pushes
     table_deltas: int = 0        # single-entry block-table scatter updates
@@ -257,6 +264,12 @@ class ServingMetrics:
         r["dispatches"] += 1
         r["need_tokens"] += need
         r["pad_tokens"] += pad
+
+    def note_lookahead_declined(self, reason: str) -> None:
+        """One decode step that a scheduler rule kept from being dispatched
+        ahead; ``reason`` is a ``lookahead_declined_*`` suffix."""
+        name = "lookahead_declined_" + reason
+        setattr(self, name, getattr(self, name) + 1)
 
     def note_decode_dispatch(
         self, rung: int, need: int,

@@ -132,7 +132,9 @@ class EngineView:
 
     @property
     def async_eligible(self) -> bool:
-        """Steady state: only decode-lane advancement left this step."""
+        """Only decode-lane advancement left this step: no lane to admit
+        into, no lane mid-prefill, no finish in flight the host can count
+        (a waiting queue with no free lane leaves the step eligible)."""
         return self._engine._async_eligible()
 
     @property
@@ -267,11 +269,13 @@ class FifoPolicy(StepPolicy):
     - spec configured, below ladder rung 1, and not paused → drain the
       lookahead, admit, advance prefills, VERIFY; if the drafter abstained
       everywhere, take a plain sync decode and pause drafting for
-      ``spec_retry_steps`` (only when the async lookahead exists to hand
-      the loop to).
-    - otherwise, async loop on, below rung 2, steady state → one async
-      lookahead dispatch; on pool-dry fallback continue below.
-    - otherwise → drain, admit, advance prefills, one sync decode.
+      ``spec_retry_steps`` (only below rung 2, where the lookahead exists
+      to hand the loop to).
+    - otherwise, below rung 2 and nothing for the scheduler to do
+      (``view.async_eligible``) → one async lookahead dispatch; on
+      pool-dry fallback continue below.
+    - otherwise → the drained sequence: drain, admit, advance prefills,
+      one sync decode.
 
     The drafting pause counter is policy state (it *is* a scheduling
     decision), carried across steps and reset with the policy."""
@@ -287,7 +291,7 @@ class FifoPolicy(StepPolicy):
     def actions(self, view: EngineView) -> Iterator[StepAction]:
         cfg = view.config
         spec_on = view.spec_enabled and view.degrade_level < 1
-        async_on = cfg.async_loop and view.degrade_level < 2
+        async_on = view.degrade_level < 2
         fused = bool(getattr(cfg, "fused_step", False))
         if spec_on and self._spec_pause <= 0:
             yield StepAction(ActionType.READBACK)   # drain the lookahead
@@ -304,8 +308,8 @@ class FifoPolicy(StepPolicy):
             yield StepAction(ActionType.VERIFY)
             if not view.last_verify_drafted:
                 # dry drafter: hand the loop to the async lookahead for a
-                # few steps instead of pinning it to sync mode; with async
-                # off there is nothing to yield to — retry every step
+                # few steps instead of pinning it to sync mode; at rung 2
+                # there is nothing to yield to — retry every step
                 if async_on:
                     self._spec_pause = cfg.spec_retry_steps
                 yield StepAction(ActionType.DECODE_DISPATCH, mode="sync")

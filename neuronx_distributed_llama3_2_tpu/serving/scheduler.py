@@ -237,7 +237,7 @@ class SloPolicy(StepPolicy):
         # construction); only the ADMIT / PREFILL_CHUNK meta differs
         cfg = view.config
         spec_on = view.spec_enabled and view.degrade_level < 1
-        async_on = cfg.async_loop and view.degrade_level < 2
+        async_on = view.degrade_level < 2
         if spec_on and self._spec_pause <= 0:
             yield StepAction(ActionType.READBACK)
             yield StepAction(ActionType.ADMIT, meta=self._admit_meta(view))
@@ -343,7 +343,7 @@ class TablePolicy(SloPolicy):
         self._step_no += 1
         cfg = view.config
         spec_on = view.spec_enabled and view.degrade_level < 1
-        async_on = cfg.async_loop and view.degrade_level < 2
+        async_on = view.degrade_level < 2
         cadence = max(int(self._vec.verify_cadence), 1)
         if (
             spec_on

@@ -138,7 +138,7 @@ def run_bench(args: argparse.Namespace) -> dict:
 
     paged_cfg = PagedConfig(
         block_size=args.block_size, num_blocks=args.num_blocks,
-        decode_reserve_blocks=1, prefill_chunk_tokens=8, async_loop=True,
+        decode_reserve_blocks=1, prefill_chunk_tokens=8,
         # spill on BOTH runs (parity compares spill-vs-spill); crossover
         # forced sky-high because tiny-model prefill FLOPs are ~free
         spill_enabled=True, host_tier_bytes=1 << 30, restore_crossover=1e9,

@@ -202,7 +202,7 @@ def _catalog_engine(prewarm=True):
         PagedConfig(
             block_size=8, num_blocks=32, kv_cache_dtype="int8",
             quant_mxu=True, on_device_sampling=True,
-            spec_draft_tokens=4, prefill_chunk_tokens=6, async_loop=True,
+            spec_draft_tokens=4, prefill_chunk_tokens=6,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
         ),
         precompile=False,
@@ -235,7 +235,7 @@ def _catalog_fused_engine(prewarm=True):
         PagedConfig(
             block_size=8, num_blocks=32, kv_cache_dtype="int8",
             quant_mxu=True, on_device_sampling=True,
-            spec_draft_tokens=4, prefill_chunk_tokens=6, async_loop=True,
+            spec_draft_tokens=4, prefill_chunk_tokens=6,
             fused_step=True,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
         ),
@@ -269,7 +269,7 @@ def _catalog_spill_engine(prewarm=True):
         PagedConfig(
             block_size=8, num_blocks=16, kv_cache_dtype="int8",
             quant_mxu=True, on_device_sampling=True,
-            spec_draft_tokens=4, prefill_chunk_tokens=6, async_loop=True,
+            spec_draft_tokens=4, prefill_chunk_tokens=6,
             spill_enabled=True, host_tier_bytes=1 << 30,
             restore_crossover=1e9,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
@@ -307,7 +307,7 @@ def _catalog_tree_engine(prewarm=True):
             block_size=8, num_blocks=32, kv_cache_dtype="int8",
             quant_mxu=True, on_device_sampling=True,
             spec_draft_tokens=4, spec_tree=True,
-            prefill_chunk_tokens=6, async_loop=True,
+            prefill_chunk_tokens=6,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
         ),
         precompile=False,

@@ -144,7 +144,7 @@ def make_engine_factory():
             GenerationConfig(max_new_tokens=4),
             PagedConfig(
                 block_size=4, num_blocks=32, prefill_chunk_tokens=4,
-                async_loop=True, enable_prefix_caching=False,
+                enable_prefix_caching=False,
                 trace_buffer_steps=256, slo_ttft_p99_ms=_TTFT_P99_MS,
             ),
             policy=policy,

@@ -120,7 +120,7 @@ def make_engine_factory(mixed: bool = False):
             GenerationConfig(max_new_tokens=5),
             PagedConfig(
                 block_size=8, num_blocks=32, prefill_chunk_tokens=4,
-                async_loop=True, trace_buffer_steps=128,
+                trace_buffer_steps=128,
             ),
             policy=policy,
             precompile=False,

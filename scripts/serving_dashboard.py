@@ -409,8 +409,7 @@ def _demo() -> int:
     paged = PagedServingEngine(
         eng, GenerationConfig(max_new_tokens=16),
         PagedConfig(
-            block_size=8, num_blocks=32, async_loop=True,
-            trace_enabled=True,
+            block_size=8, num_blocks=32, trace_enabled=True,
             # fused mixed-mode demo coverage: the dispatch panel row
             # shows a nonzero pmixed count
             fused_step=True, prefill_chunk_tokens=4,

@@ -112,7 +112,7 @@ def make_engine_factory():
             GenerationConfig(max_new_tokens=6),
             PagedConfig(
                 block_size=8, num_blocks=64, prefill_chunk_tokens=8,
-                async_loop=True, step_policy=policy_name,
+                step_policy=policy_name,
                 # graftplan: a certified table artifact for the "table"
                 # leg, loaded at construction under GC011
                 policy_table_path=table_path,
@@ -166,7 +166,6 @@ def make_churn_engine(spill: bool):
         GenerationConfig(max_new_tokens=6),
         PagedConfig(
             block_size=8, num_blocks=28, prefill_chunk_tokens=8,
-            async_loop=True,
             spill_enabled=spill,
             host_tier_bytes=(1 << 30) if spill else 0,
             restore_crossover=1e9 if spill else 1.0,

@@ -106,7 +106,7 @@ def test_cancel_while_queued(params):
     """Cancel before admission: the victim never touches a lane or a
     block; the others run exactly as if it were never submitted."""
     gen = GenerationConfig(max_new_tokens=6)
-    cfg = dict(block_size=8, num_blocks=64, async_loop=True)
+    cfg = dict(block_size=8, num_blocks=64)
     prompts = _prompts(np.random.default_rng(21), (5, 9, 12, 7, 6))
     victim = 4  # max_batch=4: rid 4 waits in the queue behind the wave
 
@@ -130,7 +130,7 @@ def test_cancel_while_queued(params):
 # chunk walk spans steps
 _CHUNK_GEN = GenerationConfig(max_new_tokens=8)
 _CHUNK_CFG = dict(
-    block_size=8, num_blocks=64, prefill_chunk_tokens=6, async_loop=True,
+    block_size=8, num_blocks=64, prefill_chunk_tokens=6,
 )
 _CHUNK_PROMPTS = _prompts(np.random.default_rng(23), (5, 26, 9, 7))
 
@@ -209,7 +209,7 @@ def test_cancel_mid_verify_speculative(params):
     in-flight lookahead without touching the survivors' accept streams."""
     gen = GenerationConfig(max_new_tokens=12)
     cfg = dict(
-        block_size=8, num_blocks=64, async_loop=True,
+        block_size=8, num_blocks=64,
         spec_draft_tokens=3,
     )
     drafter = NGramDrafter()

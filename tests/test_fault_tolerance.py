@@ -45,6 +45,7 @@ from neuronx_distributed_llama3_2_tpu.serving import (
     make_serving_engine,
 )
 
+from tests.drained_policy import LOOPS, loop_policy
 from tests.test_paged_serving import _prompts
 
 TINY = LLAMA_CONFIGS["tiny"]
@@ -64,7 +65,7 @@ _ENGINES = {}
 
 
 def _paged(params, gen, paged_cfg, model_cfg=TINY, injector=None,
-           precompile=False, drafter=None, **kw):
+           precompile=False, drafter=None, policy=None, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("buckets", [8, 16, 32])
@@ -74,7 +75,7 @@ def _paged(params, gen, paged_cfg, model_cfg=TINY, injector=None,
         _ENGINES[key] = InferenceEngine(model_cfg, params, **kw)
     return PagedServingEngine(
         _ENGINES[key], gen, paged_cfg, precompile=precompile,
-        injector=injector, drafter=drafter,
+        injector=injector, drafter=drafter, policy=policy,
     )
 
 
@@ -89,7 +90,6 @@ def _run(paged, prompts):
 # baseline drive serve several fault classes (cached below)
 GEN10 = GenerationConfig(max_new_tokens=10)
 CFG_PLAIN = PagedConfig(block_size=8, num_blocks=64)
-CFG_ASYNC = PagedConfig(block_size=8, num_blocks=64, async_loop=True)
 CFG_SPEC = PagedConfig(block_size=8, num_blocks=64, spec_draft_tokens=4)
 PLAIN_PROMPTS = _prompts(np.random.default_rng(3), (5, 12, 20, 9))
 _rep_rng = np.random.default_rng(6)
@@ -289,13 +289,15 @@ def test_prefill_fault_fails_only_the_admitting_request(params):
     _assert_clean_pool(paged)
 
 
-@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
-def test_decode_fault_fails_one_lane_others_identical(params, async_loop):
-    cfg = CFG_ASYNC if async_loop else CFG_PLAIN
+@pytest.mark.parametrize("loop", LOOPS)
+def test_decode_fault_fails_one_lane_others_identical(params, loop):
+    cfg = CFG_PLAIN
     baseline = _baseline(params, GEN10, cfg, PLAIN_PROMPTS)
 
     inj = FaultInjector(FaultPlan(seed=2, schedule=((6, "device"),)))
-    paged = _paged(params, GEN10, cfg, injector=inj)
+    paged = _paged(
+        params, GEN10, cfg, injector=inj, policy=loop_policy(loop)
+    )
     _run(paged, PLAIN_PROMPTS)
     assert inj.counts["device"] == 1
     n_finished, n_failed = _assert_survivor_parity(paged, baseline)
@@ -304,7 +306,7 @@ def test_decode_fault_fails_one_lane_others_identical(params, async_loop):
     _assert_clean_pool(paged)
 
 
-@pytest.mark.parametrize("cfg", [CFG_ASYNC, CFG_SPEC], ids=["async", "spec"])
+@pytest.mark.parametrize("cfg", [CFG_PLAIN, CFG_SPEC], ids=["plain", "spec"])
 def test_nan_quarantine_fails_the_poisoned_lane(params, cfg):
     baseline = _baseline(params, GEN10, cfg, REP_PROMPTS)
 
@@ -358,9 +360,9 @@ def test_fused_step_fault_fails_one_lane_others_identical(params, kind):
 def test_detect_nonfinite_clean_run_changes_nothing(params):
     # checked programs with healthy logits: finite everywhere, no
     # quarantines, outputs identical to the unchecked engine
-    baseline = _baseline(params, GEN10, CFG_ASYNC, PLAIN_PROMPTS)
+    baseline = _baseline(params, GEN10, CFG_PLAIN, PLAIN_PROMPTS)
     paged = _paged(
-        params, GEN10, dataclasses.replace(CFG_ASYNC, detect_nonfinite=True)
+        params, GEN10, dataclasses.replace(CFG_PLAIN, detect_nonfinite=True)
     )
     assert paged._check_logits
     assert _run(paged, PLAIN_PROMPTS) == baseline
@@ -494,7 +496,7 @@ def test_degradation_ladder_climbs_and_recovers(params):
     gen = GenerationConfig(max_new_tokens=24)
     prompts = _prompts(np.random.default_rng(16), (5, 12, 9, 17, 6, 11, 8, 14))
     cfg = PagedConfig(
-        block_size=8, num_blocks=64, async_loop=True,
+        block_size=8, num_blocks=64,
         degrade_after_faults=1, degrade_window_steps=16,
         degrade_recover_steps=4,
     )
@@ -575,7 +577,7 @@ def _chaos_soak(params, n_requests, arrival_span, max_new, plan, workload_seed,
     # (exempt from steadystate_compiles / GC008)
     cfg = PagedConfig(
         block_size=4, num_blocks=24, decode_reserve_blocks=1,
-        prefill_chunk_tokens=8, async_loop=True, spec_draft_tokens=4,
+        prefill_chunk_tokens=8, spec_draft_tokens=4,
         stall_step_limit=300, audit_interval=8, audit_debug=True,
         degrade_after_faults=3, degrade_window_steps=32,
         degrade_recover_steps=16, prewarm=True,

@@ -54,6 +54,7 @@ from neuronx_distributed_llama3_2_tpu.serving import (
     audit_engine,
 )
 
+from tests.drained_policy import LOOPS
 from tests.test_async_serving import _paged, _run
 from tests.test_paged_serving import _prompts
 
@@ -226,12 +227,12 @@ def greedy_baseline(params):
     return gen, prompts, want
 
 
-@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
-def test_fused_greedy_identity(params, greedy_baseline, async_loop):
+@pytest.mark.parametrize("loop", LOOPS)
+def test_fused_greedy_identity(params, greedy_baseline, loop):
     """Greedy traffic through the fused program (sentinel params) is
     token-identical to the plain greedy engine."""
     gen, prompts, want = greedy_baseline
-    paged = _paged(params, gen, _cfg(async_loop=async_loop))
+    paged = _paged(params, gen, _cfg(), loop=loop)
     assert _run(paged, prompts) == want
     m = paged.metrics
     assert m.sampled_steps == 0          # greedy dispatches aren't "sampled"
@@ -284,21 +285,21 @@ def test_host_sampling_counts_fallbacks(params):
     assert paged.metrics.sampled_steps == 0
 
 
-@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
-def test_sampled_steady_state_zero_uploads(params, async_loop):
+@pytest.mark.parametrize("loop", LOOPS)
+def test_sampled_steady_state_zero_uploads(params, loop):
     """The GC003 twin for sampled traffic: an event-free fused sampled
     decode step uploads NOTHING — no per-step PRNG key, no sampling
     params (the host path pays a key upload every step). Same shape as
-    test_sync_loop_is_also_resident / test_async_steady_state_no_uploads
-    in tests/test_async_serving.py, with sampling on."""
+    test_steady_state_step_is_fully_resident in
+    tests/test_async_serving.py, with sampling on."""
     gen = GenerationConfig(max_new_tokens=20, sampling=SAMPLED)
     paged = _paged(
         params, gen,
-        _cfg(block_size=32, num_blocks=8, async_loop=async_loop),
+        _cfg(block_size=32, num_blocks=8), loop=loop,
     )
     paged.submit(_prompts(np.random.default_rng(0), (4,))[0])
     paged.step()  # admission + prefill
-    paged.step()  # first decode dispatch (async: flushes the dirty lane)
+    paged.step()  # first decode dispatch: flushes the dirty lane
     m = paged.metrics
     for _ in range(12):
         before = m.h2d_uploads
@@ -324,19 +325,17 @@ def test_fused_sampling_tracer_labels(params):
 # -- engine: preempt-resume determinism --------------------------------------
 
 
-@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
-def test_sampled_preempt_resume_replays_stream(params, async_loop):
+@pytest.mark.parametrize("loop", LOOPS)
+def test_sampled_preempt_resume_replays_stream(params, loop):
     """Pool contention preempts and resumes sampled requests; the
     fold_in-by-landing-index key discipline must replay the identical
     token streams the uncontended run produces."""
     gen = GenerationConfig(max_new_tokens=24, sampling=SAMPLED)
     prompts = _prompts(np.random.default_rng(5), (12, 12, 12, 12))
-    want = _run(_paged(params, gen, _cfg(async_loop=async_loop)), prompts)
+    want = _run(_paged(params, gen, _cfg(), loop=loop), prompts)
     paged = _paged(
-        params, gen,
-        _cfg(
-            num_blocks=10, decode_reserve_blocks=1, async_loop=async_loop,
-        ),
+        params, gen, _cfg(num_blocks=10, decode_reserve_blocks=1),
+        loop=loop,
     )
     out = _run(paged, prompts)
     assert paged.metrics.preemptions > 0
@@ -382,7 +381,9 @@ def test_sampled_spec_matches_non_spec_stream(params):
     ]
     gen = GenerationConfig(max_new_tokens=10, sampling=SAMPLED)
     want = _run(_paged(params, gen, _cfg()), prompts)
-    paged = _paged(params, gen, _cfg(spec_draft_tokens=4))
+    # drafting re-tried every step: ten tokens are too few to sit out a
+    # dry-spell pause on the look-ahead
+    paged = _paged(params, gen, _cfg(spec_draft_tokens=4, spec_retry_steps=0))
     out = _run(paged, prompts)
     assert paged.metrics.verify_steps > 0
     assert out == want

@@ -6,7 +6,7 @@ and active prefill-chunk suffixes into ONE ``pmixed`` query-row grid over
 the shared paged KV pool — one model dispatch per engine step — and the
 emitted token streams stay **byte-identical** to the unfused engine (and
 therefore to the dense oracle) across the whole serving matrix:
-{gather, kernel} × {sync, async} × {spec, no-spec} × {chunked, whole}.
+{gather, kernel} × {drained, lookahead} × {spec, no-spec} × {chunked, whole}.
 
 The tier-1 quartet is a pairwise-covering slice of that cube (the PR 9
 matrix split); the remaining legs ride the opt-in slow tier. Alongside
@@ -64,10 +64,9 @@ def _dense(params, prompts):
     return _DENSE[key]
 
 
-def _leg_cfg(loop, spec, chunk, **kw):
+def _leg_cfg(spec, chunk, **kw):
     return PagedConfig(
         block_size=8, num_blocks=64,
-        async_loop=(loop == "async"),
         spec_draft_tokens=(3 if spec == "spec" else 0),
         prefill_chunk_tokens=(6 if chunk == "chunk" else None),
         fused_step=True, **kw,
@@ -85,22 +84,22 @@ _S = pytest.mark.slow
 # every dimension and all model×{loop,spec,chunk} + loop×chunk +
 # spec×chunk pairs; the full cube runs under -m slow
 CUBE = [
-    ("kernel", "sync", "spec", "chunk"),
-    ("gather", "async", "nospec", "chunk"),
-    ("kernel", "async", "nospec", "whole"),
-    ("gather", "sync", "spec", "whole"),
-    pytest.param("kernel", "sync", "nospec", "chunk", marks=_S),
-    pytest.param("kernel", "sync", "spec", "whole", marks=_S),
-    pytest.param("kernel", "sync", "nospec", "whole", marks=_S),
-    pytest.param("kernel", "async", "spec", "chunk", marks=_S),
-    pytest.param("kernel", "async", "spec", "whole", marks=_S),
-    pytest.param("kernel", "async", "nospec", "chunk", marks=_S),
-    pytest.param("gather", "sync", "spec", "chunk", marks=_S),
-    pytest.param("gather", "sync", "nospec", "chunk", marks=_S),
-    pytest.param("gather", "sync", "nospec", "whole", marks=_S),
-    pytest.param("gather", "async", "spec", "chunk", marks=_S),
-    pytest.param("gather", "async", "spec", "whole", marks=_S),
-    pytest.param("gather", "async", "nospec", "whole", marks=_S),
+    ("kernel", "drained", "spec", "chunk"),
+    ("gather", "lookahead", "nospec", "chunk"),
+    ("kernel", "lookahead", "nospec", "whole"),
+    ("gather", "drained", "spec", "whole"),
+    pytest.param("kernel", "drained", "nospec", "chunk", marks=_S),
+    pytest.param("kernel", "drained", "spec", "whole", marks=_S),
+    pytest.param("kernel", "drained", "nospec", "whole", marks=_S),
+    pytest.param("kernel", "lookahead", "spec", "chunk", marks=_S),
+    pytest.param("kernel", "lookahead", "spec", "whole", marks=_S),
+    pytest.param("kernel", "lookahead", "nospec", "chunk", marks=_S),
+    pytest.param("gather", "drained", "spec", "chunk", marks=_S),
+    pytest.param("gather", "drained", "nospec", "chunk", marks=_S),
+    pytest.param("gather", "drained", "nospec", "whole", marks=_S),
+    pytest.param("gather", "lookahead", "spec", "chunk", marks=_S),
+    pytest.param("gather", "lookahead", "spec", "whole", marks=_S),
+    pytest.param("gather", "lookahead", "nospec", "whole", marks=_S),
 ]
 
 
@@ -122,7 +121,8 @@ def test_fused_token_parity(params, model, loop, spec, chunk):
     drafter = NGramDrafter() if spec == "spec" else None
     prompts = _leg_prompts(spec)
     paged = _paged(
-        params, GEN, _leg_cfg(loop, spec, chunk), model_cfg, drafter=drafter
+        params, GEN, _leg_cfg(spec, chunk), model_cfg, drafter=drafter,
+        loop=loop,
     )
     out = _run(paged, prompts)
     assert out == _dense(params, prompts)

@@ -282,7 +282,6 @@ def _toy_workload(**over) -> Workload:
             WorkloadRequest(rid=3, prompt_tokens=3, max_new_tokens=3,
                             service_class="interactive", tenant="b"),
         ],
-        async_loop=False,
         slo_ttft_p99_ms=0.5,
     )
     base.update(over)
@@ -297,6 +296,26 @@ def test_workload_roundtrip():
     assert trace_fingerprint(again.to_dict()) == trace_fingerprint(
         w.to_dict()
     )
+
+
+@pytest.mark.parametrize("flag", [False, True])
+def test_workload_artifact_from_before_the_lookahead_was_the_engine(flag):
+    """An artifact written while the look-ahead was an option carries that
+    option in its ``config`` block. It is read without the key — not
+    refused as stale: it loads as the workload it records, simulates as
+    every engine now steps (whatever the flag said), and writes back
+    without it."""
+    w = _toy_workload()
+    old = w.to_dict()
+    key = "async" + "_loop"
+    assert key not in old["config"]
+    old["config"][key] = flag
+    again = Workload.from_dict(old)
+    assert again.to_dict() == w.to_dict()
+    assert not hasattr(again, key)
+    got, want = simulate(again, None), simulate(w, None)
+    assert got.findings == [] and got.makespan_ms == want.makespan_ms
+    assert got.steps == want.steps
 
 
 def test_simulator_fifo_drains_clean():
@@ -341,11 +360,11 @@ def test_simulator_async_overlap_is_cheaper():
         for r in _toy_workload().requests
     ]
     sync = simulate(
-        _toy_workload(async_loop=True, requests=long), PolicyVector(
+        _toy_workload(requests=long), PolicyVector(
             class_weight={}, burn_boost=0.0, prefer_async=False,
         ))
     overlap = simulate(
-        _toy_workload(async_loop=True, requests=long), PolicyVector(
+        _toy_workload(requests=long), PolicyVector(
             class_weight={}, burn_boost=0.0, prefer_async=True,
         ))
     assert overlap.findings == [] and sync.findings == []
@@ -503,7 +522,7 @@ def _calibration_factory():
             GenerationConfig(max_new_tokens=4),
             PagedConfig(
                 block_size=4, num_blocks=64, prefill_chunk_tokens=4,
-                async_loop=False, enable_prefix_caching=False,
+                enable_prefix_caching=False,
                 trace_buffer_steps=256,
             ),
             policy=policy,

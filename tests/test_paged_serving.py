@@ -230,7 +230,7 @@ def test_metrics_snapshot_shape(params):
         "submitted", "finished", "preemptions", "prefill_tokens",
         "cached_tokens", "prefix_skip_fraction", "block_utilization",
         "free_blocks", "prefix_hit_rate", "radix_nodes",
-        "decode_steps_async", "lame_duck_tokens", "sync_fallbacks",
+        "decode_steps_async", "lame_duck_tokens", "lookahead_declined_pool",
         "lane_syncs", "table_deltas", "h2d_uploads",
         "host_schedule_ms_per_step", "device_wait_ms_per_step",
     ):

@@ -48,6 +48,7 @@ from neuronx_distributed_llama3_2_tpu.serving.block_allocator import (
     kv_pool_bytes_per_rank,
 )
 
+from tests.drained_policy import LOOPS
 from tests.test_async_serving import _paged, _run
 from tests.test_paged_serving import _prompts
 
@@ -69,10 +70,10 @@ def _qcfg(**kw):
 
 @pytest.fixture(scope="module")
 def int8_baseline(params):
-    """Reference cell of the parity matrix: int8, gather, sync, whole."""
+    """Reference cell of the parity matrix: int8, gather, drained, whole."""
     gen = GenerationConfig(max_new_tokens=8)
     prompts = _prompts(np.random.default_rng(7), (5, 12, 20, 9))
-    out = _run(_paged(params, gen, _qcfg()), prompts)
+    out = _run(_paged(params, gen, _qcfg(), loop="drained"), prompts)
     return gen, prompts, out
 
 
@@ -164,17 +165,16 @@ def test_dense_path_rejects_quantized_cache(params):
 
 
 @pytest.mark.parametrize("model_cfg", [TINY, TINY_KERNEL], ids=["gather", "kernel"])
-@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
+@pytest.mark.parametrize("loop", LOOPS)
 @pytest.mark.parametrize("chunk", [None, 6], ids=["whole", "chunked"])
-def test_quantized_parity_matrix(params, int8_baseline, model_cfg, async_loop, chunk):
+def test_quantized_parity_matrix(params, int8_baseline, model_cfg, loop, chunk):
     """Every int8 cell is token-identical to the reference cell: the
     append-local scales make quantized values independent of prefill
     chunking, loop mode, and kernel-vs-gather eligibility."""
     gen, prompts, want = int8_baseline
     paged = _paged(
         params, gen,
-        _qcfg(async_loop=async_loop, prefill_chunk_tokens=chunk),
-        model_cfg=model_cfg,
+        _qcfg(prefill_chunk_tokens=chunk), model_cfg=model_cfg, loop=loop,
     )
     assert _run(paged, prompts) == want
     assert paged.metrics.kv_dtype == "int8"
@@ -228,8 +228,8 @@ def test_int8_logits_within_tolerance_of_fp(params):
 # -- low-precision MXU decode dot (PagedConfig.quant_mxu) -------------------
 
 
-@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
-def test_quant_mxu_parity_cells(params, int8_baseline, async_loop):
+@pytest.mark.parametrize("loop", LOOPS)
+def test_quant_mxu_parity_cells(params, int8_baseline, loop):
     """quant_mxu rows of the parity matrix: the int8-accumulate q·k dot
     (scales applied post-dot) stays token-identical to the reference int8
     cell on tiny — measured zero greedy drift; the formal gate is the 5%
@@ -237,8 +237,7 @@ def test_quant_mxu_parity_cells(params, int8_baseline, async_loop):
     gen, prompts, want = int8_baseline
     paged = _paged(
         params, gen,
-        _qcfg(quant_mxu=True, async_loop=async_loop),
-        model_cfg=TINY_KERNEL,
+        _qcfg(quant_mxu=True), model_cfg=TINY_KERNEL, loop=loop,
     )
     assert _run(paged, prompts) == want
     assert paged.model.config.quant_mxu
@@ -357,7 +356,7 @@ def test_quantized_steady_state_is_fully_resident(params):
     paged = _paged(
         params, gen,
         PagedConfig(
-            block_size=32, num_blocks=8, async_loop=True,
+            block_size=32, num_blocks=8,
             kv_cache_dtype="int8",
         ),
     )

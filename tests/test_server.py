@@ -99,8 +99,7 @@ def test_streamed_tokens_match_batch_run(params):
     is left open."""
     gen = GenerationConfig(max_new_tokens=6)
     cfg = dict(
-        block_size=8, num_blocks=64, prefill_chunk_tokens=8,
-        async_loop=True, step_policy="slo",
+        block_size=8, num_blocks=64, prefill_chunk_tokens=8, step_policy="slo",
     )
     prompts = _prompts(np.random.default_rng(7), (5, 12, 20, 9, 17))
 
@@ -151,7 +150,7 @@ def test_cancel_mid_stream(params):
     ``cancelled`` error payload, and leaves the survivor token-identical
     to an uncancelled engine's output for the same rid."""
     gen = GenerationConfig(max_new_tokens=12)
-    cfg = dict(block_size=8, num_blocks=64, async_loop=True)
+    cfg = dict(block_size=8, num_blocks=64)
     prompts = _prompts(np.random.default_rng(9), (6, 10))
 
     solo = _paged(params, gen, PagedConfig(**cfg))
@@ -202,7 +201,7 @@ def test_http_transport_roundtrips(params):
     client per request (``Connection: close`` framing)."""
     gen = GenerationConfig(max_new_tokens=5)
     eng = _paged(
-        params, gen, PagedConfig(block_size=8, num_blocks=64, async_loop=True)
+        params, gen, PagedConfig(block_size=8, num_blocks=64)
     )
     prompt = _prompts(np.random.default_rng(4), (7,))[0]
 
@@ -304,7 +303,7 @@ def test_slo_steady_state_resident_under_prewarm(params):
     paged = _paged(
         params, gen,
         PagedConfig(
-            block_size=32, num_blocks=8, async_loop=True, prewarm=True,
+            block_size=32, num_blocks=8, prewarm=True,
             step_policy="slo",
             slo_ttft_p99_ms=50.0, slo_tpot_p99_ms=10_000.0,
             slo_eval_steps=8,

@@ -39,6 +39,7 @@ from neuronx_distributed_llama3_2_tpu.serving import (
     audit_engine,
 )
 
+from tests.drained_policy import LOOPS, loop_policy
 from tests.test_paged_serving import _dense_outputs, _prompts
 
 TINY = LLAMA_CONFIGS["tiny"]
@@ -60,11 +61,14 @@ def _rep_prompts(rng, lengths, period=3):
     return out
 
 
-def _paged(params, gen, paged_cfg, model_cfg=TINY, drafter=None):
+def _paged(params, gen, paged_cfg, model_cfg=TINY, drafter=None, loop="lookahead"):
+    """``loop`` is one of ``tests.drained_policy.LOOPS``."""
     eng = InferenceEngine(
         model_cfg, params, max_batch=4, max_seq_len=64, buckets=[8, 16, 32]
     )
-    return PagedServingEngine(eng, gen, paged_cfg, drafter=drafter)
+    return PagedServingEngine(
+        eng, gen, paged_cfg, drafter=drafter, policy=loop_policy(loop)
+    )
 
 
 def _run(paged, prompts):
@@ -183,19 +187,19 @@ def test_spec_parity_matrix(params, model_cfg, chunk):
     assert 0.0 < m.accept_rate() <= 1.0
 
 
-def test_spec_parity_async_loop(params):
-    """spec + async_loop: verify steps run synchronously (drained pipeline)
-    while dry stretches hand the loop back to the async lookahead — output
-    must stay identical to the plain sync loop."""
+@pytest.mark.parametrize("loop", LOOPS)
+def test_spec_parity_on_both_loops(params, loop):
+    """A drafting step is a drained one; dry stretches hand the loop back
+    to the look-ahead (or, on the drained reference, retry every step) —
+    output must stay identical to the plain drained sequence."""
     gen = GenerationConfig(max_new_tokens=12)
     rng = np.random.default_rng(5)
     # mixed traffic: two repetitive prompts (draft well), two random ones
     prompts = _rep_prompts(rng, (12, 18)) + _prompts(rng, (9, 14))
     cfg = dict(block_size=8, num_blocks=64)
-    want = _run(_paged(params, gen, PagedConfig(**cfg)), prompts)
+    want = _run(_paged(params, gen, PagedConfig(**cfg), loop="drained"), prompts)
     paged = _paged(
-        params, gen,
-        PagedConfig(**cfg, async_loop=True, spec_draft_tokens=4),
+        params, gen, PagedConfig(**cfg, spec_draft_tokens=4), loop=loop,
     )
     out = _run(paged, prompts)
     assert out == want
@@ -304,7 +308,7 @@ def test_spec_steady_state_residency(params):
     paged = _paged(
         params, gen,
         PagedConfig(
-            block_size=32, num_blocks=8, async_loop=True, spec_draft_tokens=4
+            block_size=32, num_blocks=8, spec_draft_tokens=4
         ),
     )
     paged.submit(_rep_prompts(np.random.default_rng(0), (6,))[0])
@@ -547,14 +551,15 @@ def test_medusa_packed_parents():
     assert (np.asarray(anc) == bufs.ancestor_mask).all()
 
 
-# {gather, kernel} x {sync, async}: the two tier-1 legs cover every value
-# of both axes (kernel under async, gather under sync); the remaining
-# diagonal rides the opt-in slow tier, same split as test_fused_step's cube
+# {gather, kernel} x {drained, lookahead}: the two tier-1 legs cover every
+# value of both axes (kernel on the look-ahead, gather on the drained
+# reference); the remaining diagonal rides the opt-in slow tier, same split
+# as test_fused_step's cube
 _TREE_MATRIX = [
-    ("kernel", "async"),
-    ("gather", "sync"),
-    pytest.param("kernel", "sync", marks=pytest.mark.slow),
-    pytest.param("gather", "async", marks=pytest.mark.slow),
+    ("kernel", "lookahead"),
+    ("gather", "drained"),
+    pytest.param("kernel", "drained", marks=pytest.mark.slow),
+    pytest.param("gather", "lookahead", marks=pytest.mark.slow),
 ]
 
 
@@ -566,10 +571,9 @@ _TREE_MATRIX = [
 )
 def test_tree_spec_parity_matrix(params, model, loop):
     """Packed-tree greedy serving == dense engine across {gather, kernel}
-    x {sync, async} — and tree verifies must actually fire (t=5 <= the
+    x {drained, lookahead} — and tree verifies must actually fire (t=5 <= the
     kernel's max_t, so the kernel leg runs the ancestor-masked kernel)."""
     model_cfg = TINY_KERNEL if model == "kernel" else TINY
-    async_loop = loop == "async"
     gen = GenerationConfig(max_new_tokens=10)
     prompts = _rep_prompts(np.random.default_rng(3), (12, 22, 9, 17))
     want = _dense_outputs(params, prompts, gen)
@@ -577,9 +581,9 @@ def test_tree_spec_parity_matrix(params, model, loop):
         params, gen,
         PagedConfig(
             block_size=8, num_blocks=64, spec_draft_tokens=4,
-            spec_tree=True, async_loop=async_loop,
+            spec_tree=True,
         ),
-        model_cfg,
+        model_cfg, loop=loop,
     )
     out = _run(paged, prompts)
     assert out == want
@@ -615,7 +619,7 @@ def test_tree_steady_state_residency(params):
     paged = _paged(
         params, gen,
         PagedConfig(
-            block_size=32, num_blocks=8, async_loop=True,
+            block_size=32, num_blocks=8,
             spec_draft_tokens=4, spec_tree=True,
         ),
     )

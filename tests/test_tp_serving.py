@@ -193,42 +193,42 @@ def matrix_ref(params):
 
 
 # tier-1 time budget: the default tier runs a trio covering every
-# chunk/async/spec value and most pairs (whole-sync-spec completes the
+# chunk/loop/spec value and most pairs (whole-drained-spec completes the
 # pairwise quartet from the slow tier); the rest of the cube also rides
 # in the slow tier.
 @pytest.mark.parametrize(
-    "chunk,async_loop,spec",
+    "chunk,loop,spec",
     [
-        pytest.param(6, True, 3, id="chunked-async-spec"),
-        pytest.param(6, False, 0, id="chunked-sync-plain"),
-        pytest.param(None, True, 0, id="whole-async-plain"),
-        pytest.param(None, False, 3, id="whole-sync-spec",
+        pytest.param(6, "lookahead", 3, id="chunked-lookahead-spec"),
+        pytest.param(6, "drained", 0, id="chunked-drained-plain"),
+        pytest.param(None, "lookahead", 0, id="whole-lookahead-plain"),
+        pytest.param(None, "drained", 3, id="whole-drained-spec",
                      marks=pytest.mark.slow),
-        pytest.param(6, False, 3, id="chunked-sync-spec",
+        pytest.param(6, "drained", 3, id="chunked-drained-spec",
                      marks=pytest.mark.slow),
-        pytest.param(6, True, 0, id="chunked-async-plain",
+        pytest.param(6, "lookahead", 0, id="chunked-lookahead-plain",
                      marks=pytest.mark.slow),
-        pytest.param(None, True, 3, id="whole-async-spec",
+        pytest.param(None, "lookahead", 3, id="whole-lookahead-spec",
                      marks=pytest.mark.slow),
-        pytest.param(None, False, 0, id="whole-sync-plain",
+        pytest.param(None, "drained", 0, id="whole-drained-plain",
                      marks=pytest.mark.slow),
     ],
 )
-def test_tp2_engine_parity_matrix(params, matrix_ref, chunk, async_loop, spec):
+def test_tp2_engine_parity_matrix(params, matrix_ref, chunk, loop, spec):
     """Greedy outputs identical: tp=2 engine == tp=1 engine == dense engine,
-    across speculative × async-loop × chunked-prefill, with the Pallas
+    across speculative × step loop × chunked-prefill, with the Pallas
     kernel eligible (no dense-gather fallback) on both sides."""
     gen = MATRIX_GEN
     prompts, ref = matrix_ref
     cfg = dict(
         block_size=8, num_blocks=64, prefill_chunk_tokens=chunk,
-        async_loop=async_loop, spec_draft_tokens=spec,
+        spec_draft_tokens=spec,
     )
-    p1 = _paged(params, gen, PagedConfig(**cfg), TINY_KERNEL)
+    p1 = _paged(params, gen, PagedConfig(**cfg), TINY_KERNEL, loop=loop)
     assert p1.model._paged_kernel_eligible(1, None)
     out_tp1 = _run(p1, prompts)
     _tp_mesh()
-    p2 = _paged(params, gen, PagedConfig(**cfg), TINY_KERNEL)
+    p2 = _paged(params, gen, PagedConfig(**cfg), TINY_KERNEL, loop=loop)
     assert p2.model._paged_kernel_eligible(1, None), "tp=2 must not fall back"
     out_tp2 = _run(p2, prompts)
     assert out_tp2 == out_tp1
@@ -237,8 +237,8 @@ def test_tp2_engine_parity_matrix(params, matrix_ref, chunk, async_loop, spec):
     assert m.tp_size == 2
     if spec:
         assert m.verify_steps > 0 and m.accepted_tokens > 0
-    if async_loop and not spec:
-        # with spec on, verify steps run sync and this short well-drafting
+    if loop == "lookahead" and not spec:
+        # with spec on, verify steps are drained and this short well-drafting
         # workload may never re-enter the lookahead — plain cells must
         assert m.decode_steps_async > 0
 
@@ -254,7 +254,7 @@ def test_tp2_steady_state_is_fully_resident(params):
     gen = GenerationConfig(max_new_tokens=24)
     paged = _paged(
         params, gen,
-        PagedConfig(block_size=32, num_blocks=8, async_loop=True),
+        PagedConfig(block_size=32, num_blocks=8),
         TINY_KERNEL,
     )
     paged.submit(_prompts(np.random.default_rng(0), (4,))[0])
