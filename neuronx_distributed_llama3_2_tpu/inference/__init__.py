@@ -30,7 +30,9 @@ from neuronx_distributed_llama3_2_tpu.inference.model import (
     LlamaDecode,
     MixtralDecode,
     PagedKVCache,
+    RetentionDecode,
     SarvamDecode,
+    StateCache,
     decode_model_for,
 )
 from neuronx_distributed_llama3_2_tpu.inference.sampling import (
@@ -75,7 +77,9 @@ __all__ = [
     "MllamaCache",
     "MllamaDecoder",
     "PagedKVCache",
+    "RetentionDecode",
     "SarvamDecode",
+    "StateCache",
     "SamplingConfig",
     "decode_model_for",
     "SpeculativeDecoder",

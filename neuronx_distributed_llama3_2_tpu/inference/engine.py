@@ -143,27 +143,43 @@ class InferenceEngine:
         self.buckets = list(buckets) if buckets else default_buckets(max_seq_len)
         if self.buckets[-1] > max_seq_len:
             raise ValueError("largest bucket exceeds max_seq_len")
-        self.cache = self.model.init_cache(max_batch, max_seq_len, cache_dtype)
-        # place the cache on the live mesh (kv heads over tp, batch over dp
-        # when divisible) so mesh-sharded params and cache agree — the
-        # engine-side analogue of StateInitializer's per-rank state alloc
-        from neuronx_distributed_llama3_2_tpu.parallel import (
-            state as parallel_state,
-        )
-
-        if parallel_state.model_parallel_is_initialized():
-            from neuronx_distributed_llama3_2_tpu.parallel.layers import (
-                shard_pytree,
-            )
-
-            self.cache = shard_pytree(
-                self.cache, self.model.cache_specs(max_batch)
-            )
-        elif (home := committed_home(self.params)) is not None:
-            # born committed beside committed weights, as every program
-            # returns it: one lowering a program, not two
-            self.cache = jax.device_put(self.cache, home)
+        # the dense slot cache is built when something first reads it — this
+        # engine's own generate, a dense scheduler, a verify program — and
+        # never for a paged serving engine, which has a pool of its own
+        self._cache = None
+        self._cache_dtype = cache_dtype
         self._programs: Dict[Tuple, Callable] = {}
+
+    @property
+    def cache(self):
+        """The dense slot cache, built on first use: placed on the live mesh
+        (kv heads over tp, batch over dp when divisible) so mesh-sharded
+        params and cache agree — the engine-side analogue of
+        StateInitializer's per-rank state alloc — or born committed beside
+        committed weights, as every program returns it: one lowering a
+        program, not two."""
+        if self._cache is None:
+            cache = self.model.init_cache(
+                self.max_batch, self.max_seq_len, self._cache_dtype
+            )
+            from neuronx_distributed_llama3_2_tpu.parallel import (
+                state as parallel_state,
+            )
+
+            if parallel_state.model_parallel_is_initialized():
+                from neuronx_distributed_llama3_2_tpu.parallel.layers import (
+                    shard_pytree,
+                )
+
+                cache = shard_pytree(cache, self.model.cache_specs(self.max_batch))
+            elif (home := committed_home(self.params)) is not None:
+                cache = jax.device_put(cache, home)
+            self._cache = cache
+        return self._cache
+
+    @cache.setter
+    def cache(self, value) -> None:
+        self._cache = value
 
     def _live_params(self, params):
         """Dequantize QuantizedTensor leaves INSIDE the jitted program
@@ -198,6 +214,8 @@ class InferenceEngine:
         hidden, cache = model.forward(
             params, cache, ids, positions, slots,
             context_encode=True, return_hidden=True,
+            # a state keeps what a padded row does to it (RetentionDecode)
+            row_live=None if model.cache_is_positional else lengths,
         )
         # last-token gather before the LM head (model_base.py:444-452)
         last = jnp.take_along_axis(
@@ -296,6 +314,7 @@ class InferenceEngine:
         if key_ in self._programs:
             return self._programs[key_]
         model = self.model
+        refuse_unless_positional(model, "speculative verification")
 
         def verify(params, cache, tokens, positions, slots):
             return model.forward(
@@ -603,6 +622,19 @@ class InferenceEngine:
             )
         )(self.params, cache, input_ids, positions)
         return logits
+
+
+def refuse_unless_positional(model, what: str) -> None:
+    """Draft-and-verify runs a whole block of drafts through ``forward`` and
+    drops the rejected ones by rewinding the position; rows of a cache are
+    overwritten then, a state keeps what they did to it (the paged engine
+    refuses ``spec_draft_tokens`` for the same reason)."""
+    if not model.cache_is_positional:
+        raise ValueError(
+            f"{what} is not available for {type(model).__name__}: its cache is a "
+            "state per sequence, not rows per token — a rejected draft cannot be "
+            "taken back out of a state"
+        )
 
 
 # ---------------------------------------------------------------------------

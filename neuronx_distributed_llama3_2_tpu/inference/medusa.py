@@ -35,7 +35,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from neuronx_distributed_llama3_2_tpu.inference.engine import InferenceEngine, pick_bucket
+from neuronx_distributed_llama3_2_tpu.inference.engine import (
+    InferenceEngine, pick_bucket, refuse_unless_positional,
+)
 from neuronx_distributed_llama3_2_tpu.parallel.layers import (
     ColumnParallelLinear,
     Params,
@@ -202,6 +204,7 @@ class MedusaDecoder:
         buffers: MedusaBuffers = None,
         num_heads: int = 3,
     ) -> None:
+        refuse_unless_positional(engine.model, "Medusa tree verification")
         self.engine = engine
         self.heads = MedusaHeads(
             engine.config.hidden_size, engine.config.vocab_size,

@@ -151,9 +151,40 @@ def cache_row_bytes(cache: Any) -> int:
     are tiled (16, 128), so a 576-wide row occupies 640 — over its
     (layer, block or slot, row) positions. Compiles an identity; for a
     traced engine's ``setup`` record."""
+    return _laid_out_bytes(cache) // math.prod(jax.tree.leaves(cache)[0].shape[:3])
+
+
+def _laid_out_bytes(cache: Any) -> int:
+    """Argument bytes of a compiled identity over ``cache``."""
     compiled = jax.jit(lambda c: c).lower(cache).compile()
-    rows = math.prod(jax.tree.leaves(cache)[0].shape[:3])
-    return compiled.memory_analysis().argument_size_in_bytes // rows
+    return compiled.memory_analysis().argument_size_in_bytes
+
+
+class StateCache(NamedTuple):
+    """Retention cache (:mod:`..models.brumby`): what a sequence leaves
+    behind is one fixed-size state a layer — ``s`` (L, N, kv heads, φ, head)
+    and the normaliser ``z`` (L, N, kv heads, φ), float32 — and no row per
+    token. Dense: ``N`` slots; paged: ``N`` blocks of the pool, **one block one
+    whole state**, block 0 the null state that idle lanes and lanes
+    mid-prefill read and write. A block's size in tokens is not a dimension:
+    a lane's state is the block its table's first entry names. There is no
+    quantized form."""
+
+    s: jax.Array
+    z: jax.Array
+
+    @property
+    def max_batch(self) -> int:
+        return self.s.shape[1]
+
+    num_blocks = max_batch
+    quantized = False
+
+
+def cache_block_bytes(cache: Any) -> int:
+    """Bytes one block (or slot) of ``cache`` holds over all layers and all of
+    its arrays, as the device lays them out (see :func:`cache_row_bytes`)."""
+    return _laid_out_bytes(cache) // jax.tree.leaves(cache)[0].shape[1]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,6 +205,15 @@ class LlamaDecode:
         "model_parallel_is_initialized", "get_parallel_state",
         "get_tensor_model_parallel_size", "mesh_is_tp_only",
     )
+
+    # What the cache holds is rows by position: a padded or stale row is
+    # masked out by position, a prefix of a sequence's rows is a prefix of its
+    # cache, a row written past the frontier is overwritten before it is read.
+    # The serving engine's prefix sharing, speculation, fused step and spill
+    # rest on this; a model whose cache is a state (:class:`RetentionDecode`)
+    # says False and the engine turns them off (docs/serving.md "Models whose
+    # cache is a state").
+    cache_is_positional = True
 
     def _model(self) -> LlamaForCausalLM:
         return LlamaForCausalLM(self.config)
@@ -1547,6 +1587,175 @@ class SarvamDecode(MixtralDecode):
 
 
 @dataclasses.dataclass(frozen=True)
+class RetentionDecode(LlamaDecode):
+    """Decode-mode power retention (:mod:`..models.brumby`) over a
+    :class:`StateCache`: no program reads a row of the past.
+
+    Which form a program runs follows from its shape: one token a lane
+    (``pdecode``) is the recurrent form; a block of rows is the chunked form —
+    from the **zero state** under ``context_encode`` (``pctx``: a freshly
+    allocated block still holds its last owner's past, and a state is
+    read-modify-write), from the block's state otherwise (``psfx``).
+    ``row_live`` is the count of real rows of a padded block: rows at or past
+    it leave the state untouched. ``kv_limit`` is accepted and means nothing.
+
+    The states ride the layer loop as its carry and each lane's is sliced out,
+    updated and written back at ``[layer, block]`` inside a loop over lanes, so
+    a donated pool is updated in place and a program's temporaries are one
+    lane's state, never the pool. Tree (speculative) blocks and a quantized
+    pool are refused; a rejected draft cannot be taken back out of a state."""
+
+    cache_is_positional = False
+
+    def _model(self):
+        from neuronx_distributed_llama3_2_tpu.models.brumby import BrumbyForCausalLM
+
+        return BrumbyForCausalLM(self.config)
+
+    # -- cache ------------------------------------------------------------
+
+    def init_cache(self, max_batch: int, max_len: int = 0, dtype: Any = None) -> StateCache:
+        from neuronx_distributed_llama3_2_tpu.models.brumby import STATE_DTYPE
+
+        c = self.config
+        lead = (c.num_layers, max_batch, c.num_kv_heads, c.feature_width)
+        dtype = dtype or STATE_DTYPE
+        return StateCache(s=jnp.zeros(lead + (c.head_dim,), dtype), z=jnp.zeros(lead, dtype))
+
+    def init_paged_cache(
+        self, num_blocks: int, block_size: int, dtype: Any = None,
+        kv_cache_dtype: Optional[str] = None,
+    ) -> StateCache:
+        """``num_blocks`` states; ``block_size`` is not a dimension of them."""
+        if kv_cache_dtype not in (None, "bf16"):
+            raise NotImplementedError(
+                f"kv_cache_dtype={kv_cache_dtype!r}: a retention state has no "
+                "quantized form — it is a running sum of thousands of updates, "
+                "not rows with a scale each"
+            )
+        return self.init_cache(num_blocks, dtype=dtype)
+
+    def paged_cache_specs(self, quantized: bool = False) -> StateCache:
+        """States shard by kv head over tp, like the k/v pools."""
+        ha = _head_axis(self.config.num_kv_heads)
+        return StateCache(s=P(None, None, ha), z=P(None, None, ha))
+
+    def cache_specs(self, max_batch: Optional[int] = None) -> StateCache:
+        return self.paged_cache_specs()
+
+    def forbidden_gather_shapes(self, batch: int, kv_limit: int):
+        return set()
+
+    def _paged_kernel_eligible(self, t: int, tree) -> bool:
+        return False
+
+    # -- forward ----------------------------------------------------------
+
+    def _rope_rows(self, pos_block: jax.Array):
+        """(sin, cos) of the rows ``pos_block`` (b, t) themselves, (b·t, d):
+        a lane's memory has no last row, so there is no table length to size."""
+        c = self.config
+        if c.rope_scaling is not None:
+            raise NotImplementedError("rope_scaling with retention layers")
+        inv = 1.0 / (c.rope_theta ** (jnp.arange(0, c.head_dim, 2, dtype=jnp.float32) / c.head_dim))
+        freqs = pos_block.reshape(-1, 1).astype(jnp.float32) * inv
+        emb = jnp.concatenate([freqs, freqs], axis=-1)
+        return jnp.sin(emb), jnp.cos(emb)
+
+    def forward(
+        self, params: Params, cache: StateCache, tokens: jax.Array, positions: jax.Array,
+        slots: Optional[jax.Array] = None, *, context_encode: bool = False,
+        return_hidden: bool = False, tree=None, kv_limit: Optional[int] = None,
+        block_tables: Optional[jax.Array] = None, row_live: Optional[jax.Array] = None,
+    ) -> Tuple[jax.Array, StateCache]:
+        """tokens (b, T) at rows ``positions ..`` over the states (see the
+        class); returns (logits (b, T, V) or the normed hidden, the cache
+        updated)."""
+        if tree is not None:
+            raise NotImplementedError("tree verification over a retention state")
+        from neuronx_distributed_llama3_2_tpu.models.brumby import (
+            RetentionAttention,
+            retention_chunks,
+            retention_step,
+        )
+
+        c = self.config
+        model = self._model()
+        attn, norm = RetentionAttention(c), make_norm(c)
+        b, t = tokens.shape
+        pos_block = positions[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        sin, cos = self._rope_rows(pos_block)
+        rows = jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)      # into sin / cos
+        # the state a lane reads and writes: the block its table's first
+        # entry names, or its slot
+        if block_tables is not None:
+            index = block_tables[:, 0]
+        else:
+            index = slots if slots is not None else jnp.arange(b, dtype=jnp.int32)
+        recurrent = t == 1 and not context_encode and row_live is None
+        form = "step" if recurrent else "chunk"
+        live = jnp.full((b,), t, jnp.int32) if row_live is None else row_live
+        eps = c.retention_eps
+
+        def layer_body(carry, layer_in):
+            x, s_pool, z_pool = carry
+            lp, layer = layer_in
+            h = norm(lp["attn_norm"], x)
+            with jax.named_scope("attn"):
+                q, k, v, log_g = attn.project(lp["attn"], h, sin, cos, rows)
+
+                def lane(i, lane_carry):
+                    s_pool, z_pool, ys = lane_carry
+                    at = (layer, index[i], 0, 0, 0)
+                    # the state's way out of the pool and back into it is part
+                    # of the pass over it: under the form's own scope
+                    with jax.named_scope(form):
+                        if context_encode:
+                            s_in = jnp.zeros(s_pool.shape[2:], s_pool.dtype)
+                            z_in = jnp.zeros(z_pool.shape[2:], z_pool.dtype)
+                        else:
+                            s_in = jax.lax.dynamic_slice(s_pool, at, (1, 1) + s_pool.shape[2:])[0, 0]
+                            z_in = jax.lax.dynamic_slice(z_pool, at[:4], (1, 1) + z_pool.shape[2:])[0, 0]
+                    qi, ki, vi, gi = (
+                        jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+                        for a in (q, k, v, log_g)
+                    )
+                    if recurrent:
+                        y, s_out, z_out = retention_step(
+                            s_in, z_in, qi[0], ki[0], vi[0], gi[0], eps)
+                        y = y[None]
+                    else:
+                        y, s_out, z_out = retention_chunks(
+                            s_in, z_in, qi, ki, vi, gi, live[i], eps)
+                    with jax.named_scope(form):
+                        return (
+                            jax.lax.dynamic_update_slice(s_pool, s_out[None, None], at),
+                            jax.lax.dynamic_update_slice(z_pool, z_out[None, None], at[:4]),
+                            jax.lax.dynamic_update_index_in_dim(ys, y, i, 0),
+                        )
+
+                with jax.named_scope("retention"):
+                    s_pool, z_pool, y = jax.lax.fori_loop(
+                        0, b, lane, (s_pool, z_pool, jnp.zeros(q.shape[:-1] + v.shape[-1:], q.dtype)))
+                attn_out = attn.output(lp["attn"], y)
+            x = x + attn_out
+            h = norm(lp["mlp_norm"], x)
+            return (x + self._mlp_block(lp, h), s_pool, z_pool), None
+
+        x = model._embed()(params["embed"], tokens)
+        x = constrain(x, P(BATCH_AXES, None, None))
+        (x, s_new, z_new), _ = jax.lax.scan(
+            layer_body, (x, cache.s, cache.z),
+            (params["layers"], jnp.arange(c.num_layers, dtype=jnp.int32)),
+        )
+        x = norm(params["final_norm"], x)
+        new_cache = StateCache(s=s_new, z=z_new)
+        if return_hidden:
+            return x, new_cache
+        return model._logits(params, x), new_cache
+
+
+@dataclasses.dataclass(frozen=True)
 class GPTNeoXDecode(LlamaDecode):
     """Decode-mode GPT-NeoX/Pythia/CodeGen: the shared KV-cache machinery
     (:meth:`LlamaDecode._attend_with_cache`) under the family's block
@@ -1618,6 +1827,7 @@ def decode_model_for(config) -> LlamaDecode:
     """Pick the decode-model class for a training config (the engine-side
     analogue of the reference's per-family NeuronXxxForCausalLM dispatch)."""
     from neuronx_distributed_llama3_2_tpu.models.bert import BertConfig
+    from neuronx_distributed_llama3_2_tpu.models.brumby import BrumbyConfig
     from neuronx_distributed_llama3_2_tpu.models.gptneox import GPTNeoXConfig
     from neuronx_distributed_llama3_2_tpu.models.mixtral import MixtralConfig
     from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamConfig
@@ -1631,6 +1841,8 @@ def decode_model_for(config) -> LlamaDecode:
         return GPTNeoXDecode(config)
     if isinstance(config, SarvamConfig):
         return SarvamDecode(config)
+    if isinstance(config, BrumbyConfig):
+        return RetentionDecode(config)
     if isinstance(config, MixtralConfig):
         return MixtralDecode(config)
     return LlamaDecode(config)

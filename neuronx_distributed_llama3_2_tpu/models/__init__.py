@@ -22,6 +22,13 @@ from neuronx_distributed_llama3_2_tpu.models.sarvam import (  # noqa: F401
     SarvamConfig,
     SarvamForCausalLM,
 )
+from neuronx_distributed_llama3_2_tpu.models.brumby import (  # noqa: F401
+    BRUMBY_CONFIGS,
+    BrumbyConfig,
+    BrumbyForCausalLM,
+    params_from_hf_brumby,
+    params_to_hf_brumby,
+)
 from neuronx_distributed_llama3_2_tpu.models.dbrx import (  # noqa: F401
     DBRX_CONFIGS,
     DbrxConfig,
@@ -86,6 +93,11 @@ def model_registry():
         reg[name] = {
             "config": cfg, "model_cls": SarvamForCausalLM,
             "from_hf": None, "to_hf": None,
+        }
+    for name, cfg in BRUMBY_CONFIGS.items():
+        reg[name] = {
+            "config": cfg, "model_cls": BrumbyForCausalLM,
+            "from_hf": params_from_hf_brumby, "to_hf": params_to_hf_brumby,
         }
     for name, cfg in DBRX_CONFIGS.items():
         reg[name] = {

@@ -33,9 +33,6 @@ import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
 from neuronx_distributed_llama3_2_tpu import flops as flops_mod
-from neuronx_distributed_llama3_2_tpu.serving.block_allocator import (
-    kv_pool_bytes_per_rank,
-)
 from neuronx_distributed_llama3_2_tpu.serving.catalog import format_key
 
 # program kinds that run model math — these must carry nonzero FLOPs
@@ -139,6 +136,15 @@ class EngineDims:
     # arrays a cache row is spread over: k and v, or 1 for a latent pool
     # (then num_kv_heads = 1 and head_dim = the row's width)
     kv_arrays: int = 2
+    # bytes one block of the pool holds on a rank, over every layer and every
+    # leaf, taken off the cache itself (``metrics.pool_bytes_per_rank`` over
+    # its blocks); None on dims built by hand (a planner's, an older dump's),
+    # which reckon it from the row dims above
+    block_bytes: Optional[int] = None
+    # False where a block is one sequence's whole state and a token leaves no
+    # row (``cache_is_positional`` of the decode model): a program then moves
+    # whole blocks — the sequences it touches, read and written
+    cache_is_positional: bool = True
 
     @classmethod
     def from_engine(cls, engine: Any) -> "EngineDims":
@@ -146,7 +152,10 @@ class EngineDims:
         import numpy as np
 
         mc = engine.model.config
-        kv_arrays, kv_heads, kv_width = engine.model.cache_row_dims()
+        positional = bool(engine.model.cache_is_positional)
+        kv_arrays, kv_heads, kv_width = (
+            engine.model.cache_row_dims() if positional else (0, 0, 0)
+        )
         leaves = jax.tree.leaves(engine.engine.params)
         num_params = sum(int(np.prod(l.shape)) for l in leaves)
         param_bytes = sum(
@@ -174,6 +183,9 @@ class EngineDims:
             quant_mxu=bool(getattr(engine.model.config, "quant_mxu", False)),
             fused_sampling=bool(getattr(engine, "_fused", False)),
             kv_arrays=kv_arrays,
+            block_bytes=int(engine.metrics.pool_bytes_per_rank)
+            // int(engine.paged.num_blocks),
+            cache_is_positional=positional,
         )
 
     @property
@@ -190,9 +202,27 @@ class EngineDims:
 
     def kv_row_bytes(self) -> int:
         """HBM bytes one KV row (all layers, every array, local heads) holds,
-        scale arrays included when the pool is quantized."""
+        scale arrays included when the pool is quantized; 0 where the cache
+        is a state."""
+        if not self.cache_is_positional:
+            return 0
+        if self.block_bytes is not None:
+            return self.block_bytes // self.block_size
         per_head = self.head_dim * self.kv_bytes_per_elem + self.scale_bytes
         return self.kv_arrays * self.num_layers * self.kv_heads_local * per_head
+
+    def pool_bytes_local(self) -> int:
+        """Bytes of the whole pool on a rank."""
+        if self.block_bytes is not None:
+            return self.num_blocks * self.block_bytes
+        return self.num_blocks * self.block_size * self.kv_row_bytes()
+
+    def state_bytes(self, sequences: int) -> int:
+        """Bytes a program over ``sequences`` lanes moves of a state pool:
+        each lane's block read once and written once; 0 for a pool of rows."""
+        if self.cache_is_positional:
+            return 0
+        return 2 * sequences * (self.block_bytes or 0)
 
 
 def _flops_per_token(
@@ -227,12 +257,14 @@ def analytic_cost(key: tuple, dims: EngineDims) -> Tuple[float, float, str]:
             + 2 * dims.num_layers * dims.hidden_size * b * b
         rows = b
         tokens = b
+        lanes = 1
     elif kind == "psfx":
         # suffix prefill: b tokens each attending up to kv_limit rows
         b, kv = int(key[1]), int(key[2])
         f = b * _flops_per_token(dims, kv)
         rows = kv
         tokens = b
+        lanes = 1
     elif kind == "pdecode":
         # the decode kernel is where quant_mxu lives: its q·k dot runs
         # at int8 throughput, so the key's flop figure drops with it
@@ -240,6 +272,7 @@ def analytic_cost(key: tuple, dims: EngineDims) -> Tuple[float, float, str]:
         f = dims.max_batch * _flops_per_token(dims, kv, dims.quant_mxu)
         rows = dims.max_batch * kv
         tokens = dims.max_batch
+        lanes = dims.max_batch
     elif kind in ("pverify", "ptree"):
         # ptree (packed-tree verify) prices identically to linear verify:
         # the forward is the same B·(k+1) query rows over kv+k attention
@@ -252,6 +285,7 @@ def analytic_cost(key: tuple, dims: EngineDims) -> Tuple[float, float, str]:
         )
         rows = dims.max_batch * (kv + k)
         tokens = dims.max_batch * (k + 1)
+        lanes = dims.max_batch
     elif kind == "pmixed":
         # fused mixed-mode step: B lanes × t query rows over the shared
         # pool — the verify formula at draft width k = t - 1 (a prefill
@@ -262,9 +296,12 @@ def analytic_cost(key: tuple, dims: EngineDims) -> Tuple[float, float, str]:
         )
         rows = dims.max_batch * (kv + t - 1)
         tokens = dims.max_batch * t
+        lanes = dims.max_batch
     elif kind == "copy_block":
         elems = dims.kv_arrays * dims.num_layers * dims.block_size \
             * dims.kv_heads_local * dims.head_dim
+        if not dims.cache_is_positional:     # a state is copied whole
+            elems = (dims.block_bytes or 0) // dims.kv_bytes_per_elem
         return float(elems), float(2 * elems * dims.kv_bytes_per_elem), \
             "analytic-move"
     elif kind == "lane_set":
@@ -290,9 +327,10 @@ def analytic_cost(key: tuple, dims: EngineDims) -> Tuple[float, float, str]:
     else:
         return 1.0, 1.0, "analytic-move"
     # compute-kind bytes: the parameter shard streams once, the touched
-    # KV rows stream once, and the logits materialize in fp32
+    # KV rows stream once (or the touched lanes' states, there and back),
+    # and the logits materialize in fp32
     byts = dims.param_bytes_local + rows * dims.kv_row_bytes() \
-        + tokens * dims.vocab_size * 4
+        + dims.state_bytes(lanes) + tokens * dims.vocab_size * 4
     return float(f), float(byts), "analytic"
 
 
@@ -306,21 +344,10 @@ def analytic_profile(key: tuple, dims: EngineDims) -> CostProfile:
         # arguments ≈ params shard + the whole pool (every compute
         # program takes the full donated cache); outputs are the sampled
         # tokens (the cache comes back through the donation alias)
-        pool = kv_pool_bytes_per_rank(
-            num_layers=dims.num_layers,
-            num_blocks=dims.num_blocks,
-            block_size=dims.block_size,
-            num_kv_heads=dims.num_kv_heads,
-            head_dim=dims.head_dim,
-            dtype_bytes=dims.kv_bytes_per_elem,
-            tp_size=dims.tp_size,
-            scale_bytes=dims.scale_bytes,
-            arrays=dims.kv_arrays,
-        )
-        arg = dims.param_bytes_local + pool
+        arg = dims.param_bytes_local + dims.pool_bytes_local()
         out = dims.max_batch * 4
     else:
-        arg = dims.block_size * dims.kv_row_bytes()
+        arg = dims.pool_bytes_local() // dims.num_blocks
         out = arg
     return CostProfile(
         key=key, kind=kind, flops=f, bytes_accessed=b,

@@ -61,7 +61,10 @@ from neuronx_distributed_llama3_2_tpu.serving.faults import (
     FaultInjector,
     InjectedFault,
 )
-from neuronx_distributed_llama3_2_tpu.inference.model import cache_row_bytes
+from neuronx_distributed_llama3_2_tpu.inference.model import (
+    cache_block_bytes,
+    cache_row_bytes,
+)
 from neuronx_distributed_llama3_2_tpu.inference.placement import (
     committed_home,
 )
@@ -515,6 +518,31 @@ class PagedServingEngine:
         self._spec_k = int(paged.spec_draft_tokens or 0)
         if self._spec_k < 0:
             raise ValueError("spec_draft_tokens must be >= 0")
+        # what the engine learns from the decode model about its cache: rows
+        # by position (a prefix of the rows is a prefix's cache, a padded or
+        # rejected row is masked or overwritten), or a state that is neither
+        # (docs/serving.md "Models whose cache is a state")
+        self._positional = bool(self.model.cache_is_positional)
+        if not self._positional:
+            for on, name, why in (
+                (self._spec_k, "spec_draft_tokens > 0",
+                 "a rejected draft cannot be taken back out of a state"),
+                (paged.fused_step, "fused_step",
+                 "the mixed program writes every lane's padding rows, and a "
+                 "state keeps what a row did to it"),
+                (paged.spill_enabled, "spill_enabled",
+                 "the spill tier lives behind the prefix index, which shares "
+                 "nothing of a state"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{name} is not available for {type(self.model).__name__}: "
+                        f"its cache is a state per sequence, not rows per token — {why}"
+                    )
+        # prefix sharing matches token by token inside a block and copies a
+        # partly shared block; a state after N tokens says nothing about its
+        # first k, so a state model neither matches nor inserts
+        self._share_prefixes = bool(paged.enable_prefix_caching) and self._positional
         # tree speculation: verify a packed candidate tree (ptree program)
         # instead of a single chain. Set before the catalog build below —
         # the manifest swaps its verify rungs to ptree keys under the flag.
@@ -626,7 +654,6 @@ class PagedServingEngine:
             )
         from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (
             kv_cache_jax_dtype,
-            kv_scale_itemsize,
         )
 
         kv_cache_jax_dtype(paged.kv_cache_dtype)  # validate the knob early
@@ -774,25 +801,16 @@ class PagedServingEngine:
         # static pool-layout rows: under a tp mesh the kv-head-sharded pool
         # (paged_cache_specs) puts only NKV/tp heads on each chip, so the
         # same per-chip HBM holds a tp×-larger logical pool — the multi-chip
-        # capacity win, made observable in every metrics snapshot
-        from neuronx_distributed_llama3_2_tpu.serving.block_allocator import (
-            kv_pool_bytes_per_rank,
-        )
-
-        mc = self.model.config
-        tp = parallel_state.tensor_parallel_size_or(1)
-        arrays, row_heads, row_width = self.model.cache_row_dims()
-        pool_dims = dict(
-            num_layers=mc.num_layers, num_blocks=paged.num_blocks,
-            block_size=bs, num_kv_heads=row_heads, head_dim=row_width,
-            dtype_bytes=jax.tree.leaves(self.cache)[0].dtype.itemsize,
-            scale_bytes=kv_scale_itemsize(paged.kv_cache_dtype), arrays=arrays,
-        )
-        self.metrics.tp_size = tp
+        # capacity win, made observable in every metrics snapshot. The bytes
+        # are read off the pool's own leaves (payloads, scale tiles, states:
+        # whatever the decode model's cache is made of), per rank off one
+        # device's shards
+        pool_leaves = jax.tree.leaves(self.cache)
+        self.metrics.tp_size = parallel_state.tensor_parallel_size_or(1)
         self.metrics.kv_dtype = paged.kv_cache_dtype
-        self.metrics.pool_bytes_total = kv_pool_bytes_per_rank(**pool_dims)
-        self.metrics.pool_bytes_per_rank = kv_pool_bytes_per_rank(
-            **pool_dims, tp_size=tp
+        self.metrics.pool_bytes_total = sum(a.nbytes for a in pool_leaves)
+        self.metrics.pool_bytes_per_rank = sum(
+            a.addressable_shards[0].data.nbytes for a in pool_leaves
         )
 
         self._next_rid = 0
@@ -1073,8 +1091,19 @@ class PagedServingEngine:
             "program_temp_bytes_max": max(
                 (p.temp_bytes for p in profiles.values()), default=0
             ),
-            "cache_row_bytes": cache_row_bytes(self.cache),
+            # rows by position: bytes a token a layer; a state: bytes of one
+            # block over all layers
+            **({"cache_row_bytes": cache_row_bytes(self.cache)} if self._positional
+               else {"state_bytes_per_lane": cache_block_bytes(self.cache)}),
         }
+
+    def _decode_rows(self, decode_lanes) -> int:
+        """A decode dispatch record's ``rows``: the cache rows the live lanes
+        attend over, this step's included — or, where the cache is a state a
+        lane, the live lanes: the states the step has to move."""
+        if not self._positional:
+            return len(decode_lanes)
+        return int(sum(self._positions[l] for l in decode_lanes)) + len(decode_lanes)
 
     def _kv_bucket(self, needed: int) -> int:
         """kv_limit rung covering ``needed`` rows over the serving kv
@@ -1138,10 +1167,15 @@ class PagedServingEngine:
             return self._programs[key_]
         model, engine = self._step_model(), self.engine
 
+        # a state keeps what a padded row does to it: the live length reaches
+        # the model (None leaves a positional model's lowering as it was)
+        positional = self._positional
+
         def _last_logits(params, cache, ids, positions, length, table):
             hidden, cache = model.forward(
                 params, cache, ids, positions, None,
                 context_encode=True, return_hidden=True, block_tables=table,
+                row_live=None if positional else length,
             )
             last = jnp.take_along_axis(
                 hidden, (length - 1)[:, None, None], axis=1
@@ -1187,10 +1221,13 @@ class PagedServingEngine:
             return self._programs[key_]
         model, engine = self._step_model(), self.engine
 
+        positional = self._positional
+
         def _last_logits(params, cache, ids, start, length, table):
             hidden, cache = model.forward(
                 params, cache, ids, start, None,
                 return_hidden=True, block_tables=table, kv_limit=kv_limit,
+                row_live=None if positional else length,
             )
             last = jnp.take_along_axis(
                 hidden, (length - 1)[:, None, None], axis=1
@@ -2597,7 +2634,7 @@ class PagedServingEngine:
         while self._queue and self._free_lanes:
             req = self._queue[0]
             seq = req.prompt + req.out  # resume re-prefills generated tokens
-            if self.paged.enable_prefix_caching:
+            if self._share_prefixes:
                 matched, mblocks = self.index.match(seq)
                 if self._spill and self.index.num_spilled:
                     # tiered KV: the walk may extend past the resident
@@ -2738,7 +2775,7 @@ class PagedServingEngine:
             self._tables[lane, : len(table)] = table
             self._dirty_lanes.add(lane)
             self.metrics.prefill_tokens += len(suffix)
-            if self.paged.enable_prefix_caching:
+            if self._share_prefixes:
                 # register the prompt's full blocks immediately so requests
                 # admitted later in this same wave share them; the partial
                 # tail block stays private (decode writes into it)
@@ -2773,6 +2810,8 @@ class PagedServingEngine:
             table_dev = self._upload(tbl)
         tail = self._lane_sampling_args(lane) if self._fused else (key,)
         if cached == 0:
+            if not self._positional:
+                self.metrics.state_resets += 1   # this pctx begins a state from zero
             fn = self._prefill_ctx_program(bucket, self._decode_cfg())
             tok, self.cache = fn(
                 eng.params, self.cache, self._upload(ids),
@@ -2886,7 +2925,7 @@ class PagedServingEngine:
             self._positions[lane] = req.position
             self._tables[lane, : len(req.table)] = req.table
             self._dirty_lanes.add(lane)
-            if self.paged.enable_prefix_caching:
+            if self._share_prefixes:
                 n_full = len(seq) // bs
                 if n_full:
                     self.index.insert(seq[: n_full * bs], req.table[:n_full])
@@ -2989,7 +3028,7 @@ class PagedServingEngine:
             return
         req.done = True
         bs = self.paged.block_size
-        if self.paged.enable_prefix_caching and req.table:
+        if self._share_prefixes and req.table:
             # cache the whole materialized sequence (prompt + generated):
             # rows [0, position) are valid — the final token's KV was never
             # written, so it is excluded
@@ -3249,8 +3288,7 @@ class PagedServingEngine:
                 "dispatch", t_d, program=program_label(fn), mode="async",
                 sampling=smode, lanes=len(decode_lanes), kv_bucket=kv_limit,
                 kv_pad=kv_limit - kv_need,
-                # cache rows the live lanes attend over, this step's included
-                rows=int(sum(self._positions[l] for l in decode_lanes)) + len(decode_lanes),
+                rows=self._decode_rows(decode_lanes),
             )
         self._d_tokens = toks
         self._dispatch_count += 1
@@ -3320,8 +3358,7 @@ class PagedServingEngine:
                 "dispatch", t_d, program=program_label(fn), mode="sync",
                 sampling=smode, lanes=len(decode_lanes), kv_bucket=kv_limit,
                 kv_pad=kv_limit - kv_need,
-                # cache rows the live lanes attend over, this step's included
-                rows=int(sum(self._positions[l] for l in decode_lanes)) + len(decode_lanes),
+                rows=self._decode_rows(decode_lanes),
             )
         self._d_tokens = toks
         self._dispatch_count += 1
@@ -3810,7 +3847,7 @@ class PagedServingEngine:
             self.tracer.request_state(req.rid, "active")
             self._tokens[lane] = tok
             self._positions[lane] = req.position
-            if cfg.enable_prefix_caching:
+            if self._share_prefixes:
                 seq = req.prompt + req.out[:-1]
                 n_full = len(seq) // bs
                 if n_full:

@@ -63,6 +63,9 @@ class ServingMetrics:
     finished: int = 0
     truncated: int = 0        # finished early because the pool can never fit
     preemptions: int = 0      # requests bumped back to the queue
+    # states begun from zero by a whole-prompt or first-chunk prefill of a
+    # model whose cache is a state (admissions + resumed preemptions)
+    state_resets: int = 0
     decode_steps: int = 0
     # -- fused mixed-mode step (docs/serving.md "Fused mixed-mode step"):
     #    engine_steps counts every step() (the dispatches_per_step

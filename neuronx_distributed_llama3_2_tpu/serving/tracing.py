@@ -85,9 +85,13 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
 # ``attn/latent_up`` (``W_UKV`` over the rows attended) and ``attn/absorb``
 # (``W_UK`` / ``W_UV`` folded into the query and the output) are latent
 # attention's (models/sarvam.py); ``moe/shared`` the shared expert
-# (moe/model.py).
+# (moe/model.py); ``attn/gate`` (the per-kv-head decay), ``attn/retention/expand``
+# (φ of q and k), ``attn/retention/chunk`` (prefill: the in-chunk weights, the
+# carried state's read and its update) and ``attn/retention/step`` (decode: a
+# state's read and update) are power retention's (models/brumby.py).
 DETAIL_SCOPES = {
-    "attn": ("qk_norm", "latent_down", "latent_up", "absorb"),
+    "attn": ("qk_norm", "latent_down", "latent_up", "absorb", "gate", "retention"),
+    "attn/retention": ("expand", "chunk", "step"),
     "moe": ("shared",),
     "moe/experts": ("selective", "all"),
 }
