@@ -49,6 +49,7 @@ from neuronx_distributed_llama3_2_tpu.inference.sampling import (
 )
 from neuronx_distributed_llama3_2_tpu.models.llama import LlamaConfig
 from neuronx_distributed_llama3_2_tpu.utils.logger import get_logger
+from neuronx_distributed_llama3_2_tpu.utils.setup_record import SETUP
 
 logger = get_logger()
 
@@ -132,23 +133,24 @@ class InferenceEngine:
         buckets: Optional[Sequence[int]] = None,
         cache_dtype: Any = None,
     ) -> None:
-        self.config = config
-        self.model = decode_model_for(config)
-        # fused (..., in, 2, out) leaves rest in the layout their matmul
-        # reads (inference/placement.py), placed before the cache exists:
-        # the transient is one leaf beside the weights
-        self.params, self.placement = rest_fused_weights(params)
-        self.max_batch = max_batch
-        self.max_seq_len = max_seq_len
-        self.buckets = list(buckets) if buckets else default_buckets(max_seq_len)
-        if self.buckets[-1] > max_seq_len:
-            raise ValueError("largest bucket exceeds max_seq_len")
-        # the dense slot cache is built when something first reads it — this
-        # engine's own generate, a dense scheduler, a verify program — and
-        # never for a paged serving engine, which has a pool of its own
-        self._cache = None
-        self._cache_dtype = cache_dtype
-        self._programs: Dict[Tuple, Callable] = {}
+        with SETUP.span("setup.inference_engine"):
+            self.config = config
+            self.model = decode_model_for(config)
+            # fused (..., in, 2, out) leaves rest in the layout their matmul
+            # reads (inference/placement.py), placed before the cache exists:
+            # the transient is one leaf beside the weights
+            self.params, self.placement = rest_fused_weights(params)
+            self.max_batch = max_batch
+            self.max_seq_len = max_seq_len
+            self.buckets = list(buckets) if buckets else default_buckets(max_seq_len)
+            if self.buckets[-1] > max_seq_len:
+                raise ValueError("largest bucket exceeds max_seq_len")
+            # the dense slot cache is built when something first reads it — this
+            # engine's own generate, a dense scheduler, a verify program — and
+            # never for a paged serving engine, which has a pool of its own
+            self._cache = None
+            self._cache_dtype = cache_dtype
+            self._programs: Dict[Tuple, Callable] = {}
 
     @property
     def cache(self):

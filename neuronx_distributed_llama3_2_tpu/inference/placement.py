@@ -31,6 +31,7 @@ from neuronx_distributed_llama3_2_tpu.quantization.quantize import (
     fused_reduce_axes,
     walk_tree,
 )
+from neuronx_distributed_llama3_2_tpu.utils.setup_record import SETUP
 
 # second-minor extent of the TPU's (8, 128) tile: an axis narrower than this
 # in that position is what makes the default layout one no matmul reads.
@@ -88,7 +89,9 @@ def rest_fused_weights(params: Any) -> Tuple[Any, Dict[str, int]]:
     arrays here (``walk_tree`` stops at a ``QuantizedTensor``) and stay where
     quantization put them: their scales follow the logical axes and they
     dequantize in-program. A leaf already in its rest layout is passed
-    through, so a second engine over ``engine.params`` copies nothing."""
+    through, so a second engine over ``engine.params`` copies nothing. The
+    relayout compiles on every start (``_place``): the ``setup.placement``
+    span of the process's set-up record says what that costs."""
     placed = {"leaves": 0, "bytes": 0}
     on_a_mesh = (
         parallel_state.model_parallel_is_initialized()
@@ -108,7 +111,8 @@ def rest_fused_weights(params: Any) -> Tuple[Any, Dict[str, int]]:
         placed["bytes"] += int(leaf.nbytes)
         return _place(leaf, layout)
 
-    return walk_tree(params, visit), placed
+    with SETUP.span("setup.placement"):
+        return walk_tree(params, visit), placed
 
 
 def committed_home(params: Any) -> Optional[jax.sharding.Sharding]:

@@ -35,6 +35,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_llama3_2_tpu.lora import model as lora_model
@@ -65,7 +66,8 @@ from neuronx_distributed_llama3_2_tpu.parallel.loss import (
 
 Params = Dict[str, Any]
 
-NEG = jnp.float32(-1e30)
+# a numpy scalar: a jax one would start the backend when the module is imported
+NEG = np.float32(-1e30)
 
 
 # ---------------------------------------------------------------------------

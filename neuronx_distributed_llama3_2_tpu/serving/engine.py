@@ -99,6 +99,7 @@ from neuronx_distributed_llama3_2_tpu.serving.tracing import (
     program_label,
 )
 from neuronx_distributed_llama3_2_tpu.utils.logger import get_logger
+from neuronx_distributed_llama3_2_tpu.utils.setup_record import SETUP
 
 logger = get_logger()
 
@@ -484,12 +485,31 @@ class _PagedRequest:
     submitted_step: int = 0
 
 
+def _in_setup_record(init):
+    """``PagedServingEngine.__init__`` as the ``setup.paged_engine`` span of
+    the process's set-up record (utils/setup_record.py), and — once the span has
+    closed — the phase table at INFO: why this replica took as long as it did
+    to come up."""
+
+    @functools.wraps(init)
+    def construct(self, *args, **kwargs):
+        with SETUP.span("setup.paged_engine"):
+            init(self, *args, **kwargs)
+        logger.info(
+            "set-up so far, seconds by phase: %s",
+            {k: round(v, 3) for k, v in SETUP.summary().items()},
+        )
+
+    return construct
+
+
 class PagedServingEngine:
     """Block-granular continuous batching over an :class:`InferenceEngine`'s
     model/params. The dense engine's cache and programs are untouched — the
     paged path is opt-in (construct this class, or
     :func:`make_serving_engine` with a :class:`PagedConfig`)."""
 
+    @_in_setup_record
     def __init__(
         self,
         engine: InferenceEngine,
@@ -1033,41 +1053,42 @@ class PagedServingEngine:
             hbm_ledger,
         )
 
-        profiles = harvest_cost_profiles(self, deep=deep)
-        self.cost_profiles = profiles
-        self._flops_by_key = {
-            k: (p.flops, p.bytes_accessed)
-            for k, p in profiles.items()
-            if p.kind in COMPUTE_KINDS
-        }
-        ledger = hbm_ledger(
-            self, profiles=profiles,
-            budget_bytes=self.paged.hbm_budget_bytes,
-        )
-        self.hbm = ledger
-        m = self.metrics
-        m.cost_profiled_programs = len(profiles)
-        m.hbm_budget_bytes = ledger.budget_bytes
-        m.hbm_footprint_bytes = ledger.footprint_bytes
-        m.hbm_headroom_bytes = ledger.headroom_bytes
-        # per-rung roofline ceilings from the plain (non-gather, unchecked)
-        # decode profile of each kv rung: what MFU the memory system allows
-        # a decode dispatch at that attention extent
-        peak_flops = m.peak_flops_per_chip * max(m.tp_size, 1)
-        peak_bw = m.peak_hbm_bw_per_chip * max(m.tp_size, 1)
-        by_rung: Dict[int, dict] = {}
-        for key_, p in profiles.items():
-            if p.kind != "pdecode" or key_[3] or key_[4]:
-                continue
-            rung = int(key_[2])
-            by_rung[rung] = {
-                "flops": p.flops,
-                "bytes": p.bytes_accessed,
-                "arithmetic_intensity": round(p.arithmetic_intensity(), 6),
-                "roofline_mfu": round(
-                    p.roofline_mfu(peak_flops, peak_bw), 6),
+        with SETUP.span("setup.cost_profiles"):
+            profiles = harvest_cost_profiles(self, deep=deep)
+            self.cost_profiles = profiles
+            self._flops_by_key = {
+                k: (p.flops, p.bytes_accessed)
+                for k, p in profiles.items()
+                if p.kind in COMPUTE_KINDS
             }
-        m.mfu_by_rung = by_rung
+            ledger = hbm_ledger(
+                self, profiles=profiles,
+                budget_bytes=self.paged.hbm_budget_bytes,
+            )
+            self.hbm = ledger
+            m = self.metrics
+            m.cost_profiled_programs = len(profiles)
+            m.hbm_budget_bytes = ledger.budget_bytes
+            m.hbm_footprint_bytes = ledger.footprint_bytes
+            m.hbm_headroom_bytes = ledger.headroom_bytes
+            # per-rung roofline ceilings from the plain (non-gather, unchecked)
+            # decode profile of each kv rung: what MFU the memory system allows
+            # a decode dispatch at that attention extent
+            peak_flops = m.peak_flops_per_chip * max(m.tp_size, 1)
+            peak_bw = m.peak_hbm_bw_per_chip * max(m.tp_size, 1)
+            by_rung: Dict[int, dict] = {}
+            for key_, p in profiles.items():
+                if p.kind != "pdecode" or key_[3] or key_[4]:
+                    continue
+                rung = int(key_[2])
+                by_rung[rung] = {
+                    "flops": p.flops,
+                    "bytes": p.bytes_accessed,
+                    "arithmetic_intensity": round(p.arithmetic_intensity(), 6),
+                    "roofline_mfu": round(
+                        p.roofline_mfu(peak_flops, peak_bw), 6),
+                }
+            m.mfu_by_rung = by_rung
         return profiles
 
     def _setup_facts(self) -> Dict[str, int]:
@@ -1084,18 +1105,19 @@ class PagedServingEngine:
             harvest_cost_profiles,
         )
 
-        profiles = harvest_cost_profiles(self, deep=True)
-        return {
-            "relaid_leaves": self.engine.placement["leaves"],
-            "relaid_bytes": self.engine.placement["bytes"],
-            "program_temp_bytes_max": max(
-                (p.temp_bytes for p in profiles.values()), default=0
-            ),
-            # rows by position: bytes a token a layer; a state: bytes of one
-            # block over all layers
-            **({"cache_row_bytes": cache_row_bytes(self.cache)} if self._positional
-               else {"state_bytes_per_lane": cache_block_bytes(self.cache)}),
-        }
+        with SETUP.span("setup.facts"):
+            profiles = harvest_cost_profiles(self, deep=True)
+            return {
+                "relaid_leaves": self.engine.placement["leaves"],
+                "relaid_bytes": self.engine.placement["bytes"],
+                "program_temp_bytes_max": max(
+                    (p.temp_bytes for p in profiles.values()), default=0
+                ),
+                # rows by position: bytes a token a layer; a state: bytes of one
+                # block over all layers
+                **({"cache_row_bytes": cache_row_bytes(self.cache)} if self._positional
+                   else {"state_bytes_per_lane": cache_block_bytes(self.cache)}),
+            }
 
     def _decode_rows(self, decode_lanes) -> int:
         """A decode dispatch record's ``rows``: the cache rows the live lanes
@@ -1124,7 +1146,8 @@ class PagedServingEngine:
         Called automatically at the end of :meth:`prewarm`; a soak
         harness warming up through real traffic instead can call it once
         its working set has compiled."""
-        self._frozen_keys = frozenset(self._programs)
+        with SETUP.span("setup.mark_steady"):
+            self._frozen_keys = frozenset(self._programs)
 
     def _step_model(self):
         """The model instance new program traces bind: normally
@@ -2034,7 +2057,10 @@ class PagedServingEngine:
         re-lower check counts on that). Like ``_warmup``, every dispatch
         writes only into the null block or rewrites current resident
         values, so token identity is untouched; plain ``jnp`` uploads
-        keep the ``h2d_uploads`` choke-point counter at zero."""
+        keep the ``h2d_uploads`` choke-point counter at zero. The loop is
+        the ``setup.prewarm`` span of the process's set-up record and each
+        key a ``setup.program`` inside it: what JAX traces, lowers, compiles
+        or loads for a key is booked to that key (utils/setup_record.py)."""
         eng = self.engine
         self._prewarming = True
         try:
@@ -2061,152 +2087,154 @@ class PagedServingEngine:
                 )
                 if self._fused else (key,)
             )
-            for key_ in self.catalog.prewarm_keys():
-                kind = key_[0]
-                if kind == "copy_block":
-                    # copy the null block onto itself: garbage -> garbage
-                    self.cache = self._copy_block_fn(self.cache, zero, zero)
-                elif kind == "lane_set":
-                    # rewrite lane 0's resident state with its current
-                    # values (zeros + all-null table row; under fused
-                    # sampling also the sentinel params + null key data)
-                    fn = self._lane_set_program()
-                    trow = jnp.full(
-                        (self.table_width,), NULL_BLOCK, jnp.int32
-                    )
-                    if self._fused:
-                        (
-                            self._d_tokens, self._d_positions,
-                            self._d_tables, self._d_temps, self._d_topks,
-                            self._d_topps, self._d_rng,
-                        ) = fn(
-                            self._d_tokens, self._d_positions,
-                            self._d_tables, self._d_temps, self._d_topks,
-                            self._d_topps, self._d_rng,
-                            zero, zero, zero, trow,
-                            jnp.asarray(
-                                GREEDY_TEMPERATURE, jnp.float32
-                            ),
-                            zero, jnp.asarray(1.0, jnp.float32),
-                            jnp.zeros((2,), jnp.uint32),
-                        )
-                    else:
-                        self._d_tokens, self._d_positions, self._d_tables = fn(
-                            self._d_tokens, self._d_positions, self._d_tables,
-                            zero, zero, zero, trow,
-                        )
-                elif kind == "table_delta":
-                    fn = self._table_delta_program()
-                    self._d_tables = fn(
-                        self._d_tables, zero, zero,
-                        jnp.asarray(NULL_BLOCK, jnp.int32),
-                    )
-                elif kind == "block_save":
-                    # slice the null block out; the snapshot is discarded
-                    self._block_save_fn(self.cache, zero)
-                elif kind == "block_restore":
-                    # scatter an all-zeros payload into the null block at
-                    # exactly traffic's upload shapes/dtypes
-                    self.cache = self._block_restore_fn(
-                        self.cache, zero, *self._null_block_payload()
-                    )
-                elif kind == "pctx":
-                    _, bucket, cfg, _g = key_
-                    fn = self._prefill_ctx_program(bucket, cfg)
-                    _, self.cache = fn(
-                        eng.params, self.cache,
-                        jnp.zeros((1, bucket), jnp.int32),
-                        jnp.ones((1,), jnp.int32), table1, *p_tail,
-                    )
-                elif kind == "psfx":
-                    _, bucket, kv, cfg, _g = key_
-                    fn = self._prefill_suffix_program(bucket, kv, cfg)
-                    _, self.cache = fn(
-                        eng.params, self.cache,
-                        jnp.zeros((1, bucket), jnp.int32),
-                        jnp.ones((1,), jnp.int32),
-                        jnp.ones((1,), jnp.int32), table1, *p_tail,
-                    )
-                elif kind == "pdecode":
-                    _, cfg, kv, _g, _c = key_
-                    fn = self._decode_program(cfg, kv)
-                    # dispatch THE residents exactly like _step's decode
-                    # (same committedness/sharding → same lowering) and
-                    # reassign the donated outputs; every table row is
-                    # still NULL, so the write lands in the null block and
-                    # admission's lane_set rewrites the lane state anyway
-                    args = (
-                        eng.params, self.cache, self._d_tokens,
-                        self._d_positions, self._d_tables, *d_tail(),
-                    )
-                    if self._check_logits:
-                        toks, _, self._d_positions, self.cache = fn(
-                            *args, self._nan_mask((), "warmup")
-                        )
-                    else:
-                        toks, self._d_positions, self.cache = fn(*args)
-                    self._d_tokens = toks
-                elif kind == "pverify":
-                    _, kv, k, _g, _c = key_
-                    fn = self._verify_program(kv, k)
-                    args = (
-                        eng.params, self.cache, self._d_tokens,
-                        self._d_positions, self._d_tables,
-                        jnp.zeros((eng.max_batch, k), jnp.int32), zeros_b,
-                        *(d_tail() if self._fused else ()),
-                    )
-                    if self._check_logits:
-                        _, _, toks, self._d_positions, _, self.cache = fn(
-                            *args, self._nan_mask((), "warmup")
-                        )
-                    else:
-                        _, _, toks, self._d_positions, self.cache = fn(*args)
-                    self._d_tokens = toks
-                elif kind == "ptree":
-                    _, kv, k, _g, _c = key_
-                    fn = self._tree_program(kv, k)
-                    # all-zero packed payload: zero live draft nodes per
-                    # lane, so every lane is a plain decode row writing
-                    # into the null block (the chain-degenerate tree)
-                    args = (
-                        eng.params, self.cache, self._d_tokens,
-                        self._d_positions, self._d_tables,
-                        jnp.zeros((eng.max_batch, 2 * k + 1), jnp.int32),
-                        *(d_tail() if self._fused else ()),
-                    )
-                    if self._check_logits:
-                        _, _, toks, self._d_positions, _, self.cache = fn(
-                            *args, self._nan_mask((), "warmup")
-                        )
-                    else:
-                        _, _, toks, self._d_positions, self.cache = fn(*args)
-                    self._d_tokens = toks
-                elif kind == "pmixed":
-                    _, t, kv, _cfg, _g, _c = key_
-                    fn = self._mixed_program(t, kv)
-                    # all-zero row payload: every lane is a draft-len-0
-                    # decode row, so the warmup is exactly a pdecode-shaped
-                    # null-block write plus resident rewrite
-                    args = (
-                        eng.params, self.cache, self._d_tokens,
-                        self._d_positions, self._d_tables,
-                        jnp.zeros((eng.max_batch, t), jnp.int32),
-                        zeros_b, zeros_b, zeros_b,
-                        *(
-                            (jnp.zeros((eng.max_batch, t), jnp.int32),)
-                            if self._spec_tree else ()
-                        ),
-                        *(d_tail() if self._fused else ()),
-                    )
-                    if self._check_logits:
-                        _, _, toks, self._d_positions, _, self.cache = fn(
-                            *args, self._nan_mask((), "warmup")
-                        )
-                    else:
-                        _, _, toks, self._d_positions, self.cache = fn(*args)
-                    self._d_tokens = toks
-                else:  # pragma: no cover - manifest/engine kind drift
-                    raise ValueError(f"prewarm: unknown program kind {kind!r}")
+            with SETUP.span("setup.prewarm"):
+                for key_ in self.catalog.prewarm_keys():
+                    kind = key_[0]
+                    with SETUP.span("setup.program", key=str(key_), kind=kind):
+                        if kind == "copy_block":
+                            # copy the null block onto itself: garbage -> garbage
+                            self.cache = self._copy_block_fn(self.cache, zero, zero)
+                        elif kind == "lane_set":
+                            # rewrite lane 0's resident state with its current
+                            # values (zeros + all-null table row; under fused
+                            # sampling also the sentinel params + null key data)
+                            fn = self._lane_set_program()
+                            trow = jnp.full(
+                                (self.table_width,), NULL_BLOCK, jnp.int32
+                            )
+                            if self._fused:
+                                (
+                                    self._d_tokens, self._d_positions,
+                                    self._d_tables, self._d_temps, self._d_topks,
+                                    self._d_topps, self._d_rng,
+                                ) = fn(
+                                    self._d_tokens, self._d_positions,
+                                    self._d_tables, self._d_temps, self._d_topks,
+                                    self._d_topps, self._d_rng,
+                                    zero, zero, zero, trow,
+                                    jnp.asarray(
+                                        GREEDY_TEMPERATURE, jnp.float32
+                                    ),
+                                    zero, jnp.asarray(1.0, jnp.float32),
+                                    jnp.zeros((2,), jnp.uint32),
+                                )
+                            else:
+                                self._d_tokens, self._d_positions, self._d_tables = fn(
+                                    self._d_tokens, self._d_positions, self._d_tables,
+                                    zero, zero, zero, trow,
+                                )
+                        elif kind == "table_delta":
+                            fn = self._table_delta_program()
+                            self._d_tables = fn(
+                                self._d_tables, zero, zero,
+                                jnp.asarray(NULL_BLOCK, jnp.int32),
+                            )
+                        elif kind == "block_save":
+                            # slice the null block out; the snapshot is discarded
+                            self._block_save_fn(self.cache, zero)
+                        elif kind == "block_restore":
+                            # scatter an all-zeros payload into the null block at
+                            # exactly traffic's upload shapes/dtypes
+                            self.cache = self._block_restore_fn(
+                                self.cache, zero, *self._null_block_payload()
+                            )
+                        elif kind == "pctx":
+                            _, bucket, cfg, _g = key_
+                            fn = self._prefill_ctx_program(bucket, cfg)
+                            _, self.cache = fn(
+                                eng.params, self.cache,
+                                jnp.zeros((1, bucket), jnp.int32),
+                                jnp.ones((1,), jnp.int32), table1, *p_tail,
+                            )
+                        elif kind == "psfx":
+                            _, bucket, kv, cfg, _g = key_
+                            fn = self._prefill_suffix_program(bucket, kv, cfg)
+                            _, self.cache = fn(
+                                eng.params, self.cache,
+                                jnp.zeros((1, bucket), jnp.int32),
+                                jnp.ones((1,), jnp.int32),
+                                jnp.ones((1,), jnp.int32), table1, *p_tail,
+                            )
+                        elif kind == "pdecode":
+                            _, cfg, kv, _g, _c = key_
+                            fn = self._decode_program(cfg, kv)
+                            # dispatch THE residents exactly like _step's decode
+                            # (same committedness/sharding → same lowering) and
+                            # reassign the donated outputs; every table row is
+                            # still NULL, so the write lands in the null block and
+                            # admission's lane_set rewrites the lane state anyway
+                            args = (
+                                eng.params, self.cache, self._d_tokens,
+                                self._d_positions, self._d_tables, *d_tail(),
+                            )
+                            if self._check_logits:
+                                toks, _, self._d_positions, self.cache = fn(
+                                    *args, self._nan_mask((), "warmup")
+                                )
+                            else:
+                                toks, self._d_positions, self.cache = fn(*args)
+                            self._d_tokens = toks
+                        elif kind == "pverify":
+                            _, kv, k, _g, _c = key_
+                            fn = self._verify_program(kv, k)
+                            args = (
+                                eng.params, self.cache, self._d_tokens,
+                                self._d_positions, self._d_tables,
+                                jnp.zeros((eng.max_batch, k), jnp.int32), zeros_b,
+                                *(d_tail() if self._fused else ()),
+                            )
+                            if self._check_logits:
+                                _, _, toks, self._d_positions, _, self.cache = fn(
+                                    *args, self._nan_mask((), "warmup")
+                                )
+                            else:
+                                _, _, toks, self._d_positions, self.cache = fn(*args)
+                            self._d_tokens = toks
+                        elif kind == "ptree":
+                            _, kv, k, _g, _c = key_
+                            fn = self._tree_program(kv, k)
+                            # all-zero packed payload: zero live draft nodes per
+                            # lane, so every lane is a plain decode row writing
+                            # into the null block (the chain-degenerate tree)
+                            args = (
+                                eng.params, self.cache, self._d_tokens,
+                                self._d_positions, self._d_tables,
+                                jnp.zeros((eng.max_batch, 2 * k + 1), jnp.int32),
+                                *(d_tail() if self._fused else ()),
+                            )
+                            if self._check_logits:
+                                _, _, toks, self._d_positions, _, self.cache = fn(
+                                    *args, self._nan_mask((), "warmup")
+                                )
+                            else:
+                                _, _, toks, self._d_positions, self.cache = fn(*args)
+                            self._d_tokens = toks
+                        elif kind == "pmixed":
+                            _, t, kv, _cfg, _g, _c = key_
+                            fn = self._mixed_program(t, kv)
+                            # all-zero row payload: every lane is a draft-len-0
+                            # decode row, so the warmup is exactly a pdecode-shaped
+                            # null-block write plus resident rewrite
+                            args = (
+                                eng.params, self.cache, self._d_tokens,
+                                self._d_positions, self._d_tables,
+                                jnp.zeros((eng.max_batch, t), jnp.int32),
+                                zeros_b, zeros_b, zeros_b,
+                                *(
+                                    (jnp.zeros((eng.max_batch, t), jnp.int32),)
+                                    if self._spec_tree else ()
+                                ),
+                                *(d_tail() if self._fused else ()),
+                            )
+                            if self._check_logits:
+                                _, _, toks, self._d_positions, _, self.cache = fn(
+                                    *args, self._nan_mask((), "warmup")
+                                )
+                            else:
+                                _, _, toks, self._d_positions, self.cache = fn(*args)
+                            self._d_tokens = toks
+                        else:  # pragma: no cover - manifest/engine kind drift
+                            raise ValueError(f"prewarm: unknown program kind {kind!r}")
             for warning in validate_ladder(self.model, self.catalog.ladder):
                 logger.warning("catalog: %s", warning)
             logger.info(

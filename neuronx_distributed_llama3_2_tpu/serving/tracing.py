@@ -1,8 +1,9 @@
 """graftscope: the serving engine's flight recorder and span tracer.
 
-Three recorders behind one object, all pure host-side python at the
-engine's and the front door's existing funnels (the same choke points the
-chaos layer hooks):
+Four recorders behind one object (``EngineTracer``, one an engine), all pure
+host-side python at the engine's and the front door's existing funnels (the
+same choke points the chaos layer hooks), and a fifth that is the process's
+(``SETUP``):
 
 - a **ring-buffer step flight recorder** — each ``step()`` owns a list
   of phase events (admit wave, prefill chunk, decode/verify dispatch
@@ -22,7 +23,17 @@ chaos layer hooks):
   the same request's children) and ``door.first_write``; and the three
   parts of every turn of the server's driver loop, ``drive.step`` (the
   parent of the engine's step record of the same index), ``drive.pump``
-  and ``drive.yield`` (``drive.idle`` while the loop is parked).
+  and ``drive.yield`` (``drive.idle`` while the loop is parked);
+- a **routing recorder** — one entry per dispatch of a program with
+  experts in a traced engine (``moe/tap.py``): the dispatch paths, the
+  pairs computed and the live tokens each expert was routed;
+- the **set-up recorder** (``utils/setup_record.py`` ``SETUP``, re-exported
+  here) — one a process and always on: named ``setup.*`` spans from the
+  process's start to a ready engine, and one event per trace, lowering and
+  compile that JAX reports, booked to the span that was open when it
+  fired. It lives below this package so that the runtime, the dense engine
+  and the trainer's process can write it without loading ``serving``; a
+  traced engine's ``timeline()["setup"]`` and ``chrome_events()`` show it.
 
 One clock: while enabled, every step is also a
 ``jax.profiler.TraceAnnotation("graft.step", step=<index>)``. It costs next
@@ -34,14 +45,16 @@ the device trace's timeline (``benchmarks/program_trace.py``).
 
 Everything exports as Chrome trace-event JSON (``chrome://tracing`` /
 https://ui.perfetto.dev — pid 0 is the engine step timeline, pid 1 is
-one thread per request, pid 2 the server's driver loop) or as jsonl for
-ad-hoc grepping.
+one thread per request, pid 2 the server's driver loop, pid 3 the
+process's set-up spans) or as jsonl for ad-hoc grepping.
 
 Zero-interference contract (asserted in tests/test_tracing.py and the
 graftcheck gate): tracing records around device work, never in it — no
 h2d uploads, no extra device syncs, no program-registry changes. When
 ``enabled`` is False every hook is a single attribute test returning a
-shared no-op, so the always-constructed tracer costs nothing.
+shared no-op, so the always-constructed tracer costs nothing. The set-up
+recorder has no switch: its listeners run only inside JAX's trace and
+compile path.
 """
 
 from __future__ import annotations
@@ -50,6 +63,10 @@ import json
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+# the process's set-up recorder lives below every layer that opens a span of
+# it; this module draws it (``timeline()``, ``chrome_events()``)
+from neuronx_distributed_llama3_2_tpu.utils.setup_record import SETUP
 
 # request states that end a span and retire it to the done-deque
 TERMINAL_STATES = ("finished", "failed")
@@ -171,8 +188,10 @@ class EngineTracer:
         self._doors_done: deque = deque(maxlen=max(int(max_requests), 1))
         self._marks: deque = deque(maxlen=max(int(max_requests), 1))
         self._drive: deque = deque(maxlen=self.buffer_steps)
-        # what construction did, written once by the engine's prewarm:
-        # relaid_leaves, relaid_bytes, program_temp_bytes_max, cache_row_bytes
+        # what construction did, written once by a traced engine's prewarm
+        # (``_setup_facts``): relaid_leaves, relaid_bytes,
+        # program_temp_bytes_max, and cache_row_bytes or — where the cache is
+        # a state a lane — state_bytes_per_lane
         self.setup: Dict[str, int] = {}
         # routing counters, one entry per dispatch of a tapped program
         # (moe/tap.py): (step, kind, dispatch paths, pairs computed, live
@@ -317,8 +336,10 @@ class EngineTracer:
         clock — what the benchmark's readers take: ``steps`` (the flight
         recorder's records), ``requests`` (front-door roots, finished and
         open), ``states`` (rid -> [(ts, state)]), ``marks`` [(name, ts,
-        rid, args)], ``drive`` [(step, t0, t1, t2, t3)], ``setup`` (the
-        engine's construction counts), ``routed`` [(step, program kind,
+        rid, args)], ``drive`` [(step, t0, t1, t2, t3)], ``setup`` (a traced
+        engine's construction counts and, beside them, the process's set-up
+        record: ``origin``, ``spans``, ``events`` — ``utils/setup_record.py``),
+        ``routed`` [(step, program kind,
         dispatch paths, (token, expert) pairs computed, [live tokens — no
         bucket padding, no idle lane — routed to each expert, summed over
         layers])] per dispatch of a program with experts, ``routed_local``
@@ -339,7 +360,7 @@ class EngineTracer:
             "states": states,
             "marks": list(self._marks),
             "drive": list(self._drive),
-            "setup": dict(self.setup),
+            "setup": {**self.setup, **SETUP.record()} if self.enabled else {},
             "routed": [
                 (step, kind, paths, pairs, [int(n) for n in per_expert])
                 for (step, kind, paths, pairs, *_), per_expert in zip(routed, counts)
@@ -366,7 +387,9 @@ class EngineTracer:
         door's ``request`` root and ``door.*`` children and the
         ``first_token`` mark on the same thread; a connection that never
         became a request sits on thread ``-conn``), pid 2 = the server's
-        driver loop (``drive.step`` / ``drive.pump`` / ``drive.yield``)."""
+        driver loop (``drive.step`` / ``drive.pump`` / ``drive.yield``),
+        pid 3 = the process's set-up spans (``SETUP``), so an exported trace
+        shows a start beside the steps it led to."""
         evs: List[dict] = [
             {"ph": "M", "name": "process_name", "pid": 0, "tid": 0,
              "args": {"name": "engine steps"}},
@@ -374,7 +397,14 @@ class EngineTracer:
              "args": {"name": "requests"}},
             {"ph": "M", "name": "process_name", "pid": 2, "tid": 0,
              "args": {"name": "server driver loop"}},
+            {"ph": "M", "name": "process_name", "pid": 3, "tid": 0,
+             "args": {"name": "setup"}},
         ]
+        for name, t0, t1, _parent, args in list(SETUP.spans) if self.enabled else ():
+            if t1 is not None:
+                evs.append({"ph": "X", "name": name, "cat": "setup", "pid": 3,
+                            "tid": 0, "ts": self._us(t0),
+                            "dur": self._us(t1 - t0), "args": args})
         for rec in self._steps:
             evs.append({
                 "ph": "X", "name": f"step {rec['step']}", "cat": "step",

@@ -9,6 +9,8 @@ import os
 
 import jax
 
+from neuronx_distributed_llama3_2_tpu.utils.setup_record import SETUP
+
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 # <checkout>/.jax_cache — fixed, because the directory is part of the cache
@@ -56,8 +58,14 @@ def require_tpu() -> dict:
 
 def device_summary() -> dict:
     """``{"platform", "kind", "count"}`` of this process's devices, as JAX
-    reports them — what every result line names."""
-    devices = jax.devices()
+    reports them — what every result line names. The first call is the
+    backend's initialisation (seconds on a TPU): the ``setup.runtime`` span of
+    the process's set-up record (``utils/setup_record.py``)."""
+    if any(span[0] == "setup.runtime" for span in SETUP.spans):
+        devices = jax.devices()
+    else:
+        with SETUP.span("setup.runtime"):
+            devices = jax.devices()
     return {
         "platform": devices[0].platform,
         "kind": devices[0].device_kind,
