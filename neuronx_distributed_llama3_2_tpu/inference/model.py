@@ -215,6 +215,11 @@ class LlamaDecode:
     # cache is a state").
     cache_is_positional = True
 
+    def uses_state_kernel(self) -> bool:
+        """Whether a decode program of this model holds the one-pass state
+        kernel (:class:`RetentionDecode`); the engine counts such dispatches."""
+        return False
+
     def _model(self) -> LlamaForCausalLM:
         return LlamaForCausalLM(self.config)
 
@@ -1599,11 +1604,18 @@ class RetentionDecode(LlamaDecode):
     ``row_live`` is the count of real rows of a padded block: rows at or past
     it leave the state untouched. ``kv_limit`` is accepted and means nothing.
 
-    The states ride the layer loop as its carry and each lane's is sliced out,
-    updated and written back at ``[layer, block]`` inside a loop over lanes, so
-    a donated pool is updated in place and a program's temporaries are one
-    lane's state, never the pool. Tree (speculative) blocks and a quantized
-    pool are refused; a rejected draft cannot be taken back out of a state."""
+    The states ride the layer loop as its carry. The chunked form slices each
+    lane's out, updates it and writes it back at ``[layer, block]`` inside a
+    loop over lanes; the recurrent form is one pass over the named blocks a
+    layer (:func:`..kernels.retention_step_pallas.retention_step_paged`) where
+    :meth:`uses_state_kernel` says the kernel runs, and the same lane loop around
+    ``retention_step`` where it does not. Either way a donated pool is updated
+    in place and a program's temporaries are one lane's state, never the pool.
+    Tree (speculative) blocks and a quantized pool are refused; a rejected
+    draft cannot be taken back out of a state."""
+
+    # shardlint SL002 — see LlamaDecode: uses_state_kernel reads the mesh
+    __layout_deps__ = LlamaDecode.__layout_deps__
 
     cache_is_positional = False
 
@@ -1649,6 +1661,25 @@ class RetentionDecode(LlamaDecode):
     def _paged_kernel_eligible(self, t: int, tree) -> bool:
         return False
 
+    def uses_state_kernel(self) -> bool:
+        """Whether the recurrent form runs the one-pass state kernel: wherever
+        Pallas kernels run (:func:`..kernels.mode.prefer_pallas` — the CPU
+        tier's ``"reference"`` mode keeps its twin ``retention_step``) on one
+        device. On a multi-device mesh the states shard by kv head and a bare
+        Mosaic call cannot be partitioned: ``retention_step`` there (no cell
+        runs it; never compiled for the chip)."""
+        from neuronx_distributed_llama3_2_tpu.kernels.mode import prefer_pallas
+        from neuronx_distributed_llama3_2_tpu.parallel import (
+            state as parallel_state,
+        )
+
+        if (
+            parallel_state.model_parallel_is_initialized()
+            and parallel_state.get_parallel_state().mesh.size > 1
+        ):
+            return False
+        return prefer_pallas()
+
     # -- forward ----------------------------------------------------------
 
     def _rope_rows(self, pos_block: jax.Array):
@@ -1673,6 +1704,9 @@ class RetentionDecode(LlamaDecode):
         updated)."""
         if tree is not None:
             raise NotImplementedError("tree verification over a retention state")
+        from neuronx_distributed_llama3_2_tpu.kernels.retention_step_pallas import (
+            retention_step_paged,
+        )
         from neuronx_distributed_llama3_2_tpu.models.brumby import (
             RetentionAttention,
             retention_chunks,
@@ -1693,6 +1727,7 @@ class RetentionDecode(LlamaDecode):
         else:
             index = slots if slots is not None else jnp.arange(b, dtype=jnp.int32)
         recurrent = t == 1 and not context_encode and row_live is None
+        one_pass = recurrent and self.uses_state_kernel()
         form = "step" if recurrent else "chunk"
         live = jnp.full((b,), t, jnp.int32) if row_live is None else row_live
         eps = c.retention_eps
@@ -1735,8 +1770,14 @@ class RetentionDecode(LlamaDecode):
                         )
 
                 with jax.named_scope("retention"):
-                    s_pool, z_pool, y = jax.lax.fori_loop(
-                        0, b, lane, (s_pool, z_pool, jnp.zeros(q.shape[:-1] + v.shape[-1:], q.dtype)))
+                    if one_pass:
+                        y, s_pool, z_pool = retention_step_paged(
+                            s_pool, z_pool, index, layer,
+                            q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], eps)
+                        y = y[:, None]
+                    else:
+                        s_pool, z_pool, y = jax.lax.fori_loop(
+                            0, b, lane, (s_pool, z_pool, jnp.zeros(q.shape[:-1] + v.shape[-1:], q.dtype)))
                 attn_out = attn.output(lp["attn"], y)
             x = x + attn_out
             h = norm(lp["mlp_norm"], x)

@@ -66,11 +66,17 @@ def pick_bucket(buckets: Sequence[int], length: int) -> int:
     raise ValueError(f"length {length} exceeds largest bucket {buckets[-1]}")
 
 
-def complete_ladder(buckets: Sequence[int], max_seq_len: int) -> List[int]:
-    """Validated ascending ladder with ``max_seq_len`` appended when the
-    declared rungs top out early — every serving dispatch length
-    <= max_seq_len must route to SOME rung (the dense engine's
-    ``_kv_bucket`` has the same clamp-to-full-cache fallback)."""
+def complete_ladder(
+    buckets: Sequence[int], max_seq_len: int, dispatchable: Optional[int] = None
+) -> List[int]:
+    """Validated ascending ladder that ends at the rung holding the longest
+    dispatch: every serving dispatch length <= ``dispatchable`` must route to
+    SOME rung (the dense engine's ``_kv_bucket`` has the same
+    clamp-to-full-cache fallback). ``dispatchable`` is ``max_seq_len`` unless
+    the caller knows better — a chunked engine never hands prefill more than
+    its chunk. The smallest declared rung that holds it ends the ladder,
+    ``dispatchable`` itself is appended when the declared rungs top out
+    under it, and rungs nothing can ask for are dropped."""
     out = [int(b) for b in buckets]
     if not out:
         raise ValueError("bucket ladder must not be empty")
@@ -82,9 +88,9 @@ def complete_ladder(buckets: Sequence[int], max_seq_len: int) -> List[int]:
         raise ValueError(
             f"largest bucket {out[-1]} exceeds max_seq_len {max_seq_len}"
         )
-    if out[-1] < max_seq_len:
-        out.append(max_seq_len)
-    return out
+    longest = min(int(dispatchable or max_seq_len), max_seq_len)
+    top = next((b for b in out if b >= longest), longest)
+    return [b for b in out if b < top] + [top]
 
 
 @dataclasses.dataclass(frozen=True)

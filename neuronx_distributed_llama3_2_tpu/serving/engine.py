@@ -543,6 +543,8 @@ class PagedServingEngine:
         # rejected row is masked or overwritten), or a state that is neither
         # (docs/serving.md "Models whose cache is a state")
         self._positional = bool(self.model.cache_is_positional)
+        # 1 where every pdecode holds the one-pass state kernel, else 0
+        self._state_kernel = int(self.model.uses_state_kernel())
         if not self._positional:
             for on, name, why in (
                 (self._spec_k, "spec_draft_tokens > 0",
@@ -646,32 +648,31 @@ class PagedServingEngine:
         self._on_action = None
         # declared bucket ladders (serving/catalog.py): every dispatch
         # shape pads into one of these rungs, so the compiled-program set
-        # is O(ladder) however heterogeneous traffic gets. Suffix prefill
-        # must route any length <= max_seq_len even when a ladder tops
-        # out early (dense decode has the same clamp fallback), so
-        # complete_ladder appends max_seq_len to both.
+        # is O(ladder) however heterogeneous traffic gets. Each ladder
+        # must route the longest dispatch even when the declared rungs top
+        # out early (dense decode has the same clamp fallback): a kv
+        # extent reaches max_seq_len; a prefill reaches max_seq_len only
+        # on an engine that does not chunk — with a chunk set, an
+        # admission whose uncached suffix is longer is chunked and every
+        # other one is at most a chunk (_admit, _advance_prefills), so the
+        # ladder ends at the chunk's rung (the fused step's row width
+        # where that is larger) and no whole-prompt program is compiled.
+        chunk = paged.prefill_chunk_tokens
         self._prefill_buckets = complete_ladder(
-            paged.prefill_buckets or engine.buckets, engine.max_seq_len
+            paged.prefill_buckets or engine.buckets, engine.max_seq_len,
+            max(int(chunk), self._mixed_t) if chunk else None,
         )
         self._kv_buckets = complete_ladder(
             paged.kv_buckets or engine.buckets, engine.max_seq_len
         )
         # table width: logical blocks covering max_seq_len, plus overflow
-        # entries (always null) absorbing bucket-padding writes past it —
-        # sized by the largest prefill bucket so a padded suffix prefill
-        # starting near max_seq_len still indexes inside the table
+        # entries (always null) absorbing writes past it — a padded prefill
+        # that starts near max_seq_len reaches one prefill rung further, a
+        # verify at the sequence cap its rejected tail of spec_k rows.
+        # Nothing reads the overflow entries; they follow the top rung
         self.table_width = _ceil_div(engine.max_seq_len, bs) + _ceil_div(
-            self._prefill_buckets[-1], bs
+            max(self._prefill_buckets[-1], self._spec_k), bs
         )
-        if self._spec_k and engine.max_seq_len + self._spec_k > self.table_width * bs:
-            # verify writes reach row position + k; the overflow table
-            # region (always null-backed) must absorb the rejected tail of
-            # a lane sitting at the sequence cap
-            raise ValueError(
-                f"spec_draft_tokens ({self._spec_k}) exceeds the table's "
-                f"overflow region ({self.table_width * bs - engine.max_seq_len} "
-                f"rows past max_seq_len)"
-            )
         from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (
             kv_cache_jax_dtype,
         )
@@ -2039,7 +2040,7 @@ class PagedServingEngine:
             else:
                 _, _, self.cache = fn(*args)
         table1 = jnp.full((1, self.table_width), NULL_BLOCK, jnp.int32)
-        for bucket in eng.buckets:
+        for bucket in self._prefill_buckets:
             fn = self._prefill_ctx_program(bucket, self._decode_cfg())
             _, self.cache = fn(
                 eng.params, self.cache, jnp.zeros((1, bucket), jnp.int32),
@@ -2539,7 +2540,9 @@ class PagedServingEngine:
     def _restore_price(self, n_bytes: int, gain: int) -> Tuple[float, float]:
         """``(restore_seconds, recompute_seconds)`` for a spilled run:
         payload bytes over the PCIe-class host link vs prefill FLOPs at
-        the padded rung — from the harvested CostProfiles when graftmeter
+        the padded rung, once a chunk where the engine chunks (``gain /
+        chunk`` dispatches of the chunk's rung: the ladder holds no rung
+        for a whole run) — from the harvested CostProfiles when graftmeter
         ran (``PagedConfig.cost_accounting``), the same analytic formulas
         otherwise."""
         from neuronx_distributed_llama3_2_tpu.serving.accounting import (
@@ -2549,7 +2552,10 @@ class PagedServingEngine:
         )
 
         restore_s = n_bytes / HOST_LINK_BW_BYTES_PER_S
-        bucket = pick_bucket(self._prefill_buckets, max(gain, 1))
+        # a chunked engine recomputes the run a chunk a dispatch
+        piece = max(min(gain, self.paged.prefill_chunk_tokens or gain), 1)
+        pieces = max(_ceil_div(gain, piece), 1)
+        bucket = pick_bucket(self._prefill_buckets, piece)
         flops = None
         if self.cost_profiles:
             for k, p in self.cost_profiles.items():
@@ -2563,7 +2569,7 @@ class PagedServingEngine:
         peak = self.metrics.peak_flops_per_chip * max(
             self.metrics.tp_size, 1
         )
-        return restore_s, flops / max(peak, 1.0)
+        return restore_s, pieces * flops / max(peak, 1.0)
 
     def _maybe_restore(
         self, seq: List[int], matched: int, mblocks: List[int]
@@ -3283,6 +3289,7 @@ class PagedServingEngine:
         kv_need = int(max(self._positions[l] for l in decode_lanes)) + 1
         kv_limit = self._kv_bucket(kv_need)
         fn = self._decode_program(self._decode_cfg(), kv_limit)
+        self.metrics.state_kernel_steps += self._state_kernel
         self.metrics.note_decode_dispatch(
             kv_limit, kv_need,
             *(self._flops_by_key.get(fn.key) or (0.0, 0.0)),
@@ -3353,6 +3360,7 @@ class PagedServingEngine:
         kv_need = int(max(self._positions[l] for l in decode_lanes)) + 1
         kv_limit = self._kv_bucket(kv_need)
         fn = self._decode_program(self._decode_cfg(), kv_limit)
+        self.metrics.state_kernel_steps += self._state_kernel
         self.metrics.note_decode_dispatch(
             kv_limit, kv_need,
             *(self._flops_by_key.get(fn.key) or (0.0, 0.0)),

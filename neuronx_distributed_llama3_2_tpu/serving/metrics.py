@@ -66,6 +66,11 @@ class ServingMetrics:
     # states begun from zero by a whole-prompt or first-chunk prefill of a
     # model whose cache is a state (admissions + resumed preemptions)
     state_resets: int = 0
+    # pdecode dispatches whose program holds the one-pass state kernel
+    # (kernels/retention_step_pallas.py): 0 on every KV engine, and on a
+    # state model wherever retention_step runs (the "reference" kernel
+    # mode, a multi-device mesh)
+    state_kernel_steps: int = 0
     decode_steps: int = 0
     # -- fused mixed-mode step (docs/serving.md "Fused mixed-mode step"):
     #    engine_steps counts every step() (the dispatches_per_step

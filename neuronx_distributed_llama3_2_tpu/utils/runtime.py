@@ -5,7 +5,9 @@ virtual CPU mesh the host-side tiers run on."""
 
 from __future__ import annotations
 
+import importlib
 import os
+import threading
 
 import jax
 
@@ -41,6 +43,16 @@ def enable_compile_cache() -> str:
     return _DEFAULT_COMPILE_CACHE
 
 
+_KERNEL_STACK = "jax.experimental.pallas.tpu"
+
+
+def _import_quietly(name: str) -> None:
+    try:
+        importlib.import_module(name)
+    except Exception:       # whoever needs the module raises it where it matters
+        pass
+
+
 def require_tpu() -> dict:
     """The device the process runs on, as JAX reports it — or an error when
     that is not a TPU. Entry points that produce device numbers call this
@@ -65,6 +77,12 @@ def device_summary() -> dict:
         devices = jax.devices()
     else:
         with SETUP.span("setup.runtime"):
+            # the runtime's start leaves the interpreter idle for 5-11 s on a
+            # TPU (PERF.md section 5); the Pallas stack is 1.2 s of imports
+            # that the first program holding a kernel would pay inside set-up
+            # after it: import it beside. Not joined — an import that needs
+            # it waits on the module's own lock
+            threading.Thread(target=_import_quietly, args=(_KERNEL_STACK,), daemon=True).start()
             devices = jax.devices()
     return {
         "platform": devices[0].platform,

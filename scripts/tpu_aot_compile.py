@@ -68,6 +68,8 @@ def main(argv) -> int:
         jobs.append((name, cases.flash_case(*args), one))
     for name, kw in cases.PAGED_CASES.items():
         jobs.append((name, cases.paged_case(**kw), one))
+    for name, dtype in cases.RETENTION_CASES.items():
+        jobs.append((name, cases.retention_case(dtype), one))
     for name, kw in cases.TP_CASES.items():
         jobs.append(
             (name, cases.paged_case(mesh=tp_state.mesh, **kw), replicated)

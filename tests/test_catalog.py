@@ -11,6 +11,8 @@ key outside the manifest (GC007).
 """
 
 import dataclasses
+import json
+import os
 
 import jax
 import numpy as np
@@ -96,6 +98,26 @@ def test_complete_ladder_appends_max_seq_len():
 def test_complete_ladder_rejects_malformed(buckets, msg):
     with pytest.raises(ValueError, match=msg):
         cat.complete_ladder(buckets, 64)
+
+
+@pytest.mark.parametrize("buckets,max_len,longest,want", [
+    # a chunk set: the ladder ends at the rung that holds the chunk
+    ([128, 512], 8704, 512, [128, 512]),
+    ([128], 8704, 512, [128, 512]),             # the chunk itself where the rungs top out under it
+    ([128, 512], 8704, 300, [128, 512]),        # the smallest declared rung >= the chunk
+    ([128, 512, 2048], 8704, 512, [128, 512]),  # rungs no dispatch can ask for are dropped
+    ([16], 64, 64, [16, 64]),
+    ([8], 64, 100, [8, 64]),                    # never past max_seq_len
+    # no chunk: max_seq_len appended as before
+    ([8], 64, None, [8, 64]),
+    ([8, 64], 64, None, [8, 64]),
+    ([128, 512], 8704, None, [128, 512, 8704]),
+])
+def test_complete_ladder_ends_at_the_longest_dispatch(buckets, max_len, longest, want):
+    assert cat.complete_ladder(buckets, max_len, longest) == want
+    # the contract: every dispatch length up to the longest routes to a rung
+    top = min(longest or max_len, max_len)
+    assert cat.pick_bucket(want, top) == want[-1] and cat.pick_bucket(want, 1) == want[0]
 
 
 def test_bucket_ladder_routing():
@@ -351,3 +373,97 @@ def test_out_of_catalog_compile_is_caught(params):
     # GC009 rides along on a cost-accounting engine: the smuggled key
     # was compiled after the prewarm harvest, so it has no CostProfile
     assert rules == ["GC007", "GC008", "GC009"]
+
+
+# ------------------------------------- the ladder of an engine that chunks
+
+TRAFFIC = ("chat-steady", "docs-batch", "rag-batch", "prefix-pressure", "docqa-batch", "longgen-batch")
+
+
+@pytest.mark.parametrize("traffic", TRAFFIC)
+def test_a_cells_catalog_holds_no_prefill_rung_above_its_chunks(params, traffic):
+    """The engine sizes of each serving cell (on the tiny model: a ladder is
+    lengths, not widths): no ``pctx`` / ``psfx`` rung above the chunk's, every
+    ``kv`` rung the old ladder held, and a table whose overflow region is the
+    top prefill rung's."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "traffic", f"{traffic}.json")) as fh:
+        sizes = json.load(fh)["engine"]
+    chunk, bs, max_len = sizes["prefill_chunk_tokens"], sizes["block_size"], sizes["max_seq_len"]
+    eng = PagedServingEngine(
+        InferenceEngine(TINY, params, max_batch=sizes["lanes"], max_seq_len=max_len),
+        GenerationConfig(max_new_tokens=4),
+        PagedConfig(
+            block_size=bs, num_blocks=min(sizes["pool_blocks"], 32),
+            prefill_chunk_tokens=chunk, prefill_buckets=tuple(sizes["prefill_buckets"]),
+            kv_buckets=tuple(sizes["kv_buckets"]),
+        ),
+        precompile=False,
+    )
+    rung = cat.pick_bucket(sorted({*sizes["prefill_buckets"], chunk}), chunk)
+    assert eng._prefill_buckets[-1] == rung == 512 < max_len
+    keys = eng.catalog.keys()
+    prefill = [k for k in keys if k[0] in ("pctx", "psfx")]
+    assert prefill and max(k[1] for k in prefill) == rung
+    kv_rungs = cat.complete_ladder(sizes["kv_buckets"], max_len)
+    assert kv_rungs[-1] == max_len
+    assert sorted({k[2] for k in keys if k[0] == "pdecode"}) == kv_rungs
+    assert {k[2] for k in keys if k[0] == "psfx"} == {
+        kv for b in eng._prefill_buckets for kv in kv_rungs if kv >= eng._kv_bucket(min(1 + b, max_len))
+    }
+    assert eng.table_width == -(-max_len // bs) + -(-rung // bs)
+    assert set(eng.catalog.prewarm_keys()) == keys
+
+
+def test_an_unchunked_engine_keeps_its_whole_prompt_rung(params):
+    eng = _engine(params)
+    assert eng._prefill_buckets == [8, 16] and eng.table_width == 2 + 2
+    assert ("pctx", 16, GREEDY, False) in eng.catalog.keys()
+
+
+def test_a_fused_step_wider_than_the_chunk_sizes_the_ladder(params):
+    """chunk 4 under speculation k = 6: the mixed program is 7 rows wide, and
+    the table's overflow region has to take them past the sequence cap."""
+    eng = _engine(params, fused_step=True, prefill_chunk_tokens=4, spec_draft_tokens=6)
+    assert eng._mixed_t == 7 and eng._prefill_buckets == [8]
+    assert eng.table_width * 8 >= 16 + eng._mixed_t
+
+
+def test_a_chunked_engine_dispatches_catalog_keys_alone(params):
+    """Fresh, cached-suffix and preempt-resume admissions on a prewarmed
+    engine whose ladder ends at the chunk's rung: every dispatch finds its
+    program, nothing compiles, nothing is off the catalog."""
+    eng = PagedServingEngine(
+        InferenceEngine(TINY_KERNEL, params, max_batch=2, max_seq_len=64, buckets=[8, 16, 64]),
+        GenerationConfig(max_new_tokens=6),
+        PagedConfig(block_size=8, num_blocks=32, prewarm=True, prefill_chunk_tokens=8),
+        precompile=False,
+    )
+    assert eng._prefill_buckets == [8] and eng._kv_buckets == [8, 16, 64]
+    frozen = set(eng.program_registry())
+    assert frozen == eng.catalog.keys()
+    assert not any(k[0] in ("pctx", "psfx") and k[1] > 8 for k in frozen)
+    rng = np.random.default_rng(11)
+    shared = rng.integers(1, TINY.vocab_size, size=(24,)).tolist()
+
+    def tail(n):
+        return rng.integers(1, TINY.vocab_size, size=(n,)).tolist()
+
+    fresh = eng.submit(shared + tail(13))          # 37 rows: five chunks from the zero cache
+    eng.run_to_completion()
+    cached = eng.submit(shared + tail(20))         # 24 rows matched, a chunked suffix of 20
+    short = eng.submit(shared + tail(3))           # 24 matched, one psfx of 3 in the rung of 8
+    eng.run_to_completion()
+    assert eng.request_info(cached)["cached_tokens"] == 24 == eng.request_info(short)["cached_tokens"]
+    victim = eng.submit(tail(30))                  # preempted mid-decode: re-prefills prompt + output
+    while len(eng._requests[victim].out) < 3:
+        eng.step()
+    if eng._pending is not None:
+        eng._drain_pending()
+    eng._preempt(eng._requests[victim])
+    out = eng.run_to_completion()
+    assert len(out[fresh]) == len(out[victim]) == 6 and eng.metrics.preemptions == 1
+    assert eng.metrics.prefill_chunks >= 5 + 3 + 4
+    assert set(eng.program_registry()) == frozen
+    assert eng.metrics.steadystate_compiles == 0
+    assert gc.audit_programs(eng) == []

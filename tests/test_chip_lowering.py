@@ -10,7 +10,8 @@ is ``python scripts/tpu_aot_compile.py``; numerics are the chip's
 ``scripts/tpu_kernel_gate.py``.
 
 Geometry is Llama-3.2-1B's attention: 32 query heads over 8 kv heads of 64,
-pool blocks of 16 rows, bench sequence 2048.
+pool blocks of 16 rows, bench sequence 2048 — and, for the retention state
+kernel, Brumby-14B's published widths as ``brumby-longgen-batch`` runs them.
 """
 
 import itertools
@@ -159,6 +160,36 @@ RING_CASES = {
 }
 
 
+def retention_case(pool_dtype):
+    """(fn, avals) for the one-pass state kernel at Brumby-14B's widths: 24
+    lanes, 8 kv heads of 128 with 5 query heads each, φ 9,216, a pool of 6
+    layers x 27 states."""
+    from neuronx_distributed_llama3_2_tpu.kernels.retention_step_pallas import (
+        retention_step_paged,
+    )
+
+    lanes, nkv, group, d, feat, layers, blocks = 24, 8, 5, 128, 9216, 6, 27
+    avals = [
+        jax.ShapeDtypeStruct((layers, blocks, nkv, feat, d), pool_dtype),
+        jax.ShapeDtypeStruct((layers, blocks, nkv, feat), pool_dtype),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((lanes, nkv, group, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((lanes, nkv, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((lanes, nkv, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((lanes, nkv), jnp.float32),
+    ]
+
+    def fn(s_pool, z_pool, index, layer, q, k, v, log_g):
+        return retention_step_paged(s_pool, z_pool, index, layer, q, k, v, log_g, 1e-6)
+
+    return fn, avals
+
+
+# the pool's dtype: float32 as served; bfloat16 is the check's failing variant
+RETENTION_CASES = {"retention-step-f32": jnp.float32, "retention-step-bf16": jnp.bfloat16}
+
+
 def lower_for_tpu(fn, avals):
     return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
 
@@ -188,6 +219,14 @@ def test_flash_kernels_lower_for_tpu(compiled_mode, name):
 def test_paged_kernel_lowers_for_tpu(compiled_mode, name):
     lowered = lower_for_tpu(*paged_case(**PAGED_CASES[name]))
     assert_mosaic_call(lowered, "paged_flash_decode")
+
+
+@pytest.mark.parametrize("name", RETENTION_CASES)
+def test_retention_step_kernel_lowers_for_tpu(compiled_mode, name):
+    lowered = lower_for_tpu(*retention_case(RETENTION_CASES[name]))
+    assert_mosaic_call(lowered, "retention_state_pass")
+    # the pool is the call's operand 2 and its result 1: updated in place
+    assert "output_tuple_indices = [1], operand_index = 2" in lowered.as_text()
 
 
 @pytest.mark.parametrize("name", TP_CASES)

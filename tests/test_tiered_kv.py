@@ -366,3 +366,30 @@ def test_spill_config_validation(params):
             ),
             precompile=False,
         )
+
+
+@pytest.mark.parametrize("chunk,pieces,rung", [(None, 1, 32), (8, 3, 8), (16, 2, 16)])
+def test_a_recompute_is_priced_as_the_dispatches_chunking_would_run(params, chunk, pieces, rung):
+    """A spilled run of 24 rows against the prefill ladder: one ``pctx[32]``
+    where the engine does not chunk, ``ceil(24 / chunk)`` dispatches of the
+    chunk's rung where it does — the ladder of a chunked engine holds no rung
+    for the whole run, and the estimate must not ask it for one."""
+    from neuronx_distributed_llama3_2_tpu.serving.accounting import (
+        EngineDims,
+        analytic_cost,
+    )
+
+    eng = PagedServingEngine(
+        InferenceEngine(TINY, params, max_batch=2, max_seq_len=64, buckets=[8, 16, 32]),
+        GenerationConfig(max_new_tokens=2),
+        PagedConfig(
+            block_size=8, num_blocks=12, spill_enabled=True, host_tier_bytes=1 << 20,
+            prefill_chunk_tokens=chunk,
+        ),
+        precompile=False,
+    )
+    assert eng._prefill_buckets[-1] == (rung if chunk else 64)
+    restore_s, recompute_s = eng._restore_price(4096, 24)
+    flops = analytic_cost(("pctx", rung), EngineDims.from_engine(eng))[0]
+    peak = eng.metrics.peak_flops_per_chip * max(eng.metrics.tp_size, 1)
+    assert restore_s > 0 and recompute_s == pytest.approx(pieces * flops / peak)
