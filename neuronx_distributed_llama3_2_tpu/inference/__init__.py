@@ -25,9 +25,12 @@ from neuronx_distributed_llama3_2_tpu.inference.engine import (
     pick_bucket,
 )
 from neuronx_distributed_llama3_2_tpu.inference.model import (
+    CacheKind,
     KVCache,
+    LagunaDecode,
     LatentCache,
     LlamaDecode,
+    MixedKVCache,
     MixtralDecode,
     PagedKVCache,
     RetentionDecode,
@@ -77,6 +80,9 @@ __all__ = [
     "MllamaCache",
     "MllamaDecoder",
     "PagedKVCache",
+    "CacheKind",
+    "LagunaDecode",
+    "MixedKVCache",
     "RetentionDecode",
     "SarvamDecode",
     "StateCache",

@@ -120,6 +120,47 @@ class PagedKVCache(NamedTuple):
         return self.k_scale is not None
 
 
+class MixedKVCache(NamedTuple):
+    """The paged cache of a stack whose layers differ in what they keep
+    (:class:`LagunaDecode`): two :class:`PagedKVCache`s side by side, each
+    over the layers of its kind and each with a table of its own. ``full``
+    (L_f, num_blocks, block_size, n_kv, d) is the block pool every other
+    family has — a request's blocks come from the allocator and cover its
+    whole context. ``window`` (L_w, window_blocks, block_size, n_kv, d) holds
+    the window layers' rows: a lane's *ring* of blocks, in which the row of
+    position ``p`` is block ``(p // block_size) mod ring blocks`` of the
+    lane's table, row ``p mod block_size`` — a position's row is overwritten
+    by the position one ring later, which no live query can still see. Block
+    0 of each is its null block. Both honour ``cache_dtype`` and
+    ``kv_cache_dtype`` (scale tiles included)."""
+
+    full: PagedKVCache
+    window: PagedKVCache
+
+    @property
+    def num_blocks(self) -> int:
+        return self.full.num_blocks
+
+    @property
+    def block_size(self) -> int:
+        return self.full.block_size
+
+    @property
+    def quantized(self) -> bool:
+        return self.full.quantized
+
+
+class CacheKind(NamedTuple):
+    """One kind of cache a decode model's layers keep: ``name`` (the field of
+    the paged cache that holds it, where the cache has fields by kind),
+    ``layers`` of the stack that keep it, and ``rows`` a query of such a layer
+    can see — ``None``: every row of the context."""
+
+    name: str
+    layers: int
+    rows: Optional[int]
+
+
 class LatentCache(NamedTuple):
     """Latent-attention cache (MLA, models/sarvam.py): one array of rows
     ``[c ‖ k_r]`` — the normed latent and the rotated shared rotary key — and
@@ -281,6 +322,17 @@ class LlamaDecode:
         (pool size, a block's bytes, a program's cost) reads this and nothing
         else of the cache's shape."""
         return 2, self.config.num_kv_heads, self.config.head_dim
+
+    @property
+    def cache_kinds(self) -> Tuple[CacheKind, ...]:
+        """What the serving layer cannot derive from :meth:`cache_row_dims`:
+        the kinds of cache the layers keep, each with its layer count and the
+        rows a query sees (``None`` = the whole context). One kind wherever
+        every layer keeps the same thing. A kind with a row count keeps only
+        a ring of rows a lane, so a prefix of the pool's blocks is not a
+        prefix's cache (docs/serving.md "Stacks whose layers cache different
+        things")."""
+        return (CacheKind("rows", self.config.num_layers, None),)
 
     def paged_cache_specs(self, quantized: bool = False) -> PagedKVCache:
         """Paged-pool sharding: kv heads over tp (same GQA rule as the dense
@@ -798,6 +850,7 @@ class LlamaDecode:
         pos_cap: Optional[int] = None,
         sampling: Optional[tuple] = None,
         logit_poison: Optional[jax.Array] = None,
+        window_tables: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, ...]:
         """One resident-state decode step: T=1 paged forward plus the
         on-device state advance. Returns ``(logits (b, V), new_positions,
@@ -825,10 +878,15 @@ class LlamaDecode:
         after the first return — ``(tokens, finite, new_positions, cache)``.
         Both default to None (static), leaving the host-sampling traces
         bitwise unchanged.
+
+        ``window_tables`` (b, ring blocks): the window kind's table of a model
+        with two kinds of cache (:class:`LagunaDecode`), handed on to its
+        ``forward``; no other model takes one.
         """
+        kinds = {} if window_tables is None else {"window_tables": window_tables}
         logits, cache = self.forward(
             params, cache, tokens[:, None], positions, None,
-            block_tables=block_tables, kv_limit=kv_limit,
+            block_tables=block_tables, kv_limit=kv_limit, **kinds,
         )
         logits = logits[:, 0, :]
         finite = None
@@ -1797,6 +1855,276 @@ class RetentionDecode(LlamaDecode):
 
 
 @dataclasses.dataclass(frozen=True)
+class LagunaDecode(MixtralDecode):
+    """Decode-mode Laguna (:mod:`..models.laguna`): window and full attention
+    layers in one stack, over a cache of two kinds (:class:`MixedKVCache`).
+
+    A full layer reads and writes the block pool through the lane's
+    ``block_tables`` like every other family, bounded by ``kv_limit``. A window
+    layer reads and writes the window pool through ``window_tables`` — the
+    lane's ring, gathered whole (never ``kv_limit`` rows) — and where no
+    ``window_tables`` is given, through ``block_tables`` too. **One indexing
+    rule serves both**: the row of position ``p`` is block ``(p // block_size)
+    mod table width`` of the table, row ``p mod block_size``; a table as wide
+    as the context never wraps. Read back, row ``r`` of a table of ``R`` rows
+    holds, for a query at ``i``, the position ``i - ((i - r) mod R)`` — the
+    newest one at or before ``i`` that lands there — and the mask admits it
+    where it is not negative and, in a window layer, less than
+    ``sliding_window`` behind ``i``. A ring of ``window - 1 + T`` rows keeps a
+    block of ``T`` fresh rows (bucket padding included) off every row a live
+    query of that block still sees.
+
+    A row that ``block_tables`` sends to the null block — bucket padding past
+    the allocated frontier, an idle lane of the decode batch — goes to the
+    window pool's null block too, so a lane mid-prefill is not written into
+    by the decode program that runs beside it.
+
+    Both pools ride the layer loop as its carry, the layer folded into the
+    row index: a donated cache is updated in place. The weights are one stack
+    a layer shape, run in the published order (``models.laguna.layer_runs``).
+    The dense slot cache (``InferenceEngine.generate``) keeps every layer at
+    full length and the window is a mask only. The Pallas paged kernel has no
+    lower bound and is never eligible; tree (speculative) blocks are
+    refused."""
+
+    def _model(self):
+        from neuronx_distributed_llama3_2_tpu.models.laguna import LagunaForCausalLM
+
+        return LagunaForCausalLM(self.config)
+
+    # -- cache ------------------------------------------------------------
+
+    @property
+    def cache_kinds(self) -> Tuple[CacheKind, ...]:
+        c = self.config
+        return (
+            CacheKind("full", c.layers_of("full"), None),
+            CacheKind("window", c.layers_of("window"), c.sliding_window),
+        )
+
+    def init_paged_cache(
+        self, num_blocks: int, block_size: int, dtype: Any = None,
+        kv_cache_dtype: Optional[str] = None, window_blocks: Optional[int] = None,
+    ) -> MixedKVCache:
+        """``num_blocks`` sizes the full kind; ``window_blocks`` the window
+        kind (as many again where not given: a table as wide as the context
+        then serves both, ``benchmarks/check.py``'s call)."""
+        c = self.config
+
+        def pool(layers: int, blocks: int) -> PagedKVCache:
+            # a k/v pool's shape is its layers, kv heads and head width alone
+            sized = LlamaConfig(
+                num_layers=layers, num_heads=c.num_kv_heads, num_kv_heads=c.num_kv_heads,
+                head_dim=c.head_dim, dtype=c.dtype)
+            return LlamaDecode(sized).init_paged_cache(blocks, block_size, dtype, kv_cache_dtype)
+
+        return MixedKVCache(
+            full=pool(c.layers_of("full"), num_blocks),
+            window=pool(c.layers_of("window"), window_blocks or num_blocks),
+        )
+
+    def paged_cache_specs(self, quantized: bool = False) -> MixedKVCache:
+        one = LlamaDecode.paged_cache_specs(self, quantized)
+        return MixedKVCache(full=one, window=one)
+
+    def forbidden_gather_shapes(self, batch: int, kv_limit: int):
+        return set()
+
+    def _paged_kernel_eligible(self, t: int, tree) -> bool:
+        return False
+
+    # -- forward ----------------------------------------------------------
+
+    def forward(
+        self, params: Params, cache: Any, tokens: jax.Array, positions: jax.Array,
+        slots: Optional[jax.Array] = None, *, context_encode: bool = False,
+        return_hidden: bool = False, tree=None, kv_limit: Optional[int] = None,
+        block_tables: Optional[jax.Array] = None, row_live: Optional[jax.Array] = None,
+        window_tables: Optional[jax.Array] = None,
+    ) -> Tuple[jax.Array, Any]:
+        """tokens (b, T) at rows ``positions ..`` over ``cache`` — a
+        :class:`MixedKVCache` under ``block_tables`` (and ``window_tables``,
+        see the class), else the dense :class:`KVCache` of every layer;
+        returns (logits (b, T, V) or the normed hidden, the cache updated)."""
+        if tree is not None:
+            raise NotImplementedError("tree verification over a ring of window rows")
+        from neuronx_distributed_llama3_2_tpu.models.laguna import (
+            FULL, WINDOW, LagunaAttention, LagunaDecoderLayer, layer_runs, rope_tables,
+            scan_run,
+        )
+
+        c = self.config
+        model = self._model()
+        b, t = tokens.shape
+        pos_block = positions[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        paged = block_tables is not None
+        if paged:
+            bs = cache.full.block_size
+            rope_len = block_tables.shape[1] * bs
+            tables = {FULL: block_tables, WINDOW: block_tables}
+            null_rows = None
+            if window_tables is not None:
+                tables[WINDOW] = window_tables
+                null_rows = jnp.take_along_axis(block_tables, pos_block // bs, axis=1) == 0
+            pools = {kind: _pool_pair(getattr(cache, kind)) for kind in (FULL, WINDOW)}
+        else:
+            if getattr(cache, "k_scale", None) is not None:
+                raise ValueError("quantized KV storage is paged-only")
+            if slots is None:
+                slots = jnp.arange(b, dtype=jnp.int32)
+            rope_len = cache.max_len
+            pools = {"all": (cache.k, cache.v)}
+        ropes = {kind: rope_tables(c, kind, rope_len) for kind in (FULL, WINDOW)}
+        norm = make_norm(c)
+        tap = routing_tap.current()
+
+        x = model._embed()(params["embed"], tokens)
+        x = constrain(x, P(BATCH_AXES, None, None))
+        for run in layer_runs(c):
+            layer = LagunaDecoderLayer(c, run.kind, run.sparse)
+            attn = LagunaAttention(c, run.kind)
+            sin, cos = ropes[run.kind]
+            # the dense cache holds every layer; a pool the layers of its kind
+            held, first = (run.kind, run.kind_first) if paged else ("all", run.layer)
+            own_ring = paged and run.kind == WINDOW and window_tables is not None
+
+            def body(carry, lp, j, run=run, layer=layer, attn=attn, sin=sin, cos=cos,
+                     held=held, first=first, own_ring=own_ring):
+                x, pools = carry
+                h = norm(lp["attn_norm"], x)
+                with jax.named_scope("attn"), jax.named_scope(run.kind):
+                    q, k, v = attn.project(lp["attn"], h, sin, cos, pos_block)
+                    att, kc, vc = self._attend(
+                        q, k, v, *pools[held], first + j, pos_block, slots,
+                        window=attn.window(), context_encode=context_encode,
+                        table=tables[run.kind] if paged else None,
+                        limit=None if own_ring else kv_limit,
+                        null_rows=null_rows if own_ring else None,
+                    )
+                    attn_out = attn.output(lp["attn"], h, att)
+                x = x + attn_out
+                h = norm(lp["mlp_norm"], x)
+                ffn = MixtralDecode._mlp_block if run.sparse else LlamaDecode._mlp_block
+                x = x + ffn(self, lp, h)
+                return (x, {**pools, held: (kc, vc)}), (
+                    None if tap is None or not run.sparse else tap.take_layer())
+
+            (x, pools), counts = scan_run(body, (x, pools), params[run.stack], run)
+            if tap is not None and run.sparse:
+                tap.commit(counts, run.count)
+        x = norm(params["final_norm"], x)
+        if paged:
+            new_cache = MixedKVCache(
+                full=_pool_of(pools[FULL]), window=_pool_of(pools[WINDOW]))
+        else:
+            new_cache = type(cache)(*pools["all"])
+        if return_hidden:
+            return x, new_cache
+        return model._logits(params, x), new_cache
+
+    def _attend(
+        self, q, k, v, kc, vc, layer, pos_block, slots, *, window, context_encode: bool,
+        table, limit, null_rows,
+    ):
+        """Write the fresh rows k, v (b, T, NKV, D) of layer ``layer`` at
+        ``pos_block`` and attend q (b, T, N, D) — over the fresh block alone
+        under ``context_encode``, else over the rows read back (see the
+        class). kc / vc: one kind's whole pool (L, blocks, block_size, NKV, D),
+        a (payload, scale) pair each where quantized, read through ``table``
+        (b, W) — or, ``table`` None, the dense cache (L, B, S, NKV, D) at
+        ``slots``. ``limit`` bounds the rows read (None: all the table's, all
+        the cache's); ``null_rows`` (b, T) sends those rows' writes to the null
+        block. Returns (att (b, T, N, D), kc, vc)."""
+        from neuronx_distributed_llama3_2_tpu.models.laguna import masked_attention, visible
+
+        if table is None:
+            with jax.named_scope("kv_write"):
+                kc = kc.at[layer, slots[:, None], pos_block].set(k.astype(kc.dtype))
+                vc = vc.at[layer, slots[:, None], pos_block].set(v.astype(vc.dtype))
+            if not context_encode:
+                with jax.named_scope("kv_read"):
+                    k = kc[layer, slots, :limit].astype(q.dtype)
+                    v = vc[layer, slots, :limit].astype(q.dtype)
+                    ring_rows = kc.shape[2]
+        else:
+            quantized = isinstance(kc, tuple)
+            (kc, ksc), (vc, vsc) = (kc, vc) if quantized else ((kc, None), (vc, None))
+            nl, nb, bs = kc.shape[:3]
+            width = table.shape[1]
+            ring_rows = width * bs
+
+            def rows(a):      # every layer's rows in one run: never a[layer]
+                return a.reshape((nl * nb * bs,) + a.shape[3:])
+
+            def blocks(a):
+                return a.reshape((nl * nb,) + a.shape[2:])
+
+            with jax.named_scope("kv_write"):
+                block = jnp.take_along_axis(table, (pos_block // bs) % width, axis=1)
+                if null_rows is not None:
+                    block = jnp.where(null_rows, 0, block)
+                at = (layer * nb + block) * bs + pos_block % bs
+                if quantized:
+                    from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import (
+                        kv_dequantize,
+                        kv_quantize,
+                    )
+
+                    kq, ks = kv_quantize(k, kc.dtype)
+                    vq, vs = kv_quantize(v, vc.dtype)
+                    ksc = rows(ksc).at[at].set(ks).reshape(ksc.shape)
+                    vsc = rows(vsc).at[at].set(vs).reshape(vsc.shape)
+                    # the fresh block the prefill softmax sees is the round
+                    # trip a later chunk reads back
+                    k, v = kv_dequantize(kq, ks, q.dtype), kv_dequantize(vq, vs, q.dtype)
+                else:
+                    kq, vq = k.astype(kc.dtype), v.astype(vc.dtype)
+                kc = rows(kc).at[at].set(kq).reshape(kc.shape)
+                vc = rows(vc).at[at].set(vq).reshape(vc.shape)
+            if not context_encode:
+                with jax.named_scope("kv_read"):
+                    # gathered a block at a time: a block's rows lie together
+                    limit = ring_rows if limit is None else min(limit, ring_rows)
+                    at = layer * nb + table[:, : -(-limit // bs)]
+
+                    def read(a):
+                        got = blocks(a)[at]                          # (b, blocks, bs, ...)
+                        return got.reshape((got.shape[0], -1) + got.shape[3:])[:, :limit]
+
+                    if quantized:
+                        k = kv_dequantize(read(kc), read(ksc), q.dtype)
+                        v = kv_dequantize(read(vc), read(vsc), q.dtype)
+                    else:
+                        k, v = read(kc).astype(q.dtype), read(vc).astype(q.dtype)
+            if quantized:
+                kc, vc = (kc, ksc), (vc, vsc)
+        with jax.named_scope("sdpa"):
+            if context_encode:
+                k_pos = pos_block[:, None, :]
+            else:
+                # the position row r holds, as a query at i reads it
+                r = jnp.arange(k.shape[1], dtype=jnp.int32)
+                k_pos = pos_block[..., None] - (pos_block[..., None] - r) % ring_rows
+            att = masked_attention(q, k, v, visible(pos_block, k_pos, window))
+        return att, kc, vc
+
+
+def _pool_pair(pool: PagedKVCache):
+    """A pool as the layer loop carries it: (k, v), each a (payload, scale)
+    pair where quantized."""
+    if pool.quantized:
+        return (pool.k, pool.k_scale), (pool.v, pool.v_scale)
+    return pool.k, pool.v
+
+
+def _pool_of(pair) -> PagedKVCache:
+    k, v = pair
+    if isinstance(k, tuple):
+        return PagedKVCache(k=k[0], v=v[0], k_scale=k[1], v_scale=v[1])
+    return PagedKVCache(k=k, v=v)
+
+
+@dataclasses.dataclass(frozen=True)
 class GPTNeoXDecode(LlamaDecode):
     """Decode-mode GPT-NeoX/Pythia/CodeGen: the shared KV-cache machinery
     (:meth:`LlamaDecode._attend_with_cache`) under the family's block
@@ -1870,6 +2198,7 @@ def decode_model_for(config) -> LlamaDecode:
     from neuronx_distributed_llama3_2_tpu.models.bert import BertConfig
     from neuronx_distributed_llama3_2_tpu.models.brumby import BrumbyConfig
     from neuronx_distributed_llama3_2_tpu.models.gptneox import GPTNeoXConfig
+    from neuronx_distributed_llama3_2_tpu.models.laguna import LagunaConfig
     from neuronx_distributed_llama3_2_tpu.models.mixtral import MixtralConfig
     from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamConfig
 
@@ -1884,6 +2213,8 @@ def decode_model_for(config) -> LlamaDecode:
         return SarvamDecode(config)
     if isinstance(config, BrumbyConfig):
         return RetentionDecode(config)
+    if isinstance(config, LagunaConfig):
+        return LagunaDecode(config)
     if isinstance(config, MixtralConfig):
         return MixtralDecode(config)
     return LlamaDecode(config)

@@ -29,6 +29,13 @@ from neuronx_distributed_llama3_2_tpu.models.brumby import (  # noqa: F401
     params_from_hf_brumby,
     params_to_hf_brumby,
 )
+from neuronx_distributed_llama3_2_tpu.models.laguna import (  # noqa: F401
+    LAGUNA_CONFIGS,
+    LagunaConfig,
+    LagunaForCausalLM,
+    params_from_hf_laguna,
+    params_to_hf_laguna,
+)
 from neuronx_distributed_llama3_2_tpu.models.dbrx import (  # noqa: F401
     DBRX_CONFIGS,
     DbrxConfig,
@@ -98,6 +105,11 @@ def model_registry():
         reg[name] = {
             "config": cfg, "model_cls": BrumbyForCausalLM,
             "from_hf": params_from_hf_brumby, "to_hf": params_to_hf_brumby,
+        }
+    for name, cfg in LAGUNA_CONFIGS.items():
+        reg[name] = {
+            "config": cfg, "model_cls": LagunaForCausalLM,
+            "from_hf": params_from_hf_laguna, "to_hf": params_to_hf_laguna,
         }
     for name, cfg in DBRX_CONFIGS.items():
         reg[name] = {

@@ -57,9 +57,10 @@ class MoEConfig:
     # None => all-experts path (no dropping); reference SELECTIVE_LOADING /
     # forward_all_experts dispatch (expert_mlps.py:298-357)
     capacity_factor: Optional[float] = None
-    # "topk" (softmax, then the k largest) | "sinkhorn" | "sigmoid_bias"
-    # (sigmoid scores chosen by score + a learned bias, gates renormalised
-    # and scaled by ``routed_scale``: routing.sigmoid_bias_routing)
+    # "topk" (softmax, then the k largest, scaled by ``routed_scale``) |
+    # "sinkhorn" | "sigmoid_bias" (sigmoid scores chosen by score + a learned
+    # bias, gates renormalised and scaled by ``routed_scale``:
+    # routing.sigmoid_bias_routing)
     routing: str = "topk"
     normalize_top_k: bool = True
     sinkhorn_iterations: int = 3
@@ -176,6 +177,10 @@ class MoE:
             )
         else:
             gates, idx = top_k_routing(logits, c.top_k, c.normalize_top_k)
+            if c.routed_scale != 1.0:
+                # only where a model states one: the others' programs keep
+                # their HLO
+                gates = gates * c.routed_scale
         return logits, gates, idx
 
     def _ep_size(self) -> int:

@@ -145,6 +145,12 @@ class EngineDims:
     # row (``cache_is_positional`` of the decode model): a program then moves
     # whole blocks — the sequences it touches, read and written
     cache_is_positional: bool = True
+    # bytes a lane's ring holds on a rank, over the layers that keep only
+    # their last rows (``cache_kinds`` of the decode model); 0 where every
+    # layer keeps the whole context. ``block_bytes`` and ``kv_row_bytes`` are
+    # then the other layers' alone: a request's cache bytes are its context's
+    # rows at ``kv_row_bytes`` plus one ring
+    ring_bytes: int = 0
 
     @classmethod
     def from_engine(cls, engine: Any) -> "EngineDims":
@@ -165,6 +171,16 @@ class EngineDims:
             kv_scale_itemsize,
         )
 
+        # the ring kind's pool, where the cache has one: its bytes on a rank,
+        # its blocks, and a lane's share of them
+        ring_kind = getattr(engine, "_ring_kind", None)
+        ring_blocks = int(getattr(engine, "_ring_blocks", 0))
+        ring_pool_bytes = ring_pool_blocks = 0
+        if ring_kind is not None:
+            ring_leaves = jax.tree.leaves(engine._kind_pool(ring_kind))
+            ring_pool_bytes = sum(a.addressable_shards[0].data.nbytes for a in ring_leaves)
+            ring_pool_blocks = int(ring_leaves[0].shape[1])
+
         return cls(
             num_params=num_params,
             param_bytes=param_bytes,
@@ -183,9 +199,10 @@ class EngineDims:
             quant_mxu=bool(getattr(engine.model.config, "quant_mxu", False)),
             fused_sampling=bool(getattr(engine, "_fused", False)),
             kv_arrays=kv_arrays,
-            block_bytes=int(engine.metrics.pool_bytes_per_rank)
+            block_bytes=(int(engine.metrics.pool_bytes_per_rank) - ring_pool_bytes)
             // int(engine.paged.num_blocks),
             cache_is_positional=positional,
+            ring_bytes=ring_pool_bytes * ring_blocks // max(ring_pool_blocks, 1),
         )
 
     @property
@@ -212,17 +229,28 @@ class EngineDims:
         return self.kv_arrays * self.num_layers * self.kv_heads_local * per_head
 
     def pool_bytes_local(self) -> int:
-        """Bytes of the whole pool on a rank."""
+        """Bytes of the allocator's pool on a rank (the rings are beside it:
+        ``ring_bytes`` a lane)."""
         if self.block_bytes is not None:
             return self.num_blocks * self.block_bytes
         return self.num_blocks * self.block_size * self.kv_row_bytes()
 
     def state_bytes(self, sequences: int) -> int:
-        """Bytes a program over ``sequences`` lanes moves of a state pool:
-        each lane's block read once and written once; 0 for a pool of rows."""
+        """Bytes a program over ``sequences`` lanes moves of what a lane holds
+        whole: a state pool's block read once and written once; a ring read
+        once; 0 for a pool of rows alone."""
         if self.cache_is_positional:
-            return 0
+            return sequences * self.ring_bytes
         return 2 * sequences * (self.block_bytes or 0)
+
+    def request_cache_bytes(self, context: int) -> int:
+        """Bytes a request of ``context`` tokens holds in the cache on a
+        rank: its rows in whole blocks, plus a ring where a kind keeps one; a
+        state's one block."""
+        if not self.cache_is_positional:
+            return self.block_bytes or 0
+        blocks = -(-context // self.block_size)
+        return blocks * self.block_size * self.kv_row_bytes() + self.ring_bytes
 
 
 def _flops_per_token(
@@ -344,7 +372,8 @@ def analytic_profile(key: tuple, dims: EngineDims) -> CostProfile:
         # arguments ≈ params shard + the whole pool (every compute
         # program takes the full donated cache); outputs are the sampled
         # tokens (the cache comes back through the donation alias)
-        arg = dims.param_bytes_local + dims.pool_bytes_local()
+        arg = dims.param_bytes_local + dims.pool_bytes_local() \
+            + dims.max_batch * dims.ring_bytes
         out = dims.max_batch * 4
     else:
         arg = dims.pool_bytes_local() // dims.num_blocks

@@ -561,10 +561,38 @@ class PagedServingEngine:
                         f"{name} is not available for {type(self.model).__name__}: "
                         f"its cache is a state per sequence, not rows per token — {why}"
                     )
+        # a kind of cache that keeps only the last rows of a context (window
+        # layers beside full ones: docs/serving.md "Stacks whose layers cache
+        # different things"): its rows live in a ring of blocks a lane that
+        # this engine sizes and lays out, beside the allocator's pool
+        self._ring_kind = next(
+            (kind for kind in self.model.cache_kinds if kind.rows is not None), None
+        )
+        if self._ring_kind is not None:
+            for on, name, why in (
+                (self._spec_k, "spec_draft_tokens > 0",
+                 "the ring is sized for a prefill rung of fresh rows; a verify "
+                 "block's rejected rows would have to be kept out of it as well"),
+                (paged.fused_step, "fused_step",
+                 "the mixed program has no table for the ring"),
+                (paged.spill_enabled, "spill_enabled",
+                 "the spill tier lives behind the prefix index, and a prefix "
+                 "of the full layers' blocks is not a prefix's cache"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{name} is not available for {type(self.model).__name__}: its "
+                        f"{self._ring_kind.name} layers keep a ring of rows a lane — {why}"
+                    )
         # prefix sharing matches token by token inside a block and copies a
         # partly shared block; a state after N tokens says nothing about its
-        # first k, so a state model neither matches nor inserts
-        self._share_prefixes = bool(paged.enable_prefix_caching) and self._positional
+        # first k, so a state model neither matches nor inserts. Nor does a
+        # stack with a ring: a hit at token N would leave the ring's layers
+        # without the rows N - window + 1 .. N - 1
+        self._share_prefixes = (
+            bool(paged.enable_prefix_caching) and self._positional
+            and self._ring_kind is None
+        )
         # tree speculation: verify a packed candidate tree (ptree program)
         # instead of a single chain. Set before the catalog build below —
         # the manifest swaps its verify rungs to ptree keys under the flag.
@@ -700,10 +728,27 @@ class PagedServingEngine:
                 self.model = type(self.model)(
                     dataclasses.replace(self.model.config, quant_mxu=True)
                 )
+        # the ring: every row a query of the top prefill rung still sees
+        # (rows - 1 behind its first row) plus the rung's own fresh rows,
+        # padding included, in whole blocks; lane l's is blocks
+        # 1 + l * ring .. of the kind's pool, block 0 its null block. Laid out
+        # once: no allocator, no release, no per-step delta
+        self._ring_blocks = 0
+        self._ring_tables: Optional[np.ndarray] = None
+        sized = {}
+        if self._ring_kind is not None:
+            self._ring_blocks = _ceil_div(
+                self._ring_kind.rows - 1 + self._prefill_buckets[-1], bs
+            )
+            self._ring_tables = 1 + np.arange(
+                engine.max_batch * self._ring_blocks, dtype=np.int32
+            ).reshape(engine.max_batch, self._ring_blocks)
+            sized = {"window_blocks": 1 + engine.max_batch * self._ring_blocks}
+
         def init_pool():
             return self.model.init_paged_cache(
                 paged.num_blocks, bs, paged.cache_dtype,
-                kv_cache_dtype=paged.kv_cache_dtype,
+                kv_cache_dtype=paged.kv_cache_dtype, **sized,
             )
 
         from neuronx_distributed_llama3_2_tpu.parallel import (
@@ -833,6 +878,7 @@ class PagedServingEngine:
         self.metrics.pool_bytes_per_rank = sum(
             a.addressable_shards[0].data.nbytes for a in pool_leaves
         )
+        self.metrics.window_pool_blocks = sized.get("window_blocks", 0)
 
         self._next_rid = 0
         self._queue: List[_PagedRequest] = []
@@ -1116,17 +1162,50 @@ class PagedServingEngine:
                 ),
                 # rows by position: bytes a token a layer; a state: bytes of one
                 # block over all layers
-                **({"cache_row_bytes": cache_row_bytes(self.cache)} if self._positional
+                **({"cache_row_bytes": cache_row_bytes(self._kind_pool(self.model.cache_kinds[0]))}
+                   if self._positional
                    else {"state_bytes_per_lane": cache_block_bytes(self.cache)}),
+                **self._kind_facts(),
             }
 
-    def _decode_rows(self, decode_lanes) -> int:
+    def _kind_pool(self, kind):
+        """The part of the cache that holds ``kind``: the field of its name
+        where the cache has fields by kind, else all of it."""
+        return getattr(self.cache, kind.name, self.cache)
+
+    def _kind_facts(self) -> Dict[str, Any]:
+        """``cache_kinds`` of the ``setup`` record — a kind: its layers, the
+        rows a lane keeps of it (null = the whole context) and a row's bytes a
+        layer as the device lays them out — and ``window_ring_rows``; nothing
+        where the cache is a state."""
+        if not self._positional:
+            return {}
+        ring_rows = self._ring_blocks * self.paged.block_size
+        return {
+            "cache_kinds": {
+                kind.name: {
+                    "layers": kind.layers,
+                    "rows_per_lane": None if kind.rows is None else ring_rows,
+                    "row_bytes": cache_row_bytes(self._kind_pool(kind)),
+                }
+                for kind in self.model.cache_kinds
+            },
+            "window_ring_rows": ring_rows,
+        }
+
+    def _decode_rows(self, decode_lanes) -> Dict[str, int]:
         """A decode dispatch record's ``rows``: the cache rows the live lanes
         attend over, this step's included — or, where the cache is a state a
-        lane, the live lanes: the states the step has to move."""
+        lane, the live lanes: the states the step has to move. Where a kind of
+        layer sees only its last rows, ``window_rows`` beside it: the rows
+        those layers attend over, min(context, window) a live lane."""
         if not self._positional:
-            return len(decode_lanes)
-        return int(sum(self._positions[l] for l in decode_lanes)) + len(decode_lanes)
+            return {"rows": len(decode_lanes)}
+        contexts = [int(self._positions[l]) + 1 for l in decode_lanes]
+        rows = {"rows": sum(contexts)}
+        if self._ring_kind is not None:
+            rows["window_rows"] = sum(min(n, self._ring_kind.rows) for n in contexts)
+        return rows
 
     def _kv_bucket(self, needed: int) -> int:
         """kv_limit rung covering ``needed`` rows over the serving kv
@@ -1180,6 +1259,28 @@ class PagedServingEngine:
         every sampling config (and the catalog shrinks accordingly)."""
         return "lane" if self._fused else self.gen.sampling
 
+    def _prefill_table(self, table, lane: Optional[int]) -> np.ndarray:
+        """The (1, W) table a prefill program takes: the request's blocks,
+        null past them — and, where a kind of the cache keeps a ring, the
+        lane's ring after the ``table_width`` columns (:meth:`_table_kinds`
+        parts them again inside the program). ``lane`` None: a warm-up call,
+        whose rows all land in the null blocks."""
+        row = np.full((1, self.table_width + self._ring_blocks), NULL_BLOCK, np.int32)
+        row[0, : len(table)] = table
+        if self._ring_blocks and lane is not None:
+            row[0, self.table_width:] = self._ring_tables[lane]
+        return row
+
+    def _table_kinds(self, table) -> Dict[str, Any]:
+        """A prefill program's table as the model's ``forward`` takes it: the
+        block table, and the ring's as ``window_tables`` where there is one."""
+        if not self._ring_blocks:
+            return {"block_tables": table}
+        return {
+            "block_tables": table[:, : self.table_width],
+            "window_tables": table[:, self.table_width:],
+        }
+
     def _prefill_ctx_program(self, bucket: int, cfg):
         """Whole-prompt prefill (no cached prefix): context-encode forward +
         last-token gather + on-device sample, paged writes. Under fused
@@ -1198,8 +1299,9 @@ class PagedServingEngine:
         def _last_logits(params, cache, ids, positions, length, table):
             hidden, cache = model.forward(
                 params, cache, ids, positions, None,
-                context_encode=True, return_hidden=True, block_tables=table,
+                context_encode=True, return_hidden=True,
                 row_live=None if positional else length,
+                **self._table_kinds(table),
             )
             last = jnp.take_along_axis(
                 hidden, (length - 1)[:, None, None], axis=1
@@ -1250,8 +1352,9 @@ class PagedServingEngine:
         def _last_logits(params, cache, ids, start, length, table):
             hidden, cache = model.forward(
                 params, cache, ids, start, None,
-                return_hidden=True, block_tables=table, kv_limit=kv_limit,
+                return_hidden=True, kv_limit=kv_limit,
                 row_live=None if positional else length,
+                **self._table_kinds(table),
             )
             last = jnp.take_along_axis(
                 hidden, (length - 1)[:, None, None], axis=1
@@ -1308,6 +1411,9 @@ class PagedServingEngine:
             return self._programs[key_]
         model, engine = self._step_model(), self.engine
         pos_cap = self._pos_cap
+        # every lane's ring, where a kind of the cache keeps one: laid out at
+        # construction and never changed, so a constant of the program
+        ring = {} if self._ring_tables is None else {"window_tables": self._ring_tables}
 
         if self._fused and checked:
             def fn(params, cache, tokens, positions, tables,
@@ -1316,7 +1422,7 @@ class PagedServingEngine:
                 return model.decode_step(
                     params, cache, tokens, positions, tables,
                     kv_limit=kv_limit, pos_cap=pos_cap,
-                    sampling=(rng, temp, topk, topp), logit_poison=nan_mask,
+                    sampling=(rng, temp, topk, topp), logit_poison=nan_mask, **ring,
                 )
         elif self._fused:
             def fn(params, cache, tokens, positions, tables,
@@ -1325,14 +1431,14 @@ class PagedServingEngine:
                 return model.decode_step(
                     params, cache, tokens, positions, tables,
                     kv_limit=kv_limit, pos_cap=pos_cap,
-                    sampling=(rng, temp, topk, topp),
+                    sampling=(rng, temp, topk, topp), **ring,
                 )
         elif checked:
             def fn(params, cache, tokens, positions, tables, key, nan_mask):
                 params = engine._live_params(params)
                 logits, new_positions, cache = model.decode_step(
                     params, cache, tokens, positions, tables,
-                    kv_limit=kv_limit, pos_cap=pos_cap,
+                    kv_limit=kv_limit, pos_cap=pos_cap, **ring,
                 )
                 logits, finite = model.finite_logit_check(logits, nan_mask)
                 return sample(logits, key, cfg), finite, new_positions, cache
@@ -1341,7 +1447,7 @@ class PagedServingEngine:
                 params = engine._live_params(params)
                 logits, new_positions, cache = model.decode_step(
                     params, cache, tokens, positions, tables,
-                    kv_limit=kv_limit, pos_cap=pos_cap,
+                    kv_limit=kv_limit, pos_cap=pos_cap, **ring,
                 )
                 return sample(logits, key, cfg), new_positions, cache
 
@@ -2039,7 +2145,7 @@ class PagedServingEngine:
                 _, _, _, self.cache = fn(*args, self._nan_mask((), "warmup"))
             else:
                 _, _, self.cache = fn(*args)
-        table1 = jnp.full((1, self.table_width), NULL_BLOCK, jnp.int32)
+        table1 = jnp.asarray(self._prefill_table((), None))
         for bucket in self._prefill_buckets:
             fn = self._prefill_ctx_program(bucket, self._decode_cfg())
             _, self.cache = fn(
@@ -2067,7 +2173,7 @@ class PagedServingEngine:
         try:
             key = jax.random.key(0)
             zeros_b = jnp.zeros((eng.max_batch,), jnp.int32)
-            table1 = jnp.full((1, self.table_width), NULL_BLOCK, jnp.int32)
+            table1 = jnp.asarray(self._prefill_table((), None))
             zero = jnp.asarray(0, jnp.int32)
             # fused-sampling trailing args (aval twins of traffic's):
             # decode/verify dispatch THE residents, prefill the (1,·)
@@ -2839,9 +2945,7 @@ class PagedServingEngine:
         ids[0, : len(suffix)] = suffix
         length = np.asarray([max(len(suffix), 1)], np.int32)
         if table_dev is None:
-            tbl = np.full((1, self.table_width), NULL_BLOCK, np.int32)
-            tbl[0, : len(table)] = table
-            table_dev = self._upload(tbl)
+            table_dev = self._upload(self._prefill_table(table, lane))
         tail = self._lane_sampling_args(lane) if self._fused else (key,)
         if cached == 0:
             if not self._positional:
@@ -2909,9 +3013,7 @@ class PagedServingEngine:
             if req.table_dev is None:
                 # one upload for the whole chunk walk: the admission
                 # allocated the full table, so every chunk sees the same row
-                tbl = np.full((1, self.table_width), NULL_BLOCK, np.int32)
-                tbl[0, : len(req.table)] = req.table
-                req.table_dev = self._upload(tbl)
+                req.table_dev = self._upload(self._prefill_table(req.table, lane))
             t_p = time.perf_counter()
             try:
                 self._chaos_device("prefill", (lane,))
@@ -3323,7 +3425,7 @@ class PagedServingEngine:
                 "dispatch", t_d, program=program_label(fn), mode="async",
                 sampling=smode, lanes=len(decode_lanes), kv_bucket=kv_limit,
                 kv_pad=kv_limit - kv_need,
-                rows=self._decode_rows(decode_lanes),
+                **self._decode_rows(decode_lanes),
             )
         self._d_tokens = toks
         self._dispatch_count += 1
@@ -3394,7 +3496,7 @@ class PagedServingEngine:
                 "dispatch", t_d, program=program_label(fn), mode="sync",
                 sampling=smode, lanes=len(decode_lanes), kv_bucket=kv_limit,
                 kv_pad=kv_limit - kv_need,
-                rows=self._decode_rows(decode_lanes),
+                **self._decode_rows(decode_lanes),
             )
         self._d_tokens = toks
         self._dispatch_count += 1

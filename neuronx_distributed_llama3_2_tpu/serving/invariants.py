@@ -210,8 +210,10 @@ def audit_engine(engine) -> List[str]:
 
     # 7. scale arrays match the configured pool dtype
     quant = engine.paged.kv_cache_dtype != "bf16"
-    has_k = getattr(engine.cache, "k_scale", None) is not None
-    has_v = getattr(engine.cache, "v_scale", None) is not None
+    # a pool a kind of cache, where the cache has fields by kind
+    pools = [engine._kind_pool(kind) for kind in engine.model.cache_kinds]
+    has_k = all(getattr(pool, "k_scale", None) is not None for pool in pools)
+    has_v = all(getattr(pool, "v_scale", None) is not None for pool in pools)
     if quant != has_k or quant != has_v:
         v.append(
             f"kv_cache_dtype={engine.paged.kv_cache_dtype!r} but cache "

@@ -29,7 +29,7 @@ from neuronx_distributed_llama3_2_tpu.serving.radix_index import (
 # dataclass fields exported as prometheus gauges; every other numeric
 # field is a monotonic counter
 _GAUGE_FIELDS = frozenset({
-    "tp_size", "pool_bytes_per_rank", "pool_bytes_total",
+    "tp_size", "pool_bytes_per_rank", "pool_bytes_total", "window_pool_blocks",
     "degradation_level",
     # graftmeter static figures (set once at harvest/construction) and
     # the SLO burn gauges (rewritten each evaluation)
@@ -129,6 +129,11 @@ class ServingMetrics:
     pool_bytes_total: int = 0      # whole logical pool (== per_rank * tp
     #                                when the kv heads divide tp; == per_rank
     #                                on the replication fallback)
+    # blocks of the pool the engine lays out as rings, a lane each, for a
+    # kind of layer that keeps only its last rows (null block included); 0
+    # wherever every layer keeps the whole context. Their bytes are in
+    # pool_bytes_*; num_blocks counts the allocator's pool alone
+    window_pool_blocks: int = 0
     # -- speculative decoding (docs/serving.md "Speculative decoding") --
     draft_tokens: int = 0          # drafts offered to verify steps
     accepted_tokens: int = 0       # drafts the target's argmax agreed with

@@ -105,9 +105,14 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
 # (moe/model.py); ``attn/gate`` (the per-kv-head decay), ``attn/retention/expand``
 # (φ of q and k), ``attn/retention/chunk`` (prefill: the in-chunk weights, the
 # carried state's read and its update) and ``attn/retention/step`` (decode: a
-# state's read and update) are power retention's (models/brumby.py).
+# state's read and update) are power retention's (models/brumby.py);
+# ``attn/full`` and ``attn/window`` wrap the attention block of a layer of
+# that kind — the block's usual children sit below them
+# (``attn/window/kv_read``) and the shared readers book them to ``attn/kv_read``
+# as ever — and ``attn/out_gate`` is the per-head output gate (models/laguna.py).
 DETAIL_SCOPES = {
-    "attn": ("qk_norm", "latent_down", "latent_up", "absorb", "gate", "retention"),
+    "attn": ("qk_norm", "latent_down", "latent_up", "absorb", "gate", "retention",
+             "full", "window", "out_gate"),
     "attn/retention": ("expand", "chunk", "step"),
     "moe": ("shared",),
     "moe/experts": ("selective", "all"),

@@ -192,8 +192,9 @@ def test_the_vocabulary_is_covered_by_the_two_suites_above_and_nothing_else_is_u
     assert used and used <= names, used - names
     # pctx/psfx/pdecode are entered by kind, not by literal; "selective" names
     # the reference path no program enters since the no-drop dispatch is
-    # all-experts at every shape (moe/experts.py)
-    assert names - used == {"pctx", "psfx", "pdecode", "selective"}
+    # all-experts at every shape (moe/experts.py); "full" / "window" are
+    # entered by a layer's kind (models/laguna.py, LagunaDecode.forward)
+    assert names - used == {"pctx", "psfx", "pdecode", "selective", "full", "window"}
 
 
 @pytest.mark.parametrize("M,pp,bubble", [(8, 2, 0.2), (4, 4, 0.6)])

@@ -231,7 +231,7 @@ EXPECTED_SNAPSHOT_KEYS = {
     "lookahead_declined_finish", "lookahead_declined_pool",
     "lane_syncs", "table_deltas", "h2d_uploads",
     "host_schedule_ms", "device_wait_ms", "tp_size", "kv_dtype",
-    "pool_bytes_per_rank", "pool_bytes_total", "draft_tokens",
+    "pool_bytes_per_rank", "pool_bytes_total", "window_pool_blocks", "draft_tokens",
     "accepted_tokens", "verify_steps", "spec_disabled_lanes",
     # tree speculation (PagedConfig.spec_tree)
     "tree_verify_steps", "tree_draft_tokens", "tree_accept_by_shape",
