@@ -261,6 +261,13 @@ class LlamaDecode:
         kernel (:class:`RetentionDecode`); the engine counts such dispatches."""
         return False
 
+    def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
+        """How a decode step (one fresh row a lane) reads ``kind``'s rows:
+        ``"kernel"`` — a Pallas call reads the pool where it lies — or
+        ``"gather"`` — rows gathered through the table, then attended. The
+        traced engine's ``setup`` record says it a kind."""
+        return self.paged_dispatch_path(1)
+
     def _model(self) -> LlamaForCausalLM:
         return LlamaForCausalLM(self.config)
 
@@ -1649,6 +1656,25 @@ class SarvamDecode(MixtralDecode):
         return x + ffn(self, lp, h), pool
 
 
+def _kernels_on_one_device() -> bool:
+    """Where a decode model's own bare Mosaic call runs: wherever Pallas
+    kernels run (:func:`..kernels.mode.prefer_pallas` — the CPU tier's
+    ``"reference"`` mode keeps the plain twin) on one device. On a
+    multi-device mesh the cache shards by kv head and a bare Mosaic call
+    cannot be partitioned."""
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import prefer_pallas
+    from neuronx_distributed_llama3_2_tpu.parallel import (
+        state as parallel_state,
+    )
+
+    if (
+        parallel_state.model_parallel_is_initialized()
+        and parallel_state.get_parallel_state().mesh.size > 1
+    ):
+        return False
+    return prefer_pallas()
+
+
 @dataclasses.dataclass(frozen=True)
 class RetentionDecode(LlamaDecode):
     """Decode-mode power retention (:mod:`..models.brumby`) over a
@@ -1673,6 +1699,7 @@ class RetentionDecode(LlamaDecode):
     draft cannot be taken back out of a state."""
 
     # shardlint SL002 — see LlamaDecode: uses_state_kernel reads the mesh
+    # (through _kernels_on_one_device)
     __layout_deps__ = LlamaDecode.__layout_deps__
 
     cache_is_positional = False
@@ -1720,23 +1747,10 @@ class RetentionDecode(LlamaDecode):
         return False
 
     def uses_state_kernel(self) -> bool:
-        """Whether the recurrent form runs the one-pass state kernel: wherever
-        Pallas kernels run (:func:`..kernels.mode.prefer_pallas` — the CPU
-        tier's ``"reference"`` mode keeps its twin ``retention_step``) on one
-        device. On a multi-device mesh the states shard by kv head and a bare
-        Mosaic call cannot be partitioned: ``retention_step`` there (no cell
-        runs it; never compiled for the chip)."""
-        from neuronx_distributed_llama3_2_tpu.kernels.mode import prefer_pallas
-        from neuronx_distributed_llama3_2_tpu.parallel import (
-            state as parallel_state,
-        )
-
-        if (
-            parallel_state.model_parallel_is_initialized()
-            and parallel_state.get_parallel_state().mesh.size > 1
-        ):
-            return False
-        return prefer_pallas()
+        """Whether the recurrent form runs the one-pass state kernel
+        (:func:`_kernels_on_one_device`); ``retention_step`` where it does not
+        (on a mesh no cell runs it; never compiled for the chip)."""
+        return _kernels_on_one_device()
 
     # -- forward ----------------------------------------------------------
 
@@ -1883,9 +1897,10 @@ class LagunaDecode(MixtralDecode):
     row index: a donated cache is updated in place. The weights are one stack
     a layer shape, run in the published order (``models.laguna.layer_runs``).
     The dense slot cache (``InferenceEngine.generate``) keeps every layer at
-    full length and the window is a mask only. The Pallas paged kernel has no
-    lower bound and is never eligible; tree (speculative) blocks are
-    refused."""
+    full length and the window is a mask only. ``paged_flash_decode`` has no
+    lower bound and is never eligible; a full layer's read of one row a lane
+    is the block walk where :meth:`decode_read` says ``"kernel"``. Tree
+    (speculative) blocks are refused."""
 
     def _model(self):
         from neuronx_distributed_llama3_2_tpu.models.laguna import LagunaForCausalLM
@@ -1932,6 +1947,19 @@ class LagunaDecode(MixtralDecode):
 
     def _paged_kernel_eligible(self, t: int, tree) -> bool:
         return False
+
+    def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
+        """``"kernel"`` for the kind with no lower bound over an unquantized
+        pool, where :func:`_kernels_on_one_device`: one row a lane is then
+        attended by :func:`..kernels.paged_attention_pallas.paged_decode_walk`
+        over the lane's live blocks. What the program can see decides, no
+        option: a window layer's ring, a block of several rows (``psfx``), an
+        int8 pool, a mesh and the ``"reference"`` mode keep the block-wise
+        gather and ``masked_attention``, the walk's plain twin."""
+        return "kernel" if self._walks(kind.rows, quantized) else "gather"
+
+    def _walks(self, window: Optional[int], quantized: bool) -> bool:
+        return window is None and not quantized and _kernels_on_one_device()
 
     # -- forward ----------------------------------------------------------
 
@@ -2048,6 +2076,9 @@ class LagunaDecode(MixtralDecode):
                     ring_rows = kc.shape[2]
         else:
             quantized = isinstance(kc, tuple)
+            walks = (
+                q.shape[1] == 1 and not context_encode
+                and self._walks(window, quantized))
             (kc, ksc), (vc, vsc) = (kc, vc) if quantized else ((kc, None), (vc, None))
             nl, nb, bs = kc.shape[:3]
             width = table.shape[1]
@@ -2081,6 +2112,17 @@ class LagunaDecode(MixtralDecode):
                     kq, vq = k.astype(kc.dtype), v.astype(vc.dtype)
                 kc = rows(kc).at[at].set(kq).reshape(kc.shape)
                 vc = rows(vc).at[at].set(vq).reshape(vc.shape)
+            if walks:
+                # one row a lane over a pool with no lower bound: the lane's
+                # live blocks are read where they lie, nothing gathered
+                from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+                    paged_decode_walk,
+                )
+
+                with jax.named_scope("sdpa"):
+                    att = paged_decode_walk(
+                        q[:, 0], kc, vc, table, pos_block[:, 0], layer, kv_limit=limit)
+                return att[:, None], kc, vc
             if not context_encode:
                 with jax.named_scope("kv_read"):
                     # gathered a block at a time: a block's rows lie together

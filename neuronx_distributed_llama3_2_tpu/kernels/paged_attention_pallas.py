@@ -49,6 +49,16 @@ Structure (flash-decoding, Dao et al. 2023 — split-K for a single query row):
   forward while still sharing one KV DMA per block. A linear chain's
   bitmasks reproduce the block-causal mask bit for bit.
 
+:func:`paged_decode_walk` stands beside it as the body a cell runs (the one
+fresh row a lane of an unquantized pool; ``LagunaDecode._attend`` calls it):
+no static grid over the rung — a grid step is a lane, and a loop whose trip
+count is the lane's own copies its **live** blocks a group at a time from the
+pool in HBM (``memory_space=ANY``, ``make_async_copy``, two VMEM buffers) and
+folds each group into the same online softmax. It takes the pool as ``(blocks ·
+bs · NKV, D)``, which on the chip is the bytes as they lie; the ``(blocks, bs,
+NKV·D)`` view above is a re-tiling there. ``_decode_kernel``'s variants move
+onto the walk as they are needed (``ROADMAP.md`` S8, D6).
+
 The kernel mode (:mod:`.mode`) decides whether the body runs through Mosaic
 or the Pallas interpreter; the real-chip numerics gate lives in
 scripts/tpu_kernel_gate.py.
@@ -493,6 +503,194 @@ def paged_flash_decode(
     out = out.reshape(b, nkv, t, g, d).transpose(0, 2, 1, 3, 4)
     out = out.reshape(b, t, n, d).astype(q.dtype)
     return out[:, 0] if squeeze else out
+
+
+# blocks a loop trip of the walk copies and scores: 32 blocks of 16 rows are
+# 1 MB of K and 1 MB of V a buffer (chip sweep, PERF.md section 6, PR 43)
+WALK_GROUP = 32
+
+
+def _walk_kernel(
+    tbl_ref,    # scalar prefetch: (b, nblk) int32 pool blocks, the layer's offset folded in
+    live_ref,   # scalar prefetch: (b,) int32 blocks each lane walks, >= 1
+    pos_ref,    # scalar prefetch: (b,) int32 the query's row
+    q_ref,      # (N, D) this lane's query heads
+    k_hbm,      # (pool blocks · bs · NKV, D) the pool where it lies (HBM)
+    v_hbm,
+    o_ref,      # (N, D)
+    kbuf,       # (2, group · bs · NKV, D) VMEM: group n + 1 lands while n is scored
+    vbuf,
+    sem,        # DMA semaphores (k | v, buffer)
+    slot_ref,   # SMEM (1,): the buffer the group being scored lies in
+    m_scr, l_scr, acc_scr,
+    *, bs: int, nkv: int, group: int, sm_scale: float,
+):
+    """One lane a grid step, its live blocks a group a loop trip. A buffer row
+    is one (row, kv head) pair, ``row · NKV + head`` — the pool's own order —
+    so every query head is scored against every pair by one dot and keeps its
+    own kv head's columns under the mask; ``p · v`` then sums a head's own
+    pairs alone and the (N, D) accumulator is the output, no diagonal to take."""
+    i = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    pairs = bs * nkv                  # buffer rows a block
+    n = q_ref.shape[0]
+    span = group * pairs
+
+    def copies(lane, grp, slot, start: bool):
+        """The copies of group ``grp`` of ``lane`` into buffer ``slot``: its
+        live blocks and no others, each 2 · ``pairs`` · D contiguous bytes."""
+        first = grp * group
+
+        def one(j, carry):
+            at = pl.multiple_of(tbl_ref[lane, first + j] * pairs, pairs)
+            to = pl.multiple_of(j * pairs, pairs)
+            for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                copy = pltpu.make_async_copy(
+                    hbm.at[pl.ds(at, pairs)], buf.at[slot, pl.ds(to, pairs)],
+                    sem.at[which, slot])
+                if start:
+                    copy.start()
+                else:
+                    copy.wait()
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(live_ref[lane] - first, group), one, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        # rows no copy has filled yet are multiplied by p == 0: they have to
+        # be numbers. After this they hold an earlier group's rows
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        copies(0, 0, 0, True)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    groups = pl.cdiv(live_ref[i], group)
+    # the rows this lane sees: up to its position, and no further than its
+    # walk (a lane on the null block carries any position)
+    seen = jnp.minimum(pos_ref[i] + 1, live_ref[i] * bs)
+    q = q_ref[...]
+    # column c holds row c // NKV of kv head c % NKV: a query head sees its
+    # own kv head's columns, up to the last row it sees
+    col = lax.broadcasted_iota(jnp.int32, (n, span), 1)
+    own = col % nkv == lax.broadcasted_iota(jnp.int32, (n, span), 0) // (n // nkv)
+    col = jnp.where(own, col, jnp.int32(2 ** 30))
+
+    def trip(grp, carry):
+        slot = slot_ref[0]
+        # the next group — this lane's, or the next lane's first — is in
+        # flight while this one is scored
+        @pl.when(grp + 1 < groups)
+        def _():
+            copies(i, grp + 1, 1 - slot, True)
+
+        @pl.when((grp + 1 == groups) & (i + 1 < lanes))
+        def _():
+            copies(i + 1, 0, 1 - slot, True)
+
+        copies(i, grp, slot, False)
+        k, v = kbuf[slot].astype(q.dtype), vbuf[slot].astype(q.dtype)
+        sc = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale             # (N, span)
+        sc = jnp.where(col < (seen - grp * group * bs) * nkv, sc, NEG_INF)
+        m_prev = m_scr[...]
+        # row 0 of the walk is visible to every head, so m is finite from the
+        # first group on and a masked column's p is exp(-1e30 - m) == 0
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+            p.astype(q.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+        slot_ref[0] = 1 - slot
+        return carry
+
+    lax.fori_loop(0, groups, trip, 0)
+    o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def paged_decode_walk(
+    q: jax.Array,             # (b, N, D) one query row a lane
+    k_pool: jax.Array,        # (L, num_blocks, bs, NKV, D): one kind's whole pool
+    v_pool: jax.Array,
+    block_tables: jax.Array,  # (b, W) int32 blocks of a layer; 0 is the null block
+    positions: jax.Array,     # (b,) int32 the query's row
+    layer,                    # scalar: the layer of the pool this read is of
+    *,
+    kv_limit: int | None = None,
+    group: int = WALK_GROUP,
+) -> jax.Array:
+    """The decode read of a layer with no lower bound: softmax(q · k / √D over
+    rows ``<= positions``) · v, lane by lane over the lane's **live** blocks
+    only, read where they lie. Returns q's shape in q.dtype.
+
+    Lane ``i`` walks ``positions[i] // bs + 1`` blocks (at most ``kv_limit``
+    rows' worth), block ``j`` of them at ``block_tables[i, j] + layer ·
+    num_blocks`` of the pool taken as one run of ``L · num_blocks`` blocks. A
+    lane whose first block is the null block — idle, or mid-prefill beside
+    the decode batch — walks that one block whatever position it carries; its
+    output is numbers nobody reads. The pool goes in as ``(blocks · bs · NKV,
+    D)``: under the TPU's tiling of the last two dimensions that is the same
+    bytes (the optimized HLO holds a bitcast, no copy), and a block is ``bs ·
+    NKV`` whole rows of it, contiguous. Blocks are copied ``group`` at a time
+    into one of two VMEM buffers, the next group in flight while this one is
+    folded into a float32 online softmax; p is cast to q's dtype for
+    ``p · v`` as ``models.laguna.masked_attention`` does, which is this
+    kernel's plain twin over gathered rows.
+    """
+    b, n, d = q.shape
+    nl, nb, bs, nkv, _ = k_pool.shape
+    if n % nkv:
+        raise ValueError(f"q heads ({n}) must be a multiple of kv heads ({nkv})")
+    width = block_tables.shape[1]
+    nblk = width if kv_limit is None else min(width, _ceil_div(kv_limit, bs))
+    live = jnp.where(
+        block_tables[:, 0] == 0, 1, jnp.clip(positions // bs + 1, 1, nblk))
+    tables = block_tables[:, :nblk] + layer * nb
+    span = group * bs * nkv
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((None, n, d), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, n, d), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, d), k_pool.dtype),
+            pltpu.VMEM((2, span, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((n, d), jnp.float32),
+        ],
+    )
+    buffers = 2 * 2 * span * d * k_pool.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(
+            _walk_kernel, bs=bs, nkv=nkv, group=group, sm_scale=d ** -0.5),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # the buffers, and the (N, span) float32 scores and what is made of
+        # them; lanes in turn, because a lane starts the next lane's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=buffers + 10 * n * span * 4 + (8 << 20),
+        ),
+        interpret=pallas_interpret(),
+        name="paged_decode_walk",
+    )(
+        tables.astype(jnp.int32), live.astype(jnp.int32), positions.astype(jnp.int32),
+        q, k_pool.reshape(nl * nb * bs * nkv, d), v_pool.reshape(nl * nb * bs * nkv, d),
+    )
 
 
 def paged_flash_decode_tp(

@@ -1175,9 +1175,10 @@ class PagedServingEngine:
 
     def _kind_facts(self) -> Dict[str, Any]:
         """``cache_kinds`` of the ``setup`` record — a kind: its layers, the
-        rows a lane keeps of it (null = the whole context) and a row's bytes a
-        layer as the device lays them out — and ``window_ring_rows``; nothing
-        where the cache is a state."""
+        rows a lane keeps of it (null = the whole context), a row's bytes a
+        layer as the device lays them out and which read a decode step takes
+        of it (``decode_read``: ``"kernel"`` or ``"gather"``) — and
+        ``window_ring_rows``; nothing where the cache is a state."""
         if not self._positional:
             return {}
         ring_rows = self._ring_blocks * self.paged.block_size
@@ -1187,6 +1188,8 @@ class PagedServingEngine:
                     "layers": kind.layers,
                     "rows_per_lane": None if kind.rows is None else ring_rows,
                     "row_bytes": cache_row_bytes(self._kind_pool(kind)),
+                    "decode_read": self.model.decode_read(
+                        kind, self._kind_pool(kind).quantized),
                 }
                 for kind in self.model.cache_kinds
             },

@@ -66,6 +66,18 @@ Three measurements for the gather-free paged decode path (docs/serving.md):
    fused leg's ``dispatches_per_step`` strictly below the unfused one;
    steps/sec is reported, not gated.
 
+9. **The block walk alone** (``--walk``, and nothing else runs): one full
+   layer's decode read at ``laguna-mixedlen-batch``'s shape — 32 lanes, 48
+   query over 8 kv heads of 128, blocks of 16 rows, a pool of 2 x 17,920
+   blocks, contexts drawn as the cell holds them (a log-uniform prompt of
+   512-8,192 and up to 256 rows of reply) through a permuted table —
+   ``kernels.paged_attention_pallas.paged_decode_walk`` at each of
+   ``--walk-groups`` blocks a loop trip beside the block-wise gather and
+   ``masked_attention`` over the whole rung that it replaces. Prints ms a
+   layer and GB/s of *live* bytes (the K and V blocks the lanes' contexts
+   reach) for each; the gate is the kernel's distance from the gather.
+   ``PERF.md`` section 6 (PR 43) holds the sweep this was written for.
+
 Gates (record still prints on failure, like kv_block_bench.py):
 
 - per-``kv_limit`` greedy argmax parity, kernel vs gather
@@ -121,6 +133,12 @@ def build_args(argv=None) -> argparse.Namespace:
                     help="directory for graftscope artifacts (Chrome trace "
                     "JSON + prometheus text from the traced serving-loop leg); "
                     "defaults to $SERVING_TRACE_DIR; unset = no artifacts")
+    ap.add_argument("--walk", action="store_true",
+                    help="time the decode block walk against the gather + "
+                    "scores it replaces, at laguna-mixedlen-batch's shape "
+                    "(with --smoke: a tiny one), and nothing else")
+    ap.add_argument("--walk-groups", default="8,16,32,64",
+                    help="blocks a loop trip of the walk, comma-separated")
     args = ap.parse_args(argv)
     if args.smoke:
         args.kv_limits = "32"
@@ -917,6 +935,101 @@ def _fused_ab(config, params, args):
     }
 
 
+def _walk_sweep(args) -> dict:
+    """Measurement 9 of the module's list."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+        paged_decode_walk,
+    )
+    from neuronx_distributed_llama3_2_tpu.models.laguna import (
+        masked_attention,
+        visible,
+    )
+
+    if args.smoke:
+        layers, nb, bs, nkv, d, n, lanes, rung = 2, 40, 4, 2, 128, 4, 4, 32
+        low, high, reply, dtype = 6, 24, 8, jnp.float32
+    else:
+        layers, nb, bs, nkv, d, n, lanes, rung = 2, 17920, 16, 8, 128, 48, 32, 8704
+        low, high, reply, dtype = 512, 8192, 256, jnp.bfloat16
+    rng = np.random.default_rng(args.seed)
+    width = rung // bs
+    contexts = np.minimum(
+        np.exp(rng.uniform(np.log(low), np.log(high), lanes)).astype(np.int64)
+        + rng.integers(0, reply + 1, lanes), rung)
+    positions = jnp.asarray(contexts - 1, jnp.int32)
+    # every lane its own blocks, scattered over the pool; past its frontier
+    # the null block, as the engine's table has it
+    tables = np.zeros((lanes, width), np.int32)
+    free = rng.permutation(np.arange(1, nb))
+    for lane, rows in enumerate(contexts):
+        blocks = -(-int(rows) // bs)
+        tables[lane, :blocks], free = free[:blocks], free[blocks:]
+    tables = jnp.asarray(tables)
+    keys = jax.random.split(jax.random.key(args.seed), 3)
+    k_pool = jax.random.normal(keys[0], (layers, nb, bs, nkv, d), dtype)
+    v_pool = jax.random.normal(keys[1], (layers, nb, bs, nkv, d), dtype)
+    q = jax.random.normal(keys[2], (lanes, n, d), dtype)
+    live_bytes = int(
+        2 * np.sum(-(-contexts // bs)) * bs * nkv * d * k_pool.dtype.itemsize)
+
+    def gather(q, k_pool, v_pool, tables, positions, layer):
+        """``LagunaDecode._attend``'s read of a full layer at t == 1."""
+        at = layer * nb + tables
+
+        def read(a):
+            got = a.reshape((layers * nb,) + a.shape[2:])[at]
+            return got.reshape((lanes, rung) + got.shape[3:])
+
+        k_pos = jnp.arange(rung, dtype=jnp.int32)[None, None, :]
+        return masked_attention(
+            q[:, None], read(k_pool), read(v_pool),
+            visible(positions[:, None], k_pos, None))[:, 0]
+
+    def timed(read):
+        def both(q, k_pool, v_pool, tables, positions):
+            return sum(
+                read(q, k_pool, v_pool, tables, positions, jnp.int32(layer))
+                .astype(jnp.float32) for layer in range(layers))
+
+        fn = jax.jit(both)
+        operands = (q, k_pool, v_pool, tables, positions)
+        out = fn(*operands).block_until_ready()
+        for _ in range(args.warmup):
+            fn(*operands).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(args.iters):
+            out = fn(*operands)
+        out.block_until_ready()
+        ms = (time.perf_counter() - t0) * 1e3 / args.iters / layers
+        return out, {"ms_a_layer": round(ms, 4),
+                     "live_gb_s": round(live_bytes / ms / 1e6, 1)}
+
+    want, gathered = timed(gather)
+    record = {
+        "walk": True, "seed": args.seed, "platform": jax.default_backend(),
+        "lanes": lanes, "rung": rung, "mean_context": float(contexts.mean()),
+        "min_context": int(contexts.min()), "max_context": int(contexts.max()),
+        "live_mb_a_layer": round(live_bytes / 1e6, 2),
+        "gather_and_scores": gathered, "walk_by_group": {},
+    }
+    scale = float(jnp.max(jnp.abs(want)))
+    worst = 0.0
+    for group in (int(x) for x in args.walk_groups.split(",") if x):
+        got, entry = timed(
+            lambda *a, group=group: paged_decode_walk(*a, kv_limit=rung, group=group))
+        entry["distance"] = float(jnp.max(jnp.abs(got - want))) / scale
+        worst = max(worst, entry["distance"])
+        record["walk_by_group"][str(group)] = entry
+    # a bf16 pool: p and the scores are rounded at other places in the two
+    if worst > (2e-2 if dtype == jnp.bfloat16 else 1e-5):
+        record["gate_failure"] = f"walk is {worst:.3g} of the output's scale from the gather"
+    return record
+
+
 def run_bench(args: argparse.Namespace) -> dict:
     import jax
 
@@ -1045,7 +1158,7 @@ def main() -> None:
         )
 
         set_cpu_devices(max(2, args.tp))
-    record = run_bench(args)
+    record = _walk_sweep(args) if args.walk else run_bench(args)
     # the record prints even when a gate fails: a regression must still
     # yield the measured numbers, not just an exception tail
     print(json.dumps(record), flush=True)

@@ -70,6 +70,11 @@ def main(argv) -> int:
         jobs.append((name, cases.paged_case(**kw), one))
     for name, dtype in cases.RETENTION_CASES.items():
         jobs.append((name, cases.retention_case(dtype), one))
+    for name, dtype in cases.WALK_CASES.items():
+        jobs.append((name, cases.walk_case(dtype), one))
+        # every group size of scripts/paged_decode_bench.py --walk
+        for group in (8, 16, 64):
+            jobs.append((f"{name}-group{group}", cases.walk_case(dtype, group), one))
     for name, kw in cases.TP_CASES.items():
         jobs.append(
             (name, cases.paged_case(mesh=tp_state.mesh, **kw), replicated)

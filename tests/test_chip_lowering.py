@@ -190,6 +190,36 @@ def retention_case(pool_dtype):
 RETENTION_CASES = {"retention-step-f32": jnp.float32, "retention-step-bf16": jnp.bfloat16}
 
 
+def walk_case(pool_dtype, group=None):
+    """(fn, avals) for the decode block walk at ``laguna-mixedlen-batch``'s
+    shape: 32 lanes, 48 query over 8 kv heads of 128, the full kind's pool of
+    2 x 17,920 blocks of 16 rows, the 8,704-row rung."""
+    from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+        WALK_GROUP,
+        paged_decode_walk,
+    )
+
+    lanes, n, nkv, d, layers, blocks, bs, rung = 32, 48, 8, 128, 2, 17920, 16, 8704
+    pool = jax.ShapeDtypeStruct((layers, blocks, bs, nkv, d), pool_dtype)
+    avals = [
+        jax.ShapeDtypeStruct((lanes, n, d), jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((lanes, rung // bs), jnp.int32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+    ]
+
+    def fn(q, k_pool, v_pool, tables, positions, layer):
+        return paged_decode_walk(
+            q, k_pool, v_pool, tables, positions, layer, kv_limit=rung,
+            group=group or WALK_GROUP)
+
+    return fn, avals
+
+
+# the pool's dtype: bfloat16 as served; float32 is the CPU tests' pool
+WALK_CASES = {"decode-walk-bf16": jnp.bfloat16, "decode-walk-f32": jnp.float32}
+
+
 def lower_for_tpu(fn, avals):
     return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
 
@@ -227,6 +257,12 @@ def test_retention_step_kernel_lowers_for_tpu(compiled_mode, name):
     assert_mosaic_call(lowered, "retention_state_pass")
     # the pool is the call's operand 2 and its result 1: updated in place
     assert "output_tuple_indices = [1], operand_index = 2" in lowered.as_text()
+
+
+@pytest.mark.parametrize("name", WALK_CASES)
+def test_decode_walk_kernel_lowers_for_tpu(compiled_mode, name):
+    lowered = lower_for_tpu(*walk_case(WALK_CASES[name]))
+    assert_mosaic_call(lowered, "paged_decode_walk")
 
 
 @pytest.mark.parametrize("name", TP_CASES)

@@ -18,6 +18,7 @@ from neuronx_distributed_llama3_2_tpu.inference import (
     CacheKind, GenerationConfig, InferenceEngine, LagunaDecode, MixedKVCache, PagedKVCache,
 )
 from neuronx_distributed_llama3_2_tpu.inference.model import cache_row_bytes, decode_model_for
+from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
 from neuronx_distributed_llama3_2_tpu.models.laguna import LAGUNA_CONFIGS, LagunaForCausalLM
 from neuronx_distributed_llama3_2_tpu.models.llama import LLAMA_CONFIGS
 from neuronx_distributed_llama3_2_tpu.parallel.state import initialize_model_parallel
@@ -33,6 +34,9 @@ SIZES = {"lanes": LANES, "block_size": BS, "max_seq_len": 128, "pool_blocks": 14
          "prefill_chunk_tokens": CHUNK, "prefill_buckets": [8, 16], "kv_buckets": [128]}
 TOL = 1e-4
 ROW = 2 * 2 * 16 * 4            # k and v x kv heads x head x float32, a layer
+# the kernel mode decides a full layer's decode read: the gather and
+# ``masked_attention`` ("reference", this tier's), or the block walk interpreted
+MODES = ("reference", "interpret")
 
 
 @pytest.fixture(scope="module")
@@ -215,12 +219,16 @@ def test_the_benchmarks_check_passes_on_the_built_engine(fam, params):
     clean(srv)
 
 
+@pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("loop", LOOPS)
-def test_mixed_lengths_give_the_references_tokens(fam, params, loop):
+def test_mixed_lengths_give_the_references_tokens(fam, params, loop, mode, monkeypatch):
     """Prompts under the window, past the ring and several rings long in one
-    queue, more requests than lanes, look-ahead and drained steps alike."""
+    queue, more requests than lanes, look-ahead and drained steps alike, the
+    full layers' decode read the gather or the block walk."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     prompts = prompts_of(np.random.default_rng(3), (37, 5, 90, 21, 60, 16))
     srv = serving(params, new_tokens=8, policy=loop_policy(loop))
+    assert srv.model.decode_read(srv.model.cache_kinds[0]) == ("kernel" if mode == "interpret" else "gather")
     rids = [srv.submit(p) for p in prompts]
     out = srv.run_to_completion()
     for rid, prompt in zip(rids, prompts):
@@ -230,8 +238,12 @@ def test_mixed_lengths_give_the_references_tokens(fam, params, loop):
     clean(srv)
 
 
-def test_a_lane_reused_after_a_longer_request_gives_the_tokens_of_a_fresh_engine(fam, params):
-    """One lane: the second request reads a ring the first left full, never reset."""
+@pytest.mark.parametrize("mode", MODES)
+def test_a_lane_reused_after_a_longer_request_gives_the_tokens_of_a_fresh_engine(fam, params, mode, monkeypatch):
+    """One lane: the second request reads a ring the first left full, never
+    reset — and, in the full layers, blocks past its frontier that the first
+    left full: where the block walk reads one of them, it shows here."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     long, short = prompts_of(np.random.default_rng(13), (100, 30))
     srv = serving(params, new_tokens=10, max_batch=1)
     first = srv.submit(long)
@@ -347,7 +359,7 @@ def test_the_window_pool_is_lanes_times_ring_and_the_accounts_say_so(params):
     assert moved == LANES * 128 * ROW * 2 + LANES * RING * ROW * 3
 
 
-def test_a_traced_engine_records_the_kinds_and_the_window_rows(params):
+def test_a_traced_engine_records_the_kinds_and_the_window_rows(params, monkeypatch):
     srv = serving(params, new_tokens=5, precompile=True, trace_enabled=True, prewarm=True)
     prompts = prompts_of(np.random.default_rng(2), (20, 3, 50))
     for p in prompts:
@@ -356,8 +368,8 @@ def test_a_traced_engine_records_the_kinds_and_the_window_rows(params):
     tl = srv.tracer.timeline()
     setup = tl["setup"]
     assert setup["cache_kinds"] == {
-        "full": {"layers": 2, "rows_per_lane": None, "row_bytes": ROW},
-        "window": {"layers": 3, "rows_per_lane": RING, "row_bytes": ROW}}
+        "full": {"layers": 2, "rows_per_lane": None, "row_bytes": ROW, "decode_read": "gather"},
+        "window": {"layers": 3, "rows_per_lane": RING, "row_bytes": ROW, "decode_read": "gather"}}
     assert setup["window_ring_rows"] == RING and setup["cache_row_bytes"] == ROW
     records = [args for step in tl["steps"] for ph, name, _, _, args in step["events"]
                if ph == "X" and name == "dispatch"]
@@ -369,6 +381,10 @@ def test_a_traced_engine_records_the_kinds_and_the_window_rows(params):
     assert any(a["window_rows"] < a["lanes"] * 8 for a in records)
     assert len(tl["routed"]) > 0 and all(len(row[4]) == 8 for row in tl["routed"])
     assert srv.metrics.snapshot()["window_pool_blocks"] == 1 + LANES * RING_BLOCKS
+    # where Pallas kernels run, the record of an engine built there says the full kind is walked
+    monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
+    reads = {name: kind["decode_read"] for name, kind in srv._kind_facts()["cache_kinds"].items()}
+    assert reads == {"full": "kernel", "window": "gather"}
 
 
 def test_the_dense_slot_cache_runs_every_layer_at_full_length(fam, params):

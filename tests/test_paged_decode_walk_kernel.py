@@ -1,0 +1,193 @@
+"""The decode block walk (``kernels/paged_attention_pallas.py``
+``paged_decode_walk``), interpreted on the CPU: against its plain twin — the
+block-wise gather of the whole rung and ``masked_attention``, which
+``LagunaDecode._attend`` keeps everywhere the walk does not run — through a
+permuted table with ragged positions, and what "the lane's live blocks only"
+has to mean: a lane on the null block, blocks past a frontier that are never
+read, the layer's offset into the run of ``L · num_blocks`` blocks, a group
+size that does not divide the walk. Then which read a ``pdecode`` holds in
+each kernel mode."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neuronx_distributed_llama3_2_tpu.inference.model import CacheKind, decode_model_for
+from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import paged_decode_walk
+from neuronx_distributed_llama3_2_tpu.models.laguna import (
+    LAGUNA_CONFIGS, LagunaForCausalLM, masked_attention, visible,
+)
+
+LAYERS, BLOCKS, BS, NKV, GROUPS, D = 2, 40, 4, 2, 3, 16
+WIDTH = 8                                   # blocks a table row: a rung of 32 rows
+RUNG = WIDTH * BS
+# 0, 15, 16: a block's first row, its last, the next block's first — here BS is 4,
+# so also 3 and 4; one short of the rung; lane 4 idles on the null block
+POSITIONS = (0, 15, 16, RUNG - 2, 9, 3, 4)
+NULL_LANE = 4
+
+
+@pytest.fixture(autouse=True)
+def interpreted(monkeypatch):
+    monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
+
+
+def make(dtype, seed=0):
+    """(q, k_pool, v_pool, tables, positions): every live lane its own blocks,
+    scattered; past its frontier the null block, as the engine's table has it."""
+    rng = np.random.default_rng(seed)
+    k_pool, v_pool = (jnp.asarray(rng.standard_normal((LAYERS, BLOCKS, BS, NKV, D)), dtype) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((len(POSITIONS), NKV * GROUPS, D)), dtype)
+    free = rng.permutation(np.arange(1, BLOCKS))
+    tables = np.zeros((len(POSITIONS), WIDTH), np.int32)
+    for lane, pos in enumerate(POSITIONS):
+        if lane != NULL_LANE:
+            blocks = pos // BS + 1
+            tables[lane, :blocks], free = free[:blocks], free[blocks:]
+    return q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(POSITIONS, jnp.int32)
+
+
+@jax.jit
+def twin(q, k_pool, v_pool, tables, positions, layer):
+    """``LagunaDecode._attend``'s read of a full layer at one row a lane."""
+    at = layer * BLOCKS + tables
+
+    def read(a):
+        got = a.reshape((LAYERS * BLOCKS,) + a.shape[2:])[at]
+        return got.reshape((got.shape[0], RUNG) + got.shape[3:])
+
+    k_pos = jnp.arange(RUNG, dtype=jnp.int32)[None, None, :]
+    return masked_attention(q[:, None], read(k_pool), read(v_pool), visible(positions[:, None], k_pos, None))[:, 0]
+
+
+_WALK = jax.jit(paged_decode_walk, static_argnames=("kv_limit", "group"))
+
+
+def walk(q, k_pool, v_pool, tables, positions, layer, group):
+    # the layer is an operand: the tests of one dtype and group share a compile
+    return _WALK(q, k_pool, v_pool, tables, positions, jnp.int32(layer), kv_limit=RUNG, group=group)
+
+
+def live_lanes(a):
+    return jnp.delete(a, NULL_LANE, axis=0)
+
+
+@pytest.mark.parametrize("dtype,tol,group,layer", [
+    (jnp.float32, 2e-6, 3, 0), (jnp.float32, 2e-6, 3, 1), (jnp.float32, 2e-6, 8, 1),
+    (jnp.bfloat16, 2e-2, 3, 0), (jnp.bfloat16, 2e-2, 3, 1),
+], ids=["f32-group3-layer0", "f32-group3-layer1", "f32-group8-layer1", "bf16-group3-layer0", "bf16-group3-layer1"])
+def test_the_walk_is_the_gather_and_masked_attention_through_a_permuted_table(dtype, tol, group, layer):
+    """Ragged positions, a group of 3 over walks of 1, 4, 5 and 8 blocks (none a
+    multiple), a layer's blocks at ``index + layer · num_blocks``. float32 to
+    round-off; bfloat16 to the rounding of the scores and of p, which the two
+    place differently."""
+    operands = make(dtype)
+    got = walk(*operands, layer, group)
+    want = twin(*operands, jnp.int32(layer))
+    assert got.dtype == dtype and got.shape == want.shape
+    err = jnp.max(jnp.abs(live_lanes(got).astype(jnp.float32) - live_lanes(want).astype(jnp.float32)))
+    assert float(err) <= tol * float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+    # the other layer's blocks of the same index were not what was read
+    other = twin(*operands, jnp.int32(1 - layer))
+    assert float(jnp.max(jnp.abs(live_lanes(got).astype(jnp.float32) - live_lanes(other).astype(jnp.float32)))) > 0.1
+
+
+@pytest.mark.parametrize("group", [3, 8], ids=lambda g: f"group{g}")
+def test_blocks_past_a_lanes_frontier_are_never_read(group):
+    """Every block no lane's walk reaches, and the null block, hold 1e30: the
+    output of the live lanes is the clean pool's bit for bit. (The twin reads
+    them all and multiplies them by p == 0.)"""
+    q, k_pool, v_pool, tables, positions = make(jnp.float32)
+    clean = walk(q, k_pool, v_pool, tables, positions, 1, group)
+    reached = np.zeros((LAYERS, BLOCKS), bool)
+    for lane, pos in enumerate(POSITIONS):
+        if lane != NULL_LANE:
+            reached[1, np.asarray(tables[lane, :pos // BS + 1])] = True
+    spoil = jnp.asarray(~reached)[:, :, None, None, None]
+    dirty = walk(q, jnp.where(spoil, 1e30, k_pool), jnp.where(spoil, 1e30, v_pool), tables, positions, 1, group)
+    assert bool((live_lanes(dirty) == live_lanes(clean)).all())
+    assert bool(jnp.isfinite(dirty[NULL_LANE]).all())        # 1e30 · weights that sum to 1: a number
+
+
+def test_rows_of_the_last_block_past_the_position_are_masked():
+    """Within the frontier's own block the rows after ``position`` are stale:
+    changing them changes nothing."""
+    q, k_pool, v_pool, tables, positions = make(jnp.float32)
+    lane = 6
+    assert POSITIONS[lane] == BS                             # block 1 holds row 4; its rows 5..7 are stale
+    block = int(tables[lane, 1])
+    stale_k = k_pool.at[0, block, 1:].set(7.0)
+    stale_v = v_pool.at[0, block, 1:].set(-7.0)
+    a = walk(q, k_pool, v_pool, tables, positions, 0, 8)
+    b = walk(q, stale_k, stale_v, tables, positions, 0, 8)
+    assert bool((a == b).all())
+
+
+def test_a_lane_on_the_null_block_walks_one_block_whatever_its_position():
+    """An idle lane keeps stepping its position (``decode_step``, up to
+    ``pos_cap``): the walk is bounded by its table's first entry, and the live
+    lanes read what they read without it."""
+    q, k_pool, v_pool, tables, positions = make(jnp.float32)
+    far = positions.at[NULL_LANE].set(RUNG - 1)
+    a = walk(q, k_pool, v_pool, tables, positions, 0, 3)
+    b = walk(q, k_pool, v_pool, tables, far, 0, 3)
+    assert bool((live_lanes(a) == live_lanes(b)).all())
+    # block 0's rows alone, all of them visible from the far position
+    only = masked_attention(
+        q[NULL_LANE:NULL_LANE + 1, None], k_pool[0, :1].reshape(1, BS, NKV, D), v_pool[0, :1].reshape(1, BS, NKV, D),
+        jnp.ones((1, 1, BS), bool))[0, 0]
+    np.testing.assert_allclose(b[NULL_LANE], only, rtol=2e-6, atol=2e-6)
+
+
+def test_query_heads_that_do_not_divide_are_refused():
+    q, k_pool, v_pool, tables, positions = make(jnp.float32)
+    with pytest.raises(ValueError, match="multiple of kv heads"):
+        paged_decode_walk(q[:, :5], k_pool, v_pool, tables, positions, 0)
+
+
+# ---------------------------------------------------------------------------
+# which read a decode program holds
+# ---------------------------------------------------------------------------
+
+TINY = dataclasses.replace(LAGUNA_CONFIGS["tiny-laguna"], max_seq_len=128)
+
+
+@pytest.mark.parametrize("mode", ["reference", "interpret"])
+def test_the_kernel_mode_decides_which_read_a_decode_program_holds(mode, monkeypatch):
+    """``interpret``: a ``pdecode`` holds one ``pallas_call`` a full layer and no
+    (lanes, rung, kv heads, head) array of the full kind's rows; ``reference``
+    keeps the gather (the CPU tier's twin). A block of rows (``psfx``), a window
+    layer and an int8 pool keep it in either mode."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    model = decode_model_for(TINY)
+    full, window = model.cache_kinds
+    walks = mode == "interpret"
+    assert model.decode_read(full) == ("kernel" if walks else "gather")
+    assert model.decode_read(window) == "gather" and model.decode_read(full, quantized=True) == "gather"
+    assert model.decode_read(CacheKind("rows", 5, None)) == model.decode_read(full)
+    params = jax.eval_shape(LagunaForCausalLM(TINY).init, jax.random.key(0))
+    lanes, rung, bs = 3, 64, 4
+    gathered = f"[{lanes},{rung},{TINY.num_kv_heads},{TINY.head_dim}]"
+
+    def programs(pool):
+        tables = jnp.zeros((lanes, rung // bs), jnp.int32)
+        rings = jnp.zeros((lanes, 6), jnp.int32)
+        step = jax.make_jaxpr(lambda p, c: model.decode_step(
+            p, c, jnp.zeros((lanes,), jnp.int32), jnp.zeros((lanes,), jnp.int32), tables,
+            kv_limit=rung, window_tables=rings))(params, pool)
+        chunk = jax.make_jaxpr(lambda p, c: model.forward(
+            p, c, jnp.zeros((lanes, 8), jnp.int32), jnp.zeros((lanes,), jnp.int32), block_tables=tables,
+            kv_limit=rung, window_tables=rings))(params, pool)
+        return str(step), str(chunk)
+
+    step, chunk = programs(jax.eval_shape(lambda: model.init_paged_cache(20, bs, window_blocks=19)))
+    # the window layers' ring is 24 rows, never the rung: the shape is the full kind's alone
+    assert step.count("pallas_call") == (TINY.layers_of("full") if walks else 0)
+    assert (gathered in step) == (not walks)
+    assert "pallas_call" not in chunk and gathered in chunk
+    step, _ = programs(jax.eval_shape(lambda: model.init_paged_cache(20, bs, kv_cache_dtype="int8", window_blocks=19)))
+    assert "pallas_call" not in step and gathered in step
