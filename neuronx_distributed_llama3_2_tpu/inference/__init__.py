@@ -35,6 +35,7 @@ from neuronx_distributed_llama3_2_tpu.inference.model import (
     PagedKVCache,
     RetentionDecode,
     SarvamDecode,
+    XingDecode,
     StateCache,
     decode_model_for,
 )
@@ -85,6 +86,7 @@ __all__ = [
     "MixedKVCache",
     "RetentionDecode",
     "SarvamDecode",
+    "XingDecode",
     "StateCache",
     "SamplingConfig",
     "decode_model_for",

@@ -261,6 +261,12 @@ class LlamaDecode:
         kernel (:class:`RetentionDecode`); the engine counts such dispatches."""
         return False
 
+    def residual_row_bytes(self) -> Optional[int]:
+        """Bytes a token's state takes between layers where the layer loop
+        carries more than one (b, t, H) array (:class:`XingDecode`), else
+        None; the traced engine's ``setup`` record says it."""
+        return None
+
     def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
         """How a decode step (one fresh row a lane) reads ``kind``'s rows:
         ``"kernel"`` — a Pallas call reads the pool where it lies — or
@@ -1495,7 +1501,14 @@ class SarvamDecode(MixtralDecode):
     The leading dense layers are a stack of their own ahead of the expert
     layers' scan, the pool's layer index running through both. The Pallas
     paged kernel reads k/v by head and is never eligible; tree (speculative)
-    blocks and a quantized pool are refused."""
+    blocks and a quantized pool are refused.
+
+    Two families run on it. Sarvam (this class): the query is one matrix and
+    the residual is the plain ``x + F(norm(x))`` (:meth:`_latent_layer`).
+    Xing4.0 (:class:`XingDecode`): the query goes through a normed latent —
+    a fact of the config that ``LatentAttention.project`` reads, so
+    :meth:`_latent_attention` is shared as it stands — and the residual is a
+    multi-stream one, so it overrides :meth:`_latent_layer` and the carry."""
 
     def _model(self):
         from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamForCausalLM
@@ -1591,9 +1604,30 @@ class SarvamDecode(MixtralDecode):
         self, lp, x, pool, layer, sin, cos, pos_block, slots,
         *, context_encode: bool, kv_limit=None, block_tables=None,
     ):
-        """One decoder layer over the latent cache: pool (L, num_blocks,
-        block_size, W) under ``block_tables``, else (L, B, S_max, W); writes
-        the fresh rows at layer ``layer`` and attends (see the class)."""
+        """One decoder layer over the latent cache, the plain residual
+        ``x + F(norm(x))`` around both sub-layers: (x, pool)."""
+        norm = make_norm(self.config)
+        attn_out, pool = self._latent_attention(
+            lp, norm(lp["attn_norm"], x), pool, layer, sin, cos, pos_block, slots,
+            context_encode=context_encode, kv_limit=kv_limit, block_tables=block_tables,
+        )
+        x = x + attn_out
+        return x + self._feed_forward(lp, norm(lp["mlp_norm"], x)), pool
+
+    def _feed_forward(self, lp, h):
+        """The layer's feed-forward over normed h: experts where the layer
+        has them, the dense SwiGLU in a leading layer."""
+        ffn = MixtralDecode._mlp_block if "moe" in lp else LlamaDecode._mlp_block
+        return ffn(self, lp, h)
+
+    def _latent_attention(
+        self, lp, h, pool, layer, sin, cos, pos_block, slots,
+        *, context_encode: bool, kv_limit=None, block_tables=None,
+    ):
+        """The attention sub-layer of normed h (b, t, H) over the latent
+        cache: pool (L, num_blocks, block_size, W) under ``block_tables``,
+        else (L, B, S_max, W); writes the fresh rows at layer ``layer`` and
+        attends (see the class). Returns (the block's output, pool)."""
         from neuronx_distributed_llama3_2_tpu.models.sarvam import (
             LatentAttention,
             absorbed_is_cheaper,
@@ -1602,9 +1636,7 @@ class SarvamDecode(MixtralDecode):
 
         c = self.config
         attn = LatentAttention(c)
-        norm = make_norm(c)
-        t = x.shape[1]
-        h = norm(lp["attn_norm"], x)
+        t = h.shape[1]
         with jax.named_scope("attn"):
             q, rows = attn.project(lp["attn"], h, sin, cos, pos_block)
             with jax.named_scope("kv_write"):
@@ -1649,11 +1681,83 @@ class SarvamDecode(MixtralDecode):
             att = latent_attention(
                 c, lp["attn"]["kv_b"]["kernel"], q, seen, pos_block, absorbed=absorbed
             )
-            attn_out = attn.output(lp["attn"], att)
-        x = x + attn_out
-        h = norm(lp["mlp_norm"], x)
-        ffn = MixtralDecode._mlp_block if "moe" in lp else LlamaDecode._mlp_block
-        return x + ffn(self, lp, h), pool
+            return attn.output(lp["attn"], att), pool
+
+
+@dataclasses.dataclass(frozen=True)
+class XingDecode(SarvamDecode):
+    """Decode-mode Xing4.0 (:mod:`..models.xing`): :class:`SarvamDecode`'s
+    latent cache, pool row, block-wise gather, form rule and write path
+    unchanged — the query comes through its latent inside
+    ``LatentAttention.project`` — under a multi-stream residual. The layer
+    loop's carry is (b, t, ``hc_mult``, H): the embedding enters as equal
+    streams, each sub-layer reads the streams' ``H_pre`` collapse and its
+    output is spread back by ``H_post`` beside the ``H_res`` mix
+    (:class:`..models.xing.HyperConnection`), and the final norm reads the
+    streams' sum. The coefficients are a token's own, so a bucket's padding
+    rows never touch a live row's streams.
+
+    Weights replicate by their specs: ``tp > 1`` is refused here, at
+    construction, not left to fail in a program. Tree blocks are refused as
+    over any latent cache, so the model's next-token-prediction module (a
+    drafter) is not served."""
+
+    # shardlint SL002 — see LlamaDecode: the refusal below reads the same
+    # parallel state the inherited traces do
+    __layout_deps__ = MixtralDecode.__layout_deps__
+
+    def __post_init__(self):
+        from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
+
+        if (
+            parallel_state.model_parallel_is_initialized()
+            and parallel_state.get_tensor_model_parallel_size() > 1
+        ):
+            raise NotImplementedError(
+                "XingDecode under tp > 1: the multi-stream residual and the query "
+                "latent are not worked out under tensor parallelism (every weight "
+                "replicates by its spec); serve this family with tp = 1"
+            )
+
+    def _model(self):
+        from neuronx_distributed_llama3_2_tpu.models.xing import XingForCausalLM
+
+        return XingForCausalLM(self.config)
+
+    def residual_row_bytes(self) -> int:
+        return int(self.config.residual_row_bytes)
+
+    def _run_layers(self, params, cache, x, *args, **kwargs):
+        from neuronx_distributed_llama3_2_tpu.models.xing import (
+            enter_streams,
+            leave_streams,
+        )
+
+        streams, cache = super()._run_layers(
+            params, cache, enter_streams(self.config, x), *args, **kwargs)
+        return leave_streams(streams), cache
+
+    def _latent_layer(
+        self, lp, x, pool, layer, sin, cos, pos_block, slots,
+        *, context_encode: bool, kv_limit=None, block_tables=None,
+    ):
+        """One decoder layer over the latent cache, x the streams
+        (b, t, n, H), a hyper-connection around each sub-layer."""
+        from neuronx_distributed_llama3_2_tpu.models.xing import HyperConnection
+
+        hc = HyperConnection(self.config)
+        norm = make_norm(self.config)
+        x, pool = hc.around(
+            lp["attn_hc"], x,
+            lambda u: self._latent_attention(
+                lp, norm(lp["attn_norm"], u), pool, layer, sin, cos, pos_block, slots,
+                context_encode=context_encode, kv_limit=kv_limit, block_tables=block_tables,
+            ),
+        )
+        x, _ = hc.around(
+            lp["mlp_hc"], x,
+            lambda u: (self._feed_forward(lp, norm(lp["mlp_norm"], u)), None))
+        return x, pool
 
 
 def _kernels_on_one_device() -> bool:
@@ -2243,6 +2347,7 @@ def decode_model_for(config) -> LlamaDecode:
     from neuronx_distributed_llama3_2_tpu.models.laguna import LagunaConfig
     from neuronx_distributed_llama3_2_tpu.models.mixtral import MixtralConfig
     from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamConfig
+    from neuronx_distributed_llama3_2_tpu.models.xing import XingConfig
 
     if isinstance(config, BertConfig):
         raise NotImplementedError(
@@ -2251,6 +2356,8 @@ def decode_model_for(config) -> LlamaDecode:
         )
     if isinstance(config, GPTNeoXConfig):
         return GPTNeoXDecode(config)
+    if isinstance(config, XingConfig):
+        return XingDecode(config)
     if isinstance(config, SarvamConfig):
         return SarvamDecode(config)
     if isinstance(config, BrumbyConfig):

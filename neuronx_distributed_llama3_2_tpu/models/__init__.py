@@ -22,6 +22,11 @@ from neuronx_distributed_llama3_2_tpu.models.sarvam import (  # noqa: F401
     SarvamConfig,
     SarvamForCausalLM,
 )
+from neuronx_distributed_llama3_2_tpu.models.xing import (  # noqa: F401
+    XING_CONFIGS,
+    XingConfig,
+    XingForCausalLM,
+)
 from neuronx_distributed_llama3_2_tpu.models.brumby import (  # noqa: F401
     BRUMBY_CONFIGS,
     BrumbyConfig,
@@ -99,6 +104,12 @@ def model_registry():
         # the catalog publishes no tensor names for sarvam_mla: no HF map
         reg[name] = {
             "config": cfg, "model_cls": SarvamForCausalLM,
+            "from_hf": None, "to_hf": None,
+        }
+    for name, cfg in XING_CONFIGS.items():
+        # as sarvam's: the catalog publishes no tensor names for xing4_0
+        reg[name] = {
+            "config": cfg, "model_cls": XingForCausalLM,
             "from_hf": None, "to_hf": None,
         }
     for name, cfg in BRUMBY_CONFIGS.items():

@@ -32,6 +32,10 @@ config that the blocks read:
   Rotary dimensions pair as *halves* (rotate-half, as everywhere in this
   repo); a published checkpoint's neighbour-paired columns would be permuted
   on load. No HF name map is given: the catalog publishes no tensor names.
+- **The query** is one matrix here (``q_lora_rank = None``: sarvam-105b
+  publishes none). A family whose query goes through a latent of its own
+  (``W_DQ``, an RMSNorm with a learned scale, ``W_UQ``: :mod:`.xing`) sets
+  ``q_lora_rank`` and shares everything else in :class:`LatentAttention`.
 
 The training-side model (:class:`SarvamForCausalLM`) makes the weights and
 runs the expanded form; the paged serving engines run
@@ -78,6 +82,9 @@ class SarvamConfig(MixtralConfig):
     query/key head (``qk_nope_head_dim + qk_rope_head_dim``)."""
 
     kv_lora_rank: int = 512
+    # None: the query is one matrix (sarvam); a width: it goes through a
+    # normed latent of that width (W_DQ, RMSNorm, W_UQ — models/xing.py)
+    q_lora_rank: Optional[int] = None
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -283,8 +290,11 @@ def latent_attention(
 class LatentAttention:
     """The MLA block beside :class:`..llama.LlamaAttention`: same scopes
     (``attn/qkv``, ``rope``, ``sdpa``, ``o_proj``) plus ``latent_down``,
-    ``latent_up`` and ``absorb``. No ``q_lora_rank``: the query is one
-    projection."""
+    ``latent_up`` and ``absorb``. The query is one projection where the
+    config has no ``q_lora_rank`` (sarvam: ``params["q"]``); with one (xing)
+    it is ``q_b · RMSNorm(q_a · h)`` — the down-projection and its norm under
+    ``q_latent``, the up-projection under ``qkv`` — and both the expanded and
+    the absorbed form take that query as they take sarvam's."""
 
     config: SarvamConfig
 
@@ -293,7 +303,7 @@ class LatentAttention:
         kq, ka, kb, ko = jax.random.split(key, 4)
         n, r = c.num_heads, c.kv_lora_rank
         return {
-            "q": {"kernel": default_kernel_init(kq, (c.hidden_size, n * c.head_dim), c.dtype)},
+            **self._init_query(kq),
             "kv_a": {"kernel": default_kernel_init(ka, (c.hidden_size, c.cache_row_width), c.dtype)},
             "kv_norm": {"scale": jnp.ones((r,), jnp.float32)},
             "kv_b": {"kernel": default_kernel_init(
@@ -301,9 +311,26 @@ class LatentAttention:
             "o": {"kernel": default_kernel_init(ko, (n * c.v_head_dim, c.hidden_size), c.dtype)},
         }
 
-    def specs(self) -> Params:
+    def _init_query(self, key: jax.Array) -> Params:
+        c = self.config
+        width = c.num_heads * c.head_dim
+        if c.q_lora_rank is None:
+            return {"q": {"kernel": default_kernel_init(key, (c.hidden_size, width), c.dtype)}}
+        ka, kb = jax.random.split(key)
         return {
-            "q": {"kernel": P(None, None)}, "kv_a": {"kernel": P(None, None)},
+            "q_a": {"kernel": default_kernel_init(ka, (c.hidden_size, c.q_lora_rank), c.dtype)},
+            "q_norm": {"scale": jnp.ones((c.q_lora_rank,), jnp.float32)},
+            "q_b": {"kernel": default_kernel_init(kb, (c.q_lora_rank, width), c.dtype)},
+        }
+
+    def specs(self) -> Params:
+        query = (
+            {"q": {"kernel": P(None, None)}} if self.config.q_lora_rank is None
+            else {"q_a": {"kernel": P(None, None)}, "q_norm": {"scale": P(None)},
+                  "q_b": {"kernel": P(None, None)}}
+        )
+        return {
+            **query, "kv_a": {"kernel": P(None, None)},
             "kv_norm": {"scale": P(None)}, "kv_b": {"kernel": P(None, None, None)},
             "o": {"kernel": P(None, None)},
         }
@@ -315,8 +342,15 @@ class LatentAttention:
         c = self.config
         b, t, _ = h.shape
         r, dn = c.kv_lora_rank, c.qk_nope_head_dim
+        if c.q_lora_rank is not None:
+            with jax.named_scope("q_latent"):
+                h_q = RMSNorm(c.q_lora_rank, c.rms_norm_eps, c.dtype)(
+                    params["q_norm"], h @ params["q_a"]["kernel"])
+            q_kernel = params["q_b"]["kernel"]
+        else:
+            h_q, q_kernel = h, params["q"]["kernel"]
         with jax.named_scope("qkv"):
-            q = (h @ params["q"]["kernel"]).reshape(b, t, c.num_heads, c.head_dim)
+            q = (h_q @ q_kernel).reshape(b, t, c.num_heads, c.head_dim)
         with jax.named_scope("latent_down"):
             ckr = h @ params["kv_a"]["kernel"]
             latent = RMSNorm(r, c.rms_norm_eps, c.dtype)(params["kv_norm"], ckr[..., :r])
@@ -378,17 +412,27 @@ class SarvamDecoderLayer:
             self._name(): self._ffn().specs(),
         }
 
+    def attention(self, params, x, sin, cos, positions):
+        """The attention sub-layer ``F(x)``, its norm inside."""
+        c = self.config
+        return LatentAttention(c)(
+            params["attn"], make_norm(c)(params["attn_norm"], x), sin, cos, positions)
+
+    def feed_forward(self, params, x):
+        """The feed-forward sub-layer ``F(x)``, its norm inside: (y, aux)."""
+        c = self.config
+        h = make_norm(c)(params["mlp_norm"], x)
+        if not self.sparse:
+            return LlamaMLP(c)(params["mlp"], h), jnp.zeros((), jnp.float32)
+        y, router_logits, idx = self._ffn()(params["moe"], h)
+        return y, load_balancing_loss(router_logits, idx, c.num_experts)
+
     def __call__(self, params, x, sin, cos, positions):
         """Returns (x, aux): aux is the layer's load-balancing loss, 0 for a
         dense layer."""
-        c = self.config
-        norm = make_norm(c)
-        x = x + LatentAttention(c)(params["attn"], norm(params["attn_norm"], x), sin, cos, positions)
-        h = norm(params["mlp_norm"], x)
-        if not self.sparse:
-            return x + LlamaMLP(c)(params["mlp"], h), jnp.zeros((), jnp.float32)
-        y, router_logits, idx = self._ffn()(params["moe"], h)
-        return x + y, load_balancing_loss(router_logits, idx, c.num_experts)
+        x = x + self.attention(params, x, sin, cos, positions)
+        y, aux = self.feed_forward(params, x)
+        return x + y, aux
 
 
 @dataclasses.dataclass(frozen=True)
@@ -402,12 +446,23 @@ class SarvamForCausalLM:
     def _llama(self) -> LlamaForCausalLM:
         return LlamaForCausalLM(self.config)     # embed / head / final norm / loss tail
 
+    def _layer(self, sparse: bool):
+        return SarvamDecoderLayer(self.config, sparse=sparse)
+
     def _stacks(self):
         c = self.config
         return (
-            ("dense_layers", SarvamDecoderLayer(c, sparse=False), c.first_k_dense),
-            ("layers", SarvamDecoderLayer(c), c.num_layers - c.first_k_dense),
+            ("dense_layers", self._layer(False), c.first_k_dense),
+            ("layers", self._layer(True), c.num_layers - c.first_k_dense),
         )
+
+    def _enter(self, x: jax.Array) -> jax.Array:
+        """What the layer stacks carry, from the embedding (b, s, H)."""
+        return x
+
+    def _leave(self, x: jax.Array) -> jax.Array:
+        """What the final norm reads, from the stacks' carry."""
+        return x
 
     def _embed(self):
         return self._llama()._embed()
@@ -451,7 +506,7 @@ class SarvamForCausalLM:
         b, s = input_ids.shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         sin, cos = self._rope(s)
-        x = self._embed()(params["embed"], input_ids)
+        x = self._enter(self._embed()(params["embed"], input_ids))
         aux = jnp.zeros((), jnp.float32)
         for name, layer, count in self._stacks():
             if count:
@@ -460,7 +515,7 @@ class SarvamForCausalLM:
                 )
                 if layer.sparse:
                     aux = jnp.mean(auxes)
-        return self._norm()(params["final_norm"], x), aux
+        return self._norm()(params["final_norm"], self._leave(x)), aux
 
     def __call__(self, params: Params, input_ids: jax.Array) -> jax.Array:
         return self._logits(params, self._backbone(params, input_ids)[0])

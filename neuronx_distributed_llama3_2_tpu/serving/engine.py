@@ -1144,7 +1144,8 @@ class PagedServingEngine:
         (inference/placement.py), and the largest ``temp_size_in_bytes``
         among the dispatched programs — which is where a per-layer copy of
         a weight shows — and the bytes a token leaves in the pool a layer
-        (``inference.model.cache_row_bytes``). Each record is lowered as it was dispatched and
+        (``inference.model.cache_row_bytes``), and between layers where the
+        residual has several streams (``residual_row_bytes``). Each record is lowered as it was dispatched and
         compiled for its memory analysis (a persistent-cache hit where
         there is a cache, a compile where there is none), so traced
         engines only."""
@@ -1165,6 +1166,10 @@ class PagedServingEngine:
                 **({"cache_row_bytes": cache_row_bytes(self._kind_pool(self.model.cache_kinds[0]))}
                    if self._positional
                    else {"state_bytes_per_lane": cache_block_bytes(self.cache)}),
+                # a multi-stream residual (models/xing.py): bytes a token's
+                # streams take between layers
+                **({"residual_row_bytes": self.model.residual_row_bytes()}
+                   if self.model.residual_row_bytes() is not None else {}),
                 **self._kind_facts(),
             }
 

@@ -109,11 +109,20 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
 # ``attn/full`` and ``attn/window`` wrap the attention block of a layer of
 # that kind — the block's usual children sit below them
 # (``attn/window/kv_read``) and the shared readers book them to ``attn/kv_read``
-# as ever — and ``attn/out_gate`` is the per-head output gate (models/laguna.py).
+# as ever — and ``attn/out_gate`` is the per-head output gate (models/laguna.py);
+# ``attn/q_latent`` is the query's down-projection and its norm, and ``mhc`` —
+# outside every block: the readers' copy of BLOCK_SCOPES is the benchmark's to
+# change, so they book it to the program's root — is the multi-stream residual
+# around a sub-layer: ``mhc/coeff`` (the streams' norm and the three
+# coefficient products), ``mhc/sinkhorn`` (the rounds that project the residual
+# mix) and ``mhc/mix`` (the pre-collapse, the post-spread and the residual mix)
+# (models/xing.py).
 DETAIL_SCOPES = {
+    "": ("mhc",),
     "attn": ("qk_norm", "latent_down", "latent_up", "absorb", "gate", "retention",
-             "full", "window", "out_gate"),
+             "full", "window", "out_gate", "q_latent"),
     "attn/retention": ("expand", "chunk", "step"),
+    "mhc": ("coeff", "sinkhorn", "mix"),
     "moe": ("shared",),
     "moe/experts": ("selective", "all"),
 }
@@ -196,7 +205,8 @@ class EngineTracer:
         # what construction did, written once by a traced engine's prewarm
         # (``_setup_facts``): relaid_leaves, relaid_bytes,
         # program_temp_bytes_max, and cache_row_bytes or — where the cache is
-        # a state a lane — state_bytes_per_lane
+        # a state a lane — state_bytes_per_lane; residual_row_bytes where the
+        # residual has several streams
         self.setup: Dict[str, int] = {}
         # routing counters, one entry per dispatch of a tapped program
         # (moe/tap.py): (step, kind, dispatch paths, pairs computed, live
