@@ -1499,9 +1499,19 @@ class SarvamDecode(MixtralDecode):
     (heads, S, S) tensor.
 
     The leading dense layers are a stack of their own ahead of the expert
-    layers' scan, the pool's layer index running through both. The Pallas
-    paged kernel reads k/v by head and is never eligible; tree (speculative)
-    blocks and a quantized pool are refused.
+    layers' scan, the pool's layer index running through both.
+
+    Which read a decode step (one fresh row a lane under a block table) takes
+    follows from what the program can see (:meth:`decode_read`): where Pallas
+    kernels run on one device, :func:`..kernels.paged_attention_pallas.
+    latent_decode_walk` reads each lane's live blocks where they lie in the
+    pool — a row is key and value at once — between ``W_UK`` folded into q and
+    ``W_UV`` over its output; everywhere else (a block of rows, the dense
+    cache, a mesh, the ``"reference"`` kernel mode) the rung's blocks are
+    gathered through the table and :func:`..models.sarvam.latent_attention`
+    runs over them, the walk's plain twin. ``paged_flash_decode`` reads k/v
+    by head and is never eligible; tree (speculative) blocks and a quantized
+    pool are refused.
 
     Two families run on it. Sarvam (this class): the query is one matrix and
     the residual is the plain ``x + F(norm(x))`` (:meth:`_latent_layer`).
@@ -1561,6 +1571,15 @@ class SarvamDecode(MixtralDecode):
 
     def _paged_kernel_eligible(self, t: int, tree) -> bool:
         return False
+
+    def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
+        """``"kernel"`` where :func:`_kernels_on_one_device` (a latent pool has
+        no quantized form and no window): one row a lane is then attended by
+        ``latent_decode_walk`` over the lane's live blocks. What the program
+        can see decides, no option: a block of several rows (``psfx``), the
+        dense cache, a mesh and the ``"reference"`` mode keep the block-wise
+        gather and ``latent_attention``."""
+        return "kernel" if not quantized and _kernels_on_one_device() else "gather"
 
     # -- forward ----------------------------------------------------------
 
@@ -1630,6 +1649,8 @@ class SarvamDecode(MixtralDecode):
         attends (see the class). Returns (the block's output, pool)."""
         from neuronx_distributed_llama3_2_tpu.models.sarvam import (
             LatentAttention,
+            absorb_output,
+            absorb_query,
             absorbed_is_cheaper,
             latent_attention,
         )
@@ -1637,6 +1658,7 @@ class SarvamDecode(MixtralDecode):
         c = self.config
         attn = LatentAttention(c)
         t = h.shape[1]
+        kv_b = lp["attn"]["kv_b"]["kernel"]
         with jax.named_scope("attn"):
             q, rows = attn.project(lp["attn"], h, sin, cos, pos_block)
             with jax.named_scope("kv_write"):
@@ -1659,6 +1681,23 @@ class SarvamDecode(MixtralDecode):
                     )
                     flat = flat.at[wr_phys].set(stored)
                     pool = flat.reshape(pool.shape)
+            if (
+                t == 1 and block_tables is not None and not context_encode
+                and self.decode_read(self.cache_kinds[0]) == "kernel"
+            ):
+                # one row a lane: the lane's live blocks are read where they
+                # lie, nothing gathered
+                from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+                    latent_decode_walk,
+                )
+
+                q_abs = absorb_query(c, kv_b, q)
+                with jax.named_scope("sdpa"):
+                    o_lat = latent_decode_walk(
+                        q_abs[:, 0], pool, block_tables, pos_block[:, 0], layer,
+                        rank=c.kv_lora_rank, sm_scale=c.softmax_scale(), kv_limit=kv_limit)
+                att = absorb_output(c, kv_b, o_lat[:, None])
+                return attn.output(lp["attn"], att), pool
             if context_encode:
                 # the fresh block alone, expanded (what the training model runs)
                 seen, absorbed = rows, False
@@ -1678,16 +1717,15 @@ class SarvamDecode(MixtralDecode):
                         seen = blocks.reshape(blocks.shape[0], nblk * bs, w)[:, :limit]   # (b, limit, W)
                     seen = seen[..., :c.cache_row_width]
                 absorbed = absorbed_is_cheaper(c, t)
-            att = latent_attention(
-                c, lp["attn"]["kv_b"]["kernel"], q, seen, pos_block, absorbed=absorbed
-            )
+            att = latent_attention(c, kv_b, q, seen, pos_block, absorbed=absorbed)
             return attn.output(lp["attn"], att), pool
 
 
 @dataclasses.dataclass(frozen=True)
 class XingDecode(SarvamDecode):
     """Decode-mode Xing4.0 (:mod:`..models.xing`): :class:`SarvamDecode`'s
-    latent cache, pool row, block-wise gather, form rule and write path
+    latent cache, pool row, decode read (the block walk or the block-wise
+    gather, as :meth:`decode_read` says), form rule and write path
     unchanged — the query comes through its latent inside
     ``LatentAttention.project`` — under a multi-stream residual. The layer
     loop's carry is (b, t, ``hc_mult``, H): the embedding enters as equal

@@ -59,6 +59,11 @@ bs · NKV, D)``, which on the chip is the bytes as they lie; the ``(blocks, bs,
 NKV·D)`` view above is a re-tiling there. ``_decode_kernel``'s variants move
 onto the walk as they are needed (``ROADMAP.md`` S8, D6).
 
+:func:`latent_decode_walk` is the same walk over a latent (MLA) pool
+(``SarvamDecode._latent_attention`` calls it, for sarvam and Xing4.0): a row
+``[c ‖ k_r]`` is key and value at once, so a group lands in **one** buffer and
+is scored whole by the absorbed query and summed over its first ``r`` columns.
+
 The kernel mode (:mod:`.mode`) decides whether the body runs through Mosaic
 or the Pallas interpreter; the real-chip numerics gate lives in
 scripts/tpu_kernel_gate.py.
@@ -691,6 +696,218 @@ def paged_decode_walk(
         tables.astype(jnp.int32), live.astype(jnp.int32), positions.astype(jnp.int32),
         q, k_pool.reshape(nl * nb * bs * nkv, d), v_pool.reshape(nl * nb * bs * nkv, d),
     )
+
+
+# blocks a loop trip of the latent walk copies and scores: 128 blocks of 16
+# rows of 640 are 2.6 MB a buffer (chip sweep, PERF.md section 6, PR 45)
+LATENT_WALK_GROUP = 128
+# blocks whose copies one trip of the issuing loop starts, and awaits as one
+LATENT_WALK_RUN = 16
+
+
+def _latent_walk_kernel(
+    tbl_ref,    # scalar prefetch: (b, nblk) int32 pool blocks, the layer's offset folded in
+    live_ref,   # scalar prefetch: (b,) int32 blocks each lane walks, >= 1
+    pos_ref,    # scalar prefetch: (b,) int32 the query's row
+    q_ref,      # (N, W) this lane's absorbed query heads, zeros past the row's values
+    pool_hbm,   # (pool blocks · bs, W) the pool where it lies (HBM)
+    o_ref,      # (N, VW) the probabilities over the rows' first VW columns
+    buf,        # (2, group · bs, W) VMEM: group n + 1 lands while n is scored
+    sem,        # DMA semaphores (buffer)
+    slot_ref,   # SMEM (1,): the buffer the group being scored lies in
+    m_scr, l_scr, acc_scr,
+    *, bs: int, group: int, sm_scale: float,
+):
+    """One lane a grid step, its live blocks a group a loop trip. A row is
+    key and value at once — every head scores it whole and sums its first VW
+    columns — so a trip is one buffer, one run of copies and two dots."""
+    i = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    n = q_ref.shape[0]
+    vw = o_ref.shape[1]
+    span = group * bs
+
+    def copies(lane, grp, slot, start: bool):
+        """Start, or await, the copies of group ``grp`` of ``lane`` into
+        buffer ``slot``: its live blocks and no others, each ``bs`` whole
+        rows, contiguous. ``LATENT_WALK_RUN`` blocks a loop trip, then the
+        rest one by one: a trip's starts are straight-line code (issued one a
+        trip of a loop over the count, the copies cost more scalar time than
+        they took to land) and a trip's blocks are awaited as one — the
+        semaphore counts bytes."""
+        first = grp * group
+        count = jnp.clip(live_ref[lane] - first, 0, group)
+
+        def runs_of(width):
+            def started(j, base):
+                at = pl.multiple_of(tbl_ref[lane, first + base + j] * bs, bs)
+                to = pl.multiple_of((base + j) * bs, bs)
+                pltpu.make_async_copy(
+                    pool_hbm.at[pl.ds(at, bs)], buf.at[slot, pl.ds(to, bs)], sem.at[slot]).start()
+                return base
+
+            def run(k, base):
+                if start:
+                    lax.fori_loop(0, width, started, base + k * width, unroll=True)
+                else:
+                    pltpu.make_async_copy(
+                        pool_hbm.at[pl.ds(0, width * bs)],
+                        buf.at[slot, pl.ds(0, width * bs)], sem.at[slot]).wait()
+                return base
+
+            return run
+
+        wide = min(group, LATENT_WALK_RUN)
+        whole = count // wide
+        lax.fori_loop(0, whole, runs_of(wide), 0)
+        lax.fori_loop(0, count - whole * wide, runs_of(1), whole * wide)
+
+    @pl.when(i == 0)
+    def _first():
+        # rows no copy has filled yet are multiplied by p == 0: they have to
+        # be numbers. After this they hold an earlier group's rows
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    groups = pl.cdiv(live_ref[i], group)
+    # the rows this lane sees: up to its position, and no further than its
+    # walk (a lane on the null block carries any position)
+    seen = jnp.minimum(pos_ref[i] + 1, live_ref[i] * bs)
+    q = q_ref[...]
+    col = lax.broadcasted_iota(jnp.int32, (n, span), 1)
+
+    def trip(grp, carry):
+        # the group to score lies in buffer ``slot`` or is on its way there;
+        # the next one — this lane's, or the next lane's first — is started
+        # into the other before this one is awaited, and lands while it is
+        # scored. ``grp`` -1 is the call's first step: nothing to score yet
+        slot = slot_ref[0]
+        last = grp + 1 == groups
+        lane_next = jnp.where(last, jnp.minimum(i + 1, lanes - 1), i)
+        grp_next = jnp.where(last, 0, grp + 1)
+
+        @pl.when(jnp.logical_not(last & (i + 1 == lanes)))
+        def _():
+            copies(lane_next, grp_next, 1 - slot, True)
+
+        @pl.when(grp >= 0)
+        def _():
+            copies(i, grp, slot, False)
+            sc = lax.dot_general(
+                q, buf[slot].astype(q.dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale             # (N, span)
+            sc = jnp.where(col < seen - grp * span, sc, NEG_INF)
+            m_prev = m_scr[...]
+            # row 0 of the walk is visible, so m is finite from the first
+            # group on and a masked column's p is exp(-1e30 - m) == 0
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)
+            l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+                p.astype(q.dtype), buf[slot, :, :vw].astype(q.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[...] = m_new
+
+        slot_ref[0] = 1 - slot
+        return carry
+
+    lax.fori_loop(jnp.where(i == 0, -1, 0), groups, trip, 0)
+    o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def latent_decode_walk(
+    q_abs: jax.Array,         # (b, N, r + d_r) one absorbed query row a lane
+    pool: jax.Array,          # (L, num_blocks, bs, W): the latent pool, rows [c ‖ k_r ‖ 0…]
+    block_tables: jax.Array,  # (b, T) int32 blocks of a layer; 0 is the null block
+    positions: jax.Array,     # (b,) int32 the query's row
+    layer,                    # scalar: the layer of the pool this read is of
+    *,
+    rank: int,                # r: the row's first columns are the values
+    sm_scale: float,
+    kv_limit: int | None = None,
+    group: int = LATENT_WALK_GROUP,
+) -> jax.Array:
+    """The absorbed decode read of a latent layer: softmax(``sm_scale`` ·
+    q_abs · rowᵀ over rows ``<= positions``) · row[:r], lane by lane over the
+    lane's **live** blocks only, read where they lie. Returns (b, N, r) in
+    q_abs's dtype — ``o_lat``, which ``W_UV`` takes from there.
+
+    :func:`paged_decode_walk`'s walk over a pool whose row is key and value
+    at once: lane ``i`` walks ``positions[i] // bs + 1`` blocks (at most
+    ``kv_limit`` rows' worth), block ``j`` of them at ``block_tables[i, j] +
+    layer · num_blocks`` of the pool taken as one run of ``L · num_blocks``
+    blocks; a lane whose first block is the null block walks that one block
+    whatever position it carries. The pool goes in as ``(blocks · bs, W)`` —
+    the bytes as they lie, a block ``bs`` whole contiguous rows — and a loop
+    trip copies ``group`` blocks into **one** of two VMEM buffers, the next
+    group in flight while this one is scored. The query is padded with zeros
+    to the pool's ``W`` columns, so nothing depends on what a row holds past
+    its values; scores and the online softmax are float32, p is cast to the
+    query's dtype for ``p · row[:r]`` and accumulated in float32 —
+    ``models.sarvam.latent_attention(..., absorbed=True)``'s arithmetic over
+    gathered rows, which is this kernel's plain twin."""
+    d, w = q_abs.shape[-1], pool.shape[-1]
+    if not rank <= d <= w:
+        raise ValueError(f"need rank ({rank}) <= query width ({d}) <= pool row ({w})")
+    return _latent_walk(
+        q_abs, pool, block_tables, positions, jnp.asarray(layer, jnp.int32), rank=rank,
+        sm_scale=sm_scale, kv_limit=kv_limit, group=group, interpret=pallas_interpret())
+
+
+# a jit of its own: a program whose layer stacks each hold the call (the dense
+# layers' scan and the expert layers') traces and lowers the kernel once
+@functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "kv_limit", "group", "interpret"))
+def _latent_walk(q_abs, pool, block_tables, positions, layer, *, rank, sm_scale, kv_limit, group, interpret):
+    b, n, d = q_abs.shape
+    nl, nb, bs, w = pool.shape
+    width = block_tables.shape[1]
+    nblk = width if kv_limit is None else min(width, _ceil_div(kv_limit, bs))
+    live = jnp.where(
+        block_tables[:, 0] == 0, 1, jnp.clip(positions // bs + 1, 1, nblk))
+    tables = block_tables[:, :nblk] + layer * nb
+    # the values in whole lanes: the columns past r of the output are dropped
+    vw = min(w, _ceil_div(rank, 128) * 128)
+    span = group * bs
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((None, n, w), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, n, vw), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((n, vw), jnp.float32),
+        ],
+    )
+    buffers = 2 * span * w * pool.dtype.itemsize
+    o_lat = pl.pallas_call(
+        functools.partial(_latent_walk_kernel, bs=bs, group=group, sm_scale=sm_scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n, vw), q_abs.dtype),
+        # the buffers, a group's rows in the query's dtype, and the (N, span)
+        # float32 scores and what is made of them; lanes in turn, because a
+        # lane starts the next lane's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * buffers + 10 * n * span * 4 + (8 << 20),
+        ),
+        interpret=interpret,
+        name="latent_decode_walk",
+    )(
+        tables.astype(jnp.int32), live.astype(jnp.int32), positions.astype(jnp.int32),
+        jnp.pad(q_abs, ((0, 0), (0, 0), (0, w - d))), pool.reshape(nl * nb * bs, w),
+    )
+    return o_lat[..., :rank]
 
 
 def paged_flash_decode_tp(

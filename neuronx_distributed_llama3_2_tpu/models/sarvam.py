@@ -248,6 +248,22 @@ def _blocked_softmax_attention(q, keys, values, pos_block, scale, einsums):
     return out.swapaxes(0, 1).reshape(b, blocks * QUERY_BLOCK, *out.shape[3:])[:, :t]
 
 
+@jax.named_scope("absorb")
+def absorb_query(config: SarvamConfig, kv_b: jax.Array, q: jax.Array) -> jax.Array:
+    """``W_UK`` folded into q (b, t, N, d_n + d_r): ``[q·W_UK ‖ q_rope]``
+    (b, t, N, r + d_r), which scores a cache row whole."""
+    dn = config.qk_nope_head_dim
+    q_lat = jnp.einsum("btnd,rnd->btnr", q[..., :dn], kv_b[..., :dn])
+    return jnp.concatenate([q_lat, q[..., dn:]], axis=-1)
+
+
+@jax.named_scope("absorb")
+def absorb_output(config: SarvamConfig, kv_b: jax.Array, o_lat: jax.Array) -> jax.Array:
+    """``W_UV`` over o_lat (b, t, N, r), the probabilities' sum of the rows'
+    latents: the block's (b, t, N, d_v)."""
+    return jnp.einsum("btnr,rnd->btnd", o_lat, kv_b[..., config.qk_nope_head_dim:])
+
+
 def latent_attention(
     config: SarvamConfig, kv_b: jax.Array, q: jax.Array, rows: jax.Array,
     pos_block: jax.Array, *, absorbed: bool,
@@ -263,16 +279,13 @@ def latent_attention(
     scale = c.softmax_scale()
     rows = rows.astype(q.dtype)
     if absorbed:
-        with jax.named_scope("absorb"):
-            q_lat = jnp.einsum("btnd,rnd->btnr", q[..., :dn], kv_b[..., :dn])
-            q_abs = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)     # (b,t,N,r+d_r)
+        q_abs = absorb_query(c, kv_b, q)
         with jax.named_scope("sdpa"):
             o_lat = _blocked_softmax_attention(
                 q_abs, rows, rows[..., :r], pos_block, scale,
                 ("btnd,bsd->bnts", "bnts,bsr->btnr"),
             )
-        with jax.named_scope("absorb"):
-            return jnp.einsum("btnr,rnd->btnd", o_lat, kv_b[..., dn:])
+        return absorb_output(c, kv_b, o_lat)
     with jax.named_scope("latent_up"):
         kv = jnp.einsum("bsr,rnd->bsnd", rows[..., :r], kv_b)           # (b,S,N,d_n+d_v)
         k_rope = jnp.broadcast_to(

@@ -78,6 +78,21 @@ Three measurements for the gather-free paged decode path (docs/serving.md):
    reach) for each; the gate is the kernel's distance from the gather.
    ``PERF.md`` section 6 (PR 43) holds the sweep this was written for.
 
+10. **The latent walk alone** (``--latent``, and nothing else runs): one
+   latent layer's absorbed decode read at the two latent cells' shapes —
+   ``xing-longdoc-batch``: 32 lanes of 32 heads, each one of 16 shared
+   14,336-row documents + its own question and reply up to the 16,384-row
+   rung, a pool of 5 x 20,480 blocks; ``sarvam-docqa-batch``: 64 lanes of 64
+   heads, one of 24 shared 2,560-row documents + question and reply up to the
+   3,072-row rung, 5 x 12,288 blocks; bf16 rows of 640, blocks of 16 —
+   ``kernels.paged_attention_pallas.latent_decode_walk`` at each of
+   ``--walk-groups`` blocks a loop trip beside the block-wise gather of the
+   whole rung and the absorbed scores over it
+   (``SarvamDecode._latent_attention``'s read as it was, and the kernel's
+   plain twin). Prints ms a layer and GB/s of *live* pool rows (1,280 B a
+   row) for each; the gate is the kernel's distance from the gather.
+   ``PERF.md`` section 6 (PR 45) holds the sweep this was written for.
+
 Gates (record still prints on failure, like kv_block_bench.py):
 
 - per-``kv_limit`` greedy argmax parity, kernel vs gather
@@ -137,9 +152,17 @@ def build_args(argv=None) -> argparse.Namespace:
                     help="time the decode block walk against the gather + "
                     "scores it replaces, at laguna-mixedlen-batch's shape "
                     "(with --smoke: a tiny one), and nothing else")
-    ap.add_argument("--walk-groups", default="8,16,32,64",
-                    help="blocks a loop trip of the walk, comma-separated")
+    ap.add_argument("--walk-groups", default=None,
+                    help="blocks a loop trip of the walk, comma-separated "
+                    "(8,16,32,64; with --latent powers of two: 16,32,64,128,256)")
+    ap.add_argument("--latent", action="store_true",
+                    help="time the latent decode walk against the gather + "
+                    "absorbed scores it replaces, at xing-longdoc-batch's and "
+                    "sarvam-docqa-batch's shapes (with --smoke: a tiny one), "
+                    "and nothing else; --walk-groups gives the group sizes")
     args = ap.parse_args(argv)
+    if args.walk_groups is None:
+        args.walk_groups = "16,32,64,128,256" if args.latent else "8,16,32,64"
     if args.smoke:
         args.kv_limits = "32"
         args.block_size = 8
@@ -935,6 +958,31 @@ def _fused_ab(config, params, args):
     }
 
 
+def _time_a_layer(read, operands, layers, live_bytes, args):
+    """(the summed output, {ms a layer, GB/s of live bytes}) of ``read(*operands,
+    layer)`` over ``layers`` layers in one jitted program, ``args.iters`` calls
+    after the compile and ``args.warmup`` more."""
+    import jax
+    import jax.numpy as jnp
+
+    def every_layer(*operands):
+        return sum(
+            read(*operands, jnp.int32(layer)).astype(jnp.float32)
+            for layer in range(layers))
+
+    fn = jax.jit(every_layer)
+    out = fn(*operands).block_until_ready()
+    for _ in range(args.warmup):
+        fn(*operands).block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(args.iters):
+        out = fn(*operands)
+    out.block_until_ready()
+    ms = (time.perf_counter() - t0) * 1e3 / args.iters / layers
+    return out, {"ms_a_layer": round(ms, 4),
+                 "live_gb_s": round(live_bytes / ms / 1e6, 1)}
+
+
 def _walk_sweep(args) -> dict:
     """Measurement 9 of the module's list."""
     import jax
@@ -990,23 +1038,8 @@ def _walk_sweep(args) -> dict:
             visible(positions[:, None], k_pos, None))[:, 0]
 
     def timed(read):
-        def both(q, k_pool, v_pool, tables, positions):
-            return sum(
-                read(q, k_pool, v_pool, tables, positions, jnp.int32(layer))
-                .astype(jnp.float32) for layer in range(layers))
-
-        fn = jax.jit(both)
-        operands = (q, k_pool, v_pool, tables, positions)
-        out = fn(*operands).block_until_ready()
-        for _ in range(args.warmup):
-            fn(*operands).block_until_ready()
-        t0 = time.perf_counter()
-        for _ in range(args.iters):
-            out = fn(*operands)
-        out.block_until_ready()
-        ms = (time.perf_counter() - t0) * 1e3 / args.iters / layers
-        return out, {"ms_a_layer": round(ms, 4),
-                     "live_gb_s": round(live_bytes / ms / 1e6, 1)}
+        return _time_a_layer(
+            read, (q, k_pool, v_pool, tables, positions), layers, live_bytes, args)
 
     want, gathered = timed(gather)
     record = {
@@ -1024,6 +1057,95 @@ def _walk_sweep(args) -> dict:
         entry["distance"] = float(jnp.max(jnp.abs(got - want))) / scale
         worst = max(worst, entry["distance"])
         record["walk_by_group"][str(group)] = entry
+    # a bf16 pool: p and the scores are rounded at other places in the two
+    if worst > (2e-2 if dtype == jnp.bfloat16 else 1e-5):
+        record["gate_failure"] = f"walk is {worst:.3g} of the output's scale from the gather"
+    return record
+
+
+# (lanes, heads, pool blocks, rung, shared documents, a document's rows, the
+# lowest and highest question + reply rows) of the cells that run the latent walk
+LATENT_SHAPES = {
+    "xing-longdoc-batch": (32, 32, 20480, 16384, 16, 14336, 64, 2048),
+    "sarvam-docqa-batch": (64, 64, 12288, 3072, 24, 2560, 32, 512),
+}
+
+
+def _latent_sweep(args) -> dict:
+    """Measurement 10 of the module's list."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+        latent_decode_walk,
+    )
+    from neuronx_distributed_llama3_2_tpu.models.sarvam import (
+        _blocked_softmax_attention,
+    )
+
+    if args.smoke:
+        layers, bs, w, r, dr, dtype = 2, 4, 128, 32, 8, jnp.float32
+        shapes = {"smoke": (4, 4, 48, 32, 2, 12, 2, 16)}
+    else:
+        layers, bs, w, r, dr, dtype = 5, 16, 640, 512, 64, jnp.bfloat16
+        shapes = LATENT_SHAPES
+    scale = (128 + dr) ** -0.5
+    groups = [int(x) for x in args.walk_groups.split(",") if x]
+    record = {"latent": True, "seed": args.seed, "platform": jax.default_backend(), "cells": {}}
+    worst = 0.0
+    for cell, (lanes, n, nb, rung, docs, doc_rows, low, high) in shapes.items():
+        rng = np.random.default_rng(args.seed)
+        width = rung // bs
+        contexts = np.minimum(doc_rows + rng.integers(low, high + 1, lanes), rung)
+        positions = jnp.asarray(contexts - 1, jnp.int32)
+        # a document's blocks are shared by the lanes that drew it, a lane's
+        # own rows are its own blocks, all scattered over the pool; past a
+        # lane's frontier the null block, as the engine's table has it
+        free = rng.permutation(np.arange(1, nb))
+        doc_blocks = doc_rows // bs
+        shared, free = free[:docs * doc_blocks].reshape(docs, doc_blocks), free[docs * doc_blocks:]
+        tables = np.zeros((lanes, width), np.int32)
+        for lane, rows in enumerate(contexts):
+            blocks = -(-int(rows) // bs)
+            tables[lane, :doc_blocks] = shared[rng.integers(docs)]
+            own = blocks - doc_blocks
+            tables[lane, doc_blocks:blocks], free = free[:own], free[own:]
+        tables = jnp.asarray(tables)
+        keys = jax.random.split(jax.random.key(args.seed), 2)
+        pool = jax.random.normal(keys[0], (layers, nb, bs, w), dtype)
+        q_abs = jax.random.normal(keys[1], (lanes, n, r + dr), dtype)
+        live_bytes = int(np.sum(-(-contexts // bs)) * bs * w * pool.dtype.itemsize)
+
+        def gather(q_abs, pool, tables, positions, layer):
+            """``SarvamDecode._latent_attention``'s read at t == 1 before the
+            walk: the rung's blocks gathered, the absorbed scores over them."""
+            seen = pool.reshape(layers * nb, bs, w)[layer * nb + tables]
+            seen = seen.reshape(lanes, rung, w)[..., :r + dr]
+            return _blocked_softmax_attention(
+                q_abs[:, None], seen, seen[..., :r], positions[:, None], scale,
+                ("btnd,bsd->bnts", "bnts,bsr->btnr"))[:, 0]
+
+        def timed(read):
+            return _time_a_layer(
+                read, (q_abs, pool, tables, positions), layers, live_bytes, args)
+
+        want, gathered = timed(gather)
+        entry = {
+            "lanes": lanes, "heads": n, "rung": rung,
+            "mean_context": float(contexts.mean()),
+            "live_mb_a_layer": round(live_bytes / 1e6, 2),
+            "gather_and_scores": gathered, "walk_by_group": {},
+        }
+        size = float(jnp.max(jnp.abs(want)))
+        for group in groups:
+            got, walked = timed(
+                lambda *a, group=group: latent_decode_walk(
+                    *a, rank=r, sm_scale=scale, kv_limit=rung, group=group))
+            walked["distance"] = float(jnp.max(jnp.abs(got - want))) / size
+            worst = max(worst, walked["distance"])
+            entry["walk_by_group"][str(group)] = walked
+        record["cells"][cell] = entry
     # a bf16 pool: p and the scores are rounded at other places in the two
     if worst > (2e-2 if dtype == jnp.bfloat16 else 1e-5):
         record["gate_failure"] = f"walk is {worst:.3g} of the output's scale from the gather"
@@ -1158,7 +1280,10 @@ def main() -> None:
         )
 
         set_cpu_devices(max(2, args.tp))
-    record = _walk_sweep(args) if args.walk else run_bench(args)
+    if args.latent:
+        record = _latent_sweep(args)
+    else:
+        record = _walk_sweep(args) if args.walk else run_bench(args)
     # the record prints even when a gate fails: a regression must still
     # yield the measured numbers, not just an exception tail
     print(json.dumps(record), flush=True)

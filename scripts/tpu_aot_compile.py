@@ -75,6 +75,11 @@ def main(argv) -> int:
         # every group size of scripts/paged_decode_bench.py --walk
         for group in (8, 16, 64):
             jobs.append((f"{name}-group{group}", cases.walk_case(dtype, group), one))
+    for name, (dtype, shape) in cases.LATENT_WALK_CASES.items():
+        jobs.append((name, cases.latent_walk_case(dtype, shape), one))
+        # every other group size of scripts/paged_decode_bench.py --latent
+        for group in (16, 32, 64, 256):
+            jobs.append((f"{name}-group{group}", cases.latent_walk_case(dtype, shape, group), one))
     for name, kw in cases.TP_CASES.items():
         jobs.append(
             (name, cases.paged_case(mesh=tp_state.mesh, **kw), replicated)

@@ -220,6 +220,44 @@ def walk_case(pool_dtype, group=None):
 WALK_CASES = {"decode-walk-bf16": jnp.bfloat16, "decode-walk-f32": jnp.float32}
 
 
+def latent_walk_case(pool_dtype, shape, group=None):
+    """(fn, avals) for the latent decode walk at a latent cell's shape —
+    ``xing-longdoc-batch``: 32 lanes of 32 heads over the 16,384-row rung of a
+    20,480-block pool; ``sarvam-docqa-batch``: 64 lanes of 64 heads over the
+    3,072-row rung of a 12,288-block one — 5 layers of blocks of 16 rows of 640,
+    the query the row's 576 values wide."""
+    from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+        LATENT_WALK_GROUP,
+        latent_decode_walk,
+    )
+
+    lanes, n, blocks, rung = LATENT_SHAPES[shape]
+    layers, bs, w, r, dr = 5, 16, 640, 512, 64
+    avals = [
+        jax.ShapeDtypeStruct((lanes, n, r + dr), jnp.bfloat16),
+        jax.ShapeDtypeStruct((layers, blocks, bs, w), pool_dtype),
+        jax.ShapeDtypeStruct((lanes, rung // bs), jnp.int32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+    ]
+
+    def fn(q_abs, pool, tables, positions, layer):
+        return latent_decode_walk(
+            q_abs, pool, tables, positions, layer, rank=r, sm_scale=192 ** -0.5,
+            kv_limit=rung, group=group or LATENT_WALK_GROUP)
+
+    return fn, avals
+
+
+# (lanes, heads, pool blocks, rung) of the two cells that run the latent walk
+LATENT_SHAPES = {"longdoc": (32, 32, 20480, 16384), "docqa": (64, 64, 12288, 3072)}
+# the pool's dtype: bfloat16 as served; float32 is the CPU tests' pool
+LATENT_WALK_CASES = {
+    f"latent-walk-{shape}-{name}": (dtype, shape)
+    for shape in LATENT_SHAPES for name, dtype in (("bf16", jnp.bfloat16), ("f32", jnp.float32))
+}
+
+
 def lower_for_tpu(fn, avals):
     return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
 
@@ -263,6 +301,12 @@ def test_retention_step_kernel_lowers_for_tpu(compiled_mode, name):
 def test_decode_walk_kernel_lowers_for_tpu(compiled_mode, name):
     lowered = lower_for_tpu(*walk_case(WALK_CASES[name]))
     assert_mosaic_call(lowered, "paged_decode_walk")
+
+
+@pytest.mark.parametrize("name", LATENT_WALK_CASES)
+def test_latent_walk_kernel_lowers_for_tpu(compiled_mode, name):
+    lowered = lower_for_tpu(*latent_walk_case(*LATENT_WALK_CASES[name]))
+    assert_mosaic_call(lowered, "latent_decode_walk")
 
 
 @pytest.mark.parametrize("name", TP_CASES)

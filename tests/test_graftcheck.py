@@ -81,6 +81,30 @@ def test_gc001_quiet_on_kernel_path(params):
     ) == []
 
 
+@pytest.mark.parametrize("mode", ["reference", "interpret"])
+def test_gc001_on_a_latent_decode_follows_the_read_it_holds(mode, monkeypatch):
+    """A latent model's ``pdecode``: the gather's twin (the ``"reference"``
+    kernel mode) materializes the (lanes, rung, pool row) copy and GC001 names
+    it; where the block walk runs the trace holds no such array."""
+    from neuronx_distributed_llama3_2_tpu.inference.model import decode_model_for
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+    from neuronx_distributed_llama3_2_tpu.models.sarvam import SARVAM_CONFIGS, SarvamForCausalLM
+
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    tiny = dataclasses.replace(SARVAM_CONFIGS["tiny-sarvam"], max_seq_len=64)
+    model = decode_model_for(tiny)
+    latent_params = jax.eval_shape(SarvamForCausalLM(tiny).init, jax.random.key(0))
+    pool = jax.eval_shape(lambda: model.init_paged_cache(16, 8))
+    zeros = jnp.zeros((4,), jnp.int32)
+    closed = jax.make_jaxpr(lambda p, c: model.decode_step(
+        p, c, zeros, zeros, jnp.zeros((4, 8), jnp.int32), kv_limit=32, pos_cap=63))(latent_params, pool)
+    fs = gc.check_no_gather(closed, model.forbidden_gather_shapes(4, 32), "latent-pdecode")
+    if mode == "reference":
+        assert [f.rule for f in fs] == ["GC001"] and str((4, 32, 128)) in fs[0].message
+    else:
+        assert fs == []
+
+
 # ---------------------------------------------------------------- GC002
 
 

@@ -17,6 +17,7 @@ from neuronx_distributed_llama3_2_tpu.inference import (
     ContinuousBatchingEngine, GenerationConfig, InferenceEngine, LatentCache, SarvamDecode,
 )
 from neuronx_distributed_llama3_2_tpu.inference.model import cache_row_bytes, decode_model_for
+from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
 from neuronx_distributed_llama3_2_tpu.models.sarvam import SARVAM_CONFIGS, SarvamForCausalLM
 from neuronx_distributed_llama3_2_tpu.serving import PagedConfig, PagedServingEngine, audit_engine
 
@@ -26,6 +27,9 @@ TINY = dataclasses.replace(
 SIZES = {"lanes": 4, "block_size": 16, "max_seq_len": 64, "pool_blocks": 32,
          "prefill_chunk_tokens": 16, "prefill_buckets": [16], "kv_buckets": [64]}
 TOL = 1e-4
+# the kernel mode decides a decode step's read of the latent pool: the gather and
+# ``latent_attention`` ("reference", this tier's), or the block walk interpreted
+MODES = ("reference", "interpret")
 
 
 @pytest.fixture(scope="module")
@@ -157,6 +161,42 @@ def test_preempt_requeue_in_the_middle_of_a_chunked_prefill(params):
     clean(srv)
 
 
+@pytest.mark.parametrize("mode", MODES)
+def test_mixed_lengths_give_the_dense_slot_engines_tokens_in_either_mode(params, mode, monkeypatch):
+    """More requests than lanes, prompts of 3 to 30 tokens out of step, the
+    decode read the gather or the block walk: token for token the dense slot
+    engine's (whose cache no table reads: the gather's form in either mode)."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    prompts = prompts_of(np.random.default_rng(3), (5, 30, 20, 9, 26, 3))
+    srv = serving(params, new_tokens=8)
+    (kind,) = srv.model.cache_kinds
+    assert srv.model.decode_read(kind) == ("kernel" if mode == "interpret" else "gather")
+    for p in prompts:
+        srv.submit(p)
+    assert srv.run_to_completion() == dense_outputs(params, prompts, 8)
+    clean(srv)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_a_lane_reused_after_a_longer_request_gives_the_dense_slot_engines_tokens(params, mode, monkeypatch):
+    """One lane: the second request's table points at blocks the first left
+    full past its frontier — where the walk reads one of them, it shows here."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    long, short = prompts_of(np.random.default_rng(13), (40, 11))
+    srv = PagedServingEngine(
+        engine(params, max_batch=1), GenerationConfig(max_new_tokens=8),
+        PagedConfig(block_size=4, num_blocks=16, prefill_chunk_tokens=16, prefill_buckets=(16,),
+                    kv_buckets=(64,), enable_prefix_caching=False))
+    srv.submit(long)
+    srv.run_to_completion()
+    held = np.abs(np.asarray(srv.cache.kv)[0, 1:]).sum(-1) > 0
+    assert held.sum() >= 40                 # the long request's rows are still there
+    second = srv.submit(short)
+    out = srv.run_to_completion()
+    assert out[second] == dense_outputs(params, [short], 8)[0]
+    clean(srv)
+
+
 def test_mixed_traffic_matches_the_dense_slot_engine(params):
     prompts = prompts_of(np.random.default_rng(3), (5, 30, 20, 9, 26, 3))
     srv = serving(params, new_tokens=8)
@@ -167,7 +207,7 @@ def test_mixed_traffic_matches_the_dense_slot_engine(params):
     clean(srv)
 
 
-def test_a_traced_engine_records_the_row_and_the_held_pairs(params):
+def test_a_traced_engine_records_the_row_and_the_held_pairs(params, monkeypatch):
     srv = serving(params, trace_enabled=True, prewarm=True)
     for p in prompts_of(np.random.default_rng(2), (20, 33)):
         srv.submit(p)
@@ -181,3 +221,8 @@ def test_a_traced_engine_records_the_row_and_the_held_pairs(params):
     records = [args for step in tl["steps"] for ph, name, _, _, args in step["events"] if ph == "X"]
     assert any("rows" in a for a in records) and any(a.get("kv_bucket") == 64 for a in records)
     assert any(a.get("kv_bucket") == 0 for a in records if "bucket" in a)
+    kinds = tl["setup"]["cache_kinds"]
+    assert kinds == {"rows": {"layers": 3, "rows_per_lane": None, "row_bytes": 128 * 4, "decode_read": "gather"}}
+    # where Pallas kernels run, the record of an engine built there says the pool is walked
+    monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
+    assert srv._kind_facts()["cache_kinds"]["rows"]["decode_read"] == "kernel"
