@@ -478,7 +478,7 @@ def _flash_attention_bnsd(q, k, v, segment_ids, causal, sm_scale, block_q, block
 
 
 def _fwd_rule(q, k, v, segment_ids, causal, sm_scale, block_q, block_kv):
-    o, lse = _flash_fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_kv)
+    o, lse = _named(*_flash_fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_kv))
     return o, (q, k, v, segment_ids, o, lse)
 
 
@@ -514,3 +514,13 @@ def pallas_flash_attention(
         qt, kt, vt, segment_ids, causal, sm_scale, block_q, block_kv
     )
     return o.transpose(0, 2, 1, 3)
+
+
+def _named(o, lse):
+    """The forward kernel's two outputs under the names a ``jax.checkpoint``
+    policy can keep them by: they are no dot's output, and a policy that does
+    not list them runs ``flash_fwd`` again in the backward pass to make them.
+    (Defined last so that no line a forward-only program traces moves.)"""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return checkpoint_name(o, "flash_out"), checkpoint_name(lse, "flash_lse")

@@ -670,10 +670,10 @@ def _remat_policy(remat: str):
         # 33% fwd recompute), at the cost of ~2·B·S·(H+I)·L bytes of residuals
         # — the fastest policy when the batch fits
         return jax.checkpoint_policies.dots_saveable
-    # "selective": save the big matmul outputs, recompute the rest (attention
-    # scores/softmax, norms) — the analogue of the reference checkpointing
-    # CoreAttention (modeling_llama_nxd.py:214 + run_llama_nxd.py:117)
-    return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    # "selective": save the big matmul outputs and the flash kernel's output +
+    # LSE (named in its forward rule; unlisted, flash_fwd runs again), recompute
+    cp = jax.checkpoint_policies  # the rest: norms, rope, activations, softmax
+    return cp.save_from_both_policies(cp.dots_with_no_batch_dims_saveable, cp.save_only_these_names("flash_out", "flash_lse"))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -21,8 +21,9 @@ task (model.py:1382). Under single-program SPMD all of that collapses to:
   the backward pipeline in reverse — O(M) stored rotation streams;
   ``schedule="1f1b"`` (:meth:`PipelinedCausalLM.loss_and_grad`) executes
   :class:`..pipeline.scheduler.Train1F1BSchedule`'s timing with a manual
-  per-stage VJP inside a single scan — activation stash bounded O(pp)
-  (measured: 284MB vs 480MB at pp=4, M=32, and M-independent);
+  per-stage VJP inside a single scan — each stage forward runs once, its
+  pullback's residuals wait in a ring of 2pp-1 sets: activations bounded
+  O(pp) (measured: 284MB vs 480MB at pp=4, M=32, and M-independent);
   ``schedule="interleaved"`` executes Megatron virtual-pipeline chunking
   as a static chunked-rotation plan (docs/interleaved_vpp.md);
 - **shared embedding** (tied embeddings used by stage 0 and the head) needs
@@ -58,10 +59,10 @@ Params = Dict[str, Any]
 SCHEDULES = ("gpipe", "1f1b", "interleaved")
 
 # One entry per schedule an executor below has traced, in order:
-# ``PipelinedCausalLM.schedule_counters()`` of that trace (Python ints, written
-# at trace time — no device work). The benchmark's ``pipeline_bubble_share``
-# reads the last one; ``make_train_step`` puts the same numbers into the
-# step's metrics.
+# ``PipelinedCausalLM.schedule_counters()`` of that trace and the bytes of the
+# residual ring it laid out (Python ints, written at trace time — no device
+# work). The benchmark's ``pipeline_bubble_share`` reads the last one;
+# ``make_train_step`` puts the same numbers into the step's metrics.
 COMPILED_SCHEDULES: list = []
 
 
@@ -88,6 +89,31 @@ def _seq_slice_bwd(chunk: int, res, dy):
 _seq_slice.defvjp(_seq_slice_fwd, _seq_slice_bwd)
 
 
+def _split_pullback(pullback, invariant):
+    """``(waiting, rebuild)`` of a ``jax.vjp`` pullback taken inside a scan
+    body. The pullback is a pytree; its array leaves are its residuals.
+    ``waiting`` lists those this trace made — what has to be kept if the
+    pullback is to be called in a later trip of the scan. The others are the
+    same in every trip (``invariant``'s own leaves, told by identity: the
+    weights come back as the very tracers that went in; and constants) and
+    stay where they are. ``rebuild(values)`` is the pullback again with
+    ``values`` in the waiting leaves' places."""
+    leaves, treedef = jax.tree.flatten(pullback)
+    same_every_trip = {id(x) for x in jax.tree.leaves(invariant)}
+    waits = [
+        isinstance(x, jax.core.Tracer) and id(x) not in same_every_trip
+        for x in leaves
+    ]
+
+    def rebuild(values):
+        values = iter(values)
+        return jax.tree.unflatten(
+            treedef, [next(values) if w else x for w, x in zip(waits, leaves)]
+        )
+
+    return [x for w, x in zip(waits, leaves) if w], rebuild
+
+
 def _psum_pp(v):
     """psum over the pp axis, CPU-bf16-safe (parallel.layers helper)."""
     from neuronx_distributed_llama3_2_tpu.parallel.layers import (
@@ -111,8 +137,9 @@ class PipelinedCausalLM:
     # "gpipe": fwd scan + autodiff backward — O(M) stashed stage-streams,
     #   lowest bubble (M/(M+pp-1) utilization).
     # "1f1b": single scan doing one fwd + one manual-VJP bwd stage-apply per
-    #   rotation — stashed activations bounded O(pp) (ring of 2pp-1 stage
-    #   inputs) regardless of M, at the cost of pp-1 extra bubble rotations
+    #   rotation — kept activations bounded O(pp) (ring of 2pp-1 sets of the
+    #   stage pullback's residuals: what config.remat saves, a layer)
+    #   regardless of M, at the cost of pp-1 extra bubble rotations
     #   and the head computed in-lane (see loss_and_grad). The memory/compute
     #   tradeoff the reference's Train1F1BSchedule exists for
     #   (pipeline/scheduler.py:157).
@@ -186,8 +213,15 @@ class PipelinedCausalLM:
         ``useful_lane_rotations`` of those ``2 * rotations`` slots hold a
         real micro-batch — the others compute on masked data, which no
         device trace can tell from work. Bubble share =
-        ``1 - useful_lane_rotations / (2 * rotations)``."""
+        ``1 - useful_lane_rotations / (2 * rotations)``.
+
+        ``stage_forwards_per_slot``: how often a micro-batch's stage forward
+        runs, before what the ``remat`` policy re-runs inside the backward —
+        1 where the backward reads the forward's own residuals (autodiff, and
+        the 1F1B executor's ring), 2 where it replays the stage from a stashed
+        input (the interleaved memory-bounded executor)."""
         pp, M, V = self._pp(), self.num_microbatches, self.num_model_chunks
+        replays = False
         if self.schedule == "1f1b":
             rotations = M + 2 * (pp - 1)
         elif self.schedule == "gpipe":
@@ -195,23 +229,47 @@ class PipelinedCausalLM:
         else:
             from neuronx_distributed_llama3_2_tpu.pipeline import scheduler
 
+            replays = self.memory_bounded_backward
             plan = (
-                scheduler.Interleaved1F1BPlan if self.memory_bounded_backward
+                scheduler.Interleaved1F1BPlan if replays
                 else scheduler.InterleavedRotationPlan
             )
             rotations = plan(M, V, pp).num_rotations
         return {
             "rotations": rotations, "useful_lane_rotations": 2 * M * V,
+            "stage_forwards_per_slot": 2 if replays else 1,
         }
 
-    def _note_compiled(self, rotations: int) -> None:
+    def _traced_as(self) -> Dict[str, Any]:
+        """What names this model's entries in :data:`COMPILED_SCHEDULES`."""
+        return {
+            "schedule": self.schedule, "pp": self._pp(),
+            "num_microbatches": self.num_microbatches,
+        }
+
+    def traced_counters(self) -> Dict[str, int]:
+        """:meth:`schedule_counters` with what only a trace knows, from this
+        schedule's newest entry in :data:`COMPILED_SCHEDULES`:
+        ``residual_ring_bytes``, the 1F1B executor's ring of pullback
+        residuals — all ``2·pp - 1`` sets, on one lane, before tensor
+        parallelism splits it; 0 where there is no ring or no trace yet."""
+        mine = self._traced_as().items()
+        newest = next(
+            (c for c in reversed(COMPILED_SCHEDULES) if mine <= c.items()), {}
+        )
+        return {
+            **self.schedule_counters(),
+            "residual_ring_bytes": newest.get("residual_ring_bytes", 0),
+        }
+
+    def _note_compiled(self, rotations: int, residual_ring_bytes: int = 0) -> None:
         """An executor traced ``rotations`` rotations: record the counters,
         which have to be the ones the scan really runs."""
         counters = self.schedule_counters()
         assert counters["rotations"] == rotations, (counters, rotations)
         COMPILED_SCHEDULES.append({
-            "schedule": self.schedule, "pp": self._pp(),
-            "num_microbatches": self.num_microbatches, **counters,
+            **self._traced_as(), **counters,
+            "residual_ring_bytes": residual_ring_bytes,
         })
 
     def _layers_per_stage(self) -> int:
@@ -669,10 +727,17 @@ class PipelinedCausalLM:
         fwd/bwd, cooldown) as a single ``lax.scan`` of ``M + 2(pp-1)``
         rotations inside a pp-manual shard_map. Lane s at rotation t runs
         forward for microbatch ``t - s`` and manual-VJP backward for
-        microbatch ``t - (2(pp-1) - s)``; stage inputs wait in a ring stash
-        of depth ``2pp-1`` — the O(pp) activation bound that is 1F1B's
-        reason to exist (vs this class's gpipe schedule whose autodiff
-        stores O(M) rotation streams).
+        microbatch ``t - (2(pp-1) - s)``. The forward is taken under
+        ``jax.vjp`` and runs once: its pullback's residuals — per layer, the
+        layer's input and whatever ``config.remat``'s policy saves — wait in
+        a ring of depth ``2pp-1`` (written at ``t % D``, read ``2(pp-1-s)``
+        rotations later; the stage's weights are not in it, they are put
+        back when the pullback is rebuilt) — the O(pp) activation bound that
+        is 1F1B's reason to exist (vs this class's gpipe schedule whose
+        autodiff stores O(M) rotation streams). ``remat`` is the one dial:
+        it holds ``2pp-1`` sets (and one in flight) of what it saves, and
+        re-runs in the backward slot what it does not
+        (``traced_counters()["residual_ring_bytes"]``).
 
         Layout choices vs the reference: embedding runs on lane 0 and the
         final-norm/LM-head/CE on lane pp-1 (fixing the advisor's
@@ -693,9 +758,8 @@ class PipelinedCausalLM:
             raise ValueError(f"batch {gbs} not divisible by microbatches {M}")
         mbs = gbs // M
         H = cfg.hidden_size
-        D = 2 * pp - 1  # stash ring depth ≥ max in-flight (2(pp-1)) + 1
+        D = 2 * pp - 1  # ring depth ≥ max in-flight (2(pp-1)) + 1
         T = M + 2 * (pp - 1)
-        self._note_compiled(T)
         mesh = parallel_state.get_parallel_state().mesh
 
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (mbs, S))
@@ -732,10 +796,27 @@ class PipelinedCausalLM:
         def stage_fwd(stage_layers, x):
             return self._scan_stage(stage_layers, x, sin, cos, positions)
 
+        def stage_vjp(stage_layers, x):
+            """``(y, aux)``, the residuals of the pullback that have to wait
+            for the backward slot, and the pullback rebuilt around them."""
+            out, pullback = jax.vjp(stage_fwd, stage_layers, x)
+            return out, *_split_pullback(
+                pullback, (stage_layers, sin, cos, positions)
+            )
+
         def lane_body(stage_layers, head_p, embed_p, ids_all, lab_all):
             """Runs on one pp lane (manual over pp; tp/dp stay auto)."""
             # pp-sharded leaves arrive as (1, L/pp, ...) per lane
             stage_layers = jax.tree.map(lambda p: p[0], stage_layers)
+            # what a rotation's pullback keeps beside these: D sets of it
+            # are the ring, the whole of 1F1B's activation memory
+            ring_avals = jax.eval_shape(
+                lambda w, x: stage_vjp(w, x)[1],
+                stage_layers, jax.ShapeDtypeStruct((mbs, S, H), cfg.dtype),
+            )
+            self._note_compiled(T, residual_ring_bytes=D * sum(
+                r.size * r.dtype.itemsize for r in ring_avals
+            ))
             s = lax.axis_index(PP_AXIS)
             fwd_perm = [(i, (i + 1) % pp) for i in range(pp)]
             bwd_perm = [(i, (i - 1) % pp) for i in range(pp)]
@@ -754,7 +835,7 @@ class PipelinedCausalLM:
             carry0 = {
                 "inbox_fwd": jnp.zeros((mbs, S, H), cfg.dtype),
                 "inbox_bwd": jnp.zeros((mbs, S, H), cfg.dtype),
-                "stash": jnp.zeros((D, mbs, S, H), cfg.dtype),
+                "ring": [jnp.zeros((D, *r.shape), r.dtype) for r in ring_avals],
                 "grads": zeros_g,
                 "loss_sum": jnp.float32(0.0),
                 "aux_sum": jnp.float32(0.0),
@@ -781,10 +862,16 @@ class PipelinedCausalLM:
                 # ---- forward ----
                 x_embed = embed(embed_p, ids_f).astype(cfg.dtype)
                 x_in = jnp.where(is_first, x_embed, carry["inbox_fwd"])
-                stash = lax.dynamic_update_index_in_dim(
-                    carry["stash"], x_in, t % D, axis=0
-                )
-                y, aux_m = stage_fwd(stage_layers, x_in)
+                # the one stage forward of this rotation: taken under
+                # jax.vjp, its pullback's residuals wait in the ring
+                (y, aux_m), waiting, rebuild = stage_vjp(stage_layers, x_in)
+                assert [(w.shape, w.dtype) for w in waiting] == [
+                    (r.shape, r.dtype) for r in ring_avals
+                ], "the pullback's residuals are not the ones the ring was laid out for"
+                ring = [
+                    lax.dynamic_update_index_in_dim(r, w, t % D, axis=0)
+                    for r, w in zip(carry["ring"], waiting)
+                ]
                 aux_sum = carry["aux_sum"] + jnp.where(
                     fwd_valid, aux_m.astype(jnp.float32), 0.0
                 )
@@ -841,15 +928,20 @@ class PipelinedCausalLM:
                 dy_in = jnp.where(
                     is_last, dh.astype(cfg.dtype), carry["inbox_bwd"]
                 )
-                x_saved = lax.dynamic_index_in_dim(
-                    stash, (t - 2 * (pp - 1 - s)) % D, axis=0, keepdims=False
-                )
-                _, stage_vjp = jax.vjp(
-                    lambda w, x: stage_fwd(w, x), stage_layers, x_saved
-                )
+                # the pullback of the forward this lane ran 2(pp-1-s)
+                # rotations ago (the last lane: in this very rotation),
+                # rebuilt around that rotation's residuals. Before there is
+                # one (bwd_valid is false, the result masked) it is rotation
+                # 0's: a ring still at its zeros is no forward's residuals,
+                # and a pullback over them may divide by one (MoE: nan · 0)
+                slot = (t - jnp.minimum(2 * (pp - 1 - s), t)) % D
+                saved = [
+                    lax.dynamic_index_in_dim(r, slot, axis=0, keepdims=False)
+                    for r in ring
+                ]
                 # (dy, daux): the router-aux gradient rides the same stage
                 # VJP as a constant cotangent on the aux output
-                dw, dx = stage_vjp((dy_in, aux_ct))
+                dw, dx = rebuild(saved)((dy_in, aux_ct))
 
                 # embedding bwd on lane 0: dx is d(embed output)
                 _, embed_vjp = jax.vjp(lambda e: embed(e, ids_b), embed_p)
@@ -881,7 +973,7 @@ class PipelinedCausalLM:
                 return {
                     "inbox_fwd": inbox_fwd,
                     "inbox_bwd": inbox_bwd,
-                    "stash": stash,
+                    "ring": ring,
                     "grads": grads,
                     "loss_sum": loss_sum,
                     "aux_sum": aux_sum,

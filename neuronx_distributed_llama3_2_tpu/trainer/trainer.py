@@ -259,13 +259,14 @@ def make_train_step(
         return TrainState(params=new_params, opt=new_opt), metrics
 
     step = jax.jit(train_step, donate_argnums=0)
-    counters = getattr(model, "schedule_counters", None)
+    counters = getattr(model, "traced_counters", None)
     return step if counters is None else _StepWithCounters(step, counters)
 
 
 class _StepWithCounters:
     """The jitted step of a pipelined model, with the schedule's counters
-    (``rotations``, ``useful_lane_rotations``: Python ints, no device work)
+    (``rotations``, ``useful_lane_rotations``, ``stage_forwards_per_slot``,
+    ``residual_ring_bytes``: Python ints, no device work)
     merged into the metrics it returns. Everything else — ``lower``,
     ``trace``, ``_cache_size`` — is the jitted function's own."""
 

@@ -205,10 +205,18 @@ def test_1f1b_schedule_counters(M, pp, bubble):
     model = PipelinedCausalLM(
         LlamaForCausalLM(LLAMA_CONFIGS["tiny"]), num_microbatches=M, schedule="1f1b")
     c = model.schedule_counters()
-    assert c == {"rotations": M + 2 * (pp - 1), "useful_lane_rotations": 2 * M}
+    assert c == {"rotations": M + 2 * (pp - 1), "useful_lane_rotations": 2 * M,
+                 "stage_forwards_per_slot": 1}
     assert 1 - c["useful_lane_rotations"] / (2 * c["rotations"]) == pytest.approx(bubble)
     gpipe = dataclasses.replace(model, schedule="gpipe").schedule_counters()
-    assert gpipe == {"rotations": M + pp - 1, "useful_lane_rotations": 2 * M}
+    assert gpipe == {"rotations": M + pp - 1, "useful_lane_rotations": 2 * M,
+                     "stage_forwards_per_slot": 1}
+    # the interleaved memory-bounded executor still replays a stage from its
+    # stashed input; under autodiff the backward reads the forward's residuals
+    replaying = dataclasses.replace(model, schedule="interleaved", num_model_chunks=1)
+    assert replaying.schedule_counters()["stage_forwards_per_slot"] == 2
+    autodiff = dataclasses.replace(replaying, memory_bounded_backward=False)
+    assert autodiff.schedule_counters()["stage_forwards_per_slot"] == 1
 
 
 def test_train_step_of_a_pipelined_model_returns_the_counters_as_python_ints():
@@ -221,11 +229,17 @@ def test_train_step_of_a_pipelined_model_returns_the_counters_as_python_ints():
     assert (metrics["rotations"], metrics["useful_lane_rotations"]) == (6, 8)
     assert type(metrics["rotations"]) is int and type(metrics["useful_lane_rotations"]) is int
     assert float(metrics["loss"]) > 0
+    # one stage forward a slot, and the ring its residuals wait in: three sets
+    # of at least a stage's layer inputs (2 layers a stage, (1, 16, hidden) f32)
+    ring = metrics["residual_ring_bytes"]
+    assert metrics["stage_forwards_per_slot"] == 1 and type(ring) is int
+    assert ring >= 3 * 2 * 16 * LLAMA_CONFIGS["tiny"].hidden_size * 4
     # the executor recorded the schedule it traced, once; the wrapper passes
     # the jitted step's own attributes through
     assert pipeline_model.COMPILED_SCHEDULES[before:] == [{
         "schedule": "1f1b", "pp": 2, "num_microbatches": 4,
         "rotations": 6, "useful_lane_rotations": 8,
+        "stage_forwards_per_slot": 1, "residual_ring_bytes": ring,
     }]
     assert step._cache_size() == 1 and callable(step.lower)
     # an unpipelined model's step is the plain jitted function, no counters

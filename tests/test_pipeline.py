@@ -15,6 +15,7 @@ import pytest
 from neuronx_distributed_llama3_2_tpu.models.llama import LLAMA_CONFIGS, LlamaForCausalLM
 from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
 from neuronx_distributed_llama3_2_tpu.parallel.layers import shard_pytree
+from neuronx_distributed_llama3_2_tpu.pipeline import model as pipeline_model
 from neuronx_distributed_llama3_2_tpu.pipeline import (
     InferenceSchedule,
     PipelinedCausalLM,
@@ -279,26 +280,29 @@ def test_1f1b_through_trainer():
     assert np.isfinite(losses).all()
 
 
-@pytest.mark.slow
-def test_1f1b_activation_memory_below_gpipe():
+@pytest.mark.parametrize(
+    "remat,seq", [pytest.param("full", 2048, marks=pytest.mark.slow), ("selective", 512)]
+)
+def test_1f1b_activation_memory_below_gpipe(remat, seq):
     """The point of 1F1B (VERDICT #5 done-condition): peak temp memory under
     the manual schedule stays below GPipe's autodiff-stored streams once M
     outgrows pp (measured via XLA's compiled memory analysis; at
     M=32,S=2048,H=256,pp=4 this is ~284MB vs ~480MB, and the 1F1B side is
-    M-independent)."""
+    M-independent). What waits is a ring of 2pp-1 sets of the stage
+    pullback's residuals: under ``selective`` that is more than a stage's
+    input, and still the same at M = 8 and M = 32."""
     cfg = dataclasses.replace(
-        TINY, num_layers=4, remat="full", hidden_size=256, num_heads=4,
-        num_kv_heads=2, intermediate_size=1024, max_seq_len=2048,
+        TINY, num_layers=4, remat=remat, hidden_size=256, num_heads=4,
+        num_kv_heads=2, intermediate_size=1024, max_seq_len=seq,
     )
     parallel_state.initialize_model_parallel(pipeline_model_parallel_size=4)
     model = LlamaForCausalLM(cfg)
-    M = 32
-    ids = jnp.asarray(
-        np.random.default_rng(0).integers(0, cfg.vocab_size, (M, 2048)),
-        jnp.int32,
-    )
-    temps = {}
-    for sched in ["gpipe", "1f1b"]:
+
+    def temp_bytes(sched, M):
+        ids = jnp.asarray(
+            np.random.default_rng(0).integers(0, cfg.vocab_size, (M, seq)),
+            jnp.int32,
+        )
         pm = PipelinedCausalLM(model, num_microbatches=M, schedule=sched)
         params = shard_pytree(pm.to_pipeline(model.init(jax.random.key(0))), pm.specs())
         fn = (
@@ -306,9 +310,88 @@ def test_1f1b_activation_memory_below_gpipe():
             if sched == "gpipe"
             else jax.jit(pm.loss_and_grad)
         )
-        ma = fn.lower(params, ids, ids).compile().memory_analysis()
-        temps[sched] = ma.temp_size_in_bytes
+        return fn.lower(params, ids, ids).compile().memory_analysis().temp_size_in_bytes
+
+    temps = {sched: temp_bytes(sched, 32) for sched in ["gpipe", "1f1b"]}
     assert temps["1f1b"] < 0.8 * temps["gpipe"], temps
+    if remat == "selective":
+        ring = pipeline_model.COMPILED_SCHEDULES[-1]["residual_ring_bytes"]
+        at_8 = temp_bytes("1f1b", 8)
+        assert pipeline_model.COMPILED_SCHEDULES[-1]["residual_ring_bytes"] == ring
+        # what M adds is the (M, S) ids and labels a lane holds, not activations
+        assert 0 <= temps["1f1b"] - at_8 < 0.02 * at_8, (temps, at_8)
+        assert ring > 7 * seq * 256 * 4  # more than seven (1, S, H) stage inputs
+
+
+def _count_eqns(jaxpr, primitive: str) -> int:
+    """``primitive``'s equations in a jaxpr and every jaxpr inside it (a scan
+    body counts once, whatever its trip count)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == primitive
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_eqns(sub, primitive)
+    return n
+
+
+@pytest.mark.parametrize("remat", ["none", "full", "selective"])
+def test_1f1b_runs_each_stage_forward_once(remat):
+    """A rotation of the 1F1B executor holds one ``jax.vjp`` of the stage and
+    no other stage forward: its matmuls are those of that one forward and its
+    pullback (with what ``remat`` re-runs inside the pullback) and the
+    head's — where the executor that replayed the stage from a stashed input
+    had a forward's matmuls more. The ring the residuals wait in is ``2pp-1``
+    sets of exactly what ``remat`` saves: under ``full``, the layers' inputs."""
+    cfg = dataclasses.replace(TINY, remat=remat)
+    pp, M, gbs, seq = 2, 4, 8, 16
+    parallel_state.initialize_model_parallel(pipeline_model_parallel_size=pp)
+    model = LlamaForCausalLM(cfg)
+    pm = PipelinedCausalLM(
+        model, num_microbatches=M, schedule="1f1b", head_sequence_split=False
+    )
+    params = jax.eval_shape(lambda: pm.to_pipeline(model.init(jax.random.key(0))))
+    ids = jax.ShapeDtypeStruct((gbs, seq), jnp.int32)
+    step = jax.make_jaxpr(pm.loss_and_grad)(params, ids, ids)
+
+    mbs = gbs // M
+    sin, cos = model._rope(seq)
+    positions = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), (mbs, seq))
+    stage = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape[1:], p.dtype), params["layers"]
+    )
+    x = jax.ShapeDtypeStruct((mbs, seq, cfg.hidden_size), cfg.dtype)
+
+    def stage_fwd(w, x):
+        return pm._scan_stage(w, x, sin, cos, positions)
+
+    def stage_both(w, x):
+        (y, aux), pullback = jax.vjp(stage_fwd, w, x)
+        return pullback((y, aux))
+
+    def head_both(hp, h, labels):
+        loss, pullback = jax.vjp(lambda hp, h: pm._head_loss_sum(hp, h, labels), hp, h)
+        return pullback(loss)
+
+    dots = lambda fn, *args: _count_eqns(  # noqa: E731
+        jax.make_jaxpr(fn)(*args).jaxpr, "dot_general"
+    )
+    forward = dots(stage_fwd, stage, x)
+    once = dots(stage_both, stage, x) + dots(
+        head_both, pm._head_params(params), x, jax.ShapeDtypeStruct((mbs, seq), jnp.int32)
+    )
+    assert forward > 0 and _count_eqns(step.jaxpr, "dot_general") == once  # parent: once + forward
+
+    traced = pipeline_model.COMPILED_SCHEDULES[-1]
+    assert traced["stage_forwards_per_slot"] == 1
+    assert pm.traced_counters()["residual_ring_bytes"] == traced["residual_ring_bytes"]
+    layer_inputs = (2 * pp - 1) * (cfg.num_layers // pp) * mbs * seq * cfg.hidden_size * 4
+    if remat == "full":
+        assert traced["residual_ring_bytes"] == layer_inputs
+    else:
+        assert traced["residual_ring_bytes"] > layer_inputs
 
 
 # ---------------------------------------------------------------------------
