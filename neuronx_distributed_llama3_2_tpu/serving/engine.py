@@ -391,7 +391,7 @@ class PagedConfig:
     # _register_program, then freeze the registry (mark_steady): no
     # request ever pays a compile in its TTFT, and graftcheck GC007/GC008
     # turn any out-of-catalog or post-freeze compile into a finding.
-    # Supersedes the precompile flag's partial warmup.
+    # False: each program registers and compiles at its first dispatch.
     prewarm: bool = False
     # -- graftmeter: device-cost ledger + SLO burn-rate alerts
     #    (docs/serving.md "Cost accounting & SLOs"; serving/accounting.py,
@@ -515,7 +515,6 @@ class PagedServingEngine:
         engine: InferenceEngine,
         gen: GenerationConfig = GenerationConfig(),
         paged: PagedConfig = PagedConfig(),
-        precompile: bool = True,
         drafter: Optional[Any] = None,
         injector: Optional[FaultInjector] = None,
         policy: Optional[StepPolicy] = None,
@@ -1002,7 +1001,7 @@ class PagedServingEngine:
             else None
         )
         # graftplan certified policy table (analysis/graftplan.py):
-        # loaded before any warmup so a stale artifact fails fast, and
+        # loaded before prewarm so a stale artifact fails fast, and
         # checked against *this* engine's completed ladders (GC011). A
         # caller-supplied policy instance that already carries a table
         # (certification harness) is re-checked the same way.
@@ -1018,8 +1017,6 @@ class PagedServingEngine:
             )
         if paged.prewarm:
             self.prewarm()
-        elif precompile:
-            self._warmup()
 
     # -- programs ----------------------------------------------------------
 
@@ -2116,51 +2113,6 @@ class PagedServingEngine:
                 raise InvariantViolation(violations)
         return violations
 
-    def _warmup(self) -> None:
-        """Compile the decode program per kv bucket and the no-cache prefill
-        per context bucket before traffic. Warmup calls write only into the
-        null block (all-null tables), which is garbage by definition.
-        Suffix-prefill programs (per cached-length bucket pair) still
-        compile lazily on first hit — chunked prefill will collapse that
-        program family."""
-        eng = self.engine
-        key = jax.random.key(0)
-        zeros_b = jnp.zeros((eng.max_batch,), jnp.int32)
-        # fused-sampling trailing args: decode consumes THE residents
-        # (same committed arrays traffic dispatches), prefill takes aval
-        # twins of the per-admission (1,·) sampling uploads
-        d_tail = (
-            (self._d_temps, self._d_topks, self._d_topps, self._d_rng)
-            if self._fused else (key,)
-        )
-        p_tail = (
-            (
-                jnp.zeros((1, 2), jnp.uint32), jnp.zeros((1,), jnp.float32),
-                jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.float32),
-            )
-            if self._fused else (key,)
-        )
-        for kv in self._kv_buckets:
-            fn = self._decode_program(self._decode_cfg(), kv)
-            # positions are donated per call — hand each warmup its own
-            # throwaway array; the resident state itself is untouched
-            args = (
-                eng.params, self.cache, zeros_b,
-                jnp.zeros((eng.max_batch,), jnp.int32), self._d_tables,
-                *d_tail,
-            )
-            if self._check_logits:
-                _, _, _, self.cache = fn(*args, self._nan_mask((), "warmup"))
-            else:
-                _, _, self.cache = fn(*args)
-        table1 = jnp.asarray(self._prefill_table((), None))
-        for bucket in self._prefill_buckets:
-            fn = self._prefill_ctx_program(bucket, self._decode_cfg())
-            _, self.cache = fn(
-                eng.params, self.cache, jnp.zeros((1, bucket), jnp.int32),
-                jnp.ones((1,), jnp.int32), table1, *p_tail,
-            )
-
     def prewarm(self) -> None:
         """Compile the FULL declared catalog (``catalog.prewarm_keys()``)
         before any traffic, then :meth:`mark_steady` — no request ever
@@ -2169,7 +2121,7 @@ class PagedServingEngine:
         the real traffic arguments (every warmup call traces at exactly
         the shapes/dtypes traffic will dispatch at, so the jit trace
         cache holds ONE entry per program afterwards — the GC008
-        re-lower check counts on that). Like ``_warmup``, every dispatch
+        re-lower check counts on that). Every dispatch
         writes only into the null block or rewrites current resident
         values, so token identity is untouched; plain ``jnp`` uploads
         keep the ``h2d_uploads`` choke-point counter at zero. The loop is
@@ -4303,7 +4255,6 @@ def make_serving_engine(
     engine: InferenceEngine,
     gen: GenerationConfig = GenerationConfig(),
     paged: Optional[PagedConfig] = None,
-    precompile: bool = True,
     drafter: Optional[Any] = None,
     injector: Optional[FaultInjector] = None,
 ):
@@ -4319,8 +4270,7 @@ def make_serving_engine(
             ContinuousBatchingEngine,
         )
 
-        return ContinuousBatchingEngine(engine, gen, precompile=precompile)
+        return ContinuousBatchingEngine(engine, gen)
     return PagedServingEngine(
-        engine, gen, paged, precompile=precompile, drafter=drafter,
-        injector=injector,
+        engine, gen, paged, drafter=drafter, injector=injector,
     )

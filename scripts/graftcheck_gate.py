@@ -205,7 +205,6 @@ def _catalog_engine(prewarm=True):
             spec_draft_tokens=4, prefill_chunk_tokens=6,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
         ),
-        precompile=False,
     )
 
 
@@ -239,7 +238,6 @@ def _catalog_fused_engine(prewarm=True):
             fused_step=True,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
         ),
-        precompile=False,
     )
 
 
@@ -274,7 +272,6 @@ def _catalog_spill_engine(prewarm=True):
             restore_crossover=1e9,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
         ),
-        precompile=False,
     )
 
 
@@ -310,7 +307,6 @@ def _catalog_tree_engine(prewarm=True):
             prefill_chunk_tokens=6,
             trace_enabled=True, trace_buffer_steps=64, prewarm=prewarm,
         ),
-        precompile=False,
     )
 
 
@@ -338,7 +334,6 @@ def _catalog_tp2_engine(prewarm=True):
             block_size=8, num_blocks=16, prefill_chunk_tokens=3,
             prewarm=prewarm,
         ),
-        precompile=False,
     )
 
 

@@ -148,7 +148,6 @@ def make_engine_factory():
                 trace_buffer_steps=256, slo_ttft_p99_ms=_TTFT_P99_MS,
             ),
             policy=policy,
-            precompile=False,
         )
         for p, (_, sc, tenant) in zip(prompts, _WORKLOAD):
             eng.submit(p, service_class=sc, tenant=tenant)

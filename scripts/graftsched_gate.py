@@ -123,7 +123,6 @@ def make_engine_factory(mixed: bool = False):
                 trace_buffer_steps=128,
             ),
             policy=policy,
-            precompile=False,
         )
         if mixed:
             for p, (sc, tenant) in zip(prompts, _MIXED_CLASSES):

@@ -115,6 +115,7 @@ def run_spill_leg(args: argparse.Namespace, config, params, gen) -> dict:
                 spill_enabled=spill,
                 host_tier_bytes=(1 << 30) if spill else 0,
                 restore_crossover=1e9 if spill else 1.0,
+                prewarm=True,
             ),
         )
         for p in prompts:
@@ -206,7 +207,9 @@ def run_bench(args: argparse.Namespace) -> dict:
 
     paged = PagedServingEngine(
         fresh_engine(), gen,
-        PagedConfig(block_size=args.block_size, num_blocks=args.num_blocks),
+        PagedConfig(
+            block_size=args.block_size, num_blocks=args.num_blocks, prewarm=True,
+        ),
     )
     for p in prompts:
         paged.submit(p)

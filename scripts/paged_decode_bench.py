@@ -285,6 +285,7 @@ def _stall_ab(config, params, args):
             eng, gen,
             PagedConfig(
                 block_size=args.block_size, num_blocks=num_blocks,
+                prewarm=True,
                 prefill_chunk_tokens=chunk,
             ),
         )
@@ -354,20 +355,14 @@ def _loop_leg(config, params, args):
         eng, gen,
         PagedConfig(
             block_size=args.block_size, num_blocks=num_blocks,
-            trace_enabled=True,
+            trace_enabled=True, prewarm=True,
         ),
     )
-    # graftmeter: the lazily-warmed bench engine harvests explicitly —
-    # before the run so the per-dispatch FLOP fold sees the warmup
-    # programs' profiles, and again after so the ledger/profile count
-    # covers programs first compiled under traffic
-    paged.ensure_cost_profiles()
     for p in prompts:
         paged.submit(p)
     t0 = time.perf_counter()
     paged.run_to_completion()
     wall = time.perf_counter() - t0
-    paged.ensure_cost_profiles()
     snap = paged.metrics.snapshot()
     rec = {
         "loop_steps_per_s": round(paged.metrics.decode_steps / wall, 2),
@@ -432,6 +427,7 @@ def _spec_ab(config, params, args):
             eng, gen,
             PagedConfig(
                 block_size=args.block_size, num_blocks=num_blocks,
+                prewarm=True,
                 spec_draft_tokens=spec_k,
             ),
         )
@@ -508,6 +504,7 @@ def _tree_ab(config, params, args):
             eng, gen,
             PagedConfig(
                 block_size=args.block_size, num_blocks=num_blocks,
+                prewarm=True,
                 spec_draft_tokens=args.spec_draft_tokens,
                 spec_tree=spec_tree,
             ),
@@ -607,7 +604,10 @@ def _tp_ab(config, params, args):
         )
         paged = PagedServingEngine(
             eng, gen,
-            PagedConfig(block_size=args.block_size, num_blocks=num_blocks),
+            PagedConfig(
+                block_size=args.block_size, num_blocks=num_blocks,
+                prewarm=True,
+            ),
         )
         eligible = paged.model._paged_kernel_eligible(1, None)
         for p in prompts:
@@ -719,6 +719,7 @@ def _quant_ab(config, params, args):
             eng, gen,
             PagedConfig(
                 block_size=args.block_size, num_blocks=num_blocks,
+                prewarm=True,
                 kv_cache_dtype=kv_dtype,
             ),
         )
@@ -835,6 +836,7 @@ def _sampling_ab(config, params, args):
             eng, gen,
             PagedConfig(
                 block_size=args.block_size, num_blocks=num_blocks,
+                prewarm=True,
                 on_device_sampling=fused,
             ),
         )
@@ -926,6 +928,7 @@ def _fused_ab(config, params, args):
             eng, gen,
             PagedConfig(
                 block_size=args.block_size, num_blocks=num_blocks,
+                prewarm=True,
                 prefill_chunk_tokens=args.prefill_chunk_tokens,
                 fused_step=fused,
             ),

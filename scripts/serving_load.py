@@ -124,7 +124,6 @@ def make_engine_factory():
                 slo_eval_steps=8,
             ),
             policy=policy,
-            precompile=False,
         )
 
     return factory
@@ -170,7 +169,6 @@ def make_churn_engine(spill: bool):
             host_tier_bytes=(1 << 30) if spill else 0,
             restore_crossover=1e9 if spill else 1.0,
         ),
-        precompile=False,
     )
 
 

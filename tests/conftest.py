@@ -33,7 +33,10 @@ from neuronx_distributed_llama3_2_tpu.utils.runtime import (  # noqa: E402
 
 enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+# no floor on compile time: a tiny engine's programs compile in ~0.2 s each and
+# every test jits its own, so under a floor the tests of one file compiled the
+# same programs again and again — the driver's run starts from an empty cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 import pytest  # noqa: E402
 

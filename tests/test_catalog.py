@@ -25,6 +25,7 @@ from neuronx_distributed_llama3_2_tpu.inference import (
 )
 from neuronx_distributed_llama3_2_tpu.inference import engine as inf_engine
 from neuronx_distributed_llama3_2_tpu.inference.sampling import SamplingConfig
+from neuronx_distributed_llama3_2_tpu.models import model_registry
 from neuronx_distributed_llama3_2_tpu.models.llama import (
     LLAMA_CONFIGS,
     LlamaForCausalLM,
@@ -53,7 +54,6 @@ def _engine(params, *, prewarm=False, **paged_kw):
         ),
         GenerationConfig(max_new_tokens=4),
         PagedConfig(block_size=8, num_blocks=16, prewarm=prewarm, **paged_kw),
-        precompile=False,
     )
 
 
@@ -375,6 +375,67 @@ def test_out_of_catalog_compile_is_caught(params):
     assert rules == ["GC007", "GC008", "GC009"]
 
 
+# --------------------------------- prewarm the catalog, or compile at first dispatch
+
+
+def test_an_engine_that_does_not_prewarm_builds_no_model_program(params):
+    """A default ``PagedConfig`` on a four-rung ladder: construction registers
+    the pool programs it builds itself and traces none of them; ``pctx``,
+    ``psfx`` and ``pdecode`` wait for the dispatch that needs them."""
+    eng = PagedServingEngine(
+        InferenceEngine(TINY, params, max_batch=2, max_seq_len=64, buckets=[8, 16, 32, 64]),
+        GenerationConfig(max_new_tokens=4), PagedConfig(),
+    )
+    assert eng._prefill_buckets == eng._kv_buckets == [8, 16, 32, 64]
+    registry = eng.program_registry()
+    assert {rec.kind for rec in registry.values()} <= {"copy_block", "block_save", "block_restore"}
+    assert all(rec.example_args is None for rec in registry.values())
+    assert eng.metrics.programs_compiled == len(registry) and eng._frozen_keys is None
+    eng.submit([1, 2, 3])
+    eng.run_to_completion()
+    # one rung of each kind the request needed, of the eight the ladder declares
+    kinds = [k[0] for k in eng.program_registry() if k[0] in ("pctx", "psfx", "pdecode")]
+    assert sorted(kinds) == ["pctx", "pdecode"]
+
+
+# the tiny preset of every family a cell serves, and Llama's as the control
+FAMILIES = ("tiny", "tiny-brumby", "tiny-laguna", "tiny-moe", "tiny-olmoe", "tiny-sarvam", "tiny-xing")
+
+
+@pytest.mark.parametrize("preset", FAMILIES)
+def test_prewarmed_and_lazy_engines_of_every_served_family_agree(preset):
+    """The tiny preset of each family a cell serves, one prefill rung and one
+    kv rung: a prewarmed engine serves a short prompt beside a chunked one
+    without compiling; an engine that compiles at first dispatch emits the
+    same tokens from programs the prewarmed catalog holds."""
+    entry = model_registry()[preset]
+    cfg = dataclasses.replace(entry["config"], max_seq_len=64)
+    weights = jax.jit(entry["model_cls"](cfg).init)(jax.random.key(0))
+    inner = InferenceEngine(cfg, weights, max_batch=2, max_seq_len=64)
+    # one block of a state pool is a whole sequence
+    block_size, num_blocks = (64, 6) if preset == "tiny-brumby" else (16, 16)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, size=(n,)).tolist() for n in (5, 40)]
+
+    def serve(prewarm):
+        eng = PagedServingEngine(inner, GenerationConfig(max_new_tokens=4), PagedConfig(
+            block_size=block_size, num_blocks=num_blocks, prefill_chunk_tokens=16,
+            prefill_buckets=(16,), kv_buckets=(64,), prewarm=prewarm))
+        rids = [eng.submit(p) for p in prompts]
+        out = eng.run_to_completion()
+        return eng, [out[r] for r in rids]
+
+    warm, want = serve(True)
+    assert set(warm.program_registry()) == warm.catalog.keys() == warm._frozen_keys
+    assert warm.metrics.steadystate_compiles == 0 and warm.metrics.prefill_chunks >= 3
+    lazy, got = serve(False)
+    assert got == want and all(len(o) == 4 for o in got)
+    dispatched = {k for k, rec in lazy.program_registry().items() if rec.example_args is not None}
+    assert {k[0] for k in dispatched} >= {"pctx", "psfx", "pdecode"}
+    assert dispatched <= warm.catalog.keys()
+    assert lazy._frozen_keys is None and lazy.metrics.prewarm_compiles == 0
+
+
 # ------------------------------------- the ladder of an engine that chunks
 
 TRAFFIC = ("chat-steady", "docs-batch", "rag-batch", "prefix-pressure", "docqa-batch", "longgen-batch")
@@ -398,7 +459,6 @@ def test_a_cells_catalog_holds_no_prefill_rung_above_its_chunks(params, traffic)
             prefill_chunk_tokens=chunk, prefill_buckets=tuple(sizes["prefill_buckets"]),
             kv_buckets=tuple(sizes["kv_buckets"]),
         ),
-        precompile=False,
     )
     rung = cat.pick_bucket(sorted({*sizes["prefill_buckets"], chunk}), chunk)
     assert eng._prefill_buckets[-1] == rung == 512 < max_len
@@ -437,7 +497,6 @@ def test_a_chunked_engine_dispatches_catalog_keys_alone(params):
         InferenceEngine(TINY_KERNEL, params, max_batch=2, max_seq_len=64, buckets=[8, 16, 64]),
         GenerationConfig(max_new_tokens=6),
         PagedConfig(block_size=8, num_blocks=32, prewarm=True, prefill_chunk_tokens=8),
-        precompile=False,
     )
     assert eng._prefill_buckets == [8] and eng._kv_buckets == [8, 16, 64]
     frozen = set(eng.program_registry())

@@ -65,7 +65,7 @@ _ENGINES = {}
 
 
 def _paged(params, gen, paged_cfg, model_cfg=TINY, injector=None,
-           precompile=False, drafter=None, policy=None, **kw):
+           drafter=None, policy=None, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("buckets", [8, 16, 32])
@@ -74,7 +74,7 @@ def _paged(params, gen, paged_cfg, model_cfg=TINY, injector=None,
     if key not in _ENGINES:
         _ENGINES[key] = InferenceEngine(model_cfg, params, **kw)
     return PagedServingEngine(
-        _ENGINES[key], gen, paged_cfg, precompile=precompile,
+        _ENGINES[key], gen, paged_cfg,
         injector=injector, drafter=drafter, policy=policy,
     )
 
@@ -242,7 +242,6 @@ def test_stall_watchdog_names_stuck_work(params):
     paged = _paged(
         params, gen,
         PagedConfig(block_size=8, num_blocks=32, stall_step_limit=3),
-        precompile=False,
     )
     paged.submit([1, 2, 3])
     paged._free_lanes.clear()  # wedge: queued work, no lane can ever open

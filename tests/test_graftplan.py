@@ -526,7 +526,6 @@ def _calibration_factory():
                 trace_buffer_steps=256,
             ),
             policy=policy,
-            precompile=False,
         )
         for p, (_, sc, tenant) in zip(prompts, _CAL_WORKLOAD):
             eng.submit(p, service_class=sc, tenant=tenant)

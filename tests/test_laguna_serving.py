@@ -54,12 +54,12 @@ def engine(params, **kw):
                            buckets=[8, 16, 32, 128], **kw)
 
 
-def serving(params, new_tokens=6, policy=None, max_batch=LANES, precompile=False, **paged):
+def serving(params, new_tokens=6, policy=None, max_batch=LANES, **paged):
     paged = {"block_size": BS, "num_blocks": 140, "prefill_chunk_tokens": CHUNK,
              "prefill_buckets": (8, 16), "kv_buckets": (128,), **paged}
     # programs compile on first use: a test builds the ones it dispatches
     return PagedServingEngine(engine(params, max_batch=max_batch), GenerationConfig(max_new_tokens=new_tokens),
-                              PagedConfig(**paged), policy=policy, precompile=precompile)
+                              PagedConfig(**paged), policy=policy)
 
 
 _REFERENCE = {}
@@ -283,8 +283,7 @@ def test_preempt_and_resume_reproduce_the_tokens(fam, params):
     pa, pb = prompts_of(rng, (8, 30))
     srv = PagedServingEngine(
         engine(params), GenerationConfig(max_new_tokens=8),
-        PagedConfig(block_size=BS, num_blocks=12, decode_reserve_blocks=1, prefill_chunk_tokens=4),
-        precompile=False)
+        PagedConfig(block_size=BS, num_blocks=12, decode_reserve_blocks=1, prefill_chunk_tokens=4))
     preempted, orig = [], srv._preempt
     srv._preempt = lambda req: (preempted.append(req.rid), orig(req))[1]
     ra, rb = srv.submit(pa), srv.submit(pb)
@@ -360,7 +359,7 @@ def test_the_window_pool_is_lanes_times_ring_and_the_accounts_say_so(params):
 
 
 def test_a_traced_engine_records_the_kinds_and_the_window_rows(params, monkeypatch):
-    srv = serving(params, new_tokens=5, precompile=True, trace_enabled=True, prewarm=True)
+    srv = serving(params, new_tokens=5, trace_enabled=True, prewarm=True)
     prompts = prompts_of(np.random.default_rng(2), (20, 3, 50))
     for p in prompts:
         srv.submit(p)

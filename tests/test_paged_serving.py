@@ -189,7 +189,7 @@ def test_submit_validation(params):
     gen = GenerationConfig(max_new_tokens=8)
     paged = PagedServingEngine(
         _engine(params), gen,
-        PagedConfig(block_size=8, num_blocks=6), precompile=False,
+        PagedConfig(block_size=8, num_blocks=6),
     )
     with pytest.raises(ValueError, match="cache capacity"):
         paged.submit(list(range(60)))  # 60 + 8 > max_seq_len 64
@@ -199,20 +199,19 @@ def test_submit_validation(params):
         PagedServingEngine(
             _engine(params), gen,
             PagedConfig(block_size=8, decode_reserve_blocks=0),
-            precompile=False,
         )
 
 
 def test_make_serving_engine_flag(params):
     gen = GenerationConfig(max_new_tokens=4)
     assert isinstance(
-        make_serving_engine(_engine(params), gen, paged=None, precompile=False),
+        make_serving_engine(_engine(params), gen, paged=None),
         ContinuousBatchingEngine,
     )
     assert isinstance(
         make_serving_engine(
             _engine(params), gen,
-            paged=PagedConfig(block_size=8, num_blocks=32), precompile=False,
+            paged=PagedConfig(block_size=8, num_blocks=32),
         ),
         PagedServingEngine,
     )

@@ -35,7 +35,7 @@ def test_pool_bytes_are_the_pools_own(preset):
     params = jax.jit(entry["model_cls"](cfg).init)(jax.random.key(0))
     eng = InferenceEngine(cfg, params, max_batch=2, max_seq_len=64)
     srv = PagedServingEngine(eng, GenerationConfig(max_new_tokens=2), PagedConfig(
-        block_size=16, num_blocks=12, prefill_buckets=(16,), kv_buckets=(64,)), precompile=False)
+        block_size=16, num_blocks=12, prefill_buckets=(16,), kv_buckets=(64,)))
     assert eng._cache is None                        # no dense slot cache beside the pool
     want, dims = POOLS[preset]
     leaves = jax.tree.leaves(srv.cache)
@@ -63,7 +63,7 @@ def test_a_quantized_pool_counts_its_scale_tiles():
     srv = PagedServingEngine(
         InferenceEngine(cfg, params, max_batch=2, max_seq_len=64), GenerationConfig(max_new_tokens=2),
         PagedConfig(block_size=16, num_blocks=12, prefill_buckets=(16,), kv_buckets=(64,),
-                    kv_cache_dtype="int8"), precompile=False)
+                    kv_cache_dtype="int8"))
     assert srv.metrics.pool_bytes_total == kv_pool_bytes_per_rank(
         num_layers=4, num_blocks=12, block_size=16, num_kv_heads=4, head_dim=8, dtype_bytes=1,
         scale_bytes=2, arrays=2)

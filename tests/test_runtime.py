@@ -120,9 +120,9 @@ def test_chip_smoke_last_line_is_ok_and_device_only():
 
 def test_refused_kernel_variant_fails_engine_construction(monkeypatch):
     """Eligibility (``_paged_kernel_eligible``) never asks the compiler, and
-    the constructor compiles the decode programs: a variant Mosaic refuses
-    raises there, in the compiler's words — it cannot fall through to the
-    gather path and serve."""
+    a prewarming constructor (what every cell and a server build) compiles
+    the decode programs: a variant Mosaic refuses raises there, in the
+    compiler's words — it cannot fall through to the gather path and serve."""
     import dataclasses
 
     from neuronx_distributed_llama3_2_tpu.inference import (
@@ -148,4 +148,4 @@ def test_refused_kernel_variant_fails_engine_construction(monkeypatch):
     engine = InferenceEngine(cfg, params, max_batch=2, max_seq_len=64)
     with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
         PagedServingEngine(engine, GenerationConfig(max_new_tokens=2),
-                           PagedConfig(num_blocks=16))
+                           PagedConfig(num_blocks=16, prewarm=True))

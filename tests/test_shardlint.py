@@ -582,6 +582,7 @@ def test_self_lint():
         capture_output=True,
         text=True,
         cwd=REPO_ROOT,
+        timeout=300,
     )
     assert proc.returncode == 0, (
         "shardlint gate failed:\n" + proc.stdout + proc.stderr

@@ -352,7 +352,6 @@ def test_spill_config_validation(params):
             ),
             GenerationConfig(max_new_tokens=2),
             PagedConfig(block_size=8, num_blocks=12, spill_enabled=True),
-            precompile=False,
         )
     with pytest.raises(ValueError, match="prefix"):
         PagedServingEngine(
@@ -364,7 +363,6 @@ def test_spill_config_validation(params):
                 block_size=8, num_blocks=12, spill_enabled=True,
                 host_tier_bytes=1 << 20, enable_prefix_caching=False,
             ),
-            precompile=False,
         )
 
 
@@ -386,7 +384,6 @@ def test_a_recompute_is_priced_as_the_dispatches_chunking_would_run(params, chun
             block_size=8, num_blocks=12, spill_enabled=True, host_tier_bytes=1 << 20,
             prefill_chunk_tokens=chunk,
         ),
-        precompile=False,
     )
     assert eng._prefill_buckets[-1] == (rung if chunk else 64)
     restore_s, recompute_s = eng._restore_price(4096, 24)

@@ -19,7 +19,6 @@ Three layers under test (docs/serving.md "Observability"):
 import dataclasses
 import importlib.util
 import json
-import math
 import os
 
 import jax
@@ -403,41 +402,30 @@ def test_steady_state_stays_resident_with_tracing_on(params, loop):
 
 
 def test_tracing_overhead_smoke(params):
-    """Host scheduling with tracing on stays within 5% (+0.2 ms absolute
-    slack against CPU jitter) of tracing off — the least per-step host ms
-    of six warm rounds on each engine. The rounds alternate between the two
-    engines so that a change in the machine's load falls on both: with the
-    step loop running ahead, the host competes for cores with the CPU
-    "device" it dispatched to, and under the tier-1 run's load one warm
-    round a side was too few (PR 31, PR 34)."""
+    """What the recorder may cost is read as counts, not as CPU milliseconds
+    (ROADMAP D0): over two rounds of the same prompts — the second on warm
+    programs and a filled prefix cache — a traced engine emits the same
+    tokens in the same number of steps and model-program dispatches, with
+    the same uploads, lane syncs and table deltas, as an untraced one."""
     gen = GenerationConfig(max_new_tokens=12)
     prompts = _prompts(np.random.default_rng(4), (6, 9))
 
-    def engine(trace):
-        return _paged(
+    def rounds(trace):
+        paged = _paged(
             params, gen,
             PagedConfig(block_size=8, num_blocks=32, trace_enabled=trace),
         )
+        outs = []
+        for _ in range(2):
+            rids = [paged.submit(p) for p in prompts]
+            done = paged.run_to_completion()
+            outs.append([done[r] for r in rids])
+        m = paged.metrics
+        return (outs, (m.h2d_uploads, m.lane_syncs, m.table_deltas),
+                (m.engine_steps, m.compute_dispatches))
 
-    def round_ms(paged):
-        h0 = paged.metrics.host_schedule_ms
-        s0 = paged.metrics.decode_steps
-        for p in prompts:
-            paged.submit(p)
-        paged.run_to_completion()
-        d_steps = paged.metrics.decode_steps - s0
-        return (paged.metrics.host_schedule_ms - h0) / max(d_steps, 1)
-
-    engines = {False: engine(False), True: engine(True)}
-    for paged in engines.values():
-        for _ in range(2):  # the first two rounds compile
-            round_ms(paged)
-    best = {False: math.inf, True: math.inf}
-    for _ in range(6):
-        for trace, paged in engines.items():
-            best[trace] = min(best[trace], round_ms(paged))
-    on, off = best[True], best[False]
-    assert on <= off * 1.05 + 0.2, (on, off)
+    on, off = rounds(True), rounds(False)
+    assert on == off and off[2][1] > 0
 
 
 # ---------------------------------------------------------------------------
