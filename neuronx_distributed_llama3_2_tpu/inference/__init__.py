@@ -26,6 +26,8 @@ from neuronx_distributed_llama3_2_tpu.inference.engine import (
 )
 from neuronx_distributed_llama3_2_tpu.inference.model import (
     CacheKind,
+    HybridCache,
+    JambaDecode,
     KVCache,
     LagunaDecode,
     LatentCache,
@@ -82,6 +84,8 @@ __all__ = [
     "MllamaDecoder",
     "PagedKVCache",
     "CacheKind",
+    "HybridCache",
+    "JambaDecode",
     "LagunaDecode",
     "MixedKVCache",
     "RetentionDecode",

@@ -216,8 +216,9 @@ class InferenceEngine:
         hidden, cache = model.forward(
             params, cache, ids, positions, slots,
             context_encode=True, return_hidden=True,
-            # a state keeps what a padded row does to it (RetentionDecode)
-            row_live=None if model.cache_is_positional else lengths,
+            # a state keeps what a padded row does to it (RetentionDecode,
+            # JambaDecode's state-space layers)
+            row_live=lengths if model.keeps_state else None,
         )
         # last-token gather before the LM head (model_base.py:444-452)
         last = jnp.take_along_axis(
@@ -631,11 +632,11 @@ def refuse_unless_positional(model, what: str) -> None:
     drops the rejected ones by rewinding the position; rows of a cache are
     overwritten then, a state keeps what they did to it (the paged engine
     refuses ``spec_draft_tokens`` for the same reason)."""
-    if not model.cache_is_positional:
+    if model.keeps_state:
         raise ValueError(
-            f"{what} is not available for {type(model).__name__}: its cache is a "
-            "state per sequence, not rows per token — a rejected draft cannot be "
-            "taken back out of a state"
+            f"{what} is not available for {type(model).__name__}: its cache is — or "
+            "some of its layers keep — a state per sequence, not rows per token — a "
+            "rejected draft cannot be taken back out of a state"
         )
 
 

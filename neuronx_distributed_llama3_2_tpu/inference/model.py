@@ -154,11 +154,18 @@ class CacheKind(NamedTuple):
     """One kind of cache a decode model's layers keep: ``name`` (the field of
     the paged cache that holds it, where the cache has fields by kind),
     ``layers`` of the stack that keep it, and ``rows`` a query of such a layer
-    can see — ``None``: every row of the context."""
+    can see — ``None``: every row of the context, held in the allocator's
+    blocks; a count: the last so many, held in a ring of blocks a lane; 0 with
+    ``state`` set: none — what such a layer keeps of the past is one
+    fixed-size state, held in one slot a lane. A kind with a row count is laid
+    out a lane by the serving engine beside the allocator's pool, sized
+    ``<name>_blocks`` at :meth:`LlamaDecode.init_paged_cache` and addressed
+    through ``<name>_tables`` at ``forward``."""
 
     name: str
     layers: int
     rows: Optional[int]
+    state: bool = False
 
 
 class LatentCache(NamedTuple):
@@ -222,6 +229,60 @@ class StateCache(NamedTuple):
     quantized = False
 
 
+class SsmState(NamedTuple):
+    """What the state-space layers of a stack keep (:mod:`..models.jamba`):
+    ``h`` (L, slots, N, D) — float32 unless asked otherwise, the wide axis
+    minor so that it tiles without padding — and the convolution's tail
+    ``tail`` (L, slots, 16, width): a slot's (K − 1) · D values folded into
+    16 rows of whole 128-lanes (:func:`tail_width`; the places past the
+    values unused), so that a slot is whole tiles. With the slot axis among
+    the two minor ones — as a plain (L, slots, (K − 1) · D) has it, and as
+    the compiler lays out any array whose own two minor axes would pad —
+    seeing every layer's slots as one run is a copy of the pool, twice a
+    layer a call (PERF.md §6, PR 49). Slot 0 is
+    the null slot that idle lanes, lanes mid-prefill beside the decode batch
+    and warm-up calls read and write."""
+
+    h: jax.Array
+    tail: jax.Array
+
+    quantized = False
+
+
+TAIL_ROWS = 16      # rows of a bfloat16 tile: a slot's tail is this many rows of whole lanes
+
+
+def tail_width(values: int) -> int:
+    """Width of the :data:`TAIL_ROWS` rows a slot's ``values`` tail values
+    are folded into, in whole 128-lanes: 15,360 values lie in (16, 1024),
+    the last 1,024 places unused — the room a tile's padding would take."""
+    return 128 * -(-values // (TAIL_ROWS * 128))
+
+
+class HybridCache(NamedTuple):
+    """The cache of a stack that keeps **rows a token** in some layers and **a
+    state a lane** in the others (:class:`JambaDecode`): ``rows`` is the
+    attention layers' block pool — k / v (L_a, num_blocks, block_size,
+    NKV · D), a row's heads side by side (one kv head of 128 would otherwise
+    tile (16, 128) for 1 row in 16), block 0 the null block, or dense
+    (L_a, B, S_max, NKV · D) — and ``state`` the state-space layers'
+    :class:`SsmState`. A request's blocks come from the allocator; its slot is
+    its lane's. There is no quantized form."""
+
+    rows: PagedKVCache
+    state: SsmState
+
+    @property
+    def num_blocks(self) -> int:
+        return self.rows.num_blocks
+
+    @property
+    def block_size(self) -> int:
+        return self.rows.block_size
+
+    quantized = False
+
+
 def cache_block_bytes(cache: Any) -> int:
     """Bytes one block (or slot) of ``cache`` holds over all layers and all of
     its arrays, as the device lays them out (see :func:`cache_row_bytes`)."""
@@ -255,6 +316,14 @@ class LlamaDecode:
     # says False and the engine turns them off (docs/serving.md "Models whose
     # cache is a state").
     cache_is_positional = True
+
+    @property
+    def keeps_state(self) -> bool:
+        """Whether what some layer keeps of the past is a state: the whole
+        cache (``cache_is_positional`` False) or one kind of it
+        (:class:`CacheKind` ``state``). Everything that rests on rows being
+        masked, overwritten or shared by position is then off."""
+        return not self.cache_is_positional or any(kind.state for kind in self.cache_kinds)
 
     def uses_state_kernel(self) -> bool:
         """Whether a decode program of this model holds the one-pass state
@@ -863,7 +932,7 @@ class LlamaDecode:
         pos_cap: Optional[int] = None,
         sampling: Optional[tuple] = None,
         logit_poison: Optional[jax.Array] = None,
-        window_tables: Optional[jax.Array] = None,
+        **kind_tables: jax.Array,
     ) -> Tuple[jax.Array, ...]:
         """One resident-state decode step: T=1 paged forward plus the
         on-device state advance. Returns ``(logits (b, V), new_positions,
@@ -892,14 +961,14 @@ class LlamaDecode:
         Both default to None (static), leaving the host-sampling traces
         bitwise unchanged.
 
-        ``window_tables`` (b, ring blocks): the window kind's table of a model
-        with two kinds of cache (:class:`LagunaDecode`), handed on to its
-        ``forward``; no other model takes one.
+        ``kind_tables``: ``<name>_tables`` (b, blocks a lane) of each kind of
+        cache that is laid out a lane (:class:`CacheKind`: :class:`LagunaDecode`'s
+        ``window_tables``, :class:`JambaDecode`'s ``state_tables``), handed on
+        to the model's ``forward``; a model of one kind takes none.
         """
-        kinds = {} if window_tables is None else {"window_tables": window_tables}
         logits, cache = self.forward(
             params, cache, tokens[:, None], positions, None,
-            block_tables=block_tables, kv_limit=kv_limit, **kinds,
+            block_tables=block_tables, kv_limit=kv_limit, **kind_tables,
         )
         logits = logits[:, 0, :]
         finite = None
@@ -2293,6 +2362,325 @@ class LagunaDecode(MixtralDecode):
         return att, kc, vc
 
 
+@dataclasses.dataclass(frozen=True)
+class JambaDecode(LlamaDecode):
+    """Decode-mode Jamba (:mod:`..models.jamba`): Mamba-1 state-space layers
+    and attention layers in one stack, over a :class:`HybridCache`.
+
+    An attention layer writes and reads the block pool through the lane's
+    ``block_tables``, bounded by ``kv_limit``, a block at a time — **with no
+    rotary table**: q and k are cached and attended as projected. A
+    state-space layer reads and writes its lane's slot of the state kind:
+    the one ``state_tables`` names, or, where none is given, the one the
+    table's first block names (a table as wide as the context then serves
+    both kinds: ``benchmarks/check.py``'s call). A lane whose first fresh row
+    ``block_tables`` sends to the null block — an idle lane of the decode
+    batch, a lane mid-prefill beside it, a warm-up call — goes to the null
+    slot, so the decode program never writes into a state a prefill is
+    building.
+
+    Which form a state-space layer runs follows from the block's shape: one
+    token a lane (``pdecode``) is the step form, one pass a layer over
+    **every slot where it lies** — the lanes' rows go to their slots, the
+    mixer runs in slot order, a slot no lane names comes back bit for bit —
+    so each state is read once and written once and nothing is gathered
+    (lanes fewer than half the slots gather theirs instead); a block of rows
+    is the scan over its lane's slot (:meth:`chunk_scan`: one Mosaic call a
+    layer on one device, a ``lax.scan`` elsewhere) — from the **zero state and a
+    zero tail** under ``context_encode`` (``pctx``: a slot still holds its
+    last request's past), from the slot's otherwise (``psfx``). ``row_live``
+    is the count of real rows of a padded block: rows at or past it leave
+    the state *and* the convolution's tail untouched.
+
+    Both kinds ride the layer loop as its carry, the layer folded into the
+    row index: a donated cache is updated in place and a program's
+    temporaries are the lanes' states of one layer, never the pool. Tree
+    (speculative) blocks and a quantized pool are refused; a rejected draft
+    cannot be taken back out of a state. ``tp > 1`` is not run."""
+
+    def _model(self):
+        from neuronx_distributed_llama3_2_tpu.models.jamba import JambaForCausalLM
+
+        return JambaForCausalLM(self.config)
+
+    # -- cache ------------------------------------------------------------
+
+    @property
+    def cache_kinds(self) -> Tuple[CacheKind, ...]:
+        from neuronx_distributed_llama3_2_tpu.models.jamba import ATTENTION, MAMBA
+
+        c = self.config
+        return (
+            CacheKind("rows", c.layers_of(ATTENTION), None),
+            CacheKind("state", c.layers_of(MAMBA), 0, state=True),
+        )
+
+    def cache_row_dims(self) -> Tuple[int, int, int]:
+        return 2, 1, self.config.num_kv_heads * self.config.head_dim
+
+    def _state(self, slots: int, dtype: Any = None) -> SsmState:
+        from neuronx_distributed_llama3_2_tpu.models.jamba import MAMBA, STATE_DTYPE
+
+        c = self.config
+        lead = (c.layers_of(MAMBA), slots)
+        return SsmState(
+            h=jnp.zeros(lead + (c.mamba_d_state, c.d_inner), dtype or STATE_DTYPE),
+            tail=jnp.zeros(
+                lead + (TAIL_ROWS, tail_width((c.mamba_d_conv - 1) * c.d_inner)), c.dtype),
+        )
+
+    def _rows(self, lead: Tuple[int, int], dtype: Any = None) -> PagedKVCache:
+        from neuronx_distributed_llama3_2_tpu.models.jamba import ATTENTION
+
+        c = self.config
+        shape = (c.layers_of(ATTENTION),) + lead + (c.num_kv_heads * c.head_dim,)
+        dtype = dtype or c.dtype
+        return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+    def init_cache(self, max_batch: int, max_len: int, dtype: Any = None) -> HybridCache:
+        """The dense slot cache: rows (L_a, B, S_max, NKV · D), a state a slot."""
+        return HybridCache(rows=self._rows((max_batch, max_len), dtype), state=self._state(max_batch))
+
+    def init_paged_cache(
+        self, num_blocks: int, block_size: int, dtype: Any = None,
+        kv_cache_dtype: Optional[str] = None, state_blocks: Optional[int] = None,
+    ) -> HybridCache:
+        """``num_blocks`` sizes the attention layers' pool; ``state_blocks``
+        the slots of the state kind (as many again where not given). ``dtype``
+        is both kinds': a state in less than float32 is what the benchmark's
+        check has to fail."""
+        if kv_cache_dtype not in (None, "bf16"):
+            raise NotImplementedError(
+                f"kv_cache_dtype={kv_cache_dtype!r}: a state-space layer's state has no "
+                "quantized form — it is a running sum of every row so far, "
+                "not rows with a scale each"
+            )
+        return HybridCache(
+            rows=self._rows((num_blocks, block_size), dtype),
+            state=self._state(state_blocks or num_blocks, dtype),
+        )
+
+    def paged_cache_specs(self, quantized: bool = False) -> HybridCache:
+        return HybridCache(
+            rows=PagedKVCache(k=P(), v=P()), state=SsmState(h=P(), tail=P()))
+
+    def cache_specs(self, max_batch: Optional[int] = None) -> HybridCache:
+        return self.paged_cache_specs()
+
+    def forbidden_gather_shapes(self, batch: int, kv_limit: int):
+        return set()
+
+    def _paged_kernel_eligible(self, t: int, tree) -> bool:
+        return False
+
+    def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
+        """``"pass"`` for the state kind — one pass a layer over the lanes'
+        slots, every lane's, live or not — and ``"gather"`` for the rows,
+        a block at a time through the table."""
+        return "pass" if kind.state else "gather"
+
+    def chunk_scan(self) -> str:
+        """How a block of rows (``pctx`` / ``psfx``) goes through a
+        state-space layer's recurrence: ``"kernel"`` — one
+        :func:`..kernels.ssm_scan_pallas.ssm_chunk_scan` a layer — where
+        :func:`_kernels_on_one_device`, else ``"loop"``, a ``lax.scan`` a row
+        (a mesh, the ``"reference"`` mode). ``pdecode`` holds no kernel:
+        :meth:`uses_state_kernel` stays False."""
+        return "kernel" if _kernels_on_one_device() else "loop"
+
+    # -- forward ----------------------------------------------------------
+
+    def forward(
+        self, params: Params, cache: HybridCache, tokens: jax.Array, positions: jax.Array,
+        slots: Optional[jax.Array] = None, *, context_encode: bool = False,
+        return_hidden: bool = False, tree=None, kv_limit: Optional[int] = None,
+        block_tables: Optional[jax.Array] = None, row_live: Optional[jax.Array] = None,
+        state_tables: Optional[jax.Array] = None,
+    ) -> Tuple[jax.Array, HybridCache]:
+        """tokens (b, T) at rows ``positions ..`` over ``cache`` (see the
+        class); returns (logits (b, T, V) or the normed hidden, the cache
+        updated)."""
+        if tree is not None:
+            raise NotImplementedError("tree verification over a state-space layer's state")
+        from neuronx_distributed_llama3_2_tpu.models.jamba import (
+            ATTENTION, MAMBA, MambaMixer, layer_runs,
+        )
+        from neuronx_distributed_llama3_2_tpu.models.laguna import scan_run
+        from neuronx_distributed_llama3_2_tpu.models.llama import LlamaAttention
+
+        c = self.config
+        model = self._model()
+        mixer, attn, norm = MambaMixer(c), LlamaAttention(c), make_norm(c)
+        b, t = tokens.shape
+        pos_block = positions[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        if block_tables is None:
+            index = slots = jnp.arange(b, dtype=jnp.int32) if slots is None else slots
+        elif state_tables is None:
+            index = block_tables[:, 0]
+        else:
+            first = jnp.take_along_axis(
+                block_tables, positions[:, None] // cache.block_size, axis=1)[:, 0]
+            index = jnp.where(first == 0, 0, state_tables[:, 0])
+        live = jnp.full((b,), t, jnp.int32) if row_live is None else row_live
+        form = "step" if t == 1 else "scan"
+        scan_kernel = self.chunk_scan() == "kernel"
+        state_slots = cache.state.h.shape[1]
+        # one token a lane over lanes that are most of the slots (a decode
+        # batch): the pass goes over every slot of a layer where it lies,
+        # read once and written once, nothing gathered. A block of rows of
+        # one lane (a prefill) takes its slot out and puts it back, and so do
+        # a few lanes over many slots (``benchmarks/check.py`` decodes one
+        # lane over the engine's pool; the engine's own batch is every lane)
+        every_slot = t == 1 and not context_encode and 2 * b >= state_slots
+        # a slot's tail values, and the places of its folded rows past them
+        values = (c.mamba_d_conv - 1) * c.d_inner
+        unused = math.prod(cache.state.tail.shape[2:]) - values
+
+        def flat(a):        # every layer's slots in one run: never a[layer]
+            return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+
+        def fold(tail):     # (n, K - 1, D) -> a slot's rows of whole lanes, the places past the values zero
+            padded = jnp.pad(tail.reshape(tail.shape[0], -1), ((0, 0), (0, unused)))
+            return padded.reshape((tail.shape[0],) + cache.state.tail.shape[2:])
+
+        def unfold(rows):   # and back
+            flat_rows = rows.reshape(rows.shape[0], -1)[:, :values]
+            return flat_rows.reshape(rows.shape[0], c.mamba_d_conv - 1, c.d_inner)
+
+        def mamba(carry, lp, j, first):
+            x, rows, (h_pool, tail_pool) = carry
+            hn = norm(lp["attn_norm"], x)
+            layer = first + j
+            with jax.named_scope("attn"), jax.named_scope("ssm"):
+                # the states' way out of the pool and back into it is part of
+                # the pass over them: under the form's own scope
+                if every_slot:
+                    # the layer's slots where they lie, in slot order: the
+                    # lanes' rows go to their slots, a slot no lane names has
+                    # no live row and comes back as it was, bit for bit
+                    named = jnp.zeros((state_slots,), jnp.int32).at[index].set(1)
+                    by_slot = jnp.zeros((state_slots,) + hn.shape[1:], hn.dtype).at[index].set(hn)
+                    u, g = mixer.project(lp[MAMBA], by_slot)
+                    with jax.named_scope(form):
+                        h_in = jax.lax.dynamic_index_in_dim(h_pool, layer, 0, keepdims=False)
+                        tail_in = unfold(
+                            jax.lax.dynamic_index_in_dim(tail_pool, layer, 0, keepdims=False))
+                    out, h_out, tail_out = mixer.mix(lp[MAMBA], u, g, h_in, tail_in, named)
+                    with jax.named_scope(form):
+                        h_pool = jax.lax.dynamic_update_index_in_dim(h_pool, h_out, layer, 0)
+                        tail_pool = jax.lax.dynamic_update_index_in_dim(
+                            tail_pool, fold(tail_out), layer, 0)
+                    out = out[index]
+                else:
+                    at = layer * state_slots + index
+                    u, g = mixer.project(lp[MAMBA], hn)
+                    with jax.named_scope(form):
+                        h_in, tail_in = self._carried(
+                            flat(h_pool), flat(tail_pool), at, context_encode, unfold)
+                    out, h_out, tail_out = mixer.mix(
+                        lp[MAMBA], u, g, h_in, tail_in, live, kernel=scan_kernel)
+                    with jax.named_scope(form):
+                        h_pool = flat(h_pool).at[at].set(h_out).reshape(h_pool.shape)
+                        tail_pool = flat(tail_pool).at[at].set(fold(tail_out)).reshape(tail_pool.shape)
+            return x + out, rows, (h_pool, tail_pool)
+
+        def attention(carry, lp, j, first):
+            x, (kc, vc), state = carry
+            hn = norm(lp["attn_norm"], x)
+            with jax.named_scope("attn"):
+                with jax.named_scope("qkv"):
+                    q, k, v = attn._qkv()(lp[ATTENTION]["qkv"], hn)
+                    q = q.reshape(b, t, c.num_heads, c.head_dim)
+                    k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
+                    # no rotary table: q and k go on as projected
+                    q, k = attn._apply_rope(q, k, None, None, pos_block)
+                    k = k.reshape(b, t, -1)
+                att, kc, vc = self._attend_rows(
+                    q, k, v, kc, vc, first + j, pos_block, slots,
+                    context_encode=context_encode, table=block_tables, limit=kv_limit)
+                with jax.named_scope("o_proj"):
+                    out = attn._o()(lp[ATTENTION]["o"], att.reshape(b, t, -1))
+            return x + out, (kc, vc), state
+
+        mixers = {MAMBA: mamba, ATTENTION: attention}
+        x = model._embed()(params["embed"], tokens)
+        x = constrain(x, P(BATCH_AXES, None, None))
+        carry = (x, (cache.rows.k, cache.rows.v), tuple(cache.state))
+        for run in layer_runs(c):
+
+            def body(carry, lp, j, run=run):
+                x, rows, state = mixers[run.kind](carry, lp, j, run.kind_first)
+                hn = norm(lp["mlp_norm"], x)
+                return (x + self._mlp_block(lp, hn), rows, state), None
+
+            carry, _ = scan_run(body, carry, params[run.stack], run)
+        x, rows, state = carry
+        x = norm(params["final_norm"], x)
+        new_cache = HybridCache(rows=PagedKVCache(*rows), state=SsmState(*state))
+        if return_hidden:
+            return x, new_cache
+        return model._logits(params, x), new_cache
+
+    def _carried(self, h_flat, tail_flat, at, fresh: bool, unfold):
+        """What a block of rows starts from, a lane: zeros where ``fresh``
+        (``pctx``: the slot still holds its last request's past), else the
+        state and the tail (``unfold`` of its rows) at ``at`` of the pools
+        seen as one run of slots. h (b, N, D), tail (b, K − 1, D)."""
+        from neuronx_distributed_llama3_2_tpu.models.jamba import MambaMixer
+
+        if fresh:
+            return MambaMixer(self.config).zero_state(at.shape[0], h_flat.dtype, tail_flat.dtype)
+        return h_flat[at], unfold(tail_flat[at])
+
+    def _attend_rows(
+        self, q, k, v, kc, vc, layer, pos_block, slots, *, context_encode: bool, table, limit,
+    ):
+        """Write the fresh rows k, v (b, T, NKV · D) of attention layer
+        ``layer`` at ``pos_block`` and attend q (b, T, N, D) — over the fresh
+        block alone under ``context_encode``, else over the rows read back.
+        kc / vc: the whole pool (L_a, blocks, block_size, NKV · D) read through
+        ``table`` (b, W) a block at a time — a block's rows lie together — or,
+        ``table`` None, the dense cache (L_a, B, S, NKV · D) at ``slots``.
+        ``limit`` bounds the rows read. Returns (att (b, T, N, D), kc, vc)."""
+        from neuronx_distributed_llama3_2_tpu.models.laguna import masked_attention, visible
+        from neuronx_distributed_llama3_2_tpu.models.llama import core_attention
+
+        c = self.config
+        b, t = pos_block.shape
+
+        def heads(a):
+            return a.reshape(a.shape[:2] + (c.num_kv_heads, c.head_dim)).astype(q.dtype)
+
+        with jax.named_scope("kv_write"):
+            if table is None:
+                kc = kc.at[layer, slots[:, None], pos_block].set(k.astype(kc.dtype))
+                vc = vc.at[layer, slots[:, None], pos_block].set(v.astype(vc.dtype))
+            else:
+                nl, nb, bs, width = kc.shape
+                block = jnp.take_along_axis(table, pos_block // bs, axis=1)
+                at = (layer * nb + block) * bs + pos_block % bs
+                kc = kc.reshape(-1, width).at[at].set(k.astype(kc.dtype)).reshape(kc.shape)
+                vc = vc.reshape(-1, width).at[at].set(v.astype(vc.dtype)).reshape(vc.shape)
+        if context_encode:
+            with jax.named_scope("sdpa"):
+                return core_attention(q, heads(k), heads(v), causal=True), kc, vc
+        with jax.named_scope("kv_read"):
+            if table is None:
+                k_all, v_all = kc[layer, slots, :limit], vc[layer, slots, :limit]
+            else:
+                limit = table.shape[1] * bs if limit is None else limit
+                at = layer * nb + table[:, : -(-limit // bs)]
+
+                def read(a):
+                    got = a.reshape(nl * nb, bs, width)[at]             # (b, blocks, bs, W)
+                    return got.reshape(b, -1, width)[:, :limit]
+
+                k_all, v_all = read(kc), read(vc)
+        with jax.named_scope("sdpa"):
+            seen = visible(pos_block, jnp.arange(k_all.shape[1], dtype=jnp.int32), None)
+            return masked_attention(q, heads(k_all), heads(v_all), seen), kc, vc
+
+
 def _pool_pair(pool: PagedKVCache):
     """A pool as the layer loop carries it: (k, v), each a (payload, scale)
     pair where quantized."""
@@ -2382,6 +2770,7 @@ def decode_model_for(config) -> LlamaDecode:
     from neuronx_distributed_llama3_2_tpu.models.bert import BertConfig
     from neuronx_distributed_llama3_2_tpu.models.brumby import BrumbyConfig
     from neuronx_distributed_llama3_2_tpu.models.gptneox import GPTNeoXConfig
+    from neuronx_distributed_llama3_2_tpu.models.jamba import JambaConfig
     from neuronx_distributed_llama3_2_tpu.models.laguna import LagunaConfig
     from neuronx_distributed_llama3_2_tpu.models.mixtral import MixtralConfig
     from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamConfig
@@ -2400,6 +2789,8 @@ def decode_model_for(config) -> LlamaDecode:
         return SarvamDecode(config)
     if isinstance(config, BrumbyConfig):
         return RetentionDecode(config)
+    if isinstance(config, JambaConfig):
+        return JambaDecode(config)
     if isinstance(config, LagunaConfig):
         return LagunaDecode(config)
     if isinstance(config, MixtralConfig):

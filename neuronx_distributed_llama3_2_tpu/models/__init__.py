@@ -27,6 +27,13 @@ from neuronx_distributed_llama3_2_tpu.models.xing import (  # noqa: F401
     XingConfig,
     XingForCausalLM,
 )
+from neuronx_distributed_llama3_2_tpu.models.jamba import (  # noqa: F401
+    JAMBA_CONFIGS,
+    JambaConfig,
+    JambaForCausalLM,
+    params_from_hf_jamba,
+    params_to_hf_jamba,
+)
 from neuronx_distributed_llama3_2_tpu.models.brumby import (  # noqa: F401
     BRUMBY_CONFIGS,
     BrumbyConfig,
@@ -116,6 +123,11 @@ def model_registry():
         reg[name] = {
             "config": cfg, "model_cls": BrumbyForCausalLM,
             "from_hf": params_from_hf_brumby, "to_hf": params_to_hf_brumby,
+        }
+    for name, cfg in JAMBA_CONFIGS.items():
+        reg[name] = {
+            "config": cfg, "model_cls": JambaForCausalLM,
+            "from_hf": params_from_hf_jamba, "to_hf": params_to_hf_jamba,
         }
     for name, cfg in LAGUNA_CONFIGS.items():
         reg[name] = {

@@ -434,7 +434,11 @@ class LlamaAttention:
 
     def _apply_rope(self, q, k, sin, cos, positions):
         """Full-head-dim rotate-half RoPE; partial-rotary families
-        (GPT-NeoX/CodeGen) override."""
+        (GPT-NeoX/CodeGen) override. ``sin`` None: a block with no positional
+        term at all (models/jamba.py, whose state-space layers carry the
+        order) — q and k go on as they were projected, and no table exists."""
+        if sin is None:
+            return q, k
         return apply_rope(q, sin, cos, positions), apply_rope(k, sin, cos, positions)
 
     # the device-trace scopes of an attention block (serving/tracing.py

@@ -145,12 +145,15 @@ class EngineDims:
     # row (``cache_is_positional`` of the decode model): a program then moves
     # whole blocks — the sequences it touches, read and written
     cache_is_positional: bool = True
-    # bytes a lane's ring holds on a rank, over the layers that keep only
-    # their last rows (``cache_kinds`` of the decode model); 0 where every
-    # layer keeps the whole context. ``block_bytes`` and ``kv_row_bytes`` are
-    # then the other layers' alone: a request's cache bytes are its context's
-    # rows at ``kv_row_bytes`` plus one ring
+    # bytes a lane holds on a rank of the kind of cache that is laid out a
+    # lane (``cache_kinds`` of the decode model): a ring, over the layers that
+    # keep only their last rows, or — ``ring_is_state`` — a state, over the
+    # layers that keep one; 0 where every layer keeps the whole context.
+    # ``block_bytes`` and ``kv_row_bytes`` are then the other layers' alone: a
+    # request's cache bytes are its context's rows at ``kv_row_bytes`` plus
+    # one ring or one state
     ring_bytes: int = 0
+    ring_is_state: bool = False
 
     @classmethod
     def from_engine(cls, engine: Any) -> "EngineDims":
@@ -173,8 +176,8 @@ class EngineDims:
 
         # the ring kind's pool, where the cache has one: its bytes on a rank,
         # its blocks, and a lane's share of them
-        ring_kind = getattr(engine, "_ring_kind", None)
-        ring_blocks = int(getattr(engine, "_ring_blocks", 0))
+        ring_kind = getattr(engine, "_lane_kind", None)
+        ring_blocks = int(getattr(engine, "_lane_blocks", 0))
         ring_pool_bytes = ring_pool_blocks = 0
         if ring_kind is not None:
             ring_leaves = jax.tree.leaves(engine._kind_pool(ring_kind))
@@ -203,6 +206,7 @@ class EngineDims:
             // int(engine.paged.num_blocks),
             cache_is_positional=positional,
             ring_bytes=ring_pool_bytes * ring_blocks // max(ring_pool_blocks, 1),
+            ring_is_state=bool(ring_kind is not None and ring_kind.state),
         )
 
     @property
@@ -237,16 +241,17 @@ class EngineDims:
 
     def state_bytes(self, sequences: int) -> int:
         """Bytes a program over ``sequences`` lanes moves of what a lane holds
-        whole: a state pool's block read once and written once; a ring read
-        once; 0 for a pool of rows alone."""
+        whole: a state — a state pool's block, a state kind's slot — read
+        once and written once; a ring read once; 0 for a pool of rows alone."""
         if self.cache_is_positional:
-            return sequences * self.ring_bytes
+            return (2 if self.ring_is_state else 1) * sequences * self.ring_bytes
         return 2 * sequences * (self.block_bytes or 0)
 
     def request_cache_bytes(self, context: int) -> int:
         """Bytes a request of ``context`` tokens holds in the cache on a
-        rank: its rows in whole blocks, plus a ring where a kind keeps one; a
-        state's one block."""
+        rank: its rows in whole blocks, plus a ring or a state where a kind
+        of layer keeps one a lane; a state's one block where the whole cache
+        is a state."""
         if not self.cache_is_positional:
             return self.block_bytes or 0
         blocks = -(-context // self.block_size)

@@ -544,7 +544,23 @@ class PagedServingEngine:
         self._positional = bool(self.model.cache_is_positional)
         # 1 where every pdecode holds the one-pass state kernel, else 0
         self._state_kernel = int(self.model.uses_state_kernel())
-        if not self._positional:
+        # a kind of cache that is laid out a lane, beside the allocator's pool
+        # (docs/serving.md "Stacks whose layers cache different things"): the
+        # last rows of a context in a ring of blocks (window layers beside full
+        # ones), or a state in one slot (state-space layers beside attention).
+        # This engine sizes it and lays it out
+        self._lane_kind = next(
+            (kind for kind in self.model.cache_kinds if kind.rows is not None), None
+        )
+        # some layer keeps a state: the whole cache (one a block, from the
+        # allocator), or the kind laid out a lane
+        state_kind = self._lane_kind is not None and self._lane_kind.state
+        self._has_state = bool(self.model.keeps_state)
+        if self._has_state:
+            what = (
+                f"its {self._lane_kind.name} layers keep a state a lane"
+                if state_kind else "its cache is a state per sequence"
+            )
             for on, name, why in (
                 (self._spec_k, "spec_draft_tokens > 0",
                  "a rejected draft cannot be taken back out of a state"),
@@ -558,16 +574,9 @@ class PagedServingEngine:
                 if on:
                     raise ValueError(
                         f"{name} is not available for {type(self.model).__name__}: "
-                        f"its cache is a state per sequence, not rows per token — {why}"
+                        f"{what}, not rows per token — {why}"
                     )
-        # a kind of cache that keeps only the last rows of a context (window
-        # layers beside full ones: docs/serving.md "Stacks whose layers cache
-        # different things"): its rows live in a ring of blocks a lane that
-        # this engine sizes and lays out, beside the allocator's pool
-        self._ring_kind = next(
-            (kind for kind in self.model.cache_kinds if kind.rows is not None), None
-        )
-        if self._ring_kind is not None:
+        elif self._lane_kind is not None:
             for on, name, why in (
                 (self._spec_k, "spec_draft_tokens > 0",
                  "the ring is sized for a prefill rung of fresh rows; a verify "
@@ -581,16 +590,16 @@ class PagedServingEngine:
                 if on:
                     raise ValueError(
                         f"{name} is not available for {type(self.model).__name__}: its "
-                        f"{self._ring_kind.name} layers keep a ring of rows a lane — {why}"
+                        f"{self._lane_kind.name} layers keep a ring of rows a lane — {why}"
                     )
         # prefix sharing matches token by token inside a block and copies a
         # partly shared block; a state after N tokens says nothing about its
-        # first k, so a state model neither matches nor inserts. Nor does a
-        # stack with a ring: a hit at token N would leave the ring's layers
-        # without the rows N - window + 1 .. N - 1
+        # first k, so a model with a state neither matches nor inserts. Nor
+        # does a stack with a ring: a hit at token N would leave the ring's
+        # layers without the rows N - window + 1 .. N - 1
         self._share_prefixes = (
             bool(paged.enable_prefix_caching) and self._positional
-            and self._ring_kind is None
+            and self._lane_kind is None
         )
         # tree speculation: verify a packed candidate tree (ptree program)
         # instead of a single chain. Set before the catalog build below —
@@ -727,22 +736,26 @@ class PagedServingEngine:
                 self.model = type(self.model)(
                     dataclasses.replace(self.model.config, quant_mxu=True)
                 )
-        # the ring: every row a query of the top prefill rung still sees
-        # (rows - 1 behind its first row) plus the rung's own fresh rows,
-        # padding included, in whole blocks; lane l's is blocks
-        # 1 + l * ring .. of the kind's pool, block 0 its null block. Laid out
-        # once: no allocator, no release, no per-step delta
-        self._ring_blocks = 0
-        self._ring_tables: Optional[np.ndarray] = None
+        # what a lane holds of the kind laid out a lane. A ring: every row a
+        # query of the top prefill rung still sees (rows - 1 behind its first
+        # row) plus the rung's own fresh rows, padding included, in whole
+        # blocks. A state: one slot. Lane l's is blocks 1 + l * n .. of the
+        # kind's pool, block 0 its null block. Laid out once: no allocator,
+        # no release, no per-step delta — a request that is preempted or
+        # re-admitted finds nothing of its own there and builds it by prefill
+        self._lane_blocks = 0
+        self._lane_tables: Optional[np.ndarray] = None
         sized = {}
-        if self._ring_kind is not None:
-            self._ring_blocks = _ceil_div(
-                self._ring_kind.rows - 1 + self._prefill_buckets[-1], bs
+        if self._lane_kind is not None:
+            self._lane_blocks = 1 if self._lane_kind.state else _ceil_div(
+                self._lane_kind.rows - 1 + self._prefill_buckets[-1], bs
             )
-            self._ring_tables = 1 + np.arange(
-                engine.max_batch * self._ring_blocks, dtype=np.int32
-            ).reshape(engine.max_batch, self._ring_blocks)
-            sized = {"window_blocks": 1 + engine.max_batch * self._ring_blocks}
+            self._lane_tables = 1 + np.arange(
+                engine.max_batch * self._lane_blocks, dtype=np.int32
+            ).reshape(engine.max_batch, self._lane_blocks)
+            sized = {
+                f"{self._lane_kind.name}_blocks": 1 + engine.max_batch * self._lane_blocks
+            }
 
         def init_pool():
             return self.model.init_paged_cache(
@@ -1158,11 +1171,14 @@ class PagedServingEngine:
                 "program_temp_bytes_max": max(
                     (p.temp_bytes for p in profiles.values()), default=0
                 ),
-                # rows by position: bytes a token a layer; a state: bytes of one
-                # block over all layers
+                # rows by position: bytes a token a layer; a state: bytes a
+                # lane holds of it over all its layers — one block of a cache
+                # that is a state, one slot of a kind that is
                 **({"cache_row_bytes": cache_row_bytes(self._kind_pool(self.model.cache_kinds[0]))}
-                   if self._positional
-                   else {"state_bytes_per_lane": cache_block_bytes(self.cache)}),
+                   if self._positional else {}),
+                **({"state_bytes_per_lane": cache_block_bytes(
+                    self._kind_pool(self._lane_kind) if self._positional else self.cache)}
+                   if self._has_state else {}),
                 # a multi-stream residual (models/xing.py): bytes a token's
                 # streams take between layers
                 **({"residual_row_bytes": self.model.residual_row_bytes()}
@@ -1177,19 +1193,24 @@ class PagedServingEngine:
 
     def _kind_facts(self) -> Dict[str, Any]:
         """``cache_kinds`` of the ``setup`` record — a kind: its layers, the
-        rows a lane keeps of it (null = the whole context), a row's bytes a
-        layer as the device lays them out and which read a decode step takes
-        of it (``decode_read``: ``"kernel"`` or ``"gather"``) — and
-        ``window_ring_rows``; nothing where the cache is a state."""
+        rows a lane keeps of it (null = the whole context, 0 = none: a state),
+        a row's bytes a layer — or a state's over the kind's layers — as the
+        device lays them out, and which read a decode step takes of it
+        (``decode_read``: ``"kernel"``, ``"gather"`` or ``"pass"``; a state
+        kind also how a prefill's block of rows goes through it, ``chunk_scan``:
+        ``"kernel"`` or ``"loop"``) — and ``window_ring_rows``; nothing where
+        the whole cache is a state."""
         if not self._positional:
             return {}
-        ring_rows = self._ring_blocks * self.paged.block_size
+        ring_rows = 0 if self._has_state else self._lane_blocks * self.paged.block_size
         return {
             "cache_kinds": {
                 kind.name: {
                     "layers": kind.layers,
                     "rows_per_lane": None if kind.rows is None else ring_rows,
-                    "row_bytes": cache_row_bytes(self._kind_pool(kind)),
+                    **({"state_bytes": cache_block_bytes(self._kind_pool(kind)),
+                        "chunk_scan": self.model.chunk_scan()} if kind.state
+                       else {"row_bytes": cache_row_bytes(self._kind_pool(kind))}),
                     "decode_read": self.model.decode_read(
                         kind, self._kind_pool(kind).quantized),
                 }
@@ -1203,13 +1224,19 @@ class PagedServingEngine:
         attend over, this step's included — or, where the cache is a state a
         lane, the live lanes: the states the step has to move. Where a kind of
         layer sees only its last rows, ``window_rows`` beside it: the rows
-        those layers attend over, min(context, window) a live lane."""
+        those layers attend over, min(context, window) a live lane. Where a
+        kind is a state, ``state_lanes`` — the live lanes — and
+        ``state_slots_passed`` — the slots the step's pass reads and writes:
+        every slot of the kind's pool, a live lane's or not."""
         if not self._positional:
             return {"rows": len(decode_lanes)}
         contexts = [int(self._positions[l]) + 1 for l in decode_lanes]
         rows = {"rows": sum(contexts)}
-        if self._ring_kind is not None:
-            rows["window_rows"] = sum(min(n, self._ring_kind.rows) for n in contexts)
+        if self._has_state:
+            rows["state_lanes"] = len(decode_lanes)
+            rows["state_slots_passed"] = 1 + self.engine.max_batch * self._lane_blocks
+        elif self._lane_kind is not None:
+            rows["window_rows"] = sum(min(n, self._lane_kind.rows) for n in contexts)
         return rows
 
     def _kv_bucket(self, needed: int) -> int:
@@ -1266,24 +1293,25 @@ class PagedServingEngine:
 
     def _prefill_table(self, table, lane: Optional[int]) -> np.ndarray:
         """The (1, W) table a prefill program takes: the request's blocks,
-        null past them — and, where a kind of the cache keeps a ring, the
-        lane's ring after the ``table_width`` columns (:meth:`_table_kinds`
-        parts them again inside the program). ``lane`` None: a warm-up call,
-        whose rows all land in the null blocks."""
-        row = np.full((1, self.table_width + self._ring_blocks), NULL_BLOCK, np.int32)
+        null past them — and, where a kind of the cache is laid out a lane,
+        the lane's ring or slot after the ``table_width`` columns
+        (:meth:`_table_kinds` parts them again inside the program). ``lane``
+        None: a warm-up call, whose rows all land in the null blocks."""
+        row = np.full((1, self.table_width + self._lane_blocks), NULL_BLOCK, np.int32)
         row[0, : len(table)] = table
-        if self._ring_blocks and lane is not None:
-            row[0, self.table_width:] = self._ring_tables[lane]
+        if self._lane_blocks and lane is not None:
+            row[0, self.table_width:] = self._lane_tables[lane]
         return row
 
     def _table_kinds(self, table) -> Dict[str, Any]:
         """A prefill program's table as the model's ``forward`` takes it: the
-        block table, and the ring's as ``window_tables`` where there is one."""
-        if not self._ring_blocks:
+        block table, and the lane's ring or slot as ``<kind>_tables`` where a
+        kind is laid out a lane."""
+        if not self._lane_blocks:
             return {"block_tables": table}
         return {
             "block_tables": table[:, : self.table_width],
-            "window_tables": table[:, self.table_width:],
+            f"{self._lane_kind.name}_tables": table[:, self.table_width:],
         }
 
     def _prefill_ctx_program(self, bucket: int, cfg):
@@ -1299,7 +1327,7 @@ class PagedServingEngine:
 
         # a state keeps what a padded row does to it: the live length reaches
         # the model (None leaves a positional model's lowering as it was)
-        positional = self._positional
+        positional = not self._has_state
 
         def _last_logits(params, cache, ids, positions, length, table):
             hidden, cache = model.forward(
@@ -1352,7 +1380,7 @@ class PagedServingEngine:
             return self._programs[key_]
         model, engine = self._step_model(), self.engine
 
-        positional = self._positional
+        positional = not self._has_state
 
         def _last_logits(params, cache, ids, start, length, table):
             hidden, cache = model.forward(
@@ -1416,9 +1444,11 @@ class PagedServingEngine:
             return self._programs[key_]
         model, engine = self._step_model(), self.engine
         pos_cap = self._pos_cap
-        # every lane's ring, where a kind of the cache keeps one: laid out at
-        # construction and never changed, so a constant of the program
-        ring = {} if self._ring_tables is None else {"window_tables": self._ring_tables}
+        # every lane's ring or slot, where a kind of the cache is laid out a
+        # lane: laid out at construction and never changed, so a constant of
+        # the program
+        ring = {} if self._lane_tables is None else {
+            f"{self._lane_kind.name}_tables": self._lane_tables}
 
         if self._fused and checked:
             def fn(params, cache, tokens, positions, tables,
@@ -2908,7 +2938,7 @@ class PagedServingEngine:
             table_dev = self._upload(self._prefill_table(table, lane))
         tail = self._lane_sampling_args(lane) if self._fused else (key,)
         if cached == 0:
-            if not self._positional:
+            if self._has_state:
                 self.metrics.state_resets += 1   # this pctx begins a state from zero
             fn = self._prefill_ctx_program(bucket, self._decode_cfg())
             tok, self.cache = fn(

@@ -36,7 +36,9 @@ Invariants checked:
 6. Radix coherence — every indexed node's block is allocator-registered
    and maps back to its node; parent/child links are consistent.
 7. Scale-array presence — the cache carries k/v scale arrays iff
-   ``PagedConfig.kv_cache_dtype`` is quantized.
+   ``PagedConfig.kv_cache_dtype`` is quantized; and (7b) a kind of cache
+   that is laid out a lane (a ring of rows, a state's slot) has a pool of
+   the null block and every lane's blocks, each named by exactly one lane.
 8. Fused-sampling residents — with ``PagedConfig.on_device_sampling``
    the four sampling residents (temps/topks/topps/rng) are present and
    the host mirrors correctly shaped; free lanes sit parked at the
@@ -219,6 +221,20 @@ def audit_engine(engine) -> List[str]:
             f"kv_cache_dtype={engine.paged.kv_cache_dtype!r} but cache "
             f"scale arrays present=(k={has_k}, v={has_v})"
         )
+
+    # 7b. a kind laid out a lane (a ring of rows, a state's slot): its pool
+    # is the null block and every lane's blocks, each named by one lane
+    lane_kind = getattr(engine, "_lane_kind", None)
+    if lane_kind is not None:
+        import jax
+
+        tables = engine._lane_tables
+        blocks = int(jax.tree.leaves(engine._kind_pool(lane_kind))[0].shape[1])
+        if blocks != 1 + tables.size or sorted(tables.ravel().tolist()) != list(range(1, blocks)):
+            v.append(
+                f"{lane_kind.name} kind: pool of {blocks} blocks, lanes' tables "
+                f"{tables.shape} do not name each of 1..{blocks - 1} once"
+            )
 
     # 9. spilled residency (checked before 8: that one early-returns)
     tier = getattr(engine, "host_tier", None)

@@ -116,12 +116,18 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
 # around a sub-layer: ``mhc/coeff`` (the streams' norm and the three
 # coefficient products), ``mhc/sinkhorn`` (the rounds that project the residual
 # mix) and ``mhc/mix`` (the pre-collapse, the post-spread and the residual mix)
-# (models/xing.py).
+# (models/xing.py); ``attn/ssm`` wraps a Mamba mixer — its two projections sit
+# under it bare — with ``attn/ssm/conv`` (the causal convolution and its tail),
+# ``attn/ssm/params`` (``x_proj``, the three inner norms, ``dt_proj``, the
+# softplus), ``attn/ssm/scan`` (prefill: a block of rows one after another from
+# the carried state, the state's way out of its slot and back) and
+# ``attn/ssm/step`` (decode: one pass over the lanes' slots) (models/jamba.py).
 DETAIL_SCOPES = {
     "": ("mhc",),
     "attn": ("qk_norm", "latent_down", "latent_up", "absorb", "gate", "retention",
-             "full", "window", "out_gate", "q_latent"),
+             "full", "window", "out_gate", "q_latent", "ssm"),
     "attn/retention": ("expand", "chunk", "step"),
+    "attn/ssm": ("conv", "params", "scan", "step"),
     "mhc": ("coeff", "sinkhorn", "mix"),
     "moe": ("shared",),
     "moe/experts": ("selective", "all"),
@@ -204,9 +210,13 @@ class EngineTracer:
         self._drive: deque = deque(maxlen=self.buffer_steps)
         # what construction did, written once by a traced engine's prewarm
         # (``_setup_facts``): relaid_leaves, relaid_bytes,
-        # program_temp_bytes_max, and cache_row_bytes or — where the cache is
-        # a state a lane — state_bytes_per_lane; residual_row_bytes where the
-        # residual has several streams
+        # program_temp_bytes_max, cache_row_bytes and — where the cache, or a
+        # kind of it, is a state a lane — state_bytes_per_lane; cache_kinds
+        # (a kind: layers, rows_per_lane, row_bytes or state_bytes,
+        # decode_read); residual_row_bytes where the residual has several
+        # streams. A decode dispatch record carries ``rows`` and, where some
+        # kind is a state, ``state_lanes`` (live lanes) and
+        # ``state_slots_passed`` (slots the pass moved)
         self.setup: Dict[str, int] = {}
         # routing counters, one entry per dispatch of a tapped program
         # (moe/tap.py): (step, kind, dispatch paths, pairs computed, live

@@ -190,6 +190,25 @@ def retention_case(pool_dtype):
 RETENTION_CASES = {"retention-step-f32": jnp.float32, "retention-step-bf16": jnp.bfloat16}
 
 
+def ssm_scan_case(pool_dtype, rows):
+    """(fn, avals) for the chunk scan at Jamba2-3B's widths as
+    ``jamba-smallchat-bursty`` runs them: one lane's bucket of ``rows`` rows
+    over 5,120 channels of 16 states."""
+    from neuronx_distributed_llama3_2_tpu.kernels.ssm_scan_pallas import ssm_chunk_scan
+
+    n, d = 16, 5120
+    f32 = jnp.float32
+    shapes = [((1, n, d), pool_dtype), ((1, rows, d), f32), ((1, rows, d), f32),
+              ((1, rows, n), f32), ((1, rows, n), f32), ((n, d), f32), ((1,), jnp.int32)]
+    return ssm_chunk_scan, [jax.ShapeDtypeStruct(*s) for s in shapes]
+
+
+SSM_SCAN_CASES = {
+    "ssm-scan-f32-512": (jnp.float32, 512), "ssm-scan-f32-128": (jnp.float32, 128),
+    "ssm-scan-bf16-512": (jnp.bfloat16, 512),
+}
+
+
 def walk_case(pool_dtype, group=None):
     """(fn, avals) for the decode block walk at ``laguna-mixedlen-batch``'s
     shape: 32 lanes, 48 query over 8 kv heads of 128, the full kind's pool of
@@ -295,6 +314,14 @@ def test_retention_step_kernel_lowers_for_tpu(compiled_mode, name):
     assert_mosaic_call(lowered, "retention_state_pass")
     # the pool is the call's operand 2 and its result 1: updated in place
     assert "output_tuple_indices = [1], operand_index = 2" in lowered.as_text()
+
+
+@pytest.mark.parametrize("name", SSM_SCAN_CASES)
+def test_ssm_chunk_scan_kernel_lowers_for_tpu(compiled_mode, name):
+    lowered = lower_for_tpu(*ssm_scan_case(*SSM_SCAN_CASES[name]))
+    assert_mosaic_call(lowered, "ssm_chunk_scan")
+    # the state is the call's operand 1 and its result 1: updated in place
+    assert "output_tuple_indices = [1], operand_index = 1" in lowered.as_text()
 
 
 @pytest.mark.parametrize("name", WALK_CASES)

@@ -335,7 +335,7 @@ def test_tp2_on_two_virtual_devices_matches_tp1(fam, params):
 
 def test_the_window_pool_is_lanes_times_ring_and_the_accounts_say_so(params):
     srv = serving(params)
-    assert srv._ring_blocks == RING_BLOCKS and srv.table_width == -(-128 // BS) + CHUNK // BS
+    assert srv._lane_blocks == RING_BLOCKS and srv.table_width == -(-128 // BS) + CHUNK // BS
     window_blocks = 1 + LANES * RING_BLOCKS
     assert srv.cache.window.k.shape == (3, window_blocks, BS, 2, 16)
     window_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(srv.cache.window))
@@ -345,7 +345,7 @@ def test_the_window_pool_is_lanes_times_ring_and_the_accounts_say_so(params):
     snap = srv.metrics.snapshot()
     assert snap["window_pool_blocks"] == window_blocks and snap["num_blocks"] == 140
     # lane l's ring is blocks 1 + l * ring ..: laid out once, block 0 the null block
-    np.testing.assert_array_equal(srv._ring_tables[2], 1 + 2 * RING_BLOCKS + np.arange(RING_BLOCKS))
+    np.testing.assert_array_equal(srv._lane_tables[2], 1 + 2 * RING_BLOCKS + np.arange(RING_BLOCKS))
     dims = EngineDims.from_engine(srv)
     assert dims.block_bytes == BS * ROW * 2 and dims.kv_row_bytes() == ROW * 2
     assert dims.ring_bytes == RING * ROW * 3 and dims.pool_bytes_local() == full_bytes
