@@ -2379,18 +2379,22 @@ class JambaDecode(LlamaDecode):
     slot, so the decode program never writes into a state a prefill is
     building.
 
-    Which form a state-space layer runs follows from the block's shape: one
-    token a lane (``pdecode``) is the step form, one pass a layer over
-    **every slot where it lies** — the lanes' rows go to their slots, the
-    mixer runs in slot order, a slot no lane names comes back bit for bit —
-    so each state is read once and written once and nothing is gathered
-    (lanes fewer than half the slots gather theirs instead); a block of rows
-    is the scan over its lane's slot (:meth:`chunk_scan`: one Mosaic call a
-    layer on one device, a ``lax.scan`` elsewhere) — from the **zero state and a
-    zero tail** under ``context_encode`` (``pctx``: a slot still holds its
-    last request's past), from the slot's otherwise (``psfx``). ``row_live``
-    is the count of real rows of a padded block: rows at or past it leave
-    the state *and* the convolution's tail untouched.
+    Which form a state-space layer runs follows from the block's shape. One
+    token a lane (``pdecode``) is the step form: where
+    :meth:`uses_state_kernel`, **one Mosaic call a layer that visits the live
+    lanes' slots where they lie** (:mod:`..kernels.ssm_step_pallas`: ``h`` in,
+    updated, ``y`` taken from it, back to the same slot; a lane on the null
+    slot moves nothing), the lanes' tails gathered and scattered beside it in
+    lane order; elsewhere (a mesh, the ``"reference"`` mode) one pass a layer
+    over **every slot where it lies** — the lanes' rows go to their slots, the
+    mixer runs in slot order — or, for lanes fewer than half the slots, a
+    gather of theirs. Either way a slot no lane names comes back bit for bit.
+    A block of rows is the scan over its lane's slot (:meth:`chunk_scan`: one
+    Mosaic call a layer on one device, a ``lax.scan`` elsewhere) — from the
+    **zero state and a zero tail** under ``context_encode`` (``pctx``: a slot
+    still holds its last request's past), from the slot's otherwise
+    (``psfx``). ``row_live`` is the count of real rows of a padded block: rows
+    at or past it leave the state *and* the convolution's tail untouched.
 
     Both kinds ride the layer loop as its carry, the layer folded into the
     row index: a donated cache is updated in place and a program's
@@ -2473,19 +2477,33 @@ class JambaDecode(LlamaDecode):
     def _paged_kernel_eligible(self, t: int, tree) -> bool:
         return False
 
+    def uses_state_kernel(self) -> bool:
+        """Whether the step form (one token a lane: ``pdecode``) runs
+        :func:`..kernels.ssm_step_pallas.ssm_state_step` — one Mosaic call a
+        state-space layer that visits the live lanes' slots where they lie —
+        which it does where :func:`_kernels_on_one_device` (and the channels
+        are whole lanes); a mesh and the ``"reference"`` mode keep the pass
+        over every slot around ``selective_step``."""
+        from neuronx_distributed_llama3_2_tpu.kernels.ssm_step_pallas import state_step_fits
+
+        return _kernels_on_one_device() and state_step_fits(self.config.d_inner)
+
     def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
-        """``"pass"`` for the state kind — one pass a layer over the lanes'
-        slots, every lane's, live or not — and ``"gather"`` for the rows,
-        a block at a time through the table."""
-        return "pass" if kind.state else "gather"
+        """The state kind: ``"kernel"`` where :meth:`uses_state_kernel` — a
+        visit a live lane's slot — else ``"pass"``, one pass a layer over the
+        lanes' slots, every lane's, live or not. The rows: ``"gather"``, a
+        block at a time through the table."""
+        if kind.state:
+            return "kernel" if self.uses_state_kernel() else "pass"
+        return "gather"
 
     def chunk_scan(self) -> str:
         """How a block of rows (``pctx`` / ``psfx``) goes through a
         state-space layer's recurrence: ``"kernel"`` — one
         :func:`..kernels.ssm_scan_pallas.ssm_chunk_scan` a layer — where
         :func:`_kernels_on_one_device`, else ``"loop"``, a ``lax.scan`` a row
-        (a mesh, the ``"reference"`` mode). ``pdecode`` holds no kernel:
-        :meth:`uses_state_kernel` stays False."""
+        (a mesh, the ``"reference"`` mode). ``pdecode``'s own kernel is
+        :meth:`uses_state_kernel`'s."""
         return "kernel" if _kernels_on_one_device() else "loop"
 
     # -- forward ----------------------------------------------------------
@@ -2502,6 +2520,7 @@ class JambaDecode(LlamaDecode):
         updated)."""
         if tree is not None:
             raise NotImplementedError("tree verification over a state-space layer's state")
+        from neuronx_distributed_llama3_2_tpu.kernels.ssm_step_pallas import visits
         from neuronx_distributed_llama3_2_tpu.models.jamba import (
             ATTENTION, MAMBA, MambaMixer, layer_runs,
         )
@@ -2525,13 +2544,22 @@ class JambaDecode(LlamaDecode):
         form = "step" if t == 1 else "scan"
         scan_kernel = self.chunk_scan() == "kernel"
         state_slots = cache.state.h.shape[1]
-        # one token a lane over lanes that are most of the slots (a decode
-        # batch): the pass goes over every slot of a layer where it lies,
-        # read once and written once, nothing gathered. A block of rows of
-        # one lane (a prefill) takes its slot out and puts it back, and so do
-        # a few lanes over many slots (``benchmarks/check.py`` decodes one
-        # lane over the engine's pool; the engine's own batch is every lane)
-        every_slot = t == 1 and not context_encode and 2 * b >= state_slots
+        # one token a lane (a decode batch, ``benchmarks/check.py``'s one lane
+        # over the engine's pool): where the kernel runs, a visit a live
+        # lane's slot — a lane on the null slot (idle, or mid-prefill beside
+        # the batch) is not live; the dense cache has no null slot. Elsewhere
+        # lanes that are most of the slots pass over every slot of a layer
+        # where it lies, read once and written once, nothing gathered. A
+        # block of rows of one lane (a prefill) takes its slot out and puts it
+        # back, and so do a few lanes over many slots off the kernel
+        step = t == 1 and not context_encode
+        step_kernel = step and self.uses_state_kernel()
+        every_slot = step and not step_kernel and 2 * b >= state_slots
+        if step_kernel:
+            alive = live > 0 if block_tables is None else (live > 0) & (index != 0)
+            alive_rows = alive.astype(jnp.int32)
+            lane_walk, count = visits(alive)
+            slot_walk = index[lane_walk]
         # a slot's tail values, and the places of its folded rows past them
         values = (c.mamba_d_conv - 1) * c.d_inner
         unused = math.prod(cache.state.tail.shape[2:]) - values
@@ -2571,6 +2599,19 @@ class JambaDecode(LlamaDecode):
                         tail_pool = jax.lax.dynamic_update_index_in_dim(
                             tail_pool, fold(tail_out), layer, 0)
                     out = out[index]
+                elif step_kernel:
+                    # the tails out and back in lane order, a lane that is
+                    # not live's as it came; ``h`` stays where it lies
+                    at = layer * state_slots + index
+                    u, g = mixer.project(lp[MAMBA], hn)
+                    with jax.named_scope(form):
+                        tail_in = unfold(flat(tail_pool)[at])
+                    out, h_flat, tail_out = mixer.mix(
+                        lp[MAMBA], u, g, flat(h_pool), tail_in, alive_rows,
+                        walk=(layer * state_slots + slot_walk, lane_walk, count))
+                    with jax.named_scope(form):
+                        h_pool = h_flat.reshape(h_pool.shape)
+                        tail_pool = flat(tail_pool).at[at].set(fold(tail_out)).reshape(tail_pool.shape)
                 else:
                     at = layer * state_slots + index
                     u, g = mixer.project(lp[MAMBA], hn)

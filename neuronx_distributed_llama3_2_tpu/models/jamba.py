@@ -154,7 +154,10 @@ def conv_rows(weight, bias, tail, u, live):
     for j in range(k):
         acc = acc + weight[j].astype(jnp.float32) * full[:, j:j + t].astype(jnp.float32)
     # u's row i is full's row K-1+i: the last K-1 real rows start at `live`
-    new_tail = jax.vmap(lambda f, n: lax.dynamic_slice_in_dim(f, n, k - 1, axis=0))(full, live)
+    if t == 1:      # the tail moves up by the one row, or stays: a select, where a slice a lane is a gather
+        new_tail = jnp.where((live > 0)[:, None, None], full[:, 1:], full[:, :k - 1])
+    else:
+        new_tail = jax.vmap(lambda f, n: lax.dynamic_slice_in_dim(f, n, k - 1, axis=0))(full, live)
     return jax.nn.silu(acc).astype(u.dtype), new_tail.astype(tail.dtype)
 
 
@@ -261,11 +264,17 @@ class MambaMixer:
         """x (b, t, H) -> u, g (b, t, D)."""
         return jnp.split(x @ params["in_proj"]["kernel"], 2, axis=-1)
 
-    def mix(self, params: Params, u, g, h, tail, live, kernel=False):
+    def mix(self, params: Params, u, g, h, tail, live, kernel=False, walk=None):
         """Rows u, g (b, t, D) from the carried ``h`` (b, N, D) and ``tail``
         (b, K − 1, D), ``live`` (b,) of them real: (the mixer's output
         (b, t, H), h, tail). One token a lane runs the step form; ``kernel``
-        is :func:`selective_scan`'s."""
+        is :func:`selective_scan`'s. ``walk`` — (slot, lane, count) — says
+        that ``h`` is the whole pool as one run of slots and the step form
+        runs in it, in place, a live lane's slot a visit
+        (:func:`..kernels.ssm_step_pallas.ssm_step_paged`)."""
+        # importing Pallas for the TPU starts the backend: not at this module's import
+        from neuronx_distributed_llama3_2_tpu.kernels.ssm_step_pallas import ssm_step_paged
+
         cfg = self.config
         with jax.named_scope("conv"):
             c, tail = conv_rows(params["conv"]["kernel"], params["conv"]["bias"], tail, u, live)
@@ -273,11 +282,13 @@ class MambaMixer:
             delta, b_t, c_t = ssm_params(params, c, cfg)
         a = -jnp.exp(params["a_log"])
         if u.shape[1] == 1:
+            row = (c[:, 0], b_t[:, 0], c_t[:, 0], a, params["d_skip"])
             with jax.named_scope("step"):
-                # a lane with no live row: Δ = 0 leaves its state as it was
-                delta = jnp.where((live > 0)[:, None, None], delta, 0.0)
-                y, h = selective_step(
-                    h, delta[:, 0], c[:, 0], b_t[:, 0], c_t[:, 0], a, params["d_skip"])
+                if walk is None:
+                    # a lane with no live row: Δ = 0 leaves its state as it was
+                    y, h = selective_step(h, jnp.where((live > 0)[:, None], delta[:, 0], 0.0), *row)
+                else:       # a lane with no live row is not visited
+                    y, h = ssm_step_paged(h, *walk, live > 0, delta[:, 0], *row)
                 y = y[:, None]
         else:
             with jax.named_scope("scan"):

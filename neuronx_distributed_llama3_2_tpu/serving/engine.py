@@ -1226,15 +1226,18 @@ class PagedServingEngine:
         layer sees only its last rows, ``window_rows`` beside it: the rows
         those layers attend over, min(context, window) a live lane. Where a
         kind is a state, ``state_lanes`` — the live lanes — and
-        ``state_slots_passed`` — the slots the step's pass reads and writes:
-        every slot of the kind's pool, a live lane's or not."""
+        ``state_slots_passed`` — the slots the dispatched program reads and
+        writes: the live lanes' where it holds the state kernel, else every
+        slot of the kind's pool, a live lane's or not."""
         if not self._positional:
             return {"rows": len(decode_lanes)}
         contexts = [int(self._positions[l]) + 1 for l in decode_lanes]
         rows = {"rows": sum(contexts)}
         if self._has_state:
             rows["state_lanes"] = len(decode_lanes)
-            rows["state_slots_passed"] = 1 + self.engine.max_batch * self._lane_blocks
+            rows["state_slots_passed"] = (
+                len(decode_lanes) if self._state_kernel
+                else 1 + self.engine.max_batch * self._lane_blocks)
         elif self._lane_kind is not None:
             rows["window_rows"] = sum(min(n, self._lane_kind.rows) for n in contexts)
         return rows

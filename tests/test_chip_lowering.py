@@ -209,6 +209,33 @@ SSM_SCAN_CASES = {
 }
 
 
+def ssm_step_case(pool_dtype, lanes):
+    """(fn, avals) for the step kernel at Jamba2-3B's widths as
+    ``jamba-smallchat-bursty`` runs them: ``lanes`` lanes over a pool of
+    26 layers x 129 slots of 16 states x 5,120 channels, in place."""
+    from neuronx_distributed_llama3_2_tpu.kernels.ssm_step_pallas import ssm_step_paged, visits
+
+    n, d, layers, slots = 16, 5120, 26, 129
+    f32 = jnp.float32
+    shapes = [((layers * slots, n, d), pool_dtype), ((lanes,), jnp.int32), ((), jnp.int32),
+              ((lanes, d), f32), ((lanes, d), jnp.bfloat16), ((lanes, n), f32), ((lanes, n), f32),
+              ((n, d), f32), ((d,), f32)]
+
+    def fn(h_flat, index, layer, *step):
+        live = index != 0
+        lane, count = visits(live)
+        return ssm_step_paged(h_flat, layer * slots + index[lane], lane, count, live, *step)
+
+    return fn, [jax.ShapeDtypeStruct(*s) for s in shapes]
+
+
+# the engine's batch, ``benchmarks/check.py``'s one lane, and the check's failing pool
+SSM_STEP_CASES = {
+    "ssm-step-f32-128": (jnp.float32, 128), "ssm-step-f32-1": (jnp.float32, 1),
+    "ssm-step-bf16-128": (jnp.bfloat16, 128),
+}
+
+
 def walk_case(pool_dtype, group=None):
     """(fn, avals) for the decode block walk at ``laguna-mixedlen-batch``'s
     shape: 32 lanes, 48 query over 8 kv heads of 128, the full kind's pool of
@@ -322,6 +349,14 @@ def test_ssm_chunk_scan_kernel_lowers_for_tpu(compiled_mode, name):
     assert_mosaic_call(lowered, "ssm_chunk_scan")
     # the state is the call's operand 1 and its result 1: updated in place
     assert "output_tuple_indices = [1], operand_index = 1" in lowered.as_text()
+
+
+@pytest.mark.parametrize("name", SSM_STEP_CASES)
+def test_ssm_state_step_kernel_lowers_for_tpu(compiled_mode, name):
+    lowered = lower_for_tpu(*ssm_step_case(*SSM_STEP_CASES[name]))
+    assert_mosaic_call(lowered, "ssm_state_step")
+    # the pool is the call's operand 8 and its result 1: updated in place
+    assert "output_tuple_indices = [1], operand_index = 8" in lowered.as_text()
 
 
 @pytest.mark.parametrize("name", WALK_CASES)

@@ -26,6 +26,7 @@ from neuronx_distributed_llama3_2_tpu.inference.model import (
     CacheKind, cache_block_bytes, cache_row_bytes, decode_model_for,
 )
 from neuronx_distributed_llama3_2_tpu.inference.speculative import SpeculativeDecoder
+from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
 from neuronx_distributed_llama3_2_tpu.models.jamba import JAMBA_CONFIGS, JambaForCausalLM
 from neuronx_distributed_llama3_2_tpu.serving import PagedConfig, PagedServingEngine, audit_engine
 from neuronx_distributed_llama3_2_tpu.serving.accounting import EngineDims
@@ -38,6 +39,9 @@ SIZES = {"lanes": 4, "block_size": BS, "max_seq_len": 96, "pool_blocks": 40,
 # float32 against float32 at "highest": what is left is the order of the sums
 TOL = 1e-4
 STATE_BYTES = 3 * (8 * 128 * 4 + 16 * 128 * 4)     # Mamba layers x (h + the tail's 16 rows of lanes), float32 at this size
+# the kernel modes the CPU runs, and how a decode step reads the state kind in each (``JambaDecode.decode_read``)
+MODES = ("reference", "interpret")
+STATE_READ = {"reference": "pass", "interpret": "kernel"}
 
 
 def _tool(name):
@@ -148,11 +152,17 @@ def as_the_engine_runs_it(model, params, pool, prompt, fed, *, lane=0, lanes=3, 
 # the decode class, its cache and what the engine lays out
 # ---------------------------------------------------------------------------
 
-def test_the_family_gets_its_decode_class_and_a_cache_of_two_kinds():
+@pytest.mark.parametrize("mode", MODES)
+def test_the_family_gets_its_decode_class_and_a_cache_of_two_kinds(mode, monkeypatch):
+    """The state kind's decode read is the step kernel's visit a live lane
+    wherever Pallas kernels run, the pass over every slot in the CPU tier's
+    ``reference`` mode."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     model = decode_model_for(TINY)
     assert isinstance(model, JambaDecode) and model.cache_is_positional and model.keeps_state
     assert model.cache_kinds == (CacheKind("rows", 2, None), CacheKind("state", 3, 0, state=True))
-    assert [model.decode_read(kind) for kind in model.cache_kinds] == ["gather", "pass"]
+    assert [model.decode_read(kind) for kind in model.cache_kinds] == ["gather", STATE_READ[mode]]
+    assert model.uses_state_kernel() == (mode == "interpret")
     pool = model.init_paged_cache(7, BS, state_blocks=5)
     assert isinstance(pool, HybridCache) and (pool.num_blocks, pool.block_size) == (7, BS)
     # rows: a token's heads side by side, the layer axis the attention layers' alone
@@ -203,7 +213,11 @@ def test_a_quantized_pool_is_refused(params, kv):
         serving(params, kv_cache_dtype=kv)
 
 
-def test_the_dense_path_refuses_draft_and_verify_and_generates(fam, params):
+@pytest.mark.parametrize("mode", MODES)
+def test_the_dense_path_refuses_draft_and_verify_and_generates(fam, params, mode, monkeypatch):
+    """(The dense cache has no null slot: under the step kernel every slot is
+    a live lane's, slot 0 too.)"""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     eng = engine(params, max_batch=2)
     with pytest.raises(ValueError, match="not available for JambaDecode.*rejected draft"):
         SpeculativeDecoder(eng, eng, gamma=2).generate([1, 2, 3], 4)
@@ -355,7 +369,14 @@ def test_a_common_start_shares_nothing(fam, params):
     clean(srv)
 
 
-def test_a_traced_engine_records_both_kinds_and_the_slots_a_pass_moves(params):
+@pytest.mark.parametrize("mode", MODES)
+def test_a_traced_engine_records_both_kinds_and_the_slots_a_pass_moves(params, mode, monkeypatch):
+    """``reference``: the pass moves every slot, a live lane's or not, and no
+    dispatch holds the kernel. ``interpret`` (named before the first trace):
+    the step kernel visits the live lanes' slots alone, and every ``pdecode``
+    is counted."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    kernel = mode == "interpret"
     srv = serving(params, trace_enabled=True, prewarm=True)
     for p in prompts_of(np.random.default_rng(2), (20, 33)):
         srv.submit(p)
@@ -365,14 +386,37 @@ def test_a_traced_engine_records_both_kinds_and_the_slots_a_pass_moves(params):
     assert setup["window_ring_rows"] == 0 and setup["program_temp_bytes_max"] > 0
     assert setup["cache_kinds"] == {
         "rows": {"layers": 2, "rows_per_lane": None, "row_bytes": 2 * 16 * 4, "decode_read": "gather"},
-        "state": {"layers": 3, "rows_per_lane": 0, "state_bytes": STATE_BYTES, "chunk_scan": "loop",
-                  "decode_read": "pass"},
+        "state": {"layers": 3, "rows_per_lane": 0, "state_bytes": STATE_BYTES,
+                  "chunk_scan": "kernel" if kernel else "loop", "decode_read": STATE_READ[mode]},
     }
     records = [args for step in srv.tracer.timeline()["steps"] for ph, name, _, _, args in step["events"]
                if ph == "X" and name == "dispatch"]
-    assert records and all(a["state_lanes"] == a["lanes"] <= a["state_slots_passed"] == 1 + 4 for a in records)
+    assert records and all(a["state_lanes"] == a["lanes"] for a in records)
+    assert all(a["state_slots_passed"] == (a["state_lanes"] if kernel else 1 + 4) for a in records)
     assert all(a["rows"] >= a["lanes"] for a in records)                 # the attention layers' live rows
-    assert srv.metrics.snapshot()["state_resets"] == 2
+    snap = srv.metrics.snapshot()
+    assert snap["state_resets"] == 2
+    assert snap["state_kernel_steps"] == (snap["decode_steps"] if kernel else 0) and snap["decode_steps"] > 0
+
+
+def test_the_engines_tokens_through_the_step_kernel_are_the_reference_modes(params, monkeypatch):
+    """Six requests on four lanes — idle lanes beside live ones, lanes
+    mid-prefill beside the batch, two requests through used slots — in the
+    ``interpret`` mode (the chunk scan and the step kernel) and in the
+    ``reference`` mode (the loop a row and the pass over every slot): the same
+    tokens."""
+    prompts = prompts_of(np.random.default_rng(3), (37, 21, 5, 50, 16, 33))
+    tokens = {}
+    for mode in MODES:
+        monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+        srv = serving(params, new_tokens=8)
+        rids = [srv.submit(p) for p in prompts]
+        out = srv.run_to_completion()
+        tokens[mode] = [out[r] for r in rids]
+        steps = srv.metrics.snapshot()
+        assert steps["state_kernel_steps"] == (steps["decode_steps"] if mode == "interpret" else 0)
+        clean(srv)
+    assert tokens["interpret"] == tokens["reference"]
 
 
 def test_the_audit_holds_the_lanes_slots(params):

@@ -407,3 +407,51 @@ def test_paged_program_updates_the_donated_pool_in_place(v5e, program, kv):
     assert memory.alias_size_in_bytes == pool_bytes
     # as the scan's xs and ys the temporaries held a whole pool and more
     assert memory.temp_size_in_bytes < 0.2 * pool_bytes, (memory.temp_size_in_bytes, pool_bytes)
+
+
+# ---------------------------------------------------------------------------
+# compiled for a described v5e: a state-space decode step visits the state
+# pool in place
+# ---------------------------------------------------------------------------
+
+def test_a_state_space_decode_step_visits_the_donated_state_pool_in_place(v5e, monkeypatch):
+    """Jamba2-3B's widths at four layers (M A M M), 16 lanes over the cell's
+    129 slots (a pool of a few MB the compiler parks in VMEM around the call,
+    and copies back), as Mosaic compiles it: ``pdecode`` holds
+    ``ssm_state_step`` once a run of state-space layers, the ``h`` pool
+    aliased into and out of the program and of the call, and no bulk copy,
+    slice or update of the pool's shape or of a layer of it — the pass over
+    every slot it replaces was a ``dynamic-update-slice`` fusion of the whole
+    pool."""
+    from jax.sharding import SingleDeviceSharding
+
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+    from neuronx_distributed_llama3_2_tpu.models.jamba import JAMBA_CONFIGS, JambaForCausalLM
+
+    monkeypatch.setenv(KERNEL_MODE_ENV, "compiled")
+    cfg = dataclasses.replace(
+        JAMBA_CONFIGS["jamba2-3b"], num_layers=4, attn_layer_period=4, attn_layer_offset=1,
+        vocab_size=4096, max_seq_len=1024)
+    model = decode_model_for(cfg)
+    assert model.uses_state_kernel()
+    lanes, one = 16, SingleDeviceSharding(v5e)
+    on = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), t)
+    params = on(jax.eval_shape(JambaForCausalLM(cfg).init, jax.random.key(0)))
+    cache = on(jax.eval_shape(lambda: model.init_paged_cache(256, 16, state_blocks=129)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)  # noqa: E731
+
+    def pdecode(params, cache, tokens, positions, tables, slots):
+        return model.decode_step(params, cache, tokens, positions, tables, kv_limit=1024, state_tables=slots)
+
+    compiled = jax.jit(pdecode, donate_argnums=(1,)).lower(
+        params, cache, i32(lanes), i32(lanes), i32(lanes, 64), i32(lanes, 1)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"custom-call\(.*custom_call_target=\"tpu_custom_call\".*", text)
+    assert len(calls) == 2 and all("ssm_state_step" in c and "output_to_operand_aliasing={{1}: (8, {})}" in c
+                                   for c in calls), calls
+    h = cache.state.h.shape                                           # (3, 129, 16, 5120)
+    dims = {",".join(map(str, s)) for s in (h, (1,) + h[1:], h[1:], (h[0] * h[1],) + h[2:])}
+    assert not pool_sized_moves(text, dims), pool_sized_moves(text, dims)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
