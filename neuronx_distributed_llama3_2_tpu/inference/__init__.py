@@ -5,7 +5,8 @@ Role map to the reference (SURVEY.md §2.7):
   model.py        ← examples/inference/modules/model_base.py (NeuronBaseModel)
   engine.py       ← trace/model_builder.py + model_wrapper.py + autobucketing.py
                     + NeuronBaseForCausalLM routing/_sample
-  placement.py    ← (none: the physical layout fused weights rest in on the device)
+  placement.py    ← (none: the physical layout fused gate_up weights and head-split
+                    attention projections rest in on the device)
   sampling.py     ← src/neuronx_distributed/utils/sampling.py
   speculative.py  ← src/neuronx_distributed/utils/speculative_decoding.py
   benchmark.py    ← examples/inference/modules/benchmark.py

@@ -41,7 +41,7 @@ from neuronx_distributed_llama3_2_tpu.inference.model import (
 )
 from neuronx_distributed_llama3_2_tpu.inference.placement import (
     committed_home,
-    rest_fused_weights,
+    rest_weights,
 )
 from neuronx_distributed_llama3_2_tpu.inference.sampling import (
     SamplingConfig,
@@ -136,10 +136,11 @@ class InferenceEngine:
         with SETUP.span("setup.inference_engine"):
             self.config = config
             self.model = decode_model_for(config)
-            # fused (..., in, 2, out) leaves rest in the layout their matmul
-            # reads (inference/placement.py), placed before the cache exists:
-            # the transient is one leaf beside the weights
-            self.params, self.placement = rest_fused_weights(params)
+            # fused (..., in, 2, out) leaves and head-split attention
+            # projections rest in the layout their matmul reads
+            # (inference/placement.py), placed before the cache exists: the
+            # transient is one leaf beside the weights
+            self.params, self.placement = rest_weights(params)
             self.max_batch = max_batch
             self.max_seq_len = max_seq_len
             self.buckets = list(buckets) if buckets else default_buckets(max_seq_len)

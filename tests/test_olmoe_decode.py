@@ -198,15 +198,21 @@ def test_hf_checkpoint_gives_hf_logits(params):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
-def test_placement_relays_the_one_fused_leaf(params):
+def test_placement_relays_the_fused_leaf_and_the_head_split_projections(params):
     engine = InferenceEngine(TINY, params, max_batch=2, max_seq_len=32)
-    assert engine.placement["leaves"] == 1
     gate_up = params["layers"]["moe"]["experts"]["gate_up"]
-    assert engine.placement["bytes"] == gate_up.nbytes
+    qkv = params["layers"]["attn"]["qkv"]
+    assert engine.placement["leaves"] == 4
+    assert engine.placement["bytes"] == gate_up.nbytes + sum(
+        qkv[name].nbytes for name in ("q_kernel", "k_kernel", "v_kernel"))
     assert jax.tree.structure(engine.params) == jax.tree.structure(params)
     placed = engine.params["layers"]["moe"]["experts"]["gate_up"]
     assert placed.format.layout.major_to_minor == (0, 1, 3, 2, 4)
     np.testing.assert_array_equal(np.asarray(placed), np.asarray(gate_up))
+    for name in ("q_kernel", "k_kernel", "v_kernel"):
+        placed = engine.params["layers"]["attn"]["qkv"][name]
+        assert placed.format.layout.major_to_minor == (0, 2, 1)
+        np.testing.assert_array_equal(np.asarray(placed), np.asarray(qkv[name]))
 
 
 def test_joint_qk_norm_is_the_same_function_under_tensor_parallelism(params):

@@ -1,12 +1,15 @@
-"""Fused ``gate_up`` weights rest on the device in the layout the matmul reads
-(``inference/placement.py``).
+"""Weights rest on the device in the layout the matmul reads
+(``inference/placement.py``): fused ``gate_up`` leaves, and the stacked
+attention projections whose output is split into heads.
 
 Two tiers. On the CPU: an engine over re-placed weights is the engine over
 default-placed weights, bit for bit, and nothing but the physical layout of
-the fused leaves changed. For a described v5e (libtpu compiles for a topology
+the named leaves changed. For a described v5e (libtpu compiles for a topology
 without a chip — sizes, never a time): the paged programs over re-placed
-weights hold no copy of a layer's ``gate_up``, and the same programs over
-default-placed weights — the control — do.
+weights hold no copy of a layer's ``gate_up`` or of a layer's attention
+kernel, the same programs over default-placed weights — the control — do, and
+the layouts the rule names are the ones the compiler picks for itself when
+the weights' layout is left to it (``Layout.AUTO``).
 """
 
 import dataclasses
@@ -18,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.layout import Format
+from jax.experimental.layout import Format, Layout
 
 from neuronx_distributed_llama3_2_tpu.inference import (
     GenerationConfig,
@@ -30,7 +33,9 @@ from neuronx_distributed_llama3_2_tpu.analysis import graftcheck
 from neuronx_distributed_llama3_2_tpu.inference import engine as engine_mod
 from neuronx_distributed_llama3_2_tpu.inference.placement import (
     fused_rest_layout,
-    rest_fused_weights,
+    head_split_rest_layout,
+    rest_layout,
+    rest_weights,
 )
 from neuronx_distributed_llama3_2_tpu.models import (
     LLAMA_CONFIGS,
@@ -38,8 +43,18 @@ from neuronx_distributed_llama3_2_tpu.models import (
     LlamaForCausalLM,
     MixtralForCausalLM,
 )
+from neuronx_distributed_llama3_2_tpu.models.brumby import BRUMBY_CONFIGS, BrumbyForCausalLM
+from neuronx_distributed_llama3_2_tpu.models.jamba import JAMBA_CONFIGS, JambaForCausalLM
+from neuronx_distributed_llama3_2_tpu.models.laguna import (
+    LAGUNA_CONFIGS,
+    LagunaForCausalLM,
+    params_to_hf_laguna,
+)
 from neuronx_distributed_llama3_2_tpu.models.llama import params_to_hf
 from neuronx_distributed_llama3_2_tpu.models.mixtral import params_to_hf_mixtral
+from neuronx_distributed_llama3_2_tpu.models.olmoe import OLMOE_CONFIGS, OlmoeForCausalLM
+from neuronx_distributed_llama3_2_tpu.models.sarvam import SARVAM_CONFIGS, SarvamForCausalLM
+from neuronx_distributed_llama3_2_tpu.models.xing import XING_CONFIGS, XingForCausalLM
 from neuronx_distributed_llama3_2_tpu.quantization import (
     QuantizationConfig,
     quantize_params,
@@ -50,17 +65,39 @@ from neuronx_distributed_llama3_2_tpu.serving import (
     PagedServingEngine,
 )
 
+def heads(group, *names, order=(0, 2, 1)):
+    return {f"{group}/{name}": order for name in names}
+
+
+QKV = ("qkv/q_kernel", "qkv/k_kernel", "qkv/v_kernel")
+MLA = {**heads("dense_layers/attn", "kv_a/kernel"), **heads("layers/attn", "kv_a/kernel"),
+       **heads("dense_layers/attn", "kv_b/kernel", order=(0, 2, 1, 3)),
+       **heads("layers/attn", "kv_b/kernel", order=(0, 2, 1, 3)),
+       "dense_layers/mlp/gate_up": (0, 2, 1, 3), "layers/moe/shared/gate_up": (0, 2, 1, 3),
+       "layers/moe/experts/gate_up": (0, 1, 3, 2, 4)}
 FAMILIES = {
-    # preset, model, to_hf, path of the fused leaf, its rest order
+    # preset, model, to_hf (None where the family has none), and every leaf
+    # that rests in a layout of its own, with its order major to minor
     "mixtral": (MIXTRAL_CONFIGS["tiny-moe"], MixtralForCausalLM, params_to_hf_mixtral,
-                ("layers", "moe", "experts", "gate_up"), (0, 1, 3, 2, 4)),
+                {"layers/moe/experts/gate_up": (0, 1, 3, 2, 4), **heads("layers/attn", *QKV)}),
     "llama": (LLAMA_CONFIGS["tiny"], LlamaForCausalLM, params_to_hf,
-              ("layers", "mlp", "gate_up"), (0, 2, 1, 3)),
+              {"layers/mlp/gate_up": (0, 2, 1, 3), **heads("layers/attn", *QKV)}),
+    # MLA (the query through a latent, q_b: the rule's unit cases and the
+    # compiled stacks below)
+    "sarvam": (SARVAM_CONFIGS["tiny-sarvam"], SarvamForCausalLM, None,
+               {**MLA, **heads("dense_layers/attn", "q/kernel"), **heads("layers/attn", "q/kernel")}),
+    # three layer groups, two head counts
+    "laguna": (LAGUNA_CONFIGS["tiny-laguna"], LagunaForCausalLM, params_to_hf_laguna,
+               {"full_dense_layers/mlp/gate_up": (0, 2, 1, 3),
+                **{f"{g}/moe/shared/gate_up": (0, 2, 1, 3) for g in ("full_layers", "window_layers")},
+                **{f"{g}/moe/experts/gate_up": (0, 1, 3, 2, 4) for g in ("full_layers", "window_layers")},
+                **heads("full_dense_layers/attn", *QKV), **heads("full_layers/attn", *QKV),
+                **heads("window_layers/attn", *QKV)}),
 }
 
 
 def leaf_at(tree, path):
-    for key in path:
+    for key in path.split("/"):
         tree = tree[key]
     return tree
 
@@ -94,41 +131,45 @@ def serve(engine, prompts, new_tokens):
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_engine_over_rested_weights_is_the_engine_over_default_weights(family, monkeypatch):
-    cfg, model_cls, to_hf, fused_path, rest_order = FAMILIES[family]
+    cfg, model_cls, to_hf, rests = FAMILIES[family]
     params = model_cls(cfg).init(jax.random.key(0))
     kw = dict(max_batch=2, max_seq_len=64, buckets=[16, 32])
     rested = InferenceEngine(cfg, params, **kw)
     with monkeypatch.context() as m:
-        m.setattr(engine_mod, "rest_fused_weights", lambda p: (p, {"leaves": 0, "bytes": 0}))
+        m.setattr(engine_mod, "rest_weights", lambda p: (p, {"leaves": 0, "bytes": 0}))
         plain = InferenceEngine(cfg, params, **kw)
 
     # the tree the engine holds: the same keys, shapes, dtypes and shardings;
-    # one leaf in another physical order, every other leaf the caller's own
-    fused = leaf_at(params, fused_path)
-    assert rested.placement == {"leaves": 1, "bytes": fused.nbytes}
-    assert plain.params is params and order_of(leaf_at(plain.params, fused_path)) == tuple(range(fused.ndim))
+    # the named leaves in another physical order, every other leaf the
+    # caller's own
     before, after = flat(params), flat(rested.params)
+    assert set(rests) <= set(before)
+    assert rested.placement == {
+        "leaves": len(rests), "bytes": sum(before[path].nbytes for path in rests)}
+    assert plain.params is params
+    assert all(order_of(leaf) == tuple(range(leaf.ndim)) for leaf in before.values())
     assert list(before) == list(after)
     for path, old in before.items():
         new = after[path]
         assert (new.shape, new.dtype, new.sharding) == (old.shape, old.dtype, old.sharding), path
-        if path == "/".join(fused_path):
-            assert order_of(new) == rest_order
+        if path in rests:
+            assert order_of(new) == rests[path], path
             np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
         else:
             assert new is old, path
     # the caller's arrays are its own: nothing was deleted under it
-    assert not fused.is_deleted()
+    assert not any(before[path].is_deleted() for path in rests)
     # a second engine over the rested tree copies nothing
     again = InferenceEngine(cfg, rested.params, **kw)
     assert again.placement == {"leaves": 0, "bytes": 0}
-    assert leaf_at(again.params, fused_path) is leaf_at(rested.params, fused_path)
+    assert all(leaf_at(again.params, path) is after[path] for path in rests)
 
     # checkpoints leave from the rested tree as from the caller's
-    want_sd, got_sd = to_hf(params, cfg), to_hf(rested.params, cfg)
-    assert list(want_sd) == list(got_sd)
-    for name in want_sd:
-        np.testing.assert_array_equal(np.asarray(got_sd[name]), np.asarray(want_sd[name]), err_msg=name)
+    if to_hf is not None:
+        want_sd, got_sd = to_hf(params, cfg), to_hf(rested.params, cfg)
+        assert list(want_sd) == list(got_sd)
+        for name in want_sd:
+            np.testing.assert_array_equal(np.asarray(got_sd[name]), np.asarray(want_sd[name]), err_msg=name)
 
     # the same logits and tokens, bit for bit: dense prefill, bucketed
     # generate (lazily jitted), AOT-compiled generate, and the paged programs
@@ -148,7 +189,7 @@ def test_engine_over_rested_weights_is_the_engine_over_default_weights(family, m
 
 
 def test_traced_engine_reports_what_construction_placed():
-    cfg, model_cls, _, fused_path, _ = FAMILIES["mixtral"]
+    cfg, model_cls, _, rests = FAMILIES["mixtral"]
     params = model_cls(cfg).init(jax.random.key(0))
     engine = InferenceEngine(cfg, params, max_batch=2, max_seq_len=64, buckets=[16, 32])
 
@@ -160,14 +201,36 @@ def test_traced_engine_reports_what_construction_placed():
 
     traced = paged(True)
     setup = traced.tracer.timeline()["setup"]
-    assert setup["relaid_leaves"] == 1
-    assert setup["relaid_bytes"] == leaf_at(params, fused_path).nbytes
+    assert setup["relaid_leaves"] == len(rests) == 4
+    assert setup["relaid_bytes"] == sum(leaf_at(params, path).nbytes for path in rests)
     assert setup["program_temp_bytes_max"] >= 0
     # a record re-lowers as it was dispatched: for the layout the weights rest in
     rec = next(r for r in traced.program_registry().values() if r.kind == "pdecode")
-    assert order_of(leaf_at(rec.example_args[0], fused_path)) == FAMILIES["mixtral"][4]
+    assert {path: order_of(leaf_at(rec.example_args[0], path)) for path in rests} == rests
     # off unless tracing is
     assert paged(False).tracer.timeline()["setup"] == {}
+
+
+def test_leaves_of_one_shape_share_one_compiled_relayout(monkeypatch):
+    """``k`` and ``v`` of a stack, and the same projection in two layer
+    groups, are one shape, dtype and pair of layouts: the relayout (compiled
+    on every start, outside the persistent cache) compiles once for them."""
+    from neuronx_distributed_llama3_2_tpu.inference import placement
+
+    cfg, model_cls, _, rests = FAMILIES["laguna"]
+    params = model_cls(cfg).init(jax.random.key(0))
+    compiled, real = [], placement._relayout
+    monkeypatch.setattr(
+        placement, "_relayout",
+        lambda leaf, layout: (compiled.append((leaf.shape, tuple(layout.major_to_minor))),
+                              real(leaf, layout))[1])
+    rested, placed = rest_weights(params)
+    assert placed["leaves"] == len(rests) == 14
+    assert len(compiled) == len(set(compiled)) == 9
+    assert set(compiled) == {(leaf_at(params, path).shape, order) for path, order in rests.items()}
+    for path, order in rests.items():
+        assert order_of(leaf_at(rested, path)) == order
+        np.testing.assert_array_equal(np.asarray(leaf_at(rested, path)), np.asarray(leaf_at(params, path)))
 
 
 def test_placement_follows_the_leaf_and_not_the_model():
@@ -180,6 +243,25 @@ def test_placement_follows_the_leaf_and_not_the_model():
     # not fused, not a float
     assert fused_rest_layout("layers/moe/experts/down", jnp.zeros((2, 4, 2, 16), f32)) is None
     assert fused_rest_layout("layers/mlp/gate_up", jnp.zeros((2, 16, 2, 32), jnp.int8)) is None
+    # a stacked attention projection whose output is split into heads: the
+    # contraction axis minor, whatever the stack is called
+    kernel = jnp.zeros((2, 16, 32), f32)
+    for path in ("layers/attn/qkv/q_kernel", "window_layers/attn/qkv/k_kernel",
+                 "attention_layers/attention/qkv/v_kernel", "dense_layers/attn/q/kernel",
+                 "layers/attn/q_b/kernel", "layers/attn/kv_a/kernel"):
+        assert head_split_rest_layout(path, kernel).major_to_minor == (0, 2, 1), path
+        assert rest_layout(path, kernel).major_to_minor == (0, 2, 1) and fused_rest_layout(path, kernel) is None
+    assert head_split_rest_layout("layers/attn/kv_b/kernel", jnp.zeros((2, 8, 4, 16), f32)).major_to_minor == (0, 2, 1, 3)
+    # not split into heads (the output projection, the query's latent), not
+    # a stack, not under an attention block, not a float
+    for path, leaf in (("layers/attn/o/kernel", kernel), ("layers/attn/q_a/kernel", kernel),
+                       ("layers/attn/qkv/q_bias", jnp.zeros((2, 32), f32)),
+                       ("3/attn/qkv/q_kernel", jnp.zeros((16, 32), f32)),
+                       ("layers/mlp/up/kernel", kernel), ("layers/moe/router/kernel", kernel),
+                       ("layers/attn/kv_b/kernel", kernel),
+                       ("layers/attn/qkv/q_kernel", jnp.zeros((2, 16, 32), jnp.int8))):
+        assert rest_layout(path, leaf) is None, path
+    assert rest_layout("layers/mlp/gate_up", stacked[0]).major_to_minor == (0, 2, 1, 3)
 
 
 def test_the_relayout_program_never_meets_the_persistent_compile_cache(monkeypatch):
@@ -199,15 +281,15 @@ def test_the_relayout_program_never_meets_the_persistent_compile_cache(monkeypat
     try:
         leaf = jax.random.normal(jax.random.key(0), (2, 4, 16, 2, 32), jnp.float32)
         tree = {"layers": {"moe": {"experts": {"gate_up": leaf}}}}
-        rested, placed = rest_fused_weights(tree)
+        rested, placed = rest_weights(tree)
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
         # the spy sees a write: a program no earlier run can have cached
         nonce = float(time.time_ns() % 1_000_003)
         jax.block_until_ready(jax.jit(lambda a: a + nonce)(leaf))
     finally:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", before)
-    assert placed["leaves"] == 1 and written and not any("rest_fused" in n for n in written)
-    new = leaf_at(rested, FAMILIES["mixtral"][3])
+    assert placed["leaves"] == 1 and written and not any("rest_leaf" in n for n in written)
+    new = leaf_at(rested, "layers/moe/experts/gate_up")
     assert order_of(new) == (0, 1, 3, 2, 4)
     np.testing.assert_array_equal(np.asarray(new), np.asarray(leaf))
 
@@ -215,7 +297,7 @@ def test_the_relayout_program_never_meets_the_persistent_compile_cache(monkeypat
 def test_quantized_payloads_stay_where_quantization_put_them():
     cfg = LLAMA_CONFIGS["tiny"]
     qparams = quantize_params(LlamaForCausalLM(cfg).init(jax.random.key(0)), QuantizationConfig())
-    rested, placed = rest_fused_weights(qparams)
+    rested, placed = rest_weights(qparams)
     assert placed == {"leaves": 0, "bytes": 0}
     assert jax.tree.leaves(rested)[0] is jax.tree.leaves(qparams)[0]
 
@@ -230,14 +312,16 @@ def test_rested_weights_keep_their_sharding_on_a_mesh():
     parallel_state.initialize_model_parallel(tensor_model_parallel_size=2)
     model = MixtralForCausalLM(cfg)
     params = shard_pytree(model.init(jax.random.key(0)), model.specs())
-    path = FAMILIES["mixtral"][3]
+    rests = FAMILIES["mixtral"][3]
     engine = InferenceEngine(cfg, params, max_batch=2, max_seq_len=64, buckets=[16, 32])
-    old, new = leaf_at(params, path), leaf_at(engine.params, path)
-    assert isinstance(new.sharding, NamedSharding) and new.sharding == old.sharding
-    assert order_of(new) == (0, 1, 3, 2, 4)
+    for path, order in rests.items():
+        old, new = leaf_at(params, path), leaf_at(engine.params, path)
+        assert isinstance(new.sharding, NamedSharding) and new.sharding == old.sharding, path
+        assert order_of(new) == order, path
     # a leaf that jit would spread over the mesh itself is not pinned to one device
     loose = model.init(jax.random.key(0))
-    assert leaf_at(rest_fused_weights(loose)[0], path) is leaf_at(loose, path)
+    rested_loose = rest_weights(loose)[0]
+    assert all(leaf_at(rested_loose, path) is leaf_at(loose, path) for path in rests)
     prompt = list(range(1, 13))
     gen = GenerationConfig(max_new_tokens=4, sampling=SamplingConfig(greedy=True))
     got = engine.generate([prompt], gen).sequences
@@ -280,45 +364,75 @@ def v5e():
         pytest.skip(f"libtpu cannot describe a v5e here: {exc}")
 
 
-def compile_paged(device, program, rested, cfg=AOT, train=MixtralForCausalLM, blocks=256, kv=None):
+def abstract_weights(cfg, train):
+    return jax.eval_shape(train(cfg).init, jax.random.key(0))
+
+
+def compile_paged(device, program, weights, cfg=AOT, train=MixtralForCausalLM, blocks=256, kv=None,
+                  block_size=16):
     """The paged decode step at 16 lanes, or a 512-token suffix step, over
     ``cfg``'s layer stack (the 2-layer MoE where nothing else is asked) and a
-    donated pool of ``blocks`` blocks, with the weights placed as the engine
-    places them (``rested``) or as ``model.init`` leaves them."""
+    donated pool of ``blocks`` blocks, with the ``weights`` placed as the
+    engine places them (``"rested"``), as ``model.init`` leaves them
+    (``"default"``), or in whatever layout the compiler picks for each
+    (``"auto"``: ``Layout.AUTO``, its choice to be read from ``input_formats``). A kind of cache the engine lays out a
+    lane (a ring of blocks, a state's slot) gets its table as the engine
+    would size it."""
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(device)
     model = decode_model_for(cfg)
+    lanes, top = 16, 1024
+    assert weights in ("default", "rested", "auto"), weights
+    auto = weights == "auto"
 
     def place(path, a):
-        layout = fused_rest_layout(path, a) if rested else None
+        layout = rest_layout(path, a) if weights == "rested" else None
         return jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one if layout is None else Format(layout, one)
+            a.shape, a.dtype,
+            sharding=None if auto else one if layout is None else Format(layout, one),
         )
 
-    params = walk_tree(jax.eval_shape(train(cfg).init, jax.random.key(0)), place)
+    shapes = abstract_weights(cfg, train)
+    params = walk_tree(shapes, place)
+    a_lane_kind = next((kind for kind in model.cache_kinds if kind.rows is not None), None)
+    sized, a_lane = {}, ()
+    if a_lane_kind is not None:
+        per_lane = 1 if a_lane_kind.state else -(-(a_lane_kind.rows - 1 + 512) // block_size)
+        sized = {f"{a_lane_kind.name}_blocks": 1 + lanes * per_lane}
+        a_lane = (f"{a_lane_kind.name}_tables", per_lane)
     cache = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
-        jax.eval_shape(lambda: model.init_paged_cache(blocks, 16, kv_cache_dtype=kv)),
+        jax.eval_shape(lambda: model.init_paged_cache(blocks, block_size, kv_cache_dtype=kv, **sized)),
     )
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)  # noqa: E731
+    width = -(-top // block_size) + -(-512 // block_size)
     if program == "pdecode":
-        def fn(params, cache, tokens, positions, tables):
-            return model.decode_step(params, cache, tokens, positions, tables, kv_limit=1024)
-        args = (i32(16), i32(16), i32(16, 64))
+        def fn(params, cache, tokens, positions, tables, *lane):
+            by_lane = {a_lane[0]: lane[0]} if lane else {}
+            return model.decode_step(params, cache, tokens, positions, tables, kv_limit=top, **by_lane)
+        args = (i32(lanes), i32(lanes), i32(lanes, width)) + ((i32(lanes, a_lane[1]),) if a_lane else ())
     else:
-        def fn(params, cache, ids, start, table):
+        def fn(params, cache, ids, start, length, table, *lane):
+            by_lane = {a_lane[0]: lane[0]} if lane else {}
+            # a state keeps what a padded row does to it: it is told the live rows
             return model.forward(params, cache, ids, start, None, return_hidden=True,
-                                 block_tables=table, kv_limit=1024)
-        args = (i32(1, 512), i32(1), i32(1, 64))
-    return jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args).compile()
+                                 block_tables=table, kv_limit=top,
+                                 row_live=length if model.keeps_state else None, **by_lane)
+        args = (i32(1, 512), i32(1), i32(1), i32(1, width)) + ((i32(1, a_lane[1]),) if a_lane else ())
+    layouts = {}
+    if auto:
+        layouts["in_shardings"] = (
+            jax.tree.map(lambda a: Format(Layout.AUTO, one), shapes),
+            jax.tree.map(lambda a: one, cache), *(one for _ in args))
+    return jax.jit(fn, donate_argnums=(1,), **layouts).lower(params, cache, *args).compile()
 
 
 @pytest.mark.parametrize("program", ["pdecode", "psfx"])
 def test_paged_program_over_rested_weights_copies_no_layer_of_gate_up(v5e, program):
     one_layer = r"(\S*dynamic-slice\S*) = bf16\[1,%s\]" % ",".join(map(str, LAYER_SHAPE))
-    control = compile_paged(v5e, program, rested=False)
-    rested = compile_paged(v5e, program, rested=True)
+    control = compile_paged(v5e, program, "default")
+    rested = compile_paged(v5e, program, "rested")
     # the control copies a layer's gate_up out of the stack (and re-tiles it)
     assert re.search(one_layer, control.as_text())
     assert not re.search(one_layer, rested.as_text())
@@ -328,6 +442,127 @@ def test_paged_program_over_rested_weights_copies_no_layer_of_gate_up(v5e, progr
     assert saved >= 0.95 * LAYER_BYTES, (saved, LAYER_BYTES)
     # the parameter rests tiled as the dot reads it
     assert "bf16[2,%s]{4,2,3,1,0:T(8,128)(2,1)}" % ",".join(map(str, LAYER_SHAPE)) in rested.as_text()
+
+
+# ---------------------------------------------------------------------------
+# compiled for a described v5e: no copy of a layer's attention kernel, and the
+# rule is the compiler's own choice
+# ---------------------------------------------------------------------------
+
+def published(presets, name, **changes):
+    """A served family's published widths over a stack a few layers deep, with
+    a small vocabulary: what a layer's kernels look like in a cell."""
+    return dataclasses.replace(
+        presets[name], vocab_size=2048, max_seq_len=1024, dtype=jnp.bfloat16, **changes)
+
+
+def laguna_stack():
+    from neuronx_distributed_llama3_2_tpu.models.laguna import _published_lists
+
+    # f w w w f: two full layers (one dense, one sparse) and a window stack of three
+    return published(LAGUNA_CONFIGS, "laguna-xs.2", num_layers=5, num_experts=8,
+                     **_published_lists(5, 48, 64))
+
+
+# family -> (config, training model, block size of the paged pool, its blocks)
+STACKS = {
+    "llama": lambda: (published(LLAMA_CONFIGS, "llama3.2-1b", num_layers=2), LlamaForCausalLM, 16, 256),
+    "mixtral": lambda: (published(MIXTRAL_CONFIGS, "mixtral-8x7b", num_layers=2, num_experts=4,
+                                  intermediate_size=3584), MixtralForCausalLM, 16, 256),
+    "olmoe": lambda: (published(OLMOE_CONFIGS, "olmoe-1b-7b", num_layers=2, num_experts=8),
+                      OlmoeForCausalLM, 16, 256),
+    # MLA: one dense layer and two of experts
+    "sarvam": lambda: (published(SARVAM_CONFIGS, "sarvam-105b", num_layers=3, num_experts=8),
+                       SarvamForCausalLM, 16, 256),
+    "xing": lambda: (published(XING_CONFIGS, "xing4.0-29b-a4b", num_layers=3, num_experts=8),
+                     XingForCausalLM, 16, 256),
+    "laguna": lambda: (laguna_stack(), LagunaForCausalLM, 16, 256),
+    # a block of the pool is one sequence's state: 16 lanes and the null block
+    "brumby": lambda: (published(BRUMBY_CONFIGS, "brumby-14b", num_layers=2), BrumbyForCausalLM, 1024, 17),
+    # M A M M A: two multi-query attention layers among three state-space ones
+    "jamba": lambda: (published(JAMBA_CONFIGS, "jamba2-3b", num_layers=5, attn_layer_period=3,
+                                attn_layer_offset=1), JambaForCausalLM, 16, 256),
+}
+# where the compiler, left to itself, lays a leaf of a megabyte a layer or more
+# out otherwise than the rule, and copies nothing either way (the first test
+# below says so for the attention kernels; a prefill chunk expands latents
+# through kv_b heads-major, where the decode step, which the rule follows,
+# absorbs it; x_proj's three-way split of a state-space layer is no head split
+# and the default-placed program holds no copy of it)
+THE_COMPILER_ALONE = {
+    ("sarvam", "psfx"): {"dense_layers/attn/kv_b/kernel": (0, 2, 3, 1), "layers/attn/kv_b/kernel": (0, 2, 3, 1)},
+    ("xing", "psfx"): {"dense_layers/attn/kv_b/kernel": (0, 2, 3, 1), "layers/attn/kv_b/kernel": (0, 2, 3, 1)},
+    ("jamba", "pdecode"): {"mamba_layers/mamba/x_proj/kernel": (0, 2, 1)},
+    ("jamba", "psfx"): {"mamba_layers/mamba/x_proj/kernel": (0, 2, 1)},
+}
+A_LAYER_THAT_COUNTS = 1_000_000     # bytes: below it a leaf is re-laid or not at no cost
+
+
+def copies_of_a_layer(text, leaves):
+    """``copy`` instructions of the compiled text whose result is one layer of
+    one of ``leaves`` (with or without the leading 1): (name, type) pairs."""
+    dims = set()
+    for leaf in leaves:
+        dims |= {",".join(map(str, leaf.shape[1:])), ",".join(map(str, (1,) + leaf.shape[1:]))}
+    return [
+        (name, result)
+        for name, result, op in re.findall(r"^\s*(?:ROOT )?%?(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(", text, re.M)
+        if op == "copy" and result[result.index("[") + 1:-1] in dims
+    ]
+
+
+def compile_stack(device, family, program, weights, monkeypatch):
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+
+    monkeypatch.setenv(KERNEL_MODE_ENV, "compiled")
+    cfg, train, block_size, blocks = STACKS[family]()
+    compiled = compile_paged(device, program, weights, cfg=cfg, train=train, blocks=blocks,
+                             block_size=block_size)
+    return compiled, flat(abstract_weights(cfg, train))
+
+
+@pytest.mark.parametrize("program", ["pdecode", "psfx"])
+@pytest.mark.parametrize("family", sorted(STACKS))
+def test_paged_program_over_rested_weights_copies_no_layer_of_an_attention_kernel(
+        v5e, family, program, monkeypatch):
+    control, shapes = compile_stack(v5e, family, program, "default", monkeypatch)
+    rested, _ = compile_stack(v5e, family, program, "rested", monkeypatch)
+    split = [leaf for path, leaf in shapes.items() if head_split_rest_layout(path, leaf) is not None]
+    assert len(split) >= 3
+    # the control slices a layer's kernel out of the stack and transposes it
+    # before the matmul; over rested weights the slice is what the dot reads
+    assert copies_of_a_layer(control.as_text(), split)
+    assert not copies_of_a_layer(rested.as_text(), split), copies_of_a_layer(rested.as_text(), split)
+
+
+@pytest.mark.parametrize("program", ["pdecode", "psfx"])
+@pytest.mark.parametrize("family", sorted(STACKS))
+def test_the_rule_is_the_compilers_own_choice(v5e, family, program, monkeypatch):
+    """Lowered with ``Layout.AUTO`` on the weights, the compiled program says
+    in ``input_formats`` how it wants each: for every leaf of a megabyte a
+    layer or more that is the layout the rule gives — fused ``gate_up``
+    (PR 24's rule) and the attention projections alike — and the default one
+    where the rule gives none."""
+    compiled, shapes = compile_stack(v5e, family, program, "auto", monkeypatch)
+    # no layout: the program does not read the leaf (a suffix step stops at
+    # the hidden state and never meets the head)
+    chosen = {path: fmt.layout and tuple(fmt.layout.major_to_minor)
+              for path, fmt in flat(compiled.input_formats[0][0]).items()}
+    assert list(chosen) == list(shapes)
+    alone = THE_COMPILER_ALONE.get((family, program), {})
+    assert set(alone) <= set(shapes)
+    judged = relaid = 0
+    for path, leaf in shapes.items():
+        if leaf.size * leaf.dtype.itemsize // leaf.shape[0] < A_LAYER_THAT_COUNTS or chosen[path] is None:
+            continue
+        layout = rest_layout(path, leaf)
+        ruled = tuple(range(leaf.ndim)) if layout is None else tuple(layout.major_to_minor)
+        assert chosen[path] == alone.get(path, ruled), (path, leaf.shape, chosen[path], ruled)
+        assert path not in alone or alone[path] != ruled, path
+        judged += 1
+        relaid += layout is not None
+    # every stack has its gate_up and its head-split projections among them
+    assert judged > relaid >= 3, (judged, relaid)
 
 
 # ---------------------------------------------------------------------------
@@ -380,7 +615,7 @@ def test_a_pool_that_is_the_scans_xs_and_ys_is_copied_whole(v5e):
 @pytest.mark.parametrize("program", ["pdecode", "psfx"])
 def test_paged_program_updates_the_donated_pool_in_place(v5e, program, kv):
     compiled = compile_paged(
-        v5e, program, rested=True, cfg=POOL_AOT, train=LlamaForCausalLM,
+        v5e, program, "rested", cfg=POOL_AOT, train=LlamaForCausalLM,
         blocks=POOL_BLOCKS, kv=kv,
     )
     text = compiled.as_text()
