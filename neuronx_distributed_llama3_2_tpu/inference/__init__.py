@@ -37,6 +37,7 @@ from neuronx_distributed_llama3_2_tpu.inference.model import (
     MixtralDecode,
     PagedKVCache,
     RetentionDecode,
+    SalaDecode,
     SarvamDecode,
     XingDecode,
     StateCache,
@@ -87,6 +88,7 @@ __all__ = [
     "CacheKind",
     "HybridCache",
     "JambaDecode",
+    "SalaDecode",
     "LagunaDecode",
     "MixedKVCache",
     "RetentionDecode",

@@ -199,7 +199,8 @@ def cache_row_bytes(cache: Any) -> int:
     are tiled (16, 128), so a 576-wide row occupies 640 — over its
     (layer, block or slot, row) positions. Compiles an identity; for a
     traced engine's ``setup`` record."""
-    return _laid_out_bytes(cache) // math.prod(jax.tree.leaves(cache)[0].shape[:3])
+    positions = getattr(cache, "positions", None) or math.prod(jax.tree.leaves(cache)[0].shape[:3])
+    return _laid_out_bytes(cache) // positions
 
 
 def _laid_out_bytes(cache: Any) -> int:
@@ -283,6 +284,51 @@ class HybridCache(NamedTuple):
     quantized = False
 
 
+class SparseRows(NamedTuple):
+    """The ``rows`` kind of a stack whose attention layers choose the blocks
+    they read (:class:`SalaDecode`): k / v (L_s, num_blocks, NKV, block_size,
+    D), block 0 the null block — **a block's rows of one kv head lie
+    together**, because a kv group chooses its own blocks and reads its own
+    head of them (side by side in a row, as :class:`HybridCache`'s other rows
+    are, a group's gather moves every head's bytes) — and a third leaf beside
+    them, the **pooled index keys** ``pooled`` (L_s, R, NKV · D): one row a
+    kernel, kernel ``j`` of the sequence — the mean of its rows ``stride · j
+    ..`` — in row ``block · (block_size / stride) + j mod (block_size /
+    stride)`` of the pool block that holds its first row, so the block table
+    that names a row's block names its kernels' too. ``R`` is the blocks' rows
+    rounded up to whole (16, 128) tiles, so every layer's kernels are one run
+    of rows without a copy."""
+
+    k: jax.Array
+    v: jax.Array
+    pooled: jax.Array
+
+    @property
+    def num_blocks(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def positions(self) -> int:
+        """(layer, block, row) positions: what :func:`cache_row_bytes` divides by."""
+        return self.k.shape[0] * self.k.shape[1] * self.k.shape[3]
+
+    quantized = False
+
+
+class MatrixState(NamedTuple):
+    """What the Lightning layers of a stack keep (:mod:`..models.minicpm_sala`):
+    ``s`` (L_l, slots, heads, D, D), a decayed sum of ``kᵀ v`` a head, float32
+    unless asked otherwise. Slot 0 is the null slot (see :class:`SsmState`)."""
+
+    s: jax.Array
+
+    quantized = False
+
+
 def cache_block_bytes(cache: Any) -> int:
     """Bytes one block (or slot) of ``cache`` holds over all layers and all of
     its arrays, as the device lays them out (see :func:`cache_row_bytes`)."""
@@ -329,6 +375,12 @@ class LlamaDecode:
         """Whether a decode program of this model holds the one-pass state
         kernel (:class:`RetentionDecode`); the engine counts such dispatches."""
         return False
+
+    def selected_rows(self, context: int) -> Optional[Tuple[int, int]]:
+        """Where a layer reads only the blocks it chooses (:class:`SalaDecode`):
+        (rows a decode step's query at the last of ``context`` rows reads a
+        layer, blocks among them it did not choose by score), else None."""
+        return None
 
     def residual_row_bytes(self) -> Optional[int]:
         """Bytes a token's state takes between layers where the layer loop
@@ -2722,6 +2774,368 @@ class JambaDecode(LlamaDecode):
             return masked_attention(q, heads(k_all), heads(v_all), seen), kc, vc
 
 
+# rows of a chunk's sparse read a loop trip attends over: 32 blocks of 64
+SPARSE_TILE_ROWS = 2048
+# slots of the Lightning state where :meth:`SalaDecode.init_paged_cache` is
+# given no count (``benchmarks/check.py``'s pools): the null slot and one a
+# lane up to 32 lanes — 2.1 MB a slot a layer at the published widths, where
+# one a *block* would be gigabytes
+DEFAULT_STATE_SLOTS = 33
+
+
+@dataclasses.dataclass(frozen=True)
+class SalaDecode(LlamaDecode):
+    """Decode-mode MiniCPM-SALA (:mod:`..models.minicpm_sala`): block-sparse
+    softmax attention layers and Lightning linear-attention layers in one
+    stack named layer by layer, over a :class:`HybridCache` of
+    :class:`SparseRows` and a :class:`MatrixState`, paged only.
+
+    A **sparse layer** writes its fresh k / v rows through the lane's
+    ``block_tables`` and then the pooled keys of the kernels those rows
+    complete — a kernel's first rows may lie in an earlier call's, so its rows
+    are read back from the pool. Then it selects
+    (:func:`..models.minicpm_sala.select_blocks` over the lane's pooled keys,
+    bounded by ``kv_limit``) and reads: one token a lane (``pdecode``) gathers
+    **the chosen blocks alone** — ``sparse_topk`` a kv group whatever the
+    context — and a block of rows (``pctx`` / ``psfx``) walks the context a
+    tile of :data:`SPARSE_TILE_ROWS` rows at a time with a running max and
+    sum, the selection a per-(row, block) mask, so no (heads, rows, context)
+    array exists at any context. ``pctx`` reads its own rows back through the
+    table like any other call: the rule that picks a row's blocks is the same
+    at every row, so the result does not depend on how a prompt was chunked.
+    No rotary table touches these layers.
+
+    A **Lightning layer** reads and writes its lane's slot of the state kind
+    (``state_tables``'; where none is given lane ``i``'s is slot ``1 + i``):
+    one token a lane is the step form as a pass over every slot of a layer
+    where it lies (a slot no live lane names comes back bit for bit); a block
+    of rows is the chunk form — from the **zero state** under
+    ``context_encode`` (a slot still holds its last request's past), from the
+    slot's otherwise. ``row_live`` is the count of real rows of a padded
+    block: rows at or past it leave the state untouched, and complete no
+    kernel. A lane whose first fresh row ``block_tables`` sends to the null
+    block goes to the null slot (see :class:`JambaDecode`).
+
+    Both kinds ride the layer loop as its carry, the layer folded into the
+    row index. Refused, with the reason: tree (speculative) blocks and a
+    quantized pool (a state has neither rejected rows to take back nor a scale
+    a row), the dense slot cache (the selection is addressed through a block
+    table), ``tp > 1`` (two kv heads, and a bare gather of chosen blocks a kv
+    group, are not partitioned), and a pool whose block is not the selection's."""
+
+    # shardlint SL002 — see LlamaDecode: the refusal below reads the same
+    # parallel state the inherited traces do
+    __layout_deps__ = LlamaDecode.__layout_deps__
+
+    def __post_init__(self):
+        from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
+
+        if (
+            parallel_state.model_parallel_is_initialized()
+            and parallel_state.get_tensor_model_parallel_size() > 1
+        ):
+            raise NotImplementedError(
+                "SalaDecode under tp > 1: the sparse layers have two kv heads and "
+                "gather a kv group's chosen blocks whole; neither is partitioned")
+
+    def _model(self):
+        from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import SalaForCausalLM
+
+        return SalaForCausalLM(self.config)
+
+    # -- cache ------------------------------------------------------------
+
+    @property
+    def cache_kinds(self) -> Tuple[CacheKind, ...]:
+        from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import LIGHTNING, SPARSE
+
+        c = self.config
+        return (
+            CacheKind("rows", c.layers_of(SPARSE), None),
+            CacheKind("state", c.layers_of(LIGHTNING), 0, state=True),
+        )
+
+    def cache_row_dims(self) -> Tuple[int, int, int]:
+        return 2, 1, self.config.num_kv_heads * self.config.head_dim
+
+    def init_cache(self, max_batch: int, max_len: int, dtype: Any = None):
+        raise NotImplementedError(
+            "SalaDecode has no dense slot cache: a sparse layer's selection is addressed "
+            "through a block table — serve it with the paged engine")
+
+    def init_paged_cache(
+        self, num_blocks: int, block_size: int, dtype: Any = None,
+        kv_cache_dtype: Optional[str] = None, state_blocks: Optional[int] = None,
+    ) -> HybridCache:
+        """``num_blocks`` sizes the sparse layers' pool, pooled keys included;
+        ``state_blocks`` the slots of the state kind (where not given, as many
+        as the blocks up to :data:`DEFAULT_STATE_SLOTS`). ``dtype`` is both
+        kinds': a state in less than float32 is what the benchmark's check has
+        to fail."""
+        from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import (
+            LIGHTNING, SPARSE, STATE_DTYPE,
+        )
+
+        c = self.config
+        if kv_cache_dtype not in (None, "bf16"):
+            raise NotImplementedError(
+                f"kv_cache_dtype={kv_cache_dtype!r}: a Lightning layer's state is a running "
+                "sum of every row so far, not rows with a scale each, and the pooled "
+                "keys are means of rows")
+        if block_size != c.sparse_block_size:
+            raise ValueError(
+                f"block_size {block_size}: a pool block is one selection block "
+                f"({c.sparse_block_size} rows), so that a choice of blocks is a choice "
+                "of table entries")
+        width = c.num_kv_heads * c.head_dim
+        rows = (c.layers_of(SPARSE), num_blocks, c.num_kv_heads, block_size, c.head_dim)
+        kernels = num_blocks * c.kernels_per_block
+        tile = math.lcm(16, c.kernels_per_block)
+        pooled = (c.layers_of(SPARSE), tile * -(-kernels // tile), width)
+        slots = state_blocks or min(num_blocks, DEFAULT_STATE_SLOTS)
+        state = (c.layers_of(LIGHTNING), slots, c.lightning_heads, c.head_dim, c.head_dim)
+        row_dtype = dtype or c.dtype
+        return HybridCache(
+            rows=SparseRows(
+                k=jnp.zeros(rows, row_dtype), v=jnp.zeros(rows, row_dtype),
+                pooled=jnp.zeros(pooled, row_dtype)),
+            state=MatrixState(s=jnp.zeros(state, dtype or STATE_DTYPE)),
+        )
+
+    def paged_cache_specs(self, quantized: bool = False) -> HybridCache:
+        return HybridCache(rows=SparseRows(k=P(), v=P(), pooled=P()), state=MatrixState(s=P()))
+
+    def cache_specs(self, max_batch: Optional[int] = None) -> HybridCache:
+        return self.paged_cache_specs()
+
+    def forbidden_gather_shapes(self, batch: int, kv_limit: int):
+        return set()
+
+    def _paged_kernel_eligible(self, t: int, tree) -> bool:
+        return False
+
+    def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
+        """Both kinds ``"gather"``: the lanes' states through their slots, and
+        of the rows the chosen blocks alone, through the table."""
+        return "gather"
+
+    def chunk_scan(self) -> str:
+        """How a block of rows goes through a Lightning layer: ``"chunk"``,
+        the matmul form over the block and the carried state."""
+        return "chunk"
+
+    def selected_rows(self, context: int) -> Tuple[int, int]:
+        c = self.config
+        bs, p = c.sparse_block_size, context - 1
+        behind = p // bs + 1
+        first = max(p - c.sparse_window + 1, 0) // bs
+        forced = min(behind, behind - first + min(c.sparse_init_blocks, first))
+        taken = min(behind, c.sparse_topk)
+        # every taken block whole but the one the query's own row lies in
+        return (taken - 1) * bs + p % bs + 1, forced
+
+    # -- forward ----------------------------------------------------------
+
+    def forward(
+        self, params: Params, cache: HybridCache, tokens: jax.Array, positions: jax.Array,
+        slots: Optional[jax.Array] = None, *, context_encode: bool = False,
+        return_hidden: bool = False, tree=None, kv_limit: Optional[int] = None,
+        block_tables: Optional[jax.Array] = None, row_live: Optional[jax.Array] = None,
+        state_tables: Optional[jax.Array] = None,
+    ) -> Tuple[jax.Array, HybridCache]:
+        """tokens (b, T) at rows ``positions ..`` over ``cache`` (see the
+        class); returns (logits (b, T, V) or the normed hidden, the cache
+        updated)."""
+        if tree is not None:
+            raise NotImplementedError("tree verification over a Lightning layer's state")
+        if block_tables is None:
+            raise NotImplementedError("SalaDecode is paged only: pass block_tables")
+        from neuronx_distributed_llama3_2_tpu.models.laguna import scan_run
+        from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import (
+            LIGHTNING, SPARSE, SalaMixer, layer_runs, lightning_chunk, lightning_slopes,
+            lightning_step,
+        )
+
+        c = self.config
+        model = self._model()
+        norm = make_norm(c)
+        mixers = {kind: SalaMixer(c, kind) for kind in (SPARSE, LIGHTNING)}
+        b, t = tokens.shape
+        bs = cache.block_size
+        pos_block = positions[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        state_slots = cache.state.s.shape[1]
+        if state_tables is None:
+            if state_slots < b + 1:
+                raise ValueError(
+                    f"{state_slots} state slots for {b} lanes and no state_tables: "
+                    "lane i's slot is 1 + i")
+            own = 1 + jnp.arange(b, dtype=jnp.int32)
+        else:
+            own = state_tables[:, 0]
+        first = jnp.take_along_axis(block_tables, positions[:, None] // bs, axis=1)[:, 0]
+        index = jnp.where(first == 0, 0, own)
+        live = jnp.full((b,), t, jnp.int32) if row_live is None else row_live
+        # one token a lane passes over every slot of a layer where it lies,
+        # read once and written once, nothing gathered (:class:`JambaDecode`'s
+        # pass); a block of rows of one lane takes its slot out and puts it back
+        step = t == 1 and not context_encode
+        if step:        # the slots some live lane names; never the null slot
+            named = jnp.zeros((state_slots,), bool).at[index].set(live > 0).at[0].set(False)
+        width = block_tables.shape[1] * bs
+        # rows a sparse layer may read: the rung, or — a context-encode call
+        # reads its own rows back — the block's own, in whole blocks
+        limit = bs * -(-t // bs) if context_encode else min(kv_limit or width, width)
+        sin, cos = self._rope_tables(width)
+        slopes = lightning_slopes(c.lightning_heads)
+        scale = c.residual_scale
+
+        def lightning(carry, lp, j, first_layer):
+            x, rows, (s_pool,) = carry
+            hn = norm(lp["attn_norm"], x)
+            with jax.named_scope("attn"):
+                q, k, v = mixers[LIGHTNING].project(lp["attn"], hn, sin, cos, pos_block)
+                # a state's way out of its slot and back is part of the form
+                with jax.named_scope("lightning"):
+                    if step:
+                        with jax.named_scope("step"):
+                            # the layer's slots where they lie, in slot order:
+                            # the lanes' rows go to their slots, a slot no live
+                            # lane names comes back as it was, bit for bit
+                            to_slots = lambda a: jnp.zeros(  # noqa: E731
+                                (state_slots,) + a.shape[2:], a.dtype).at[index].set(a[:, 0])
+                            o, s_out = lightning_step(
+                                to_slots(q), to_slots(k), to_slots(v),
+                                jax.lax.dynamic_index_in_dim(s_pool, first_layer + j, 0, keepdims=False),
+                                named, slopes)
+                            o = o[index][:, None]
+                            s_pool = jax.lax.dynamic_update_index_in_dim(s_pool, s_out, first_layer + j, 0)
+                    else:
+                        with jax.named_scope("chunk"):
+                            flat = s_pool.reshape((-1,) + s_pool.shape[2:])     # every layer's slots in one run
+                            at = (first_layer + j) * state_slots + index
+                            s_in = jnp.zeros((b,) + flat.shape[1:], flat.dtype) if context_encode else flat[at]
+                            o, s_out = lightning_chunk(q, k, v, s_in, live, slopes)
+                            s_pool = flat.at[at].set(s_out).reshape(s_pool.shape)
+                out = mixers[LIGHTNING].output(lp["attn"], hn, o)
+            return x + scale * out, rows, (s_pool,)
+
+        def sparse(carry, lp, j, first_layer):
+            x, rows, state = carry
+            hn = norm(lp["attn_norm"], x)
+            with jax.named_scope("attn"):
+                q, k, v = mixers[SPARSE].project(lp["attn"], hn, None, None, pos_block)
+                att, rows = self._attend_sparse(
+                    q, k, v, rows, first_layer + j, pos_block, live, block_tables, limit)
+                out = mixers[SPARSE].output(lp["attn"], hn, att)
+            return x + scale * out, rows, state
+
+        bodies = {LIGHTNING: lightning, SPARSE: sparse}
+        x = model.embed(params, tokens)
+        x = constrain(x, P(BATCH_AXES, None, None))
+        carry = (x, tuple(cache.rows), tuple(cache.state))
+        for run in layer_runs(c):
+
+            def body(carry, lp, j, run=run):
+                x, rows, state = bodies[run.kind](carry, lp, j, run.kind_first)
+                hn = norm(lp["mlp_norm"], x)
+                return (x + scale * self._mlp_block(lp, hn), rows, state), None
+
+            carry, _ = scan_run(body, carry, params[run.stack], run)
+        x, rows, state = carry
+        x = norm(params["final_norm"], x)
+        new_cache = HybridCache(rows=SparseRows(*rows), state=MatrixState(*state))
+        if return_hidden:
+            return x, new_cache
+        return model._logits(params, x), new_cache
+
+    def _attend_sparse(self, q, k, v, rows, layer, pos_block, live, table, limit: int):
+        """One sparse layer over the pool: write the fresh rows k, v (b, T,
+        NKV, D) at ``pos_block`` and the pooled keys they complete, select, and
+        attend q (b, T, N, D) over the chosen blocks of the first ``limit``
+        rows. ``rows``: the pool's (k, v, pooled). Returns (att (b, T, N, D),
+        rows)."""
+        from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import (
+            attend_tiles, block_mask, pool_keys, select_blocks,
+        )
+
+        c = self.config
+        kc, vc, pc = rows
+        b, t = pos_block.shape
+        nl, nb, nkv, bs, d = kc.shape
+        width, per = nkv * d, c.kernels_per_block
+        heads = jnp.arange(nkv, dtype=jnp.int32)
+
+        def rows_at(at):        # rows ``at`` (b, ...) of the layer's blocks, a kv head each: (b, ..., NKV)
+            return ((layer * nb + block_of(at // bs))[..., None] * nkv + heads) * bs + (at % bs)[..., None]
+        stride, size, kernel_rows = c.kernel_stride, c.kernel_size, pc.shape[1]
+        wide = table.shape[1]
+
+        def block_of(at):       # table entry of logical block ``at`` (b, ...), past the table the null block
+            got = jnp.take_along_axis(table, jnp.minimum(at, wide - 1).reshape(b, -1), axis=1)
+            return jnp.where(at < wide, got.reshape(at.shape), 0)
+
+        with jax.named_scope("kv_write"):
+            at = rows_at(pos_block)
+            kc = kc.reshape(-1, d).at[at].set(k.astype(kc.dtype)).reshape(kc.shape)
+            vc = vc.reshape(-1, d).at[at].set(v.astype(vc.dtype)).reshape(vc.shape)
+        with jax.named_scope("sparse"):
+            with jax.named_scope("pool_keys"):
+                # the kernels whose last row is one of the live fresh rows: at
+                # most one a stride; their rows are read back from the pool
+                start = pos_block[:, 0]
+                j = jnp.maximum((start - size + stride) // stride, 0)[:, None] + jnp.arange(
+                    -(-t // stride), dtype=jnp.int32)[None, :]
+                last = stride * j + size - 1
+                done = (last >= start[:, None]) & (last < (start + live)[:, None])
+                of = stride * j[..., None] + jnp.arange(size, dtype=jnp.int32)      # (b, n, size)
+                got = jnp.moveaxis(kc.reshape(-1, d)[rows_at(of)], 2, 3)           # (b, n, NKV, size, D)
+                at = layer * kernel_rows + jnp.where(done, block_of(j // per) * per + j % per, 0)
+                pc = pc.reshape(-1, width).at[at].set(pool_keys(got).reshape(b, -1, width)).reshape(pc.shape)
+            blocks = -(-limit // bs)
+            ids = table[:, :blocks]
+            with jax.named_scope("select"):
+                at = layer * kernel_rows + ids[..., None] * per + jnp.arange(per, dtype=jnp.int32)
+                pooled = pc.reshape(-1, width)[at.reshape(b, -1)].reshape(b, -1, nkv, d)
+                chosen, taken = select_blocks(q, pooled.astype(q.dtype), pos_block, c)
+            with jax.named_scope("read"):
+                k_blocks, v_blocks = (a.reshape(nl * nb * nkv, bs, d) for a in (kc, vc))
+                if t == 1:
+                    # one token a lane: the chosen blocks alone, a kv group
+                    # its own head of each
+                    kk = chosen.shape[-1]
+                    at = (layer * nb + jnp.take_along_axis(
+                        ids, chosen.reshape(b, -1), axis=1).reshape(b, nkv, kk)) * nkv + heads[:, None]
+
+                    def group_rows(pool):       # (b, NKV, kk · bs, D)
+                        return pool[at].reshape(b, nkv, kk * bs, d).astype(q.dtype)
+
+                    at_rows = (chosen[:, 0, :, :, None] * bs + jnp.arange(bs, dtype=jnp.int32))
+                    seen = taken[:, 0, :, :, None] & (at_rows <= pos_block[:, :, None, None])
+                    qg = q.reshape(b, nkv, c.num_heads // nkv, d)
+                    s = jnp.einsum(
+                        "bkgd,bksd->bkgs", qg, group_rows(k_blocks),
+                        preferred_element_type=jnp.float32) * d ** -0.5
+                    s = jnp.where(seen.reshape(b, nkv, 1, kk * bs), s, jnp.float32(-1e30))
+                    w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+                    att = jnp.einsum("bkgs,bksd->bkgd", w, group_rows(v_blocks))
+                    att = att.reshape(b, 1, c.num_heads, d)
+                else:
+                    tile = min(blocks, max(SPARSE_TILE_ROWS // bs, 1))
+                    tiles = -(-blocks // tile)
+                    ids = jnp.pad(ids, ((0, 0), (0, tiles * tile - blocks)))
+
+                    def read(i):        # a tile's blocks, every kv head's: (b, tile · bs, NKV, D)
+                        at = (layer * nb + jax.lax.dynamic_slice_in_dim(
+                            ids, i * tile, tile, axis=1))[..., None] * nkv + heads
+                        return tuple(
+                            jnp.swapaxes(pool[at], 2, 3).reshape(b, tile * bs, nkv, d).astype(q.dtype)
+                            for pool in (k_blocks, v_blocks))
+
+                    att = attend_tiles(
+                        q, pos_block, block_mask(chosen, taken, blocks), read, tiles, tile, bs)
+        return att, (kc, vc, pc)
+
+
 def _pool_pair(pool: PagedKVCache):
     """A pool as the layer loop carries it: (k, v), each a (payload, scale)
     pair where quantized."""
@@ -2813,6 +3227,7 @@ def decode_model_for(config) -> LlamaDecode:
     from neuronx_distributed_llama3_2_tpu.models.gptneox import GPTNeoXConfig
     from neuronx_distributed_llama3_2_tpu.models.jamba import JambaConfig
     from neuronx_distributed_llama3_2_tpu.models.laguna import LagunaConfig
+    from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import SalaConfig
     from neuronx_distributed_llama3_2_tpu.models.mixtral import MixtralConfig
     from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamConfig
     from neuronx_distributed_llama3_2_tpu.models.xing import XingConfig
@@ -2832,6 +3247,8 @@ def decode_model_for(config) -> LlamaDecode:
         return RetentionDecode(config)
     if isinstance(config, JambaConfig):
         return JambaDecode(config)
+    if isinstance(config, SalaConfig):
+        return SalaDecode(config)
     if isinstance(config, LagunaConfig):
         return LagunaDecode(config)
     if isinstance(config, MixtralConfig):

@@ -44,7 +44,12 @@ weights, ``pdecode`` and a 512-row ``psfx`` of every served family report
 these layouts in ``input_formats`` (``tests/test_weight_placement.py`` holds
 the rules to that). Leaves of kilobytes a layer that the compiler would also
 transpose (``router/kernel``, ``out_gate``, ``gate``, ``phi``) stay default:
-a program prefetches them whole and copies none.
+a program prefetches them whole and copies none. MiniCPM-SALA's two
+full-width output gates (``attn/gate/kernel``, 33 MB a layer) stay default
+for another reason: their output is multiplied in whole, not split into
+heads, and the compiler reads them ``(L, in, out)`` as stored (the same
+test, ``sala`` among its stacks); that family's q / k / v kernels are
+``attn/qkv/*_kernel`` and take the rule above.
 
 :func:`rest_weights` places the table's leaves once, at construction. Only
 the physical layout changes: logical shape, key, dtype and sharding stay, so

@@ -34,6 +34,11 @@ from neuronx_distributed_llama3_2_tpu.models.jamba import (  # noqa: F401
     params_from_hf_jamba,
     params_to_hf_jamba,
 )
+from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import (  # noqa: F401
+    SALA_CONFIGS,
+    SalaConfig,
+    SalaForCausalLM,
+)
 from neuronx_distributed_llama3_2_tpu.models.brumby import (  # noqa: F401
     BRUMBY_CONFIGS,
     BrumbyConfig,
@@ -128,6 +133,12 @@ def model_registry():
         reg[name] = {
             "config": cfg, "model_cls": JambaForCausalLM,
             "from_hf": params_from_hf_jamba, "to_hf": params_to_hf_jamba,
+        }
+    for name, cfg in SALA_CONFIGS.items():
+        # the catalog publishes the configuration and no tensor names: no HF map
+        reg[name] = {
+            "config": cfg, "model_cls": SalaForCausalLM,
+            "from_hf": None, "to_hf": None,
         }
     for name, cfg in LAGUNA_CONFIGS.items():
         reg[name] = {

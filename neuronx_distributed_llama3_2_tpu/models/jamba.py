@@ -352,9 +352,10 @@ def stack_name(kind: str) -> str:
     return f"{kind}_layers"
 
 
-def layer_runs(config: JambaConfig) -> List[Run]:
-    """The published order as runs of consecutive layers of one kind (a kind
-    is a stack of weights: :class:`..laguna.Run`)."""
+def layer_runs(config: JambaConfig, name=stack_name) -> List[Run]:
+    """The published order (``config.layer_kinds``) as runs of consecutive
+    layers of one kind (a kind is a stack of weights, ``name(kind)``:
+    :class:`..laguna.Run`)."""
     runs: List[Run] = []
     seen: Dict[str, int] = {}
     for layer, kind in enumerate(config.layer_kinds):
@@ -362,7 +363,7 @@ def layer_runs(config: JambaConfig) -> List[Run]:
             runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
         else:
             first = seen.get(kind, 0)
-            runs.append(Run(stack_name(kind), kind, False, first, 1, first, layer))
+            runs.append(Run(name(kind), kind, False, first, 1, first, layer))
         seen[kind] = seen.get(kind, 0) + 1
     return runs
 

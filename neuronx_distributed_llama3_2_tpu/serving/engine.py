@@ -1228,7 +1228,10 @@ class PagedServingEngine:
         kind is a state, ``state_lanes`` — the live lanes — and
         ``state_slots_passed`` — the slots the dispatched program reads and
         writes: the live lanes' where it holds the state kernel, else every
-        slot of the kind's pool, a live lane's or not."""
+        slot of the kind's pool, a live lane's or not. Where a kind's layers choose the blocks
+        they read (``selected_rows``), ``sparse_rows_cached`` /
+        ``sparse_rows_read`` / ``sparse_blocks_forced``, a layer's, summed over
+        the live lanes."""
         if not self._positional:
             return {"rows": len(decode_lanes)}
         contexts = [int(self._positions[l]) + 1 for l in decode_lanes]
@@ -1238,6 +1241,14 @@ class PagedServingEngine:
             rows["state_slots_passed"] = (
                 len(decode_lanes) if self._state_kernel
                 else 1 + self.engine.max_batch * self._lane_blocks)
+            if contexts and self.model.selected_rows(contexts[0]) is not None:
+                selected = [self.model.selected_rows(n) for n in contexts]
+                # layers that read only the blocks they choose: a layer's
+                # rows the live lanes hold, the rows it reads of them, and the
+                # blocks among those it took unscored (the first, the window)
+                rows["sparse_rows_cached"] = sum(contexts)
+                rows["sparse_rows_read"] = sum(read for read, _ in selected)
+                rows["sparse_blocks_forced"] = sum(forced for _, forced in selected)
         elif self._lane_kind is not None:
             rows["window_rows"] = sum(min(n, self._lane_kind.rows) for n in contexts)
         return rows

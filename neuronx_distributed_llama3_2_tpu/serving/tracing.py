@@ -121,13 +121,23 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
 # ``attn/ssm/params`` (``x_proj``, the three inner norms, ``dt_proj``, the
 # softplus), ``attn/ssm/scan`` (prefill: a block of rows one after another from
 # the carried state, the state's way out of its slot and back) and
-# ``attn/ssm/step`` (decode: one pass over the lanes' slots) (models/jamba.py).
+# ``attn/ssm/step`` (decode: one pass over the lanes' slots) (models/jamba.py);
+# ``attn/sparse`` is a block-sparse attention layer's own work — ``pool_keys``
+# (the kernels the fresh rows complete: their rows read back, the mean, the
+# write), ``select`` (the lane's pooled keys read and scored, the blocks
+# chosen) and ``read`` (the chosen blocks gathered and attended, or a chunk's
+# walk over the context's tiles) — and ``attn/lightning`` a Lightning layer's:
+# ``chunk`` (prefill: the chunk form, the state's way out of its slot and
+# back), ``step`` (decode: the lanes' states out, one update each, back) and
+# ``gate_norm`` (the output norm) (models/minicpm_sala.py).
 DETAIL_SCOPES = {
     "": ("mhc",),
     "attn": ("qk_norm", "latent_down", "latent_up", "absorb", "gate", "retention",
-             "full", "window", "out_gate", "q_latent", "ssm"),
+             "full", "window", "out_gate", "q_latent", "ssm", "sparse", "lightning"),
     "attn/retention": ("expand", "chunk", "step"),
     "attn/ssm": ("conv", "params", "scan", "step"),
+    "attn/sparse": ("pool_keys", "select", "read"),
+    "attn/lightning": ("chunk", "step", "gate_norm"),
     "mhc": ("coeff", "sinkhorn", "mix"),
     "moe": ("shared",),
     "moe/experts": ("selective", "all"),
@@ -216,7 +226,10 @@ class EngineTracer:
         # decode_read); residual_row_bytes where the residual has several
         # streams. A decode dispatch record carries ``rows`` and, where some
         # kind is a state, ``state_lanes`` (live lanes) and
-        # ``state_slots_passed`` (slots the pass moved)
+        # ``state_slots_passed`` (slots the pass moved); where a kind's
+        # layers choose the blocks they read, ``sparse_rows_cached``,
+        # ``sparse_rows_read`` and ``sparse_blocks_forced`` (a layer's, summed
+        # over the live lanes)
         self.setup: Dict[str, int] = {}
         # routing counters, one entry per dispatch of a tapped program
         # (moe/tap.py): (step, kind, dispatch paths, pairs computed, live

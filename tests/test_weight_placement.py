@@ -45,6 +45,7 @@ from neuronx_distributed_llama3_2_tpu.models import (
 )
 from neuronx_distributed_llama3_2_tpu.models.brumby import BRUMBY_CONFIGS, BrumbyForCausalLM
 from neuronx_distributed_llama3_2_tpu.models.jamba import JAMBA_CONFIGS, JambaForCausalLM
+from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import SALA_CONFIGS, SalaForCausalLM
 from neuronx_distributed_llama3_2_tpu.models.laguna import (
     LAGUNA_CONFIGS,
     LagunaForCausalLM,
@@ -482,13 +483,18 @@ STACKS = {
     # M A M M A: two multi-query attention layers among three state-space ones
     "jamba": lambda: (published(JAMBA_CONFIGS, "jamba2-3b", num_layers=5, attn_layer_period=3,
                                 attn_layer_offset=1), JambaForCausalLM, 16, 256),
+    # L S: a Lightning layer (32 kv heads) and a block-sparse one (2); a pool block is a selection block
+    "sala": lambda: (published(SALA_CONFIGS, "minicpm-sala", num_layers=2, mixer_types=(
+        "lightning-attn", "minicpm4")), SalaForCausalLM, 64, 512),
 }
 # where the compiler, left to itself, lays a leaf of a megabyte a layer or more
 # out otherwise than the rule, and copies nothing either way (the first test
 # below says so for the attention kernels; a prefill chunk expands latents
 # through kv_b heads-major, where the decode step, which the rule follows,
 # absorbs it; x_proj's three-way split of a state-space layer is no head split
-# and the default-placed program holds no copy of it)
+# and the default-placed program holds no copy of it. The two full-width
+# output gates of a MiniCPM-SALA layer — ``attn/gate/kernel``, no head split —
+# are left default by the rule and by the compiler alike)
 THE_COMPILER_ALONE = {
     ("sarvam", "psfx"): {"dense_layers/attn/kv_b/kernel": (0, 2, 3, 1), "layers/attn/kv_b/kernel": (0, 2, 3, 1)},
     ("xing", "psfx"): {"dense_layers/attn/kv_b/kernel": (0, 2, 3, 1), "layers/attn/kv_b/kernel": (0, 2, 3, 1)},
