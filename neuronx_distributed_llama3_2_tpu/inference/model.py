@@ -382,6 +382,20 @@ class LlamaDecode:
         layer, blocks among them it did not choose by score), else None."""
         return None
 
+    def chunk_read(self) -> Optional[str]:
+        """Where a layer's read for a block of rows (``pctx`` / ``psfx``) walks
+        the context in tiles under a block mask (:class:`SalaDecode`):
+        ``"kernel"`` or ``"tiles"``; else None. The traced engine's ``setup``
+        record says it beside ``decode_read``."""
+        return None
+
+    def chunk_tiles(self, t: int, start: int, limit: Optional[int]) -> Optional[Tuple[bool, int, int]]:
+        """Where :meth:`chunk_read` is not None, what a block of ``t`` rows
+        from position ``start`` over the first ``limit`` rows (None: its own
+        rows, ``pctx``) costs a layer: (whether the program holds the kernel,
+        the kv tiles at or before the block's last position, the rung's)."""
+        return None
+
     def residual_row_bytes(self) -> Optional[int]:
         """Bytes a token's state takes between layers where the layer loop
         carries more than one (b, t, H) array (:class:`XingDecode`), else
@@ -2798,9 +2812,14 @@ class SalaDecode(LlamaDecode):
     bounded by ``kv_limit``) and reads: one token a lane (``pdecode``) gathers
     **the chosen blocks alone** — ``sparse_topk`` a kv group whatever the
     context — and a block of rows (``pctx`` / ``psfx``) walks the context a
-    tile of :data:`SPARSE_TILE_ROWS` rows at a time with a running max and
-    sum, the selection a per-(row, block) mask, so no (heads, rows, context)
-    array exists at any context. ``pctx`` reads its own rows back through the
+    tile at a time with a running max and sum, the selection a per-(row,
+    block) mask, so no (heads, rows, context) array exists at any context:
+    one :func:`..kernels.sparse_chunk_pallas.sparse_chunk_attend` a layer over
+    the rung's rows gathered in order, a tile's scores never out of VMEM,
+    where :meth:`chunk_read` says ``"kernel"`` and the shape fits; else
+    :func:`..models.minicpm_sala.attend_tiles`, a ``lax.scan`` over tiles of
+    :data:`SPARSE_TILE_ROWS` rows (a mesh, the ``"reference"`` mode: the
+    kernel's plain twin). ``pctx`` reads its own rows back through the
     table like any other call: the rule that picks a row's blocks is the same
     at every row, so the result does not depend on how a prompt was chunked.
     No rotary table touches these layers.
@@ -2924,6 +2943,34 @@ class SalaDecode(LlamaDecode):
         the matmul form over the block and the carried state."""
         return "chunk"
 
+    def chunk_read(self) -> str:
+        """How a block of rows (``pctx`` / ``psfx``) reads a sparse layer's
+        context: ``"kernel"`` — one
+        :func:`..kernels.sparse_chunk_pallas.sparse_chunk_attend` a layer —
+        where :func:`_kernels_on_one_device`, else ``"tiles"``,
+        ``attend_tiles``' ``lax.scan`` (a mesh, the ``"reference"`` mode).
+        ``pdecode`` gathers its chosen blocks either way."""
+        return "kernel" if _kernels_on_one_device() else "tiles"
+
+    def _chunk_limit(self, t: int, limit: Optional[int]) -> int:
+        bs = self.config.sparse_block_size
+        return bs * -(-t // bs) if limit is None else limit
+
+    def _chunk_kernel_takes(self, t: int, limit: int) -> bool:
+        from neuronx_distributed_llama3_2_tpu.kernels.sparse_chunk_pallas import chunk_attend_fits
+
+        return self.chunk_read() == "kernel" and chunk_attend_fits(t, limit, self.config.sparse_block_size)
+
+    def chunk_tiles(self, t: int, start: int, limit: Optional[int]) -> Tuple[bool, int, int]:
+        from neuronx_distributed_llama3_2_tpu.kernels.sparse_chunk_pallas import kv_tile
+
+        limit = self._chunk_limit(t, limit)
+        if self._chunk_kernel_takes(t, limit):
+            tile = kv_tile(limit)
+            return True, min((start + t - 1) // tile + 1, limit // tile), limit // tile
+        tiles = -(-limit // SPARSE_TILE_ROWS)           # the scan computes every tile of the rung
+        return False, tiles, tiles
+
     def selected_rows(self, context: int) -> Tuple[int, int]:
         c = self.config
         bs, p = c.sparse_block_size, context - 1
@@ -2984,7 +3031,7 @@ class SalaDecode(LlamaDecode):
         width = block_tables.shape[1] * bs
         # rows a sparse layer may read: the rung, or — a context-encode call
         # reads its own rows back — the block's own, in whole blocks
-        limit = bs * -(-t // bs) if context_encode else min(kv_limit or width, width)
+        limit = self._chunk_limit(t, None) if context_encode else min(kv_limit or width, width)
         sin, cos = self._rope_tables(width)
         slopes = lightning_slopes(c.lightning_heads)
         scale = c.residual_scale
@@ -3119,6 +3166,19 @@ class SalaDecode(LlamaDecode):
                     w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
                     att = jnp.einsum("bkgs,bksd->bkgd", w, group_rows(v_blocks))
                     att = att.reshape(b, 1, c.num_heads, d)
+                elif self._chunk_kernel_takes(t, blocks * bs):
+                    # the rung's rows in order, a kv head's together, gathered
+                    # a block at a time; the scores stay in the kernel
+                    from neuronx_distributed_llama3_2_tpu.kernels.sparse_chunk_pallas import (
+                        sparse_chunk_attend,
+                    )
+
+                    at = (layer * nb + ids)[:, None, :] * nkv + heads[:, None]      # (b, NKV, blocks)
+                    k_rung, v_rung = (
+                        pool[at].reshape(b, nkv, blocks * bs, d).astype(q.dtype)
+                        for pool in (k_blocks, v_blocks))
+                    att = sparse_chunk_attend(
+                        q, k_rung, v_rung, block_mask(chosen, taken, blocks), pos_block[:, 0], bs)
                 else:
                     tile = min(blocks, max(SPARSE_TILE_ROWS // bs, 1))
                     tiles = -(-blocks // tile)

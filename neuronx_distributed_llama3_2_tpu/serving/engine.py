@@ -544,6 +544,8 @@ class PagedServingEngine:
         self._positional = bool(self.model.cache_is_positional)
         # 1 where every pdecode holds the one-pass state kernel, else 0
         self._state_kernel = int(self.model.uses_state_kernel())
+        # some layers read a block of rows tile by tile under a block mask
+        self._chunk_tiles = self.model.chunk_read() is not None
         # a kind of cache that is laid out a lane, beside the allocator's pool
         # (docs/serving.md "Stacks whose layers cache different things"): the
         # last rows of a context in a ring of blocks (window layers beside full
@@ -953,6 +955,9 @@ class PagedServingEngine:
         self._last_log_step = 0      # dedupe periodic metrics logging
         self._last_prefill_bucket = 0  # bucket of the most recent prefill
         self._last_prefill_kv = 0      # its kv_limit rung; 0 = pctx, no cache read
+        # its ``sparse_tiles_walked`` / ``sparse_tiles_rung`` where the model's
+        # chunk read walks tiles (``chunk_tiles``), else nothing
+        self._last_prefill_tiles: Dict[str, int] = {}
         self._programs: Dict[tuple, ProgramRecord] = {}
         # the block programs move whatever arrays the decode model's cache
         # is made of — k and v, their scale tiles under quantized storage
@@ -1198,11 +1203,14 @@ class PagedServingEngine:
         device lays them out, and which read a decode step takes of it
         (``decode_read``: ``"kernel"``, ``"gather"`` or ``"pass"``; a state
         kind also how a prefill's block of rows goes through it, ``chunk_scan``:
-        ``"kernel"`` or ``"loop"``) — and ``window_ring_rows``; nothing where
-        the whole cache is a state."""
+        ``"kernel"`` or ``"loop"``; a kind of rows whose layers read a block
+        of rows under a block mask also how, ``chunk_read``: ``"kernel"`` or
+        ``"tiles"``) — and ``window_ring_rows``; nothing where the whole cache
+        is a state."""
         if not self._positional:
             return {}
         ring_rows = 0 if self._has_state else self._lane_blocks * self.paged.block_size
+        chunk_read = self.model.chunk_read()
         return {
             "cache_kinds": {
                 kind.name: {
@@ -1213,6 +1221,7 @@ class PagedServingEngine:
                        else {"row_bytes": cache_row_bytes(self._kind_pool(kind))}),
                     "decode_read": self.model.decode_read(
                         kind, self._kind_pool(kind).quantized),
+                    **({"chunk_read": chunk_read} if chunk_read and not kind.state else {}),
                 }
                 for kind in self.model.cache_kinds
             },
@@ -2909,6 +2918,7 @@ class PagedServingEngine:
                     bucket=self._last_prefill_bucket,
                     kv_bucket=self._last_prefill_kv,
                     pad=self._last_prefill_bucket - max(len(suffix), 1),
+                    **self._last_prefill_tiles,
                 )
             req.out.append(first)
             req.position = len(seq)
@@ -2970,6 +2980,11 @@ class PagedServingEngine:
                 self._upload(np.asarray([cached], np.int32)),
                 self._upload(length), table_dev, *tail,
             )
+        if self._chunk_tiles:
+            kernel, walked, rung = self.model.chunk_tiles(
+                bucket, cached, self._last_prefill_kv or None)
+            self.metrics.sparse_kernel_chunks += int(kernel)
+            self._last_prefill_tiles = {"sparse_tiles_walked": walked, "sparse_tiles_rung": rung}
         # graftmeter pad-waste fold: every prefill (admission or chunk)
         # funnels through here with `fn` bound to the dispatched program
         self.metrics.note_prefill_dispatch(
@@ -3042,6 +3057,7 @@ class PagedServingEngine:
                     bucket=self._last_prefill_bucket,
                     kv_bucket=self._last_prefill_kv,
                     pad=self._last_prefill_bucket - max(len(piece), 1),
+                    **self._last_prefill_tiles,
                 )
             req.prefill_pos = start + len(piece)
             spent += len(piece)

@@ -71,6 +71,11 @@ class ServingMetrics:
     # state model wherever retention_step runs (the "reference" kernel
     # mode, a multi-device mesh)
     state_kernel_steps: int = 0
+    # prefill dispatches (pctx / psfx) whose program holds the sparse layers'
+    # chunk-read kernel (kernels/sparse_chunk_pallas.py): every one of a
+    # model whose layers choose their blocks, wherever Pallas kernels run on
+    # one device and the shape fits; 0 on every other engine
+    sparse_kernel_chunks: int = 0
     decode_steps: int = 0
     # -- fused mixed-mode step (docs/serving.md "Fused mixed-mode step"):
     #    engine_steps counts every step() (the dispatches_per_step

@@ -74,6 +74,8 @@ def main(argv) -> int:
         jobs.append((name, cases.ssm_scan_case(*args), one))
     for name, args in cases.SSM_STEP_CASES.items():
         jobs.append((name, cases.ssm_step_case(*args), one))
+    for name, args in cases.SPARSE_CHUNK_CASES.items():
+        jobs.append((name, cases.sparse_chunk_case(*args), one))
     for name, dtype in cases.WALK_CASES.items():
         jobs.append((name, cases.walk_case(dtype), one))
         # every group size of scripts/paged_decode_bench.py --walk

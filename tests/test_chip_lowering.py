@@ -304,6 +304,27 @@ LATENT_WALK_CASES = {
 }
 
 
+def sparse_chunk_case(rows, rung, dtype=jnp.bfloat16):
+    """(fn, avals) for the sparse layers' chunk read at MiniCPM-SALA's widths
+    as ``sala-longctx-steady`` runs them: one lane's bucket of ``rows`` rows,
+    32 query over 2 kv heads of 128, over the ``rung``'s rows in blocks of 64."""
+    from neuronx_distributed_llama3_2_tpu.kernels.sparse_chunk_pallas import sparse_chunk_attend
+
+    n, nkv, d, bs = 32, 2, 128, 64
+    kv = jax.ShapeDtypeStruct((1, nkv, rung, d), dtype)
+    avals = [jax.ShapeDtypeStruct((1, rows, n, d), dtype), kv, kv,
+             jax.ShapeDtypeStruct((1, rows, nkv, rung // bs), jnp.bool_),
+             jax.ShapeDtypeStruct((1,), jnp.int32)]
+    return lambda q, k, v, mask, start: sparse_chunk_attend(q, k, v, mask, start, bs), avals
+
+
+# both prefill buckets, the ladder's first and last rungs, and pctx's own rows
+SPARSE_CHUNK_CASES = {
+    "sparse-chunk-512x33280": (512, 33280), "sparse-chunk-128x2048": (128, 2048),
+    "sparse-chunk-512x512": (512, 512), "sparse-chunk-128x128": (128, 128),
+}
+
+
 def lower_for_tpu(fn, avals):
     return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
 
@@ -369,6 +390,12 @@ def test_decode_walk_kernel_lowers_for_tpu(compiled_mode, name):
 def test_latent_walk_kernel_lowers_for_tpu(compiled_mode, name):
     lowered = lower_for_tpu(*latent_walk_case(*LATENT_WALK_CASES[name]))
     assert_mosaic_call(lowered, "latent_decode_walk")
+
+
+@pytest.mark.parametrize("name", SPARSE_CHUNK_CASES)
+def test_sparse_chunk_kernel_lowers_for_tpu(compiled_mode, name):
+    lowered = lower_for_tpu(*sparse_chunk_case(*SPARSE_CHUNK_CASES[name]))
+    assert_mosaic_call(lowered, "sparse_chunk_attend")
 
 
 @pytest.mark.parametrize("name", TP_CASES)

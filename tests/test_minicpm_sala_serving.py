@@ -24,6 +24,7 @@ from neuronx_distributed_llama3_2_tpu.inference import (
 from neuronx_distributed_llama3_2_tpu.inference.model import (
     CacheKind, MatrixState, SparseRows, cache_block_bytes, cache_row_bytes, decode_model_for,
 )
+from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
 from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import SALA_CONFIGS, SalaForCausalLM
 from neuronx_distributed_llama3_2_tpu.serving import PagedConfig, PagedServingEngine, audit_engine
 from tests.drained_policy import LOOPS, loop_policy
@@ -34,6 +35,9 @@ BS, CHUNK = 4, 16                     # a pool block is one selection block
 SIZES = {"lanes": 4, "block_size": BS, "max_seq_len": TOP, "pool_blocks": 144,
          "prefill_chunk_tokens": CHUNK, "prefill_buckets": [8, 16], "kv_buckets": [64, TOP]}
 TOL = 1e-4
+# the kernel mode the CPU tier names, and the one that runs the sparse layers'
+# chunk read through ``kernels/sparse_chunk_pallas.py``, interpreted
+MODES = ["reference", "interpret"]
 STATE_BYTES = 3 * 4 * 16 * 16 * 4      # Lightning layers x heads x d x d, float32
 
 
@@ -153,7 +157,7 @@ def test_the_family_gets_its_decode_class_and_a_cache_of_two_kinds_and_three_row
     assert isinstance(model, SalaDecode) and model.cache_is_positional and model.keeps_state
     assert model.cache_kinds == (CacheKind("rows", 2, None), CacheKind("state", 3, 0, state=True))
     assert [model.decode_read(kind) for kind in model.cache_kinds] == ["gather", "gather"]
-    assert not model.uses_state_kernel() and model.chunk_scan() == "chunk"
+    assert not model.uses_state_kernel() and model.chunk_scan() == "chunk" and model.chunk_read() == "tiles"
     pool = model.init_paged_cache(9, BS, state_blocks=5)
     assert isinstance(pool, HybridCache) and (pool.num_blocks, pool.block_size) == (9, BS)
     assert isinstance(pool.rows, SparseRows) and isinstance(pool.state, MatrixState)
@@ -216,13 +220,18 @@ def test_what_a_state_cannot_undo_is_refused_at_construction(params, knobs, word
 @pytest.mark.parametrize("n_prompt,chunk,buckets", [
     (93, 16, (8, 16)), (93, 10, (10,)), (96, 32, (32,)), (5, 16, (8, 16))],
     ids=["padded-last-chunk", "chunks-that-cut-blocks-and-kernels", "whole-rungs", "under-a-bucket"])
-def test_chunks_however_cut_and_padded_match_the_reference(fam, params, n_prompt, chunk, buckets):
+@pytest.mark.parametrize("mode", MODES)
+def test_chunks_however_cut_and_padded_match_the_reference(fam, params, n_prompt, chunk, buckets, mode, monkeypatch):
     """93 rows = 23 blocks behind the last row, of which it reads 6; chunks of
     16 (a kernel of 4 rows every 2 straddles every boundary), of 10 (a
     boundary inside a block and inside a kernel's stride), of 32; the last one
     padded; the request on lane 1 of three, lanes 0 and 2 idle beside it on
-    the null table, whose slots come back bit for bit."""
+    the null table, whose slots come back bit for bit. Under ``interpret`` the
+    chunks' sparse reads go through the chunk-read kernel — but the chunks of
+    10 rows, which it refuses and the tile walk takes."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     model = decode_model_for(TINY)
+    assert model.chunk_tiles(max(buckets), 16, 64)[0] == (mode == "interpret" and chunk != 10)
     rng = np.random.default_rng(n_prompt + chunk)
     prompt, fed = rng.integers(1, 256, n_prompt).tolist(), rng.integers(1, 256, 5).tolist()
     pool = jax.tree.map(
@@ -257,10 +266,13 @@ def test_a_second_request_through_the_same_slot_and_blocks_matches_the_reference
 
 
 @pytest.mark.parametrize("fault", ["no_selection", "unpooled", "no_decay", "rotary", "no_carry", "no_window"])
-def test_each_planted_fault_fails_the_comparison(fam, params, fault, monkeypatch):
+@pytest.mark.parametrize("mode", MODES)
+def test_each_planted_fault_fails_the_comparison(fam, params, fault, mode, monkeypatch):
     """The faults ``benchmarks/tools/check_sala_variant.py`` plants on the
     chip, here against every row, where a sound run holds every row inside a
-    hundredth of a percent."""
+    hundredth of a percent — through the tile walk and through the chunk-read
+    kernel alike."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     for owner, name, value in _tool("check_sala_variant").FAULTS[fault]():
         monkeypatch.setattr(owner, name, value)
     model = decode_model_for(TINY)
@@ -315,7 +327,10 @@ def test_long_chunked_padded_prompts_give_the_references_tokens(fam, params, loo
     clean(srv)
 
 
-def test_a_traced_engine_records_both_kinds_the_pooled_leaf_and_the_rows_a_step_reads(params):
+@pytest.mark.parametrize("mode", MODES)
+def test_a_traced_engine_records_both_kinds_the_pooled_leaf_and_the_rows_a_step_reads(params, mode, monkeypatch):
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    kernel = mode == "interpret"
     srv = serving(params, trace_enabled=True, prewarm=True)
     for p in prompts_of(np.random.default_rng(2), (90, 33)):
         srv.submit(p)
@@ -325,7 +340,8 @@ def test_a_traced_engine_records_both_kinds_the_pooled_leaf_and_the_rows_a_step_
     assert setup["state_bytes_per_lane"] == STATE_BYTES and setup["cache_row_bytes"] == row_bytes
     assert row_bytes > 2 * 32 * 4                                          # k and v and the pooled keys' share
     assert setup["cache_kinds"] == {
-        "rows": {"layers": 2, "rows_per_lane": None, "row_bytes": row_bytes, "decode_read": "gather"},
+        "rows": {"layers": 2, "rows_per_lane": None, "row_bytes": row_bytes, "decode_read": "gather",
+                 "chunk_read": "kernel" if kernel else "tiles"},
         "state": {"layers": 3, "rows_per_lane": 0, "state_bytes": STATE_BYTES, "chunk_scan": "chunk",
                   "decode_read": "gather"},
     }
@@ -338,4 +354,10 @@ def test_a_traced_engine_records_both_kinds_the_pooled_leaf_and_the_rows_a_step_
     assert all(a["sparse_rows_read"] <= 24 * a["lanes"] for a in records)
     assert any(a["sparse_rows_cached"] > 3 * a["sparse_rows_read"] for a in records)
     assert all(a["lanes"] <= a["sparse_blocks_forced"] <= 4 * a["lanes"] for a in records)
+    # a prefill dispatch: the kv tiles of a sparse layer's read to the chunk's last row and in its rung — at
+    # this size one tile holds any rung — and whether its program held the chunk-read kernel
+    prefills = [args for step in srv.tracer.timeline()["steps"] for ph, name, _, _, args in step["events"]
+                if ph == "X" and name in ("prefill", "prefill_chunk")]
+    assert len(prefills) == 6 + 3 and all(a["sparse_tiles_walked"] == a["sparse_tiles_rung"] == 1 for a in prefills)
+    assert srv.metrics.snapshot()["sparse_kernel_chunks"] == (len(prefills) if kernel else 0)
     clean(srv)

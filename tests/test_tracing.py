@@ -220,7 +220,8 @@ def test_request_info_timing_survives_into_finished_records(done_engine):
 EXPECTED_SNAPSHOT_KEYS = {
     # dataclass counters
     "submitted", "admitted", "admit_blocked", "finished", "truncated",
-    "preemptions", "state_resets", "state_kernel_steps", "decode_steps", "engine_steps", "compute_dispatches",
+    "preemptions", "state_resets", "state_kernel_steps", "sparse_kernel_chunks", "decode_steps", "engine_steps",
+    "compute_dispatches",
     "mixed_dispatches", "prefill_tokens", "prefill_chunks",
     "cached_tokens", "decode_steps_async", "lame_duck_tokens",
     # why a decode step was not dispatched ahead (PR 34: the pool reason

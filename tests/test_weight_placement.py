@@ -696,3 +696,50 @@ def test_a_state_space_decode_step_visits_the_donated_state_pool_in_place(v5e, m
     assert not pool_sized_moves(text, dims), pool_sized_moves(text, dims)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+
+
+# ---------------------------------------------------------------------------
+# a sparse layer's chunk read: the Mosaic call in the program, and no tile of
+# float32 scores in HBM
+# ---------------------------------------------------------------------------
+
+TILE_OF_SCORES = re.compile(r"f32\[[\d,]*512,2048\]")
+
+
+@pytest.mark.parametrize("mode", ["compiled", "reference"])
+def test_a_sparse_layers_chunk_read_keeps_its_scores_out_of_hbm(v5e, mode, monkeypatch):
+    """MiniCPM-SALA's widths at two layers (L S), a ``psfx`` of 512 rows at
+    the ladder's last rung as ``sala-longctx-steady`` runs it, compiled for the
+    described v5e: with Pallas kernels on, the program holds one
+    ``sparse_chunk_attend`` and no float32 array of a tile's scores — the
+    ``(1, 2, 16, 512, 2048)`` that the tile walk, which the ``reference`` mode
+    keeps, writes and reads back a tile (the control: the pattern finds it
+    there)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    rows, rung, bs = 512, 33280, 64
+    cfg = dataclasses.replace(
+        SALA_CONFIGS["minicpm-sala"], num_layers=2, mixer_types=("lightning-attn", "minicpm4"),
+        vocab_size=2048, max_seq_len=rung, dtype=jnp.bfloat16)
+    model = decode_model_for(cfg)
+    kernel = mode == "compiled"
+    assert model.chunk_tiles(rows, rung - rows, rung) == ((True, 65, 65) if kernel else (False, 17, 17))
+    one = SingleDeviceSharding(v5e)
+    on = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), t)
+    params = on(abstract_weights(cfg, SalaForCausalLM))
+    cache = on(jax.eval_shape(lambda: model.init_paged_cache(1024, bs, state_blocks=2)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)  # noqa: E731
+
+    def psfx(params, cache, ids, start, length, table, slots):
+        return model.forward(params, cache, ids, start, None, return_hidden=True, block_tables=table,
+                             kv_limit=rung, row_live=length, state_tables=slots)
+
+    text = jax.jit(psfx, donate_argnums=(1,)).lower(
+        params, cache, i32(1, rows), i32(1), i32(1), i32(1, (rung + rows) // bs), i32(1, 1)).compile().as_text()
+    calls = re.findall(r"custom-call\(.*custom_call_target=\"tpu_custom_call\".*", text)
+    assert [("sparse_chunk_attend" in c) for c in calls] == ([True] if kernel else []), calls
+    assert bool(TILE_OF_SCORES.search(text)) != kernel, TILE_OF_SCORES.findall(text)[:4]
