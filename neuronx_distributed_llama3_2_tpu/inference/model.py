@@ -406,8 +406,26 @@ class LlamaDecode:
         """How a decode step (one fresh row a lane) reads ``kind``'s rows:
         ``"kernel"`` — a Pallas call reads the pool where it lies — or
         ``"gather"`` — rows gathered through the table, then attended. The
-        traced engine's ``setup`` record says it a kind."""
-        return self.paged_dispatch_path(1)
+        traced engine's ``setup`` record says it a kind. What the program can
+        see decides, no option: where :meth:`_walks`, the block walk; else
+        what ``use_paged_kernel`` asked for (:meth:`paged_dispatch_path`)."""
+        return "kernel" if self._walks(kind.rows, quantized) else self.paged_dispatch_path(1)
+
+    def _walks(self, window: Optional[int], quantized: bool) -> bool:
+        """Whether one fresh row a lane of a ``(k, v)`` pool is attended by
+        :func:`..kernels.paged_attention_pallas.paged_decode_walk` over the
+        lane's live blocks: a kind with no lower bound over an unquantized
+        pool whose rows the walk takes (``walk_fits``), where
+        :func:`_kernels_on_one_device`. A window's ring, a block of several
+        rows (``psfx``, a verify block, a tree), an int8 / fp8 pool, a mesh and
+        the ``"reference"`` mode keep the gather, the walk's plain twin."""
+        if window is not None or quantized or not _kernels_on_one_device():
+            return False
+        from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+            walk_fits,
+        )
+
+        return walk_fits(self.config.head_dim)
 
     def _model(self) -> LlamaForCausalLM:
         return LlamaForCausalLM(self.config)
@@ -884,6 +902,20 @@ class LlamaDecode:
                 kv_limit if kv_limit is not None
                 else block_tables.shape[1] * bs
             )
+            if q.shape[1] == 1 and tree is None and self._walks(None, quantized):
+                # one row a lane: the lane's live blocks are read where they
+                # lie, nothing gathered — whatever use_paged_kernel says (the
+                # static-grid kernel below reads the rung, the walk what is
+                # live)
+                from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+                    paged_decode_walk,
+                )
+
+                with jax.named_scope("sdpa"):
+                    att = paged_decode_walk(
+                        q[:, 0], kc, vc, block_tables, positions, layer,
+                        kv_limit=limit)
+                return att[:, None], kc, vc
             if self._paged_kernel_eligible(q.shape[1], tree):
                 # gather-free read: the kernel dereferences the block table
                 # inside its BlockSpec index maps, so the (b, limit, NKV, D)
@@ -2234,9 +2266,6 @@ class LagunaDecode(MixtralDecode):
         int8 pool, a mesh and the ``"reference"`` mode keep the block-wise
         gather and ``masked_attention``, the walk's plain twin."""
         return "kernel" if self._walks(kind.rows, quantized) else "gather"
-
-    def _walks(self, window: Optional[int], quantized: bool) -> bool:
-        return window is None and not quantized and _kernels_on_one_device()
 
     # -- forward ----------------------------------------------------------
 

@@ -49,15 +49,32 @@ Structure (flash-decoding, Dao et al. 2023 — split-K for a single query row):
   forward while still sharing one KV DMA per block. A linear chain's
   bitmasks reproduce the block-causal mask bit for bit.
 
-:func:`paged_decode_walk` stands beside it as the body a cell runs (the one
-fresh row a lane of an unquantized pool; ``LagunaDecode._attend`` calls it):
-no static grid over the rung — a grid step is a lane, and a loop whose trip
-count is the lane's own copies its **live** blocks a group at a time from the
-pool in HBM (``memory_space=ANY``, ``make_async_copy``, two VMEM buffers) and
-folds each group into the same online softmax. It takes the pool as ``(blocks ·
-bs · NKV, D)``, which on the chip is the bytes as they lie; the ``(blocks, bs,
-NKV·D)`` view above is a re-tiling there. ``_decode_kernel``'s variants move
-onto the walk as they are needed (``ROADMAP.md`` S8, D6).
+:func:`paged_decode_walk` stands beside it as the body the cells run (the one
+fresh row a lane of an unquantized pool; ``LlamaDecode._attend_paged`` and
+``LagunaDecode._attend`` call it where ``LlamaDecode._walks`` says so): no
+static grid over the rung — a grid step is a lane, and a loop whose trip count
+is the lane's own copies its **live** blocks a group at a time from the pool
+in HBM (``memory_space=ANY``, ``make_async_copy``, two VMEM buffers) and folds
+each group into the same online softmax. It takes the pool as ``(blocks · bs ·
+NKV, D)``, which on the chip is the bytes as they lie where D is 128
+(:func:`walk_fits`); the ``(blocks, bs, NKV·D)`` view above is a re-tiling
+there. Which read a paged program of a ``(k, v)`` family holds, the first row
+that fits deciding (``docs/serving.md`` "Gather-free decode" has it in full):
+
+==========================================  =================  ====================
+the fresh block; pool; devices              flag unset         ``use_paged_kernel``
+==========================================  =================  ====================
+one row a lane, no tree; unquantized rows   the walk           the walk
+of 128; one device; not ``"reference"``
+up to ``paged_kernel_max_t`` rows, trees    gather             ``paged_flash_decode``
+of <= 32 nodes; any pool; one device
+the same; a pure-tp mesh, heads divisible   gather             ``..._decode_tp``
+anything else (long ``psfx`` blocks, other  gather             gather
+meshes)
+==========================================  =================  ====================
+
+``_decode_kernel``'s variants (several rows, trees, int8 / fp8 pools, a tp
+mesh) move onto the walk as they are needed (``ROADMAP.md`` D6).
 
 :func:`latent_decode_walk` is the same walk over a latent (MLA) pool
 (``SarvamDecode._latent_attention`` calls it, for sarvam and Xing4.0): a row
@@ -510,9 +527,27 @@ def paged_flash_decode(
     return out[:, 0] if squeeze else out
 
 
-# blocks a loop trip of the walk copies and scores: 32 blocks of 16 rows are
-# 1 MB of K and 1 MB of V a buffer (chip sweep, PERF.md section 6, PR 43)
-WALK_GROUP = 32
+# (row, kv head) pairs a loop trip of the walk copies and scores: at 8 kv heads
+# 32 blocks of 16 rows, 1 MB of K and 1 MB of V a buffer and an (N, 4096) score
+# tile (chip sweeps, PERF.md section 6, PR 43 and PR 56)
+WALK_SPAN = 4096
+
+
+def walk_group(bs: int, nkv: int) -> int:
+    """Blocks a loop trip of :func:`paged_decode_walk` takes where none is
+    given: as many as hold :data:`WALK_SPAN` (row, kv head) pairs — 32 blocks
+    of 16 rows at 8 kv heads, 16 at 16 — so a buffer and a score tile keep
+    their size whatever the pool's kv heads and block are."""
+    return max(1, WALK_SPAN // (bs * nkv))
+
+
+def walk_fits(head_dim: int) -> bool:
+    """Whether :func:`paged_decode_walk` takes a pool of ``head_dim`` columns.
+    Mosaic wants a pool row to be one register's 128 lanes: it refuses to slice
+    a block out of a run of 64-column rows (the tiling pads them), and at 256
+    the ``(rows, D)`` view is a copy of the whole pool, not a bitcast. The
+    interpreter has no tiling and takes any width."""
+    return pallas_interpret() or head_dim == 128
 
 
 def _walk_kernel(
@@ -629,7 +664,7 @@ def paged_decode_walk(
     layer,                    # scalar: the layer of the pool this read is of
     *,
     kv_limit: int | None = None,
-    group: int = WALK_GROUP,
+    group: int | None = None,
 ) -> jax.Array:
     """The decode read of a layer with no lower bound: softmax(q · k / √D over
     rows ``<= positions``) · v, lane by lane over the lane's **live** blocks
@@ -644,10 +679,11 @@ def paged_decode_walk(
     D)``: under the TPU's tiling of the last two dimensions that is the same
     bytes (the optimized HLO holds a bitcast, no copy), and a block is ``bs ·
     NKV`` whole rows of it, contiguous. Blocks are copied ``group`` at a time
-    into one of two VMEM buffers, the next group in flight while this one is
-    folded into a float32 online softmax; p is cast to q's dtype for
-    ``p · v`` as ``models.laguna.masked_attention`` does, which is this
-    kernel's plain twin over gathered rows.
+    (None: :func:`walk_group` of the pool's shape) into one of two VMEM
+    buffers, the next group in flight while this one is folded into a float32
+    online softmax; p is cast to q's dtype for ``p · v`` as
+    ``models.laguna.masked_attention`` and ``LlamaDecode._cache_attention``
+    do, which are this kernel's plain twins over gathered rows.
     """
     b, n, d = q.shape
     nl, nb, bs, nkv, _ = k_pool.shape
@@ -658,6 +694,8 @@ def paged_decode_walk(
     live = jnp.where(
         block_tables[:, 0] == 0, 1, jnp.clip(positions // bs + 1, 1, nblk))
     tables = block_tables[:, :nblk] + layer * nb
+    if group is None:
+        group = walk_group(bs, nkv)
     span = group * bs * nkv
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

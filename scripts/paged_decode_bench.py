@@ -66,17 +66,25 @@ Three measurements for the gather-free paged decode path (docs/serving.md):
    fused leg's ``dispatches_per_step`` strictly below the unfused one;
    steps/sec is reported, not gated.
 
-9. **The block walk alone** (``--walk``, and nothing else runs): one full
-   layer's decode read at ``laguna-mixedlen-batch``'s shape — 32 lanes, 48
-   query over 8 kv heads of 128, blocks of 16 rows, a pool of 2 x 17,920
-   blocks, contexts drawn as the cell holds them (a log-uniform prompt of
-   512-8,192 and up to 256 rows of reply) through a permuted table —
+9. **The block walk alone** (``--walk``, and nothing else runs): one
+   layer's decode read of a ``(k, v)`` pool at the shapes of the cells that
+   walk it (``WALK_SHAPES``; heads of 128, blocks of 16 rows, contexts drawn
+   as the cell holds them through a permuted table, an idle lane on the null
+   block): ``laguna-mixedlen-batch`` — 32 lanes, 48 query over 8 kv heads,
+   a log-uniform prompt of 512-8,192 and up to 256 rows of reply over the
+   8,704-row rung; ``mixtral-chat-steady`` — 16 lanes of 32 over 8 on the
+   2,304-row rung, 2 live and 16 live; ``olmoe-rag-batch`` — 8 lanes of 16
+   over 16 on the 2,176-row rung —
    ``kernels.paged_attention_pallas.paged_decode_walk`` at each of
-   ``--walk-groups`` blocks a loop trip beside the block-wise gather and
-   ``masked_attention`` over the whole rung that it replaces. Prints ms a
-   layer and GB/s of *live* bytes (the K and V blocks the lanes' contexts
-   reach) for each; the gate is the kernel's distance from the gather.
-   ``PERF.md`` section 6 (PR 43) holds the sweep this was written for.
+   ``--walk-groups`` blocks a loop trip beside the gather of the whole rung
+   and the attention over it that it replaced (``LagunaDecode._attend``'s
+   block-wise gather and ``masked_attention``; ``LlamaDecode._attend_paged``'s
+   gather of rows and ``_cache_attention``). Prints ms a layer, GB/s of
+   *live* bytes (the K and V blocks the live lanes' contexts reach) and their
+   share of the chip's bandwidth peak for each, and the group the pool's
+   shape derives (``walk_group``); the gate is the kernel's distance from the
+   gather. ``PERF.md`` section 6 (PR 43, PR 56) holds the sweeps this was
+   written for.
 
 10. **The latent walk alone** (``--latent``, and nothing else runs): one
    latent layer's absorbed decode read at the two latent cells' shapes —
@@ -150,8 +158,8 @@ def build_args(argv=None) -> argparse.Namespace:
                     "defaults to $SERVING_TRACE_DIR; unset = no artifacts")
     ap.add_argument("--walk", action="store_true",
                     help="time the decode block walk against the gather + "
-                    "scores it replaces, at laguna-mixedlen-batch's shape "
-                    "(with --smoke: a tiny one), and nothing else")
+                    "scores it replaced, at the shapes of the cells that walk "
+                    "(with --smoke: tiny ones), and nothing else")
     ap.add_argument("--walk-groups", default=None,
                     help="blocks a loop trip of the walk, comma-separated "
                     "(8,16,32,64; with --latent powers of two: 16,32,64,128,256)")
@@ -986,80 +994,119 @@ def _time_a_layer(read, operands, layers, live_bytes, args):
                  "live_gb_s": round(live_bytes / ms / 1e6, 1)}
 
 
+# a (k, v) pool's decode read at the shapes of the cells that walk it: (lanes,
+# of them live, query heads, kv heads, the layers timed in one program, pool
+# blocks a layer, rung, lowest and highest prompt, most reply rows, which
+# gather the walk replaced). ``laguna``: its block-wise gather and
+# ``masked_attention``; ``llama``: ``LlamaDecode._attend_paged``'s gather of
+# rows and ``_cache_attention``. The pools are the cells' own a layer; the
+# layers are as many as make one program's time a device time, not a dispatch
+WALK_SHAPES = {
+    "laguna-mixedlen-batch": (32, 32, 48, 8, 2, 17920, 8704, 512, 8192, 256, "laguna"),
+    "mixtral-chat-steady-2-live": (16, 2, 32, 8, 12, 3072, 2304, 64, 2048, 128, "llama"),
+    "mixtral-chat-steady-16-live": (16, 16, 32, 8, 12, 3072, 2304, 64, 2048, 128, "llama"),
+    "olmoe-rag-batch": (8, 8, 16, 16, 16, 1152, 2176, 512, 2048, 32, "llama"),
+}
+
+
 def _walk_sweep(args) -> dict:
     """Measurement 9 of the module's list."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from neuronx_distributed_llama3_2_tpu.flops import chip_peaks
+    from neuronx_distributed_llama3_2_tpu.inference.model import LlamaDecode
     from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
         paged_decode_walk,
+        walk_group,
     )
     from neuronx_distributed_llama3_2_tpu.models.laguna import (
         masked_attention,
         visible,
     )
+    from neuronx_distributed_llama3_2_tpu.models.llama import LlamaConfig
 
     if args.smoke:
-        layers, nb, bs, nkv, d, n, lanes, rung = 2, 40, 4, 2, 128, 4, 4, 32
-        low, high, reply, dtype = 6, 24, 8, jnp.float32
+        bs, d, dtype = 4, 128, jnp.float32
+        shapes = {"smoke-laguna": (4, 4, 4, 2, 2, 40, 32, 6, 24, 8, "laguna"),
+                  "smoke-llama": (4, 2, 4, 4, 2, 40, 32, 6, 24, 8, "llama")}
     else:
-        layers, nb, bs, nkv, d, n, lanes, rung = 2, 17920, 16, 8, 128, 48, 32, 8704
-        low, high, reply, dtype = 512, 8192, 256, jnp.bfloat16
-    rng = np.random.default_rng(args.seed)
-    width = rung // bs
-    contexts = np.minimum(
-        np.exp(rng.uniform(np.log(low), np.log(high), lanes)).astype(np.int64)
-        + rng.integers(0, reply + 1, lanes), rung)
-    positions = jnp.asarray(contexts - 1, jnp.int32)
-    # every lane its own blocks, scattered over the pool; past its frontier
-    # the null block, as the engine's table has it
-    tables = np.zeros((lanes, width), np.int32)
-    free = rng.permutation(np.arange(1, nb))
-    for lane, rows in enumerate(contexts):
-        blocks = -(-int(rows) // bs)
-        tables[lane, :blocks], free = free[:blocks], free[blocks:]
-    tables = jnp.asarray(tables)
-    keys = jax.random.split(jax.random.key(args.seed), 3)
-    k_pool = jax.random.normal(keys[0], (layers, nb, bs, nkv, d), dtype)
-    v_pool = jax.random.normal(keys[1], (layers, nb, bs, nkv, d), dtype)
-    q = jax.random.normal(keys[2], (lanes, n, d), dtype)
-    live_bytes = int(
-        2 * np.sum(-(-contexts // bs)) * bs * nkv * d * k_pool.dtype.itemsize)
-
-    def gather(q, k_pool, v_pool, tables, positions, layer):
-        """``LagunaDecode._attend``'s read of a full layer at t == 1."""
-        at = layer * nb + tables
-
-        def read(a):
-            got = a.reshape((layers * nb,) + a.shape[2:])[at]
-            return got.reshape((lanes, rung) + got.shape[3:])
-
-        k_pos = jnp.arange(rung, dtype=jnp.int32)[None, None, :]
-        return masked_attention(
-            q[:, None], read(k_pool), read(v_pool),
-            visible(positions[:, None], k_pos, None))[:, 0]
-
-    def timed(read):
-        return _time_a_layer(
-            read, (q, k_pool, v_pool, tables, positions), layers, live_bytes, args)
-
-    want, gathered = timed(gather)
-    record = {
-        "walk": True, "seed": args.seed, "platform": jax.default_backend(),
-        "lanes": lanes, "rung": rung, "mean_context": float(contexts.mean()),
-        "min_context": int(contexts.min()), "max_context": int(contexts.max()),
-        "live_mb_a_layer": round(live_bytes / 1e6, 2),
-        "gather_and_scores": gathered, "walk_by_group": {},
-    }
-    scale = float(jnp.max(jnp.abs(want)))
+        bs, d, dtype = 16, 128, jnp.bfloat16
+        shapes = WALK_SHAPES
+    peak = chip_peaks().hbm_bw
+    groups = [int(x) for x in args.walk_groups.split(",") if x]
+    record = {"walk": True, "seed": args.seed, "platform": jax.default_backend(), "cells": {}}
     worst = 0.0
-    for group in (int(x) for x in args.walk_groups.split(",") if x):
-        got, entry = timed(
-            lambda *a, group=group: paged_decode_walk(*a, kv_limit=rung, group=group))
-        entry["distance"] = float(jnp.max(jnp.abs(got - want))) / scale
-        worst = max(worst, entry["distance"])
-        record["walk_by_group"][str(group)] = entry
+    for cell, (lanes, live, n, nkv, layers, nb, rung, low, high, reply, twin) in shapes.items():
+        rng = np.random.default_rng(args.seed)
+        width = rung // bs
+        contexts = np.minimum(
+            np.exp(rng.uniform(np.log(low), np.log(high), lanes)).astype(np.int64)
+            + rng.integers(0, reply + 1, lanes), rung)
+        # every live lane its own blocks, scattered over the pool; past its
+        # frontier — and all of an idle lane's row — the null block, as the
+        # engine's table has it (an idle lane keeps a position all the same)
+        tables = np.zeros((lanes, width), np.int32)
+        free = rng.permutation(np.arange(1, nb))
+        for lane, rows in enumerate(contexts[:live]):
+            blocks = -(-int(rows) // bs)
+            tables[lane, :blocks], free = free[:blocks], free[blocks:]
+        tables, positions = jnp.asarray(tables), jnp.asarray(contexts - 1, jnp.int32)
+        keys = jax.random.split(jax.random.key(args.seed), 3)
+        k_pool = jax.random.normal(keys[0], (layers, nb, bs, nkv, d), dtype)
+        v_pool = jax.random.normal(keys[1], (layers, nb, bs, nkv, d), dtype)
+        q = jax.random.normal(keys[2], (lanes, n, d), dtype)
+        live_bytes = int(
+            2 * np.sum(-(-contexts[:live] // bs)) * bs * nkv * d * k_pool.dtype.itemsize)
+
+        model = LlamaDecode(LlamaConfig(num_heads=n, num_kv_heads=nkv, head_dim=d))
+
+        def gather(q, k_pool, v_pool, tables, positions, layer):
+            """What the walk replaced at t == 1: ``LagunaDecode._attend``'s
+            read of a full layer, or ``LlamaDecode._attend_paged``'s."""
+            if twin == "laguna":
+                at = layer * nb + tables
+
+                def read(a):
+                    got = a.reshape((layers * nb,) + a.shape[2:])[at]
+                    return got.reshape((lanes, rung) + got.shape[3:])
+
+                k_pos = jnp.arange(rung, dtype=jnp.int32)[None, None, :]
+                return masked_attention(
+                    q[:, None], read(k_pool), read(v_pool),
+                    visible(positions[:, None], k_pos, None))[:, 0]
+            j = jnp.arange(rung, dtype=jnp.int32)
+            at = layer * nb * bs + tables[:, j // bs] * bs + (j % bs)[None, :]
+
+            def read(a):
+                return a.reshape((layers * nb * bs,) + a.shape[3:])[at]
+
+            return model._cache_attention(
+                q[:, None], read(k_pool), read(v_pool), positions[:, None], None)[:, 0]
+
+        def timed(read):
+            got, entry = _time_a_layer(
+                read, (q, k_pool, v_pool, tables, positions), layers, live_bytes, args)
+            entry["share_of_hbm_peak"] = round(entry["live_gb_s"] * 1e9 / peak, 4)
+            return got[:live], entry
+
+        want, gathered = timed(gather)
+        entry = {
+            "lanes": lanes, "live_lanes": live, "heads": [n, nkv], "rung": rung, "layers": layers,
+            "mean_context": float(contexts[:live].mean()),
+            "live_mb_a_layer": round(live_bytes / 1e6, 2),
+            "derived_group": walk_group(bs, nkv),
+            "gather_and_scores": gathered, "walk_by_group": {},
+        }
+        scale = float(jnp.max(jnp.abs(want)))
+        for group in groups:
+            got, walked = timed(
+                lambda *a, group=group: paged_decode_walk(*a, kv_limit=rung, group=group))
+            walked["distance"] = float(jnp.max(jnp.abs(got - want))) / scale
+            worst = max(worst, walked["distance"])
+            entry["walk_by_group"][str(group)] = walked
+        record["cells"][cell] = entry
     # a bf16 pool: p and the scores are rounded at other places in the two
     if worst > (2e-2 if dtype == jnp.bfloat16 else 1e-5):
         record["gate_failure"] = f"walk is {worst:.3g} of the output's scale from the gather"

@@ -76,11 +76,11 @@ def main(argv) -> int:
         jobs.append((name, cases.ssm_step_case(*args), one))
     for name, args in cases.SPARSE_CHUNK_CASES.items():
         jobs.append((name, cases.sparse_chunk_case(*args), one))
-    for name, dtype in cases.WALK_CASES.items():
-        jobs.append((name, cases.walk_case(dtype), one))
+    for name, (dtype, shape) in cases.WALK_CASES.items():
+        jobs.append((name, cases.walk_case(dtype, shape), one))
         # every group size of scripts/paged_decode_bench.py --walk
-        for group in (8, 16, 64):
-            jobs.append((f"{name}-group{group}", cases.walk_case(dtype, group), one))
+        for group in (8, 16, 32, 64):
+            jobs.append((f"{name}-group{group}", cases.walk_case(dtype, shape, group), one))
     for name, (dtype, shape) in cases.LATENT_WALK_CASES.items():
         jobs.append((name, cases.latent_walk_case(dtype, shape), one))
         # every other group size of scripts/paged_decode_bench.py --latent

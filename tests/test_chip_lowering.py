@@ -236,16 +236,26 @@ SSM_STEP_CASES = {
 }
 
 
-def walk_case(pool_dtype, group=None):
-    """(fn, avals) for the decode block walk at ``laguna-mixedlen-batch``'s
-    shape: 32 lanes, 48 query over 8 kv heads of 128, the full kind's pool of
-    2 x 17,920 blocks of 16 rows, the 8,704-row rung."""
+# (lanes, query heads, kv heads, the kind's layers, its pool's blocks, the rung)
+# of the cells whose decode read is the walk: laguna-mixedlen-batch's full
+# kind, mixtral-chat-steady, olmoe-rag-batch
+WALK_SHAPES = {
+    "laguna": (32, 48, 8, 2, 17920, 8704),
+    "mixtral": (16, 32, 8, 3, 3072, 2304),
+    "olmoe": (8, 16, 16, 8, 1152, 2176),
+}
+
+
+def walk_case(pool_dtype, shape="laguna", group=None):
+    """(fn, avals) for the decode block walk at a cell's shape (heads of 128,
+    blocks of 16 rows), ``group`` blocks a loop trip (None: what the pool's
+    shape gives)."""
     from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
-        WALK_GROUP,
         paged_decode_walk,
     )
 
-    lanes, n, nkv, d, layers, blocks, bs, rung = 32, 48, 8, 128, 2, 17920, 16, 8704
+    lanes, n, nkv, layers, blocks, rung = WALK_SHAPES[shape]
+    d, bs = 128, 16
     pool = jax.ShapeDtypeStruct((layers, blocks, bs, nkv, d), pool_dtype)
     avals = [
         jax.ShapeDtypeStruct((lanes, n, d), jnp.bfloat16), pool, pool,
@@ -256,14 +266,16 @@ def walk_case(pool_dtype, group=None):
 
     def fn(q, k_pool, v_pool, tables, positions, layer):
         return paged_decode_walk(
-            q, k_pool, v_pool, tables, positions, layer, kv_limit=rung,
-            group=group or WALK_GROUP)
+            q, k_pool, v_pool, tables, positions, layer, kv_limit=rung, group=group)
 
     return fn, avals
 
 
 # the pool's dtype: bfloat16 as served; float32 is the CPU tests' pool
-WALK_CASES = {"decode-walk-bf16": jnp.bfloat16, "decode-walk-f32": jnp.float32}
+WALK_CASES = {
+    "decode-walk-bf16": (jnp.bfloat16, "laguna"), "decode-walk-f32": (jnp.float32, "laguna"),
+    "decode-walk-mixtral-bf16": (jnp.bfloat16, "mixtral"), "decode-walk-olmoe-bf16": (jnp.bfloat16, "olmoe"),
+}
 
 
 def latent_walk_case(pool_dtype, shape, group=None):
@@ -382,7 +394,7 @@ def test_ssm_state_step_kernel_lowers_for_tpu(compiled_mode, name):
 
 @pytest.mark.parametrize("name", WALK_CASES)
 def test_decode_walk_kernel_lowers_for_tpu(compiled_mode, name):
-    lowered = lower_for_tpu(*walk_case(WALK_CASES[name]))
+    lowered = lower_for_tpu(*walk_case(*WALK_CASES[name]))
     assert_mosaic_call(lowered, "paged_decode_walk")
 
 

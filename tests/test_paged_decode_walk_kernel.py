@@ -5,8 +5,11 @@ block-wise gather of the whole rung and ``masked_attention``, which
 permuted table with ragged positions, and what "the lane's live blocks only"
 has to mean: a lane on the null block, blocks past a frontier that are never
 read, the layer's offset into the run of ``L · num_blocks`` blocks, a group
-size that does not divide the walk. Then which read a ``pdecode`` holds in
-each kernel mode."""
+size that does not divide the walk. The same against
+``LlamaDecode._cache_attention`` over the rows ``_attend_paged`` gathers, at
+the head groupings of the families that decode through it (Mixtral's 32 on 8,
+OLMoE's 16 on 16), with two lanes sharing a prefix's blocks. Then which read a
+``pdecode`` holds in each kernel mode, for laguna and for the Llama family."""
 
 import dataclasses
 
@@ -15,12 +18,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from neuronx_distributed_llama3_2_tpu.inference.model import CacheKind, decode_model_for
+from neuronx_distributed_llama3_2_tpu.inference.model import CacheKind, LlamaDecode, decode_model_for
 from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
-from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import paged_decode_walk
+from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
+    paged_decode_walk, walk_fits, walk_group,
+)
 from neuronx_distributed_llama3_2_tpu.models.laguna import (
     LAGUNA_CONFIGS, LagunaForCausalLM, masked_attention, visible,
 )
+from neuronx_distributed_llama3_2_tpu.models.llama import LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM
+from neuronx_distributed_llama3_2_tpu.models.mixtral import MIXTRAL_CONFIGS, MixtralForCausalLM
+from neuronx_distributed_llama3_2_tpu.parallel.state import initialize_model_parallel
 
 LAYERS, BLOCKS, BS, NKV, GROUPS, D = 2, 40, 4, 2, 3, 16
 WIDTH = 8                                   # blocks a table row: a rung of 32 rows
@@ -36,19 +44,22 @@ def interpreted(monkeypatch):
     monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
 
 
-def make(dtype, seed=0):
+def make(dtype, seed=0, heads=(NKV * GROUPS, NKV), positions=POSITIONS, null_lane=NULL_LANE, shared=0):
     """(q, k_pool, v_pool, tables, positions): every live lane its own blocks,
-    scattered; past its frontier the null block, as the engine's table has it."""
+    scattered; past its frontier the null block, as the engine's table has it;
+    lane 1's first ``shared`` blocks are lane 0's (a prefix both were given)."""
+    n, nkv = heads
     rng = np.random.default_rng(seed)
-    k_pool, v_pool = (jnp.asarray(rng.standard_normal((LAYERS, BLOCKS, BS, NKV, D)), dtype) for _ in range(2))
-    q = jnp.asarray(rng.standard_normal((len(POSITIONS), NKV * GROUPS, D)), dtype)
+    k_pool, v_pool = (jnp.asarray(rng.standard_normal((LAYERS, BLOCKS, BS, nkv, D)), dtype) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((len(positions), n, D)), dtype)
     free = rng.permutation(np.arange(1, BLOCKS))
-    tables = np.zeros((len(POSITIONS), WIDTH), np.int32)
-    for lane, pos in enumerate(POSITIONS):
-        if lane != NULL_LANE:
+    tables = np.zeros((len(positions), WIDTH), np.int32)
+    for lane, pos in enumerate(positions):
+        if lane != null_lane:
             blocks = pos // BS + 1
             tables[lane, :blocks], free = free[:blocks], free[blocks:]
-    return q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(POSITIONS, jnp.int32)
+    tables[1, :shared] = tables[0, :shared]
+    return q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(positions, jnp.int32)
 
 
 @jax.jit
@@ -72,8 +83,8 @@ def walk(q, k_pool, v_pool, tables, positions, layer, group):
     return _WALK(q, k_pool, v_pool, tables, positions, jnp.int32(layer), kv_limit=RUNG, group=group)
 
 
-def live_lanes(a):
-    return jnp.delete(a, NULL_LANE, axis=0)
+def live_lanes(a, null_lane=NULL_LANE):
+    return jnp.delete(a, null_lane, axis=0)
 
 
 @pytest.mark.parametrize("dtype,tol,group,layer", [
@@ -150,6 +161,65 @@ def test_query_heads_that_do_not_divide_are_refused():
 
 
 # ---------------------------------------------------------------------------
+# the Llama family's groupings, against LlamaDecode's own gather and attention
+# ---------------------------------------------------------------------------
+
+# (query heads, kv heads): Mixtral's, and OLMoE's (one query head a kv head)
+GROUPINGS = {"32on8": (32, 8), "16on16": (16, 16)}
+# lanes 0 and 1 share their first two blocks (a prefix the radix cache gave
+# both) and part at row 8; lane 2 idles on the null block; lane 4 is at row 0
+SHARED_POSITIONS = (21, 13, 30, 30, 0)
+SHARED_BLOCKS, SHARED_NULL = 2, 2
+
+
+@jax.jit
+def llama_twin(q, k_pool, v_pool, tables, positions, layer):
+    """``LlamaDecode._attend_paged``'s read at one row a lane: the rung's rows
+    gathered through the table, then ``_cache_attention``."""
+    n, nkv = q.shape[1], k_pool.shape[3]
+    model = LlamaDecode(LlamaConfig(num_heads=n, num_kv_heads=nkv, head_dim=D))
+    j = jnp.arange(RUNG, dtype=jnp.int32)
+    at = layer * BLOCKS * BS + tables[:, j // BS] * BS + (j % BS)[None, :]
+
+    def read(a):
+        return a.reshape((LAYERS * BLOCKS * BS,) + a.shape[3:])[at]
+
+    return model._cache_attention(q[:, None], read(k_pool), read(v_pool), positions[:, None], None)[:, 0]
+
+
+@pytest.mark.parametrize("dtype,tol,group", [
+    (jnp.float32, 2e-6, None), (jnp.float32, 2e-6, 3), (jnp.bfloat16, 2e-2, None),
+], ids=["f32-derived", "f32-group3", "bf16-derived"])
+@pytest.mark.parametrize("heads", GROUPINGS)
+def test_the_walk_is_llamas_gather_and_cache_attention_at_its_families_groupings(heads, dtype, tol, group):
+    """Mixtral's and OLMoE's query-on-kv-head groupings, the group a trip
+    derived from the pool's shape or 3 (over walks of 1, 4, 6 and 8 blocks),
+    layer 1's blocks, two lanes reading the same two pool blocks."""
+    operands = make(dtype, heads=GROUPINGS[heads], positions=SHARED_POSITIONS, null_lane=SHARED_NULL,
+                    shared=SHARED_BLOCKS)
+    got = walk(*operands, 1, group)
+    want = llama_twin(*operands, jnp.int32(1))
+    assert got.dtype == dtype and got.shape == want.shape == (len(SHARED_POSITIONS), GROUPINGS[heads][0], D)
+
+    def live(a):
+        return live_lanes(a, SHARED_NULL).astype(jnp.float32)
+
+    assert float(jnp.max(jnp.abs(live(got) - live(want)))) <= tol * float(jnp.max(jnp.abs(live(want))))
+    other = llama_twin(*operands, jnp.int32(0))
+    assert float(jnp.max(jnp.abs(live(got) - live(other)))) > 0.1
+    assert bool(jnp.isfinite(got[SHARED_NULL].astype(jnp.float32)).all())
+
+
+def test_the_group_a_trip_follows_the_pools_shape():
+    """As many blocks as hold 4,096 (row, kv head) pairs: laguna's and
+    Mixtral's 32 at 8 kv heads of 16-row blocks, OLMoE's 16 at 16, never 0."""
+    assert walk_group(16, 8) == 32 and walk_group(16, 16) == 16
+    assert walk_group(16, 1) == 256 and walk_group(64, 128) == 1
+    # Mosaic takes rows of one register's lanes alone; the interpreter any
+    assert walk_fits(128) and walk_fits(D)
+
+
+# ---------------------------------------------------------------------------
 # which read a decode program holds
 # ---------------------------------------------------------------------------
 
@@ -191,3 +261,59 @@ def test_the_kernel_mode_decides_which_read_a_decode_program_holds(mode, monkeyp
     assert "pallas_call" not in chunk and gathered in chunk
     step, _ = programs(jax.eval_shape(lambda: model.init_paged_cache(20, bs, kv_cache_dtype="int8", window_blocks=19)))
     assert "pallas_call" not in step and gathered in step
+
+
+FAMILIES = {
+    "llama": (LLAMA_CONFIGS["tiny"], LlamaForCausalLM),
+    "mixtral": (MIXTRAL_CONFIGS["tiny-moe"], MixtralForCausalLM),
+}
+
+
+@pytest.mark.parametrize("mode", ["reference", "interpret"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_kernel_mode_decides_which_read_a_llama_family_decode_program_holds(family, mode, monkeypatch):
+    """``LlamaDecode`` / ``MixtralDecode``: in ``interpret`` a ``pdecode`` holds
+    the walk and no (lanes, rung, kv heads, head) copy, ``use_paged_kernel`` or
+    not; ``reference`` keeps the gather. A block of several rows, a tree, an
+    int8 pool and a mesh keep what they had in either mode."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    config, net = FAMILIES[family]
+    model = decode_model_for(config)
+    (rows,) = model.cache_kinds
+    walks = mode == "interpret"
+    assert model.decode_read(rows) == ("kernel" if walks else "gather")
+    assert model.decode_read(rows, quantized=True) == "gather"
+    assert model.decode_read(CacheKind("ring", 1, 8)) == "gather"
+    asked = decode_model_for(dataclasses.replace(config, use_paged_kernel=True))
+    assert asked.decode_read(rows) == asked.decode_read(rows, quantized=True) == "kernel"
+    params = jax.eval_shape(net(config).init, jax.random.key(0))
+    lanes, rung, bs = 3, 64, 4
+    gathered = f"[{lanes},{rung},{config.num_kv_heads},{config.head_dim}]"
+    tables = jnp.zeros((lanes, rung // bs), jnp.int32)
+    zeros = jnp.zeros((lanes,), jnp.int32)
+
+    def step(model, pool):
+        return str(jax.make_jaxpr(lambda p, c: model.decode_step(p, c, zeros, zeros, tables, kv_limit=rung))(params, pool))
+
+    def block(model, pool, t, tree=None):
+        return str(jax.make_jaxpr(lambda p, c: model.forward(
+            p, c, jnp.zeros((lanes, t), jnp.int32), zeros, block_tables=tables, kv_limit=rung, tree=tree))(params, pool))
+
+    pool = jax.eval_shape(lambda: model.init_paged_cache(20, bs))
+    got = step(model, pool)
+    assert ("paged_decode_walk" in got) == walks and (gathered in got) == (not walks)
+    # where the static-grid kernel was asked for, one row a lane is still the walk's
+    got = step(asked, pool)
+    assert ("paged_decode_walk" in got) == walks and ("paged_flash_decode" in got) == (not walks)
+    assert gathered not in got
+    # several rows, and one row under a tree: never the walk
+    one_node = (jnp.zeros((1,), jnp.int32), jnp.ones((1, 1), bool))
+    for got in (block(model, pool, 8), block(model, pool, 1, one_node)):
+        assert "pallas_call" not in got and gathered in got
+    assert "paged_decode_walk" not in block(asked, pool, 2) and "paged_decode_walk" not in block(asked, pool, 1, one_node)
+    got = step(model, jax.eval_shape(lambda: model.init_paged_cache(20, bs, kv_cache_dtype="int8")))
+    assert "pallas_call" not in got and gathered in got
+    # a mesh of more than one device: the pool shards by kv head
+    initialize_model_parallel(tensor_model_parallel_size=2, devices=jax.devices()[:2])
+    assert model.decode_read(rows) == "gather" and asked.decode_read(rows) == "kernel"
+    assert "paged_decode_walk" not in step(model, pool)

@@ -330,6 +330,62 @@ def test_paged_kernel_decode_never_materializes_gather(params):
         )
 
 
+@pytest.fixture(scope="module")
+def moe_params():
+    from neuronx_distributed_llama3_2_tpu.models.mixtral import MIXTRAL_CONFIGS, MixtralForCausalLM
+
+    return jax.jit(MixtralForCausalLM(MIXTRAL_CONFIGS["tiny-moe"]).init)(jax.random.key(0))
+
+
+def _moe_serving(moe_params, mode, monkeypatch, lanes):
+    """A tiny Mixtral engine built (and its programs traced) under kernel
+    mode ``mode``: ``"interpret"`` walks a lane's live blocks at one row a
+    lane, ``"reference"`` (this tier's) gathers the rung."""
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+    from neuronx_distributed_llama3_2_tpu.models.mixtral import MIXTRAL_CONFIGS
+
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    eng = InferenceEngine(
+        MIXTRAL_CONFIGS["tiny-moe"], moe_params, max_batch=lanes, max_seq_len=64, buckets=[8, 16, 32])
+    return PagedServingEngine(
+        eng, GenerationConfig(max_new_tokens=10),
+        PagedConfig(block_size=4, num_blocks=96, prefill_chunk_tokens=8, prefill_buckets=(8,), kv_buckets=(64,)))
+
+
+def test_the_block_walk_serves_a_tiny_mixtral_token_for_token_with_the_gather(moe_params, monkeypatch):
+    """``LlamaDecode._attend_paged`` at one row a lane, the walk
+    (``"interpret"``) beside the gather (``"reference"``), under what the
+    engine does to a table: a prefix two lanes share, a long prompt prefilled
+    in chunks beside live decodes (its lane on the null block meanwhile), more
+    requests than lanes (a finished lane's slot and blocks reused)."""
+    rng = np.random.default_rng(21)
+    vocab = 256
+    shared = rng.integers(1, vocab, size=(12,)).tolist()
+    own = [rng.integers(1, vocab, size=(n,)).tolist() for n in (3, 5, 6, 41, 9, 30, 2)]
+    prompts = [shared + own[0], own[1], shared + own[2]] + own[3:]
+    outs, reads = {}, {}
+    for mode in ("reference", "interpret"):
+        srv = _moe_serving(moe_params, mode, monkeypatch, lanes=4)
+        reads[mode] = srv._kind_facts()["cache_kinds"]["rows"]["decode_read"]
+        rids = [srv.submit(p) for p in prompts[:2]]
+        for _ in range(3):
+            srv.step()                     # two lanes decoding, the prefix indexed
+        rids += [srv.submit(p) for p in prompts[2:]]
+        # the 41-token prompt's chunks run beside the others' decode steps
+        beside = 0
+        while srv.step():
+            live = list(srv._active.values())
+            beside += any(r.prefilling for r in live) and any(not r.prefilling for r in live)
+        out = srv.run_to_completion()
+        outs[mode] = [out[r] for r in rids]
+        m = srv.metrics
+        assert beside >= 3 and m.prefill_chunks >= 6 and m.cached_tokens >= 12, (beside, m.prefill_chunks)
+        assert m.finished == len(prompts) > 3
+        assert srv.allocator.leak_check() == [] and audit_engine(srv) == []
+    assert reads == {"reference": "gather", "interpret": "kernel"}
+    assert outs["interpret"] == outs["reference"] and all(len(o) == 10 for o in outs["interpret"])
+
+
 def test_chunked_prefill_interleaves_decode(params):
     """Acceptance: with prefill_chunk_tokens set, a long-prompt admission
     interleaves — the already-active lane gains a decode token on the same
