@@ -38,6 +38,7 @@ from neuronx_distributed_llama3_2_tpu.inference.model import (
     PagedKVCache,
     RetentionDecode,
     SalaDecode,
+    SmallThinkerDecode,
     SarvamDecode,
     XingDecode,
     StateCache,
@@ -90,6 +91,7 @@ __all__ = [
     "JambaDecode",
     "SalaDecode",
     "LagunaDecode",
+    "SmallThinkerDecode",
     "MixedKVCache",
     "RetentionDecode",
     "SarvamDecode",

@@ -1629,8 +1629,17 @@ class MixtralDecode(LlamaDecode):
         "get_expert_model_parallel_size",
     )
 
-    def _mlp_block(self, lp: Params, h: jax.Array) -> jax.Array:
+    def _moe(self):
+        """The layer's expert block as inference runs it: the training
+        config's capacity factor ignored, so ``ExpertMLPs.__call__`` takes the
+        no-drop (all-experts) dispatch (single dispatch site)."""
         from neuronx_distributed_llama3_2_tpu.moe.model import MoE
+
+        return MoE(dataclasses.replace(self.config.moe_config(), capacity_factor=None))
+
+    def _mlp_block(self, lp: Params, h: jax.Array, routes=None) -> jax.Array:
+        """``routes``: ``MoE.route``'s result where the model routes from
+        another tensor than ``h`` (:class:`SmallThinkerDecode`), else None."""
         from neuronx_distributed_llama3_2_tpu.parallel import state as parallel_state
 
         if (
@@ -1642,10 +1651,7 @@ class MixtralDecode(LlamaDecode):
                 "under an ep>1 mesh would allgather every EP-sharded expert "
                 "weight per token. Serve MoE models with tp/dp sharding."
             )
-        # capacity_factor=None routes through the no-drop (all-experts)
-        # dispatch in ExpertMLPs.__call__ (single dispatch site)
-        cfg = dataclasses.replace(self.config.moe_config(), capacity_factor=None)
-        y, _, _ = MoE(cfg)(lp["moe"], h)
+        y, _, _ = self._moe()(lp["moe"], h, routes=routes)
         return y
 
 
@@ -2283,8 +2289,7 @@ class LagunaDecode(MixtralDecode):
         if tree is not None:
             raise NotImplementedError("tree verification over a ring of window rows")
         from neuronx_distributed_llama3_2_tpu.models.laguna import (
-            FULL, WINDOW, LagunaAttention, LagunaDecoderLayer, layer_runs, rope_tables,
-            scan_run,
+            FULL, WINDOW, layer_runs, scan_run,
         )
 
         c = self.config
@@ -2308,24 +2313,24 @@ class LagunaDecode(MixtralDecode):
                 slots = jnp.arange(b, dtype=jnp.int32)
             rope_len = cache.max_len
             pools = {"all": (cache.k, cache.v)}
-        ropes = {kind: rope_tables(c, kind, rope_len) for kind in (FULL, WINDOW)}
+        ropes = model._ropes(rope_len)
         norm = make_norm(c)
         tap = routing_tap.current()
 
         x = model._embed()(params["embed"], tokens)
         x = constrain(x, P(BATCH_AXES, None, None))
         for run in layer_runs(c):
-            layer = LagunaDecoderLayer(c, run.kind, run.sparse)
-            attn = LagunaAttention(c, run.kind)
+            attn = model._layer(run.kind, run.sparse)._attn()
             sin, cos = ropes[run.kind]
             # the dense cache holds every layer; a pool the layers of its kind
             held, first = (run.kind, run.kind_first) if paged else ("all", run.layer)
             own_ring = paged and run.kind == WINDOW and window_tables is not None
 
-            def body(carry, lp, j, run=run, layer=layer, attn=attn, sin=sin, cos=cos,
+            def body(carry, lp, j, run=run, attn=attn, sin=sin, cos=cos,
                      held=held, first=first, own_ring=own_ring):
                 x, pools = carry
                 h = norm(lp["attn_norm"], x)
+                routes = self._early_routes(lp, h) if run.sparse else None
                 with jax.named_scope("attn"), jax.named_scope(run.kind):
                     q, k, v = attn.project(lp["attn"], h, sin, cos, pos_block)
                     att, kc, vc = self._attend(
@@ -2338,8 +2343,10 @@ class LagunaDecode(MixtralDecode):
                     attn_out = attn.output(lp["attn"], h, att)
                 x = x + attn_out
                 h = norm(lp["mlp_norm"], x)
-                ffn = MixtralDecode._mlp_block if run.sparse else LlamaDecode._mlp_block
-                x = x + ffn(self, lp, h)
+                if run.sparse:
+                    x = x + MixtralDecode._mlp_block(self, lp, h, routes)
+                else:
+                    x = x + LlamaDecode._mlp_block(self, lp, h)
                 return (x, {**pools, held: (kc, vc)}), (
                     None if tap is None or not run.sparse else tap.take_layer())
 
@@ -2355,6 +2362,12 @@ class LagunaDecode(MixtralDecode):
         if return_hidden:
             return x, new_cache
         return model._logits(params, x), new_cache
+
+    def _early_routes(self, lp: Params, h: jax.Array):
+        """A layer's routes where the model makes them from its normed input
+        ``h``, ahead of attention (:class:`SmallThinkerDecode`); None where the
+        expert block routes the tensor it is given."""
+        return None
 
     def _attend(
         self, q, k, v, kc, vc, layer, pos_block, slots, *, window, context_encode: bool,
@@ -2455,6 +2468,29 @@ class LagunaDecode(MixtralDecode):
                 k_pos = pos_block[..., None] - (pos_block[..., None] - r) % ring_rows
             att = masked_attention(q, k, v, visible(pos_block, k_pos, window))
         return att, kc, vc
+
+
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerDecode(LagunaDecode):
+    """Decode-mode SmallThinker (:mod:`..models.smallthinker`):
+    :class:`LagunaDecode`'s two kinds of cache, ring rule, ``_attend`` and
+    layer loop, with this family's layer — a full layer's q and k carry no
+    position (the model's ``_ropes`` gives that kind no table), no output
+    gate — and its one change to the loop: **the layer's experts are routed
+    from its normed input, before the attention block** (``moe/router`` ahead
+    of ``attn`` in a layer's trace), and the post-attention state is
+    dispatched by those routes. The window (4,096 as published) is 8 x
+    Laguna's: the lane's ring is the larger read of a decode step."""
+
+    def _model(self):
+        from neuronx_distributed_llama3_2_tpu.models.smallthinker import (
+            SmallThinkerForCausalLM,
+        )
+
+        return SmallThinkerForCausalLM(self.config)
+
+    def _early_routes(self, lp: Params, h: jax.Array):
+        return self._moe().route(lp["moe"], h)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -3319,6 +3355,7 @@ def decode_model_for(config) -> LlamaDecode:
     from neuronx_distributed_llama3_2_tpu.models.minicpm_sala import SalaConfig
     from neuronx_distributed_llama3_2_tpu.models.mixtral import MixtralConfig
     from neuronx_distributed_llama3_2_tpu.models.sarvam import SarvamConfig
+    from neuronx_distributed_llama3_2_tpu.models.smallthinker import SmallThinkerConfig
     from neuronx_distributed_llama3_2_tpu.models.xing import XingConfig
 
     if isinstance(config, BertConfig):
@@ -3340,6 +3377,8 @@ def decode_model_for(config) -> LlamaDecode:
         return SalaDecode(config)
     if isinstance(config, LagunaConfig):
         return LagunaDecode(config)
+    if isinstance(config, SmallThinkerConfig):
+        return SmallThinkerDecode(config)
     if isinstance(config, MixtralConfig):
         return MixtralDecode(config)
     return LlamaDecode(config)

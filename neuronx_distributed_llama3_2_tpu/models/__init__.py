@@ -53,6 +53,13 @@ from neuronx_distributed_llama3_2_tpu.models.laguna import (  # noqa: F401
     params_from_hf_laguna,
     params_to_hf_laguna,
 )
+from neuronx_distributed_llama3_2_tpu.models.smallthinker import (  # noqa: F401
+    SMALLTHINKER_CONFIGS,
+    SmallThinkerConfig,
+    SmallThinkerForCausalLM,
+    params_from_hf_smallthinker,
+    params_to_hf_smallthinker,
+)
 from neuronx_distributed_llama3_2_tpu.models.dbrx import (  # noqa: F401
     DBRX_CONFIGS,
     DbrxConfig,
@@ -144,6 +151,11 @@ def model_registry():
         reg[name] = {
             "config": cfg, "model_cls": LagunaForCausalLM,
             "from_hf": params_from_hf_laguna, "to_hf": params_to_hf_laguna,
+        }
+    for name, cfg in SMALLTHINKER_CONFIGS.items():
+        reg[name] = {
+            "config": cfg, "model_cls": SmallThinkerForCausalLM,
+            "from_hf": params_from_hf_smallthinker, "to_hf": params_to_hf_smallthinker,
         }
     for name, cfg in DBRX_CONFIGS.items():
         reg[name] = {

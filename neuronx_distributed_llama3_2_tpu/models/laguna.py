@@ -288,7 +288,8 @@ class LagunaAttention:
 
     def project(self, params: Params, h: jax.Array, sin, cos, positions):
         """q (b, t, N, d), k, v (b, t, NKV, d) of h (b, t, H), q and k
-        rotated by the kind's table."""
+        rotated by the kind's table (``sin`` None: a kind that carries no
+        position, :mod:`.smallthinker`'s full layers)."""
         c = self.config
         b, t, _ = h.shape
         with jax.named_scope("qkv"):
@@ -296,6 +297,8 @@ class LagunaAttention:
             q = q.reshape(b, t, self.heads, c.head_dim)
             k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
             v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
+        if sin is None:
+            return q, k, v
         with jax.named_scope("rope"):
             return rotate(q, sin, cos, positions), rotate(k, sin, cos, positions), v
 
@@ -455,6 +458,10 @@ class LagunaForCausalLM:
     def _logits(self, params: Params, hidden: jax.Array) -> jax.Array:
         return self._llama()._logits(params, hidden)
 
+    def _layer(self, kind: str, sparse: bool):
+        """The decoder layer of one stack (a family on this class gives its own)."""
+        return LagunaDecoderLayer(self.config, kind, sparse)
+
     def _ropes(self, s: int) -> Dict[str, Tuple[jax.Array, jax.Array]]:
         """A (sin, cos) pair a kind."""
         return {kind: rope_tables(self.config, kind, s) for kind in (FULL, WINDOW)}
@@ -465,7 +472,7 @@ class LagunaForCausalLM:
         params = {"embed": self._embed().init(ke), "final_norm": self._norm().init(kh)}
         for i, (name, (kind, sparse, count)) in enumerate(stack_sizes(c).items()):
             keys = jax.random.split(jax.random.fold_in(kl, i), count)
-            params[name] = jax.vmap(LagunaDecoderLayer(c, kind, sparse).init)(keys)
+            params[name] = jax.vmap(self._layer(kind, sparse).init)(keys)
         if not c.tie_word_embeddings:
             params["lm_head"] = self._llama()._lm_head().init(kh)
         return params
@@ -475,7 +482,7 @@ class LagunaForCausalLM:
         specs = {"embed": self._embed().specs(), "final_norm": self._norm().specs()}
         for name, (kind, sparse, _) in stack_sizes(c).items():
             specs[name] = jax.tree.map(
-                lambda s: P(None, *s), LagunaDecoderLayer(c, kind, sparse).specs(),
+                lambda s: P(None, *s), self._layer(kind, sparse).specs(),
                 is_leaf=lambda s: isinstance(s, P),
             )
         if not c.tie_word_embeddings:
@@ -492,7 +499,7 @@ class LagunaForCausalLM:
         x = self._embed()(params["embed"], input_ids)
         aux, sparse_layers = jnp.zeros((), jnp.float32), 0
         for run in layer_runs(c):
-            layer = LagunaDecoderLayer(c, run.kind, run.sparse)
+            layer = self._layer(run.kind, run.sparse)
             sin, cos = ropes[run.kind]
             x, auxes = scan_run(
                 lambda x, lp, _: layer(lp, x, sin, cos, positions), x, params[run.stack], run)
@@ -515,9 +522,11 @@ class LagunaForCausalLM:
 # HF names
 # ---------------------------------------------------------------------------
 
-def _hf_layer_leaves(config: LagunaConfig, layer: int, sparse: bool):
+def _hf_layer_leaves(config: LagunaConfig, layer: int, sparse: bool, out_gate: bool = True):
     """(path in a layer's params, HF name, the map between torch's layout and
-    ours — its own inverse) of one layer's leaves but the feed-forward's. The catalog publishes the configuration and no tensor names:
+    ours — its own inverse) of one layer's leaves but the feed-forward's;
+    ``out_gate``: whether the layer has one (a family on this file's stacks
+    without it, :mod:`.smallthinker`). The catalog publishes the configuration and no tensor names:
     these are the Qwen-MoE lineage's, whose keys the configuration uses
     (``mlp.gate`` the router, ``mlp.shared_expert``), the output gate as
     ``self_attn.g_proj``. Linear weights are torch's (out, in)."""
@@ -531,8 +540,9 @@ def _hf_layer_leaves(config: LagunaConfig, layer: int, sparse: bool):
         (("attn", "qkv", "k_kernel"), p + "self_attn.k_proj.weight", t),
         (("attn", "qkv", "v_kernel"), p + "self_attn.v_proj.weight", t),
         (("attn", "o", "kernel"), p + "self_attn.o_proj.weight", t),
-        (("attn", "out_gate", "kernel"), p + "self_attn.g_proj.weight", t),
     ]
+    if out_gate:
+        rows.append((("attn", "out_gate", "kernel"), p + "self_attn.g_proj.weight", t))
     if sparse:
         rows.append((("moe", "router", "kernel"), p + "mlp.gate.weight", t))
     return rows
@@ -544,7 +554,8 @@ def _hf_swiglu_names(prefix: str):
 
 def params_to_hf_laguna(params: Params, config: LagunaConfig) -> Dict[str, Any]:
     """Stacked pytree -> a ``state_dict`` under the names of
-    :func:`_hf_layer_leaves` (numpy fp32, torch (out, in) layout)."""
+    :func:`_hf_layer_leaves` (numpy fp32, torch (out, in) layout). An output
+    gate and a shared expert are written where the layer has them."""
     import numpy as np
 
     def np32(x):
@@ -564,7 +575,8 @@ def params_to_hf_laguna(params: Params, config: LagunaConfig) -> Dict[str, Any]:
         stack = jax.tree.map(np32, params[run.stack])
         for j in range(run.count):
             layer, lp = run.layer + j, jax.tree.map(lambda a: a[run.first + j], stack)
-            for path, name, to_ours in _hf_layer_leaves(config, layer, run.sparse):
+            for path, name, to_ours in _hf_layer_leaves(
+                    config, layer, run.sparse, "out_gate" in lp["attn"]):
                 leaf = lp
                 for key in path:
                     leaf = leaf[key]
@@ -573,7 +585,8 @@ def params_to_hf_laguna(params: Params, config: LagunaConfig) -> Dict[str, Any]:
             if not run.sparse:
                 swiglu(sd, mlp, lp["mlp"]["gate_up"], lp["mlp"]["down"]["kernel"])
                 continue
-            swiglu(sd, mlp + ".shared_expert", lp["moe"]["shared"]["gate_up"], lp["moe"]["shared"]["down"])
+            if "shared" in lp["moe"]:
+                swiglu(sd, mlp + ".shared_expert", lp["moe"]["shared"]["gate_up"], lp["moe"]["shared"]["down"])
             for e in range(config.num_experts):
                 swiglu(sd, f"{mlp}.experts.{e}", lp["moe"]["experts"]["gate_up"][e],
                        lp["moe"]["experts"]["down"][e])
@@ -581,7 +594,8 @@ def params_to_hf_laguna(params: Params, config: LagunaConfig) -> Dict[str, Any]:
 
 
 def params_from_hf_laguna(state_dict: Dict[str, Any], config: LagunaConfig) -> Params:
-    """Inverse of :func:`params_to_hf_laguna`."""
+    """Inverse of :func:`params_to_hf_laguna` (an output gate and a shared
+    expert read where the ``state_dict`` names them)."""
     import numpy as np
 
     def t(name):
@@ -596,7 +610,8 @@ def params_from_hf_laguna(state_dict: Dict[str, Any], config: LagunaConfig) -> P
 
     def layer_params(layer: int, sparse: bool):
         lp: Params = {}
-        for path, name, to_ours in _hf_layer_leaves(config, layer, sparse):
+        gated = f"model.layers.{layer}.self_attn.g_proj.weight" in state_dict
+        for path, name, to_ours in _hf_layer_leaves(config, layer, sparse, gated):
             at = lp
             for key in path[:-1]:
                 at = at.setdefault(key, {})
@@ -607,11 +622,11 @@ def params_from_hf_laguna(state_dict: Dict[str, Any], config: LagunaConfig) -> P
             lp["mlp"] = {"gate_up": gate_up, "down": {"kernel": down}}
             return lp
         experts = [swiglu(f"{mlp}.experts.{e}") for e in range(config.num_experts)]
-        shared = swiglu(mlp + ".shared_expert")
-        lp["moe"].update(
-            experts={"gate_up": np.stack([g for g, _ in experts]), "down": np.stack([d for _, d in experts])},
-            shared={"gate_up": shared[0], "down": shared[1]},
-        )
+        lp["moe"]["experts"] = {
+            "gate_up": np.stack([g for g, _ in experts]), "down": np.stack([d for _, d in experts])}
+        if _hf_swiglu_names(mlp + ".shared_expert")[0] in state_dict:
+            shared = swiglu(mlp + ".shared_expert")
+            lp["moe"]["shared"] = {"gate_up": shared[0], "down": shared[1]}
         return lp
 
     def typed(path, a):

@@ -47,10 +47,15 @@ from neuronx_distributed_llama3_2_tpu.parallel.state import EP_AXIS, TP_AXIS
 
 Params = Dict[str, Any]
 
+# what ``activation`` may name: the function on the gate branch of a gated
+# expert (``down(act(gate) * up)``), on the only branch of an ungated one
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
 
 @dataclasses.dataclass(frozen=True)
 class ExpertMLPs:
-    """Fused gate_up/down projections for E experts (SwiGLU)."""
+    """Fused gate_up/down projections for E experts (SwiGLU; ReGLU where
+    ``activation`` is ``"relu"``)."""
 
     num_experts: int
     hidden_size: int
@@ -58,6 +63,7 @@ class ExpertMLPs:
     capacity_factor: Optional[float] = None  # None => all-experts path
     glu: bool = True
     dtype: Any = jnp.bfloat16
+    activation: str = "silu"     # a key of ACTIVATIONS
     # a share of a wider router's experts (one rank of an expert-parallel
     # deployment run alone): the stack holds ids ``first_expert ..
     # first_expert + num_experts - 1`` of ``routed_experts``; a pair routed
@@ -95,11 +101,12 @@ class ExpertMLPs:
         over the whole expert batch → large MXU matmuls (reference einsum
         'e...h,ehi->e...i', moe_parallel_layers.py:13)."""
         h1 = jnp.einsum("ech,ehti->ecti", x, params["gate_up"])
+        fn = ACTIVATIONS[self.activation]
         if self.glu:
             gate, up = h1[:, :, 0], h1[:, :, 1]
-            act = jax.nn.silu(gate) * up
+            act = fn(gate) * up
         else:
-            act = jax.nn.silu(h1[:, :, 0])
+            act = fn(h1[:, :, 0])
         return jnp.einsum("eci,eio->eco", act, params["down"])
 
     # -- dispatch paths ----------------------------------------------------
@@ -215,10 +222,11 @@ class ExpertMLPs:
         w_gu = jnp.take(params["gate_up"], idx, axis=0)
         w_dn = jnp.take(params["down"], idx, axis=0)
         h1 = jnp.einsum("th,tkhui->tkui", x, w_gu)
+        fn = ACTIVATIONS[self.activation]
         if self.glu:
-            act = jax.nn.silu(h1[:, :, 0]) * h1[:, :, 1]
+            act = fn(h1[:, :, 0]) * h1[:, :, 1]
         else:
-            act = jax.nn.silu(h1[:, :, 0])
+            act = fn(h1[:, :, 0])
         y = jnp.einsum("tki,tkih->tkh", act, w_dn)  # (T,k,H)
         return jnp.sum(y * gates[:, :, None].astype(y.dtype), axis=1)
 

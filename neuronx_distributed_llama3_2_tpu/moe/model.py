@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from neuronx_distributed_llama3_2_tpu.moe.experts import ExpertMLPs
+from neuronx_distributed_llama3_2_tpu.moe.experts import ACTIVATIONS, ExpertMLPs
 from neuronx_distributed_llama3_2_tpu.moe.routing import (
     Router,
     sigmoid_bias_routing,
@@ -66,6 +66,9 @@ class MoEConfig:
     sinkhorn_iterations: int = 3
     routed_scale: float = 1.0
     glu: bool = True
+    # the experts' (and the shared expert's) gate activation: "silu" (SwiGLU)
+    # | "relu" (ReGLU)
+    activation: str = "silu"
     dtype: Any = jnp.bfloat16
     # width of the shared expert(s) every token passes through beside its
     # routed ones (n shared experts of width w are one MLP of width n·w);
@@ -84,6 +87,10 @@ class MoEConfig:
             raise ValueError(
                 f"routing must be topk|sinkhorn|sigmoid_bias, got {self.routing!r}"
             )
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(
+                f"activation must be one of {sorted(ACTIVATIONS)}, got {self.activation!r}"
+            )
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError("need 1 <= top_k <= num_experts")
         if not (self.held >= 1 and 0 <= self.first_held <= self.num_experts - self.held):
@@ -100,7 +107,10 @@ class MoEConfig:
 @dataclasses.dataclass(frozen=True)
 class MoE:
     """The MoE block. ``__call__(params, x (B,S,H))`` →
-    ``(y (B,S,H), router_logits (T,E), expert_idx (T,k))``."""
+    ``(y (B,S,H), router_logits (T,E), expert_idx (T,k))``. A model that
+    routes from another tensor than the one it dispatches (the layer's input,
+    before attention) calls :meth:`route` on that one and hands the result to
+    ``__call__(..., routes=)``."""
 
     config: MoEConfig
     # trace layout depends on global parallel state (shardlint SL002); valid
@@ -127,6 +137,7 @@ class MoE:
             capacity_factor=c.capacity_factor,
             glu=c.glu,
             dtype=c.dtype,
+            activation=c.activation,
             routed_experts=c.num_experts,
             first_expert=c.first_held,
         )
@@ -158,9 +169,9 @@ class MoE:
 
     @jax.named_scope("shared")
     def _shared(self, params: Params, x_flat: jax.Array) -> jax.Array:
-        """The shared expert: a SwiGLU MLP over every token, x (T, H)."""
+        """The shared expert: a gated MLP over every token, x (T, H)."""
         h1 = jnp.einsum("th,hui->tui", x_flat, params["gate_up"])
-        return (jax.nn.silu(h1[:, 0]) * h1[:, 1]) @ params["down"]
+        return (ACTIVATIONS[self.config.activation](h1[:, 0]) * h1[:, 1]) @ params["down"]
 
     @jax.named_scope("router")
     def _route(self, router_params: Params, x_flat: jax.Array):
@@ -188,17 +199,29 @@ class MoE:
             return 1
         return parallel_state.get_expert_model_parallel_size()
 
+    @jax.named_scope("moe")
+    def route(self, params: Params, x: jax.Array):
+        """(router_logits (T,E), gates (T,k), expert_idx (T,k)) of x (B,S,H),
+        as ``__call__`` routes its own input — for a block that dispatches
+        another tensor by them (``routes=``). Scope ``moe/router``."""
+        return self._route(params["router"], x.reshape(-1, x.shape[-1]))
+
     # device-trace scopes (serving/tracing.py SCOPES): moe/router, moe/experts
     @jax.named_scope("moe")
     def __call__(
-        self, params: Params, x: jax.Array
+        self, params: Params, x: jax.Array, routes=None,
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """``routes``: what :meth:`route` returned for the tensor this block
+        is routed by, where that is not ``x``; None routes ``x`` itself."""
         b, s, h = x.shape
         x_flat = x.reshape(b * s, h)  # (T, H) — reference flatten :112
         if self._ep_size() > 1:
+            if routes is not None:
+                raise NotImplementedError("routes made elsewhere under an ep > 1 mesh")
             y, logits, idx = self._ep_forward(params, x_flat)
         else:
-            logits, gates, idx = self._route(params["router"], x_flat)
+            logits, gates, idx = (
+                self._route(params["router"], x_flat) if routes is None else routes)
             y = self._experts()(params["experts"], x_flat, gates, idx)
         if self.config.shared_intermediate_size:
             y = y + self._shared(params["shared"], x_flat)

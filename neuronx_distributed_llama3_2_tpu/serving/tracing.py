@@ -110,6 +110,9 @@ SCOPES = PROGRAM_SCOPES + BLOCK_SCOPES + tuple(
 # that kind — the block's usual children sit below them
 # (``attn/window/kv_read``) and the shared readers book them to ``attn/kv_read``
 # as ever — and ``attn/out_gate`` is the per-head output gate (models/laguna.py);
+# a layer's ``moe/router`` may precede its ``attn`` (models/smallthinker.py
+# routes from the layer's normed input, before attention) with ``moe/experts``
+# after it, so a layer opens ``moe`` twice: the shared readers book both to ``moe``;
 # ``attn/q_latent`` is the query's down-projection and its norm, and ``mhc`` —
 # outside every block: the readers' copy of BLOCK_SCOPES is the benchmark's to
 # change, so they book it to the program's root — is the multi-stream residual
