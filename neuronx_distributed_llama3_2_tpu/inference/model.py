@@ -2380,8 +2380,10 @@ class LagunaDecode(MixtralDecode):
         a (payload, scale) pair each where quantized, read through ``table``
         (b, W) — or, ``table`` None, the dense cache (L, B, S, NKV, D) at
         ``slots``. ``limit`` bounds the rows read (None: all the table's, all
-        the cache's); ``null_rows`` (b, T) sends those rows' writes to the null
-        block. Returns (att (b, T, N, D), kc, vc)."""
+        the cache's), a block at a time through the view of a payload pool in
+        which a block is its ``block_size * NKV`` rows of ``D``; ``null_rows``
+        (b, T) sends those rows' writes to the null block. Returns
+        (att (b, T, N, D), kc, vc)."""
         from neuronx_distributed_llama3_2_tpu.models.laguna import masked_attention, visible
 
         if table is None:
@@ -2407,7 +2409,11 @@ class LagunaDecode(MixtralDecode):
                 return a.reshape((nl * nb * bs,) + a.shape[3:])
 
             def blocks(a):
-                return a.reshape((nl * nb,) + a.shape[2:])
+                # a block as its rows of the minor axis: a payload's bs * NKV
+                # rows of D — whole rows whatever NKV is; asked for
+                # (bs, NKV, D) slices, the compiler re-tiled a pool of under
+                # 8 kv heads a layer a call — and a scale array's bs of NKV
+                return a.reshape((nl * nb, -1) + a.shape[-1:])
 
             with jax.named_scope("kv_write"):
                 block = jnp.take_along_axis(table, (pos_block // bs) % width, axis=1)
@@ -2449,8 +2455,8 @@ class LagunaDecode(MixtralDecode):
                     at = layer * nb + table[:, : -(-limit // bs)]
 
                     def read(a):
-                        got = blocks(a)[at]                          # (b, blocks, bs, ...)
-                        return got.reshape((got.shape[0], -1) + got.shape[3:])[:, :limit]
+                        got = blocks(a)[at]                   # (b, blocks, block rows, ...)
+                        return got.reshape((got.shape[0], -1) + a.shape[3:])[:, :limit]
 
                     if quantized:
                         k = kv_dequantize(read(kc), read(ksc), q.dtype)

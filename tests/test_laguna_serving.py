@@ -319,6 +319,66 @@ def test_an_int8_pool_runs_both_kinds(fam, params):
     clean(srv)
 
 
+def read_by_slices(pool, scale, at, limit, dtype):
+    """The read ``_attend`` had before the flat view: ``(bs, NKV, D)`` slices
+    of the run of blocks, payload and scales alike."""
+    from neuronx_distributed_llama3_2_tpu.quantization.kv_cache import kv_dequantize
+
+    def read(a):
+        got = a.reshape((-1,) + a.shape[2:])[at]
+        return got.reshape((got.shape[0], -1) + got.shape[3:])[:, :limit]
+
+    return read(pool).astype(dtype) if scale is None else kv_dequantize(read(pool), read(scale), dtype)
+
+
+@pytest.mark.parametrize("shape", ["ring_wrapped_twice", "limit_cuts_a_block"])
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("nkv", [4, 8])
+def test_the_block_gathers_flat_view_reads_what_the_slices_read(nkv, kv, shape):
+    """``_attend`` gathers a payload block as its ``bs * NKV`` rows of ``D``
+    (scales through the block view): bit for bit the ``(bs, NKV, D)``-slice
+    read it replaced, under and at a tile's 8 kv heads, a bf16 and an int8
+    pool — one row a lane through a ring wrapped more than twice with an idle
+    lane on the null block (a window layer's ``pdecode``), and a block of rows
+    whose ``limit`` ends inside a block (a full layer's ``psfx``)."""
+    from neuronx_distributed_llama3_2_tpu.models.laguna import masked_attention, visible
+
+    ring = shape == "ring_wrapped_twice"
+    layers, nb, bs, d, lanes = 3, 23, 4, 16, 3
+    window, width, t, limit = (8, 4, 1, None) if ring else (None, 7, 5, 22)
+    model = LagunaDecode(dataclasses.replace(
+        TINY, num_kv_heads=nkv, num_heads=2 * nkv, num_heads_per_layer=(2 * nkv,) * TINY.num_layers,
+        head_dim=d, dtype=jnp.bfloat16))
+    rng = np.random.default_rng(nkv + 10 * ring)
+    pool = model.init_paged_cache(nb, bs, kv_cache_dtype=kv).window
+    fill = lambda a: jnp.asarray(  # noqa: E731
+        rng.integers(-100, 100, a.shape) if a.dtype == jnp.int8 else rng.normal(size=a.shape), a.dtype)
+    pool = jax.tree.map(fill, pool)
+    table = jnp.asarray(rng.permutation(np.arange(1, nb))[: lanes * width].reshape(lanes, width), jnp.int32)
+    # positions: the ring (16 rows) wrapped more than twice, or a block that ends on row 21
+    first = jnp.asarray([37, 41, 0] if ring else [17, 3, 9], jnp.int32)
+    pos = first[:, None] + jnp.arange(t, dtype=jnp.int32)
+    null = jnp.asarray([[False], [False], [True]]) if ring else None
+    if ring:
+        table = table.at[2].set(0)                  # an idle lane: its table is the null block's
+    q, k, v = (jnp.asarray(rng.normal(size=(lanes, t, n, d)), jnp.bfloat16) for n in (2 * nkv, nkv, nkv))
+    kc, vc = ((pool.k, pool.k_scale), (pool.v, pool.v_scale)) if kv else (pool.k, pool.v)
+    layer = jnp.int32(1)
+    att, kc, vc = jax.jit(lambda *a: model._attend(
+        *a, layer, pos, None, window=window, context_encode=False, table=table, limit=limit,
+        null_rows=null))(q, k, v, kc, vc)
+    rows = width * bs if limit is None else limit
+    at = layer * nb + table[:, : -(-rows // bs)]
+    (kp, ks), (vp, vs) = (kc, vc) if kv else ((kc, None), (vc, None))
+    k_back, v_back = (read_by_slices(a, sc, at, rows, q.dtype) for a, sc in ((kp, ks), (vp, vs)))
+    assert k_back.shape == (lanes, rows, nkv, d)
+    r = jnp.arange(rows, dtype=jnp.int32)
+    k_pos = pos[..., None] - (pos[..., None] - r) % (width * bs)
+    want = masked_attention(q, k_back, v_back, visible(pos, k_pos, window))
+    np.testing.assert_array_equal(np.asarray(att, np.float32), np.asarray(want, np.float32))
+    assert np.isfinite(np.asarray(att, np.float32)).all()
+
+
 def test_tp2_on_two_virtual_devices_matches_tp1(fam, params):
     """tp = 1 gives the reference's tokens (the tests above): so must tp = 2."""
     prompts = prompts_of(np.random.default_rng(9), (45, 9, 70))

@@ -55,6 +55,10 @@ from neuronx_distributed_llama3_2_tpu.models.llama import params_to_hf
 from neuronx_distributed_llama3_2_tpu.models.mixtral import params_to_hf_mixtral
 from neuronx_distributed_llama3_2_tpu.models.olmoe import OLMOE_CONFIGS, OlmoeForCausalLM
 from neuronx_distributed_llama3_2_tpu.models.sarvam import SARVAM_CONFIGS, SarvamForCausalLM
+from neuronx_distributed_llama3_2_tpu.models.smallthinker import (
+    SMALLTHINKER_CONFIGS,
+    SmallThinkerForCausalLM,
+)
 from neuronx_distributed_llama3_2_tpu.models.xing import XING_CONFIGS, XingForCausalLM
 from neuronx_distributed_llama3_2_tpu.quantization import (
     QuantizationConfig,
@@ -648,6 +652,96 @@ def test_paged_program_updates_the_donated_pool_in_place(v5e, program, kv):
     assert memory.alias_size_in_bytes == pool_bytes
     # as the scan's xs and ys the temporaries held a whole pool and more
     assert memory.temp_size_in_bytes < 0.2 * pool_bytes, (memory.temp_size_in_bytes, pool_bytes)
+
+
+# ---------------------------------------------------------------------------
+# compiled for a described v5e: a mixed stack's block gather re-tiles neither
+# pool, and the gather of (bs, NKV, D) slices it replaced did at 4 kv heads
+# ---------------------------------------------------------------------------
+
+def smallthinker_stack():
+    from neuronx_distributed_llama3_2_tpu.models.smallthinker import _published_layout
+
+    # f w w w: one period at the published attention widths (28 query heads
+    # over 4 kv heads of 128, window 4,096)
+    return published(SMALLTHINKER_CONFIGS, "smallthinker-21b-a3b", num_layers=4, num_experts=8,
+                     sliding_window_layout=_published_layout(4), rope_layout=_published_layout(4))
+
+
+# family -> (config, training model, blocks a lane's ring takes at a chunk of 512)
+MIXED_STACKS = {
+    "laguna": lambda: (laguna_stack(), LagunaForCausalLM, 64),
+    "smallthinker": lambda: (smallthinker_stack(), SmallThinkerForCausalLM, 288),
+}
+MIXED_BLOCKS = 4096       # the full kind's: 67 MB a layer at 4 kv heads, nothing VMEM hides
+
+
+def pool_dims(*pools):
+    """The spellings of a pool's shape a bulk move of it could have: whole, a
+    layer of it with and without its leading 1, the run of blocks a gather
+    indexes."""
+    return {",".join(map(str, s)) for k in pools
+            for s in (k, (1,) + k[1:], k[1:], (k[0] * k[1],) + k[2:])}
+
+
+@pytest.mark.parametrize("nkv,found", [(4, True), (8, False)])
+def test_a_gather_of_block_slices_re_tiles_a_pool_of_under_eight_kv_heads(v5e, nkv, found):
+    """The control: the read ``LagunaDecode._attend`` had. A bf16 pool of 4 kv
+    heads rests in half-tiles (``T(4,128)(2,1)``); asked for ``(bs, NKV, D)``
+    slices in the attention dot's layout behind the row scatter, the compiler
+    re-tiles the gather's operand — the whole pool — and the search finds that
+    copy. At 8 kv heads (Laguna's) the tile is full and there never was one."""
+    from jax.sharding import SingleDeviceSharding
+
+    from neuronx_distributed_llama3_2_tpu.models.laguna import masked_attention
+
+    layers, blocks, bs, d, lanes, width = 3, 1 + 16 * 288, 16, 128, 16, 288
+    on = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=SingleDeviceSharding(v5e))
+    shape = (layers, blocks, bs, nkv, d)
+
+    def fn(kc, vc, table, q, k, v, pos):
+        layer = jnp.int32(1)
+        row = (layer * blocks + jnp.take_along_axis(table, (pos // bs) % width, axis=1)) * bs + pos % bs
+
+        def write(a, fresh):
+            return a.reshape((-1,) + a.shape[3:]).at[row].set(fresh).reshape(a.shape)
+
+        def read(a):
+            got = a.reshape((-1,) + a.shape[2:])[layer * blocks + table]
+            return got.reshape((lanes, -1) + got.shape[3:])
+
+        kc, vc = write(kc, k), write(vc, v)
+        return masked_attention(q, read(kc), read(vc), jnp.ones((lanes, 1, width * bs), bool)), kc, vc
+
+    text = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        on(shape), on(shape), on((lanes, width), jnp.int32), on((lanes, 1, 7 * nkv, d)),
+        on((lanes, 1, nkv, d)), on((lanes, 1, nkv, d)), on((lanes, 1), jnp.int32)).compile().as_text()
+    moves = pool_sized_moves(text, pool_dims(shape))
+    assert bool(moves) == found, moves
+
+
+@pytest.mark.parametrize("program", ["pdecode", "psfx"])
+@pytest.mark.parametrize("family", sorted(MIXED_STACKS))
+def test_a_mixed_stacks_block_gather_re_tiles_neither_pool(v5e, family, program, monkeypatch):
+    """SmallThinker (4 kv heads: the case that was not) and Laguna (8: the one
+    that always was) at their published attention widths, 16 lanes over the
+    ring a lane the engine would lay out: neither ``pdecode`` nor a 512-row
+    ``psfx`` holds a bulk copy, slice or update of the window pool's shape, the
+    full pool's, or a layer of either."""
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+
+    monkeypatch.setenv(KERNEL_MODE_ENV, "compiled")
+    cfg, train, ring = MIXED_STACKS[family]()
+    model = decode_model_for(cfg)
+    assert -(-(cfg.sliding_window - 1 + 512) // 16) == ring
+    compiled = compile_paged(v5e, program, "rested", cfg=cfg, train=train, blocks=MIXED_BLOCKS)
+    pool = jax.eval_shape(lambda: model.init_paged_cache(MIXED_BLOCKS, 16, window_blocks=1 + 16 * ring))
+    assert pool.window.k.shape[1:] == (1 + 16 * ring, 16, cfg.num_kv_heads, 128)
+    moves = pool_sized_moves(compiled.as_text(), pool_dims(pool.full.k.shape, pool.window.k.shape))
+    assert not moves, moves
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
 # ---------------------------------------------------------------------------
