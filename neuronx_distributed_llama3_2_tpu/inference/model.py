@@ -409,17 +409,18 @@ class LlamaDecode:
         traced engine's ``setup`` record says it a kind. What the program can
         see decides, no option: where :meth:`_walks`, the block walk; else
         what ``use_paged_kernel`` asked for (:meth:`paged_dispatch_path`)."""
-        return "kernel" if self._walks(kind.rows, quantized) else self.paged_dispatch_path(1)
+        return "kernel" if self._walks(quantized) else self.paged_dispatch_path(1)
 
-    def _walks(self, window: Optional[int], quantized: bool) -> bool:
+    def _walks(self, quantized: bool) -> bool:
         """Whether one fresh row a lane of a ``(k, v)`` pool is attended by
         :func:`..kernels.paged_attention_pallas.paged_decode_walk` over the
-        lane's live blocks: a kind with no lower bound over an unquantized
-        pool whose rows the walk takes (``walk_fits``), where
-        :func:`_kernels_on_one_device`. A window's ring, a block of several
-        rows (``psfx``, a verify block, a tree), an int8 / fp8 pool, a mesh and
-        the ``"reference"`` mode keep the gather, the walk's plain twin."""
-        if window is not None or quantized or not _kernels_on_one_device():
+        blocks that hold the rows the lane sees — all its live blocks, or a
+        window's of its ring: an unquantized pool whose rows the walk takes
+        (``walk_fits``), where :func:`_kernels_on_one_device`. A block of
+        several rows (``psfx``, a verify block, a tree), an int8 / fp8 pool, a
+        mesh and the ``"reference"`` mode keep the gather, the walk's plain
+        twin."""
+        if quantized or not _kernels_on_one_device():
             return False
         from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
             walk_fits,
@@ -902,7 +903,7 @@ class LlamaDecode:
                 kv_limit if kv_limit is not None
                 else block_tables.shape[1] * bs
             )
-            if q.shape[1] == 1 and tree is None and self._walks(None, quantized):
+            if q.shape[1] == 1 and tree is None and self._walks(quantized):
                 # one row a lane: the lane's live blocks are read where they
                 # lie, nothing gathered — whatever use_paged_kernel says (the
                 # static-grid kernel below reads the rung, the walk what is
@@ -2191,7 +2192,7 @@ class LagunaDecode(MixtralDecode):
     A full layer reads and writes the block pool through the lane's
     ``block_tables`` like every other family, bounded by ``kv_limit``. A window
     layer reads and writes the window pool through ``window_tables`` — the
-    lane's ring, gathered whole (never ``kv_limit`` rows) — and where no
+    lane's ring (never ``kv_limit`` rows of it) — and where no
     ``window_tables`` is given, through ``block_tables`` too. **One indexing
     rule serves both**: the row of position ``p`` is block ``(p // block_size)
     mod table width`` of the table, row ``p mod block_size``; a table as wide
@@ -2213,9 +2214,11 @@ class LagunaDecode(MixtralDecode):
     a layer shape, run in the published order (``models.laguna.layer_runs``).
     The dense slot cache (``InferenceEngine.generate``) keeps every layer at
     full length and the window is a mask only. ``paged_flash_decode`` has no
-    lower bound and is never eligible; a full layer's read of one row a lane
-    is the block walk where :meth:`decode_read` says ``"kernel"``. Tree
-    (speculative) blocks are refused."""
+    lower bound and is never eligible; a layer's read of one row a lane is
+    the block walk where :meth:`decode_read` says ``"kernel"`` — a full
+    layer's over the lane's live blocks, a window layer's over the blocks of
+    its ring that hold the window — and everywhere else the ring is gathered
+    whole and the mask does the rest. Tree (speculative) blocks are refused."""
 
     def _model(self):
         from neuronx_distributed_llama3_2_tpu.models.laguna import LagunaForCausalLM
@@ -2264,14 +2267,15 @@ class LagunaDecode(MixtralDecode):
         return False
 
     def decode_read(self, kind: CacheKind, quantized: bool = False) -> str:
-        """``"kernel"`` for the kind with no lower bound over an unquantized
-        pool, where :func:`_kernels_on_one_device`: one row a lane is then
-        attended by :func:`..kernels.paged_attention_pallas.paged_decode_walk`
-        over the lane's live blocks. What the program can see decides, no
-        option: a window layer's ring, a block of several rows (``psfx``), an
-        int8 pool, a mesh and the ``"reference"`` mode keep the block-wise
-        gather and ``masked_attention``, the walk's plain twin."""
-        return "kernel" if self._walks(kind.rows, quantized) else "gather"
+        """``"kernel"`` for either kind over an unquantized pool, where
+        :func:`_kernels_on_one_device`: one row a lane is then attended by
+        :func:`..kernels.paged_attention_pallas.paged_decode_walk` — a full
+        layer's over the lane's live blocks, a window layer's over the blocks
+        of its ring that hold the window. What the program can see decides, no
+        option: a block of several rows (``psfx``), an int8 pool, a mesh and
+        the ``"reference"`` mode keep the block-wise gather and
+        ``masked_attention``, the walk's plain twin."""
+        return "kernel" if self._walks(quantized) else "gather"
 
     # -- forward ----------------------------------------------------------
 
@@ -2381,8 +2385,10 @@ class LagunaDecode(MixtralDecode):
         (b, W) — or, ``table`` None, the dense cache (L, B, S, NKV, D) at
         ``slots``. ``limit`` bounds the rows read (None: all the table's, all
         the cache's), a block at a time through the view of a payload pool in
-        which a block is its ``block_size * NKV`` rows of ``D``; ``null_rows``
-        (b, T) sends those rows' writes to the null block. Returns
+        which a block is its ``block_size * NKV`` rows of ``D`` — or, one row
+        a lane where :meth:`_walks`, by the block walk over the blocks that
+        hold the rows the lane sees; ``null_rows`` (b, T) sends those rows'
+        writes to the null block (and tells the walk its null lanes). Returns
         (att (b, T, N, D), kc, vc)."""
         from neuronx_distributed_llama3_2_tpu.models.laguna import masked_attention, visible
 
@@ -2397,9 +2403,7 @@ class LagunaDecode(MixtralDecode):
                     ring_rows = kc.shape[2]
         else:
             quantized = isinstance(kc, tuple)
-            walks = (
-                q.shape[1] == 1 and not context_encode
-                and self._walks(window, quantized))
+            walks = q.shape[1] == 1 and not context_encode and self._walks(quantized)
             (kc, ksc), (vc, vsc) = (kc, vc) if quantized else ((kc, None), (vc, None))
             nl, nb, bs = kc.shape[:3]
             width = table.shape[1]
@@ -2438,15 +2442,18 @@ class LagunaDecode(MixtralDecode):
                 kc = rows(kc).at[at].set(kq).reshape(kc.shape)
                 vc = rows(vc).at[at].set(vq).reshape(vc.shape)
             if walks:
-                # one row a lane over a pool with no lower bound: the lane's
-                # live blocks are read where they lie, nothing gathered
+                # one row a lane: the blocks that hold the rows it sees — the
+                # lane's live ones, or the window's of its ring — are read
+                # where they lie, nothing gathered
                 from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
                     paged_decode_walk,
                 )
 
                 with jax.named_scope("sdpa"):
                     att = paged_decode_walk(
-                        q[:, 0], kc, vc, table, pos_block[:, 0], layer, kv_limit=limit)
+                        q[:, 0], kc, vc, table, pos_block[:, 0], layer, kv_limit=limit,
+                        window=window,
+                        null_lanes=None if null_rows is None else null_rows[:, 0])
                 return att[:, None], kc, vc
             if not context_encode:
                 with jax.named_scope("kv_read"):
@@ -2486,7 +2493,7 @@ class SmallThinkerDecode(LagunaDecode):
     from its normed input, before the attention block** (``moe/router`` ahead
     of ``attn`` in a layer's trace), and the post-attention state is
     dispatched by those routes. The window (4,096 as published) is 8 x
-    Laguna's: the lane's ring is the larger read of a decode step."""
+    Laguna's: a decode step walks up to 257 blocks of a lane's 288-block ring."""
 
     def _model(self):
         from neuronx_distributed_llama3_2_tpu.models.smallthinker import (

@@ -53,7 +53,8 @@ Structure (flash-decoding, Dao et al. 2023 — split-K for a single query row):
 fresh row a lane of an unquantized pool; ``LlamaDecode._attend_paged`` and
 ``LagunaDecode._attend`` call it where ``LlamaDecode._walks`` says so): no
 static grid over the rung — a grid step is a lane, and a loop whose trip count
-is the lane's own copies its **live** blocks a group at a time from the pool
+is the lane's own copies its **live** blocks — under a ``window``, the blocks
+of its ring that hold the window — a group at a time from the pool
 in HBM (``memory_space=ANY``, ``make_async_copy``, two VMEM buffers) and folds
 each group into the same online softmax. It takes the pool as ``(blocks · bs ·
 NKV, D)``, which on the chip is the bytes as they lie where D is 128
@@ -541,6 +542,17 @@ def walk_group(bs: int, nkv: int) -> int:
     return max(1, WALK_SPAN // (bs * nkv))
 
 
+def window_walk_group(blocks: int, bs: int, nkv: int) -> int:
+    """Blocks a loop trip of a walk of at most ``blocks`` blocks takes where a
+    window bounds it: the walk cut into the nearest whole number of trips of
+    :func:`walk_group` blocks, evenly — 257 blocks of 16 rows at 4 kv heads go
+    65 a trip in 4 trips, 33 at 8 go in one — where ``walk_group`` itself
+    would spend a whole score tile on the odd block (64 × 4 + 1, 32 + 1)."""
+    span = walk_group(bs, nkv)
+    trips = max(1, (blocks + span // 2) // span)
+    return _ceil_div(blocks, trips)
+
+
 def walk_fits(head_dim: int) -> bool:
     """Whether :func:`paged_decode_walk` takes a pool of ``head_dim`` columns.
     Mosaic wants a pool row to be one register's 128 lanes: it refuses to slice
@@ -553,7 +565,7 @@ def walk_fits(head_dim: int) -> bool:
 def _walk_kernel(
     tbl_ref,    # scalar prefetch: (b, nblk) int32 pool blocks, the layer's offset folded in
     live_ref,   # scalar prefetch: (b,) int32 blocks each lane walks, >= 1
-    pos_ref,    # scalar prefetch: (b,) int32 the query's row
+    pos_ref,    # scalar prefetch: (b,) int32 the query's row, counted from the walk's first block
     q_ref,      # (N, D) this lane's query heads
     k_hbm,      # (pool blocks · bs · NKV, D) the pool where it lies (HBM)
     v_hbm,
@@ -563,13 +575,18 @@ def _walk_kernel(
     sem,        # DMA semaphores (k | v, buffer)
     slot_ref,   # SMEM (1,): the buffer the group being scored lies in
     m_scr, l_scr, acc_scr,
-    *, bs: int, nkv: int, group: int, sm_scale: float,
+    *, bs: int, nkv: int, group: int, sm_scale: float, first_ref=None, lo_ref=None,
 ):
     """One lane a grid step, its live blocks a group a loop trip. A buffer row
     is one (row, kv head) pair, ``row · NKV + head`` — the pool's own order —
     so every query head is scored against every pair by one dot and keeps its
     own kv head's columns under the mask; ``p · v`` then sums a head's own
-    pairs alone and the (N, D) accumulator is the output, no diagonal to take."""
+    pairs alone and the (N, D) accumulator is the output, no diagonal to take.
+    Under a window (:func:`_bounded_walk_kernel`) two more (b,) int32: block
+    ``j`` of lane ``i``'s walk is table column ``first_ref[i] + j``, less the
+    table's width where that passes it — the table is a ring — and
+    ``lo_ref[i]`` is the first row of the walk the query sees, inside the
+    walk's first block."""
     i = pl.program_id(0)
     lanes = pl.num_programs(0)
     pairs = bs * nkv                  # buffer rows a block
@@ -582,7 +599,14 @@ def _walk_kernel(
         first = grp * group
 
         def one(j, carry):
-            at = pl.multiple_of(tbl_ref[lane, first + j] * pairs, pairs)
+            column = first + j
+            if first_ref is not None:
+                # one compare and one select a block on the scalar unit: the
+                # table rotated ahead of the call was a gather a layer a step
+                # that cost half as much again as the walk (PERF.md, PR 62)
+                column += first_ref[lane]
+                column = jnp.where(column >= tbl_ref.shape[1], column - tbl_ref.shape[1], column)
+            at = pl.multiple_of(tbl_ref[lane, column] * pairs, pairs)
             to = pl.multiple_of(j * pairs, pairs)
             for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
                 copy = pltpu.make_async_copy(
@@ -636,10 +660,14 @@ def _walk_kernel(
         sc = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale             # (N, span)
-        sc = jnp.where(col < (seen - grp * group * bs) * nkv, sc, NEG_INF)
+        sees = col < (seen - grp * group * bs) * nkv
+        if lo_ref is not None:
+            sees &= col >= (lo_ref[i] - grp * group * bs) * nkv
+        sc = jnp.where(sees, sc, NEG_INF)
         m_prev = m_scr[...]
-        # row 0 of the walk is visible to every head, so m is finite from the
-        # first group on and a masked column's p is exp(-1e30 - m) == 0
+        # a row of the walk's first block (row 0, or lo) is visible to every
+        # head, so m is finite from the first group on and a masked column's
+        # p is exp(-1e30 - m) == 0
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(sc - m_new)
@@ -655,6 +683,13 @@ def _walk_kernel(
     o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
+def _bounded_walk_kernel(tbl_ref, live_ref, pos_ref, first_ref, lo_ref, *refs, **static):
+    """:func:`_walk_kernel` under a window: two more scalar-prefetch operands,
+    the walk's first column and the lower bound, ahead of the operands it has
+    without one."""
+    _walk_kernel(tbl_ref, live_ref, pos_ref, *refs, first_ref=first_ref, lo_ref=lo_ref, **static)
+
+
 def paged_decode_walk(
     q: jax.Array,             # (b, N, D) one query row a lane
     k_pool: jax.Array,        # (L, num_blocks, bs, NKV, D): one kind's whole pool
@@ -665,25 +700,41 @@ def paged_decode_walk(
     *,
     kv_limit: int | None = None,
     group: int | None = None,
+    window: int | None = None,
+    null_lanes: jax.Array | None = None,
 ) -> jax.Array:
-    """The decode read of a layer with no lower bound: softmax(q · k / √D over
-    rows ``<= positions``) · v, lane by lane over the lane's **live** blocks
-    only, read where they lie. Returns q's shape in q.dtype.
+    """The decode read of a layer: softmax(q · k / √D over the rows a lane's
+    query sees) · v, lane by lane over the blocks that hold those rows and no
+    others, read where they lie. Returns q's shape in q.dtype.
 
-    Lane ``i`` walks ``positions[i] // bs + 1`` blocks (at most ``kv_limit``
-    rows' worth), block ``j`` of them at ``block_tables[i, j] + layer ·
-    num_blocks`` of the pool taken as one run of ``L · num_blocks`` blocks. A
-    lane whose first block is the null block — idle, or mid-prefill beside
-    the decode batch — walks that one block whatever position it carries; its
-    output is numbers nobody reads. The pool goes in as ``(blocks · bs · NKV,
-    D)``: under the TPU's tiling of the last two dimensions that is the same
-    bytes (the optimized HLO holds a bitcast, no copy), and a block is ``bs ·
-    NKV`` whole rows of it, contiguous. Blocks are copied ``group`` at a time
-    (None: :func:`walk_group` of the pool's shape) into one of two VMEM
-    buffers, the next group in flight while this one is folded into a float32
-    online softmax; p is cast to q's dtype for ``p · v`` as
-    ``models.laguna.masked_attention`` and ``LlamaDecode._cache_attention``
-    do, which are this kernel's plain twins over gathered rows.
+    A query at ``positions[i]`` sees the rows at or before it — with a
+    ``window`` (static), the last ``window`` of them, itself included. The row
+    of position ``p`` lies in block ``(p // bs) mod W`` of the lane's table,
+    row ``p mod bs``: a table as wide as the context never wraps, a ring does.
+    Lane ``i`` walks from the block of the first row it sees (block 0 without a
+    window) to the block of its own row — ``positions[i] // bs + 1`` blocks
+    without a window, at most ``(window − 1) // bs + 2`` with one, and never
+    more than ``kv_limit`` rows' worth or the table's width (a ring holds at
+    least ``window − 1 + bs`` rows) — block ``j`` of them at the table's entry
+    ``+ layer · num_blocks`` of the pool taken as one run of ``L · num_blocks``
+    blocks. Under a window the kernel takes the position counted from the
+    walk's first block and two more scalar operands a lane — the table column
+    of that block (the copy loop wraps from there) and the first row seen,
+    inside it; **with no window the call and its operands are what they were
+    before the walk knew of one**. A null lane — ``null_lanes`` (b,) where
+    given (a lane with a ring of its own: a ring's table is never 0), else one
+    whose first block is the null block: idle, or mid-prefill beside the
+    decode batch — walks the null block alone whatever position it carries;
+    its output is numbers nobody reads. The pool goes in as ``(blocks · bs · NKV, D)``: under the
+    TPU's tiling of the last two dimensions that is the same bytes (the
+    optimized HLO holds a bitcast, no copy), and a block is ``bs · NKV`` whole
+    rows of it, contiguous. Blocks are copied ``group`` at a time (None:
+    :func:`walk_group` of the pool's shape, :func:`window_walk_group` of the
+    window's blocks) into one of two VMEM buffers, the next group in flight
+    while this one is folded into a float32 online softmax; p is cast to q's
+    dtype for ``p · v`` as ``models.laguna.masked_attention`` and
+    ``LlamaDecode._cache_attention`` do, which are this kernel's plain twins
+    over gathered rows.
     """
     b, n, d = q.shape
     nl, nb, bs, nkv, _ = k_pool.shape
@@ -691,14 +742,27 @@ def paged_decode_walk(
         raise ValueError(f"q heads ({n}) must be a multiple of kv heads ({nkv})")
     width = block_tables.shape[1]
     nblk = width if kv_limit is None else min(width, _ceil_div(kv_limit, bs))
-    live = jnp.where(
-        block_tables[:, 0] == 0, 1, jnp.clip(positions // bs + 1, 1, nblk))
-    tables = block_tables[:, :nblk] + layer * nb
-    if group is None:
-        group = walk_group(bs, nkv)
+    null = block_tables[:, 0] == 0 if null_lanes is None else null_lanes
+    if window is None:
+        live = jnp.where(null, 1, jnp.clip(positions // bs + 1, 1, nblk))
+        tables = block_tables[:, :nblk] + layer * nb
+        scalars = (tables, live, positions)
+        kernel = _walk_kernel
+        if group is None:
+            group = walk_group(bs, nkv)
+    else:
+        nblk = min(nblk, (window - 1) // bs + 2)
+        lo = jnp.where(null, 0, jnp.maximum(positions - (window - 1), 0))
+        first = lo // bs
+        live = jnp.where(null, 1, jnp.clip(positions // bs - first + 1, 1, nblk))
+        tables = jnp.where(null[:, None], 0, block_tables) + layer * nb
+        scalars = (tables, live, positions - first * bs, first % width, lo - first * bs)
+        kernel = _bounded_walk_kernel
+        if group is None:
+            group = window_walk_group(nblk, bs, nkv)
     span = group * bs * nkv
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(b,),
         in_specs=[
             pl.BlockSpec((None, n, d), lambda i, *_: (i, 0, 0)),
@@ -718,8 +782,7 @@ def paged_decode_walk(
     )
     buffers = 2 * 2 * span * d * k_pool.dtype.itemsize
     return pl.pallas_call(
-        functools.partial(
-            _walk_kernel, bs=bs, nkv=nkv, group=group, sm_scale=d ** -0.5),
+        functools.partial(kernel, bs=bs, nkv=nkv, group=group, sm_scale=d ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         # the buffers, and the (N, span) float32 scores and what is made of
@@ -731,7 +794,7 @@ def paged_decode_walk(
         interpret=pallas_interpret(),
         name="paged_decode_walk",
     )(
-        tables.astype(jnp.int32), live.astype(jnp.int32), positions.astype(jnp.int32),
+        *(a.astype(jnp.int32) for a in scalars),
         q, k_pool.reshape(nl * nb * bs * nkv, d), v_pool.reshape(nl * nb * bs * nkv, d),
     )
 

@@ -1233,8 +1233,12 @@ class PagedServingEngine:
         attend over, this step's included — or, where the cache is a state a
         lane, the live lanes: the states the step has to move. Where a kind of
         layer sees only its last rows, ``window_rows`` beside it: the rows
-        those layers attend over, min(context, window) a live lane. Where a
-        kind is a state, ``state_lanes`` — the live lanes — and
+        those layers attend over, min(context, window) a live lane, and
+        ``window_rows_passed`` — the rows the dispatched program reads of that
+        kind a layer: where its ``decode_read`` is ``"kernel"``, the blocks the
+        walk takes (the window's first row's to the lane's own) in rows, a live
+        lane, and one block a lane that is not; else every lane's whole ring.
+        Where a kind is a state, ``state_lanes`` — the live lanes — and
         ``state_slots_passed`` — the slots the dispatched program reads and
         writes: the live lanes' where it holds the state kernel, else every
         slot of the kind's pool, a live lane's or not. Where a kind's layers choose the blocks
@@ -1259,7 +1263,14 @@ class PagedServingEngine:
                 rows["sparse_rows_read"] = sum(read for read, _ in selected)
                 rows["sparse_blocks_forced"] = sum(forced for _, forced in selected)
         elif self._lane_kind is not None:
-            rows["window_rows"] = sum(min(n, self._lane_kind.rows) for n in contexts)
+            window, bs = self._lane_kind.rows, self.paged.block_size
+            rows["window_rows"] = sum(min(n, window) for n in contexts)
+            if self.model.decode_read(self._lane_kind, self._kv_quantized) == "kernel":
+                walked = sum((n - 1) // bs - max(0, n - window) // bs + 1 for n in contexts)
+                rows["window_rows_passed"] = bs * (
+                    walked + self.engine.max_batch - len(decode_lanes))
+            else:
+                rows["window_rows_passed"] = self.engine.max_batch * self._lane_blocks * bs
         return rows
 
     def _kv_bucket(self, needed: int) -> int:

@@ -74,17 +74,25 @@ Three measurements for the gather-free paged decode path (docs/serving.md):
    a log-uniform prompt of 512-8,192 and up to 256 rows of reply over the
    8,704-row rung; ``mixtral-chat-steady`` — 16 lanes of 32 over 8 on the
    2,304-row rung, 2 live and 16 live; ``olmoe-rag-batch`` — 8 lanes of 16
-   over 16 on the 2,176-row rung —
+   over 16 on the 2,176-row rung; and the two window kinds, whose table is a
+   ring a lane (never the null block: the null lanes are said beside it) and
+   whose contexts pass it: ``smallthinker-longchat-steady`` — 32 lanes, 10
+   live, 28 query over 4 kv heads, a window of 4,096 in a 288-block ring,
+   prompts of 2,048-12,288 and up to 512 rows of reply;
+   ``laguna-mixedlen-batch``'s window kind — 32 lanes of 64 over 8, a window
+   of 512 in a 64-block ring —
    ``kernels.paged_attention_pallas.paged_decode_walk`` at each of
-   ``--walk-groups`` blocks a loop trip beside the gather of the whole rung
-   and the attention over it that it replaced (``LagunaDecode._attend``'s
-   block-wise gather and ``masked_attention``; ``LlamaDecode._attend_paged``'s
-   gather of rows and ``_cache_attention``). Prints ms a layer, GB/s of
-   *live* bytes (the K and V blocks the live lanes' contexts reach) and their
-   share of the chip's bandwidth peak for each, and the group the pool's
-   shape derives (``walk_group``); the gate is the kernel's distance from the
-   gather. ``PERF.md`` section 6 (PR 43, PR 56) holds the sweeps this was
-   written for.
+   ``--walk-groups`` blocks a loop trip (a window kind: its blocks over one
+   to six trips, and the group it would take with no window) beside the
+   gather of the whole rung, or ring, and the attention over it that it
+   replaced (``LagunaDecode._attend``'s block-wise gather and
+   ``masked_attention``; ``LlamaDecode._attend_paged``'s gather of rows and
+   ``_cache_attention``). Prints ms a layer, GB/s of *live* bytes (the K and V
+   blocks the live lanes' contexts, or windows, reach) and their share of the
+   chip's bandwidth peak for each, and the group the pool's shape — or the
+   window's blocks — derives (``walk_group``, ``window_walk_group``); the gate
+   is the kernel's distance from the gather. ``PERF.md`` section 6 (PR 43,
+   PR 56, PR 62) holds the sweeps this was written for.
 
 10. **The latent walk alone** (``--latent``, and nothing else runs): one
    latent layer's absorbed decode read at the two latent cells' shapes —
@@ -163,6 +171,8 @@ def build_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--walk-groups", default=None,
                     help="blocks a loop trip of the walk, comma-separated "
                     "(8,16,32,64; with --latent powers of two: 16,32,64,128,256)")
+    ap.add_argument("--walk-cells", default="",
+                    help="with --walk: only the shapes whose name holds this")
     ap.add_argument("--latent", action="store_true",
                     help="time the latent decode walk against the gather + "
                     "absorbed scores it replaces, at xing-longdoc-batch's and "
@@ -1000,13 +1010,26 @@ def _time_a_layer(read, operands, layers, live_bytes, args):
 # gather the walk replaced). ``laguna``: its block-wise gather and
 # ``masked_attention``; ``llama``: ``LlamaDecode._attend_paged``'s gather of
 # rows and ``_cache_attention``. The pools are the cells' own a layer; the
-# layers are as many as make one program's time a device time, not a dispatch
+# layers are as many as make one program's time a device time, not a dispatch.
+# A last entry is a window: the "rung" is then the lane's ring (its table),
+# the pool a null block and a ring a lane, and a context is not cut to it
 WALK_SHAPES = {
-    "laguna-mixedlen-batch": (32, 32, 48, 8, 2, 17920, 8704, 512, 8192, 256, "laguna"),
-    "mixtral-chat-steady-2-live": (16, 2, 32, 8, 12, 3072, 2304, 64, 2048, 128, "llama"),
-    "mixtral-chat-steady-16-live": (16, 16, 32, 8, 12, 3072, 2304, 64, 2048, 128, "llama"),
-    "olmoe-rag-batch": (8, 8, 16, 16, 16, 1152, 2176, 512, 2048, 32, "llama"),
+    "laguna-mixedlen-batch": (32, 32, 48, 8, 2, 17920, 8704, 512, 8192, 256, "laguna", None),
+    "mixtral-chat-steady-2-live": (16, 2, 32, 8, 12, 3072, 2304, 64, 2048, 128, "llama", None),
+    "mixtral-chat-steady-16-live": (16, 16, 32, 8, 12, 3072, 2304, 64, 2048, 128, "llama", None),
+    "olmoe-rag-batch": (8, 8, 16, 16, 16, 1152, 2176, 512, 2048, 32, "llama", None),
+    "smallthinker-longchat-steady-window": (
+        32, 10, 28, 4, 3, 1 + 32 * 288, 288 * 16, 2048, 12288, 512, "laguna", 4096),
+    "laguna-mixedlen-batch-window": (32, 32, 64, 8, 3, 1 + 32 * 64, 64 * 16, 512, 8192, 256, "laguna", 512),
 }
+
+
+def window_sweep_groups(window: int, bs: int, nkv: int) -> list:
+    """The groups a window kind's walk is timed (and compiled,
+    ``scripts/tpu_aot_compile.py``) at: its blocks over one to six even trips,
+    and the group it would take with no window."""
+    reach = (window - 1) // bs + 2
+    return sorted({-(-reach // trips) for trips in range(1, 7)} | {max(1, 4096 // (bs * nkv))})
 
 
 def _walk_sweep(args) -> dict:
@@ -1020,6 +1043,7 @@ def _walk_sweep(args) -> dict:
     from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
         paged_decode_walk,
         walk_group,
+        window_walk_group,
     )
     from neuronx_distributed_llama3_2_tpu.models.laguna import (
         masked_attention,
@@ -1029,8 +1053,9 @@ def _walk_sweep(args) -> dict:
 
     if args.smoke:
         bs, d, dtype = 4, 128, jnp.float32
-        shapes = {"smoke-laguna": (4, 4, 4, 2, 2, 40, 32, 6, 24, 8, "laguna"),
-                  "smoke-llama": (4, 2, 4, 4, 2, 40, 32, 6, 24, 8, "llama")}
+        shapes = {"smoke-laguna": (4, 4, 4, 2, 2, 40, 32, 6, 24, 8, "laguna", None),
+                  "smoke-llama": (4, 2, 4, 4, 2, 40, 32, 6, 24, 8, "llama", None),
+                  "smoke-window": (4, 3, 4, 2, 2, 1 + 4 * 8, 32, 6, 90, 8, "laguna", 22)}
     else:
         bs, d, dtype = 16, 128, jnp.bfloat16
         shapes = WALK_SHAPES
@@ -1038,27 +1063,40 @@ def _walk_sweep(args) -> dict:
     groups = [int(x) for x in args.walk_groups.split(",") if x]
     record = {"walk": True, "seed": args.seed, "platform": jax.default_backend(), "cells": {}}
     worst = 0.0
-    for cell, (lanes, live, n, nkv, layers, nb, rung, low, high, reply, twin) in shapes.items():
+    for cell, (lanes, live, n, nkv, layers, nb, rung, low, high, reply, twin, window) in shapes.items():
+        if args.walk_cells not in cell:
+            continue
         rng = np.random.default_rng(args.seed)
         width = rung // bs
-        contexts = np.minimum(
-            np.exp(rng.uniform(np.log(low), np.log(high), lanes)).astype(np.int64)
-            + rng.integers(0, reply + 1, lanes), rung)
-        # every live lane its own blocks, scattered over the pool; past its
-        # frontier — and all of an idle lane's row — the null block, as the
-        # engine's table has it (an idle lane keeps a position all the same)
-        tables = np.zeros((lanes, width), np.int32)
+        contexts = (np.exp(rng.uniform(np.log(low), np.log(high), lanes)).astype(np.int64)
+                    + rng.integers(0, reply + 1, lanes))
         free = rng.permutation(np.arange(1, nb))
-        for lane, rows in enumerate(contexts[:live]):
-            blocks = -(-int(rows) // bs)
-            tables[lane, :blocks], free = free[:blocks], free[blocks:]
+        if window is None:
+            contexts = np.minimum(contexts, rung)
+            # every live lane its own blocks, scattered over the pool; past its
+            # frontier — and all of an idle lane's row — the null block, as the
+            # engine's table has it (an idle lane keeps a position all the same)
+            tables = np.zeros((lanes, width), np.int32)
+            for lane, rows in enumerate(contexts[:live]):
+                blocks = -(-int(rows) // bs)
+                tables[lane, :blocks], free = free[:blocks], free[blocks:]
+            null = None
+            walked = -(-contexts[:live] // bs)
+            sweep = groups
+        else:
+            # every lane a ring of its own, scattered; which lanes are null is
+            # said beside the table
+            tables = free[:lanes * width].reshape(lanes, width).astype(np.int32)
+            null = jnp.arange(lanes) >= live
+            last = contexts[:live] - 1
+            walked = last // bs - np.maximum(last - window + 1, 0) // bs + 1
+            sweep = window_sweep_groups(window, bs, nkv)
         tables, positions = jnp.asarray(tables), jnp.asarray(contexts - 1, jnp.int32)
         keys = jax.random.split(jax.random.key(args.seed), 3)
         k_pool = jax.random.normal(keys[0], (layers, nb, bs, nkv, d), dtype)
         v_pool = jax.random.normal(keys[1], (layers, nb, bs, nkv, d), dtype)
         q = jax.random.normal(keys[2], (lanes, n, d), dtype)
-        live_bytes = int(
-            2 * np.sum(-(-contexts[:live] // bs)) * bs * nkv * d * k_pool.dtype.itemsize)
+        live_bytes = int(2 * np.sum(walked) * bs * nkv * d * k_pool.dtype.itemsize)
 
         model = LlamaDecode(LlamaConfig(num_heads=n, num_kv_heads=nkv, head_dim=d))
 
@@ -1072,10 +1110,13 @@ def _walk_sweep(args) -> dict:
                     got = a.reshape((layers * nb,) + a.shape[2:])[at]
                     return got.reshape((lanes, rung) + got.shape[3:])
 
-                k_pos = jnp.arange(rung, dtype=jnp.int32)[None, None, :]
+                # the position a row of the table holds, as a query reads it:
+                # the row's own where the table is as wide as the context
+                pos = positions[:, None, None]
+                k_pos = pos - (pos - jnp.arange(rung, dtype=jnp.int32)) % rung
                 return masked_attention(
                     q[:, None], read(k_pool), read(v_pool),
-                    visible(positions[:, None], k_pos, None))[:, 0]
+                    visible(positions[:, None], k_pos, window))[:, 0]
             j = jnp.arange(rung, dtype=jnp.int32)
             at = layer * nb * bs + tables[:, j // bs] * bs + (j % bs)[None, :]
 
@@ -1096,13 +1137,15 @@ def _walk_sweep(args) -> dict:
             "lanes": lanes, "live_lanes": live, "heads": [n, nkv], "rung": rung, "layers": layers,
             "mean_context": float(contexts[:live].mean()),
             "live_mb_a_layer": round(live_bytes / 1e6, 2),
-            "derived_group": walk_group(bs, nkv),
+            "derived_group": (walk_group(bs, nkv) if window is None
+                              else window_walk_group((window - 1) // bs + 2, bs, nkv)),
             "gather_and_scores": gathered, "walk_by_group": {},
         }
         scale = float(jnp.max(jnp.abs(want)))
-        for group in groups:
+        for group in sweep:
             got, walked = timed(
-                lambda *a, group=group: paged_decode_walk(*a, kv_limit=rung, group=group))
+                lambda *a, group=group: paged_decode_walk(
+                    *a, kv_limit=rung, group=group, window=window, null_lanes=null))
             walked["distance"] = float(jnp.max(jnp.abs(got - want))) / scale
             worst = max(worst, walked["distance"])
             entry["walk_by_group"][str(group)] = walked

@@ -15,8 +15,10 @@ One entry is whole programs, not a kernel: ``smallthinker`` compiles
 cell's own sizes, read from ``benchmarks/``) and prints, a program, the bulk
 moves of either pool's shape it holds (none: a pool of 4 kv heads rests in
 half-tiles, and a gather that asked for ``(bs, NKV, D)`` slices re-tiled the
-whole pool a layer a call) and its ``temp_size_in_bytes``. A program that
-holds such a move is a ``[FAIL]``. It builds a 4.7-GB model's abstract weights,
+whole pool a layer a call), its ``temp_size_in_bytes`` and, for a ``pdecode``,
+the gathers of every lane's whole ring it holds (none: a window layer's one
+row a lane is ``paged_decode_walk``'s) beside its count of walk calls. A
+program that holds such a move or gather is a ``[FAIL]``. It builds a 4.7-GB model's abstract weights,
 so it runs only where a substring names it (≈ 45 s for the eight programs).
 
 Usage: ``python scripts/tpu_aot_compile.py [substring ...]`` — exit 0 when
@@ -27,6 +29,7 @@ from __future__ import annotations
 
 import importlib.util
 import os
+import re
 import sys
 import time
 
@@ -44,6 +47,9 @@ os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
 import jax  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: E402
+
+
+WALK_CALL = re.compile(r"custom_call_target=\"tpu_custom_call\".*paged_decode_walk")
 
 
 def _load_test(name):
@@ -90,12 +96,20 @@ def cell_programs(workload, device):
     width = -(-top // bs) + -(-chunk // bs)
     head = model._model()._logits
 
-    def report(fn, donate, *args):
+    def report(fn, donate, *args, rings_of=None):
         ps.destroy_model_parallel()     # one chip: the kernel cases' meshes are not this program's
         compiled = jax.jit(fn, donate_argnums=donate).lower(params, cache, *args).compile()
-        moves = placement.pool_sized_moves(compiled.as_text(), dims)
-        print(f"       temp_size_in_bytes {compiled.memory_analysis().temp_size_in_bytes:,}; "
-              f"bulk moves of a pool's shape: {moves or 'none'}", flush=True)
+        text = compiled.as_text()
+        moves = placement.pool_sized_moves(text, dims)
+        said = (f"       temp_size_in_bytes {compiled.memory_analysis().temp_size_in_bytes:,}; "
+                f"bulk moves of a pool's shape: {moves or 'none'}")
+        if rings_of is not None:
+            # a decode step walks a window layer's ring: no gather of every lane's
+            gathers = placement.ring_gathers(text, rings_of, ring, cfg.num_kv_heads, bs)
+            said += (f"; gathers of the lanes' rings: {gathers or 'none'}; "
+                     f"paged_decode_walk calls: {len(WALK_CALL.findall(text))}")
+            moves = moves + gathers
+        print(said, flush=True)
         return moves
 
     for kv in sizes["kv_buckets"]:
@@ -114,10 +128,12 @@ def cell_programs(workload, device):
         yield f"smallthinker-psfx[{chunk},kv={kv}]", lambda fn=psfx: report(
             fn, 1, i32(1, chunk), i32(1), i32(1), i32(1, width), i32(1, ring))
         yield f"smallthinker-pdecode[kv={kv}]", lambda fn=pdecode: report(
-            fn, (1, 3), i32(lanes), i32(lanes), i32(lanes, width), i32(lanes, ring))
+            fn, (1, 3), i32(lanes), i32(lanes), i32(lanes, width), i32(lanes, ring), rings_of=lanes)
 
 
 def main(argv) -> int:
+    from scripts.paged_decode_bench import window_sweep_groups
+
     cases = _load_test("test_chip_lowering")
     devices = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2"
@@ -149,7 +165,8 @@ def main(argv) -> int:
     for name, (dtype, shape) in cases.WALK_CASES.items():
         jobs.append((name, cases.walk_case(dtype, shape), one))
         # every group size of scripts/paged_decode_bench.py --walk
-        for group in (8, 16, 32, 64):
+        nkv, window = cases.WALK_SHAPES[shape][2], cases.WALK_SHAPES[shape][-1]
+        for group in (8, 16, 32, 64) if window is None else window_sweep_groups(window, 16, nkv):
             jobs.append((f"{name}-group{group}", cases.walk_case(dtype, shape, group), one))
     for name, (dtype, shape) in cases.LATENT_WALK_CASES.items():
         jobs.append((name, cases.latent_walk_case(dtype, shape), one))

@@ -236,25 +236,31 @@ SSM_STEP_CASES = {
 }
 
 
-# (lanes, query heads, kv heads, the kind's layers, its pool's blocks, the rung)
-# of the cells whose decode read is the walk: laguna-mixedlen-batch's full
-# kind, mixtral-chat-steady, olmoe-rag-batch
+# (lanes, query heads, kv heads, the kind's layers, its pool's blocks, the rung,
+# the window) of the cells whose decode read is the walk: laguna-mixedlen-batch's
+# full kind, mixtral-chat-steady, olmoe-rag-batch; and the two window kinds, whose
+# table is the lane's ring (window - 1 + the 512-row chunk, in blocks) and whose
+# pool is a null block + a ring a lane: smallthinker-longchat-steady's and
+# laguna-mixedlen-batch's
 WALK_SHAPES = {
-    "laguna": (32, 48, 8, 2, 17920, 8704),
-    "mixtral": (16, 32, 8, 3, 3072, 2304),
-    "olmoe": (8, 16, 16, 8, 1152, 2176),
+    "laguna": (32, 48, 8, 2, 17920, 8704, None),
+    "mixtral": (16, 32, 8, 3, 3072, 2304, None),
+    "olmoe": (8, 16, 16, 8, 1152, 2176, None),
+    "smallthinker-window": (32, 28, 4, 3, 1 + 32 * 288, 288 * 16, 4096),
+    "laguna-window": (32, 64, 8, 3, 1 + 32 * 64, 64 * 16, 512),
 }
 
 
 def walk_case(pool_dtype, shape="laguna", group=None):
     """(fn, avals) for the decode block walk at a cell's shape (heads of 128,
     blocks of 16 rows), ``group`` blocks a loop trip (None: what the pool's
-    shape gives)."""
+    shape, or the window's blocks, gives). A window kind says which lanes are
+    null beside its table, as ``LagunaDecode._attend`` does."""
     from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
         paged_decode_walk,
     )
 
-    lanes, n, nkv, layers, blocks, rung = WALK_SHAPES[shape]
+    lanes, n, nkv, layers, blocks, rung, window = WALK_SHAPES[shape]
     d, bs = 128, 16
     pool = jax.ShapeDtypeStruct((layers, blocks, bs, nkv, d), pool_dtype)
     avals = [
@@ -266,7 +272,8 @@ def walk_case(pool_dtype, shape="laguna", group=None):
 
     def fn(q, k_pool, v_pool, tables, positions, layer):
         return paged_decode_walk(
-            q, k_pool, v_pool, tables, positions, layer, kv_limit=rung, group=group)
+            q, k_pool, v_pool, tables, positions, layer, kv_limit=rung, group=group,
+            window=window, null_lanes=None if window is None else positions < 0)
 
     return fn, avals
 
@@ -275,6 +282,8 @@ def walk_case(pool_dtype, shape="laguna", group=None):
 WALK_CASES = {
     "decode-walk-bf16": (jnp.bfloat16, "laguna"), "decode-walk-f32": (jnp.float32, "laguna"),
     "decode-walk-mixtral-bf16": (jnp.bfloat16, "mixtral"), "decode-walk-olmoe-bf16": (jnp.bfloat16, "olmoe"),
+    "decode-walk-smallthinker-window-bf16": (jnp.bfloat16, "smallthinker-window"),
+    "decode-walk-laguna-window-bf16": (jnp.bfloat16, "laguna-window"),
 }
 
 

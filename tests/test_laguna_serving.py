@@ -34,7 +34,7 @@ SIZES = {"lanes": LANES, "block_size": BS, "max_seq_len": 128, "pool_blocks": 14
          "prefill_chunk_tokens": CHUNK, "prefill_buckets": [8, 16], "kv_buckets": [128]}
 TOL = 1e-4
 ROW = 2 * 2 * 16 * 4            # k and v x kv heads x head x float32, a layer
-# the kernel mode decides a full layer's decode read: the gather and
+# the kernel mode decides a layer's decode read, full or window: the gather and
 # ``masked_attention`` ("reference", this tier's), or the block walk interpreted
 MODES = ("reference", "interpret")
 
@@ -223,12 +223,15 @@ def test_the_benchmarks_check_passes_on_the_built_engine(fam, params):
 @pytest.mark.parametrize("loop", LOOPS)
 def test_mixed_lengths_give_the_references_tokens(fam, params, loop, mode, monkeypatch):
     """Prompts under the window, past the ring and several rings long in one
-    queue, more requests than lanes, look-ahead and drained steps alike, the
-    full layers' decode read the gather or the block walk."""
+    queue, more requests than lanes (an idle lane beside live ones at the
+    end), look-ahead and drained steps alike, both kinds' decode read the
+    gather or the block walk — the window kind's over a ring wrapped up to
+    four times."""
     monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     prompts = prompts_of(np.random.default_rng(3), (37, 5, 90, 21, 60, 16))
     srv = serving(params, new_tokens=8, policy=loop_policy(loop))
-    assert srv.model.decode_read(srv.model.cache_kinds[0]) == ("kernel" if mode == "interpret" else "gather")
+    for kind in srv.model.cache_kinds:
+        assert srv.model.decode_read(kind) == ("kernel" if mode == "interpret" else "gather")
     rids = [srv.submit(p) for p in prompts]
     out = srv.run_to_completion()
     for rid, prompt in zip(rids, prompts):
@@ -418,6 +421,35 @@ def test_the_window_pool_is_lanes_times_ring_and_the_accounts_say_so(params):
     assert moved == LANES * 128 * ROW * 2 + LANES * RING * ROW * 3
 
 
+def dispatches(srv):
+    return [args for step in srv.tracer.timeline()["steps"] for ph, name, _, _, args in step["events"]
+            if ph == "X" and name == "dispatch"]
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_a_decode_record_says_the_window_rows_the_program_passed(params, mode, monkeypatch):
+    """``window_rows_passed`` beside ``window_rows``: every lane's whole ring
+    where the program gathers; where it walks, the blocks from the window's
+    first row to the lane's own — at most two blocks a live lane over what it
+    needs — and one block a lane that is not live."""
+    monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+    srv = serving(params, new_tokens=5, trace_enabled=True)
+    for p in prompts_of(np.random.default_rng(2), (20, 3, 50)):
+        srv.submit(p)
+    srv.run_to_completion()
+    records = dispatches(srv)
+    assert records and all("window_rows_passed" in a for a in records)
+    for a in records:
+        assert a["window_rows"] <= a["window_rows_passed"] <= LANES * RING
+        if mode == "reference":
+            assert a["window_rows_passed"] == LANES * RING
+        else:
+            idle = (LANES - a["lanes"]) * BS
+            assert a["window_rows_passed"] - idle <= a["window_rows"] + a["lanes"] * 2 * BS
+            assert (a["window_rows_passed"] - idle) % BS == 0 and a["window_rows_passed"] < LANES * RING
+    clean(srv)
+
+
 def test_a_traced_engine_records_the_kinds_and_the_window_rows(params, monkeypatch):
     srv = serving(params, new_tokens=5, trace_enabled=True, prewarm=True)
     prompts = prompts_of(np.random.default_rng(2), (20, 3, 50))
@@ -430,8 +462,7 @@ def test_a_traced_engine_records_the_kinds_and_the_window_rows(params, monkeypat
         "full": {"layers": 2, "rows_per_lane": None, "row_bytes": ROW, "decode_read": "gather"},
         "window": {"layers": 3, "rows_per_lane": RING, "row_bytes": ROW, "decode_read": "gather"}}
     assert setup["window_ring_rows"] == RING and setup["cache_row_bytes"] == ROW
-    records = [args for step in tl["steps"] for ph, name, _, _, args in step["events"]
-               if ph == "X" and name == "dispatch"]
+    records = dispatches(srv)
     assert records and all("rows" in a and "window_rows" in a for a in records)
     for a in records:
         assert a["lanes"] <= a["window_rows"] <= min(a["rows"], a["lanes"] * 8)
@@ -440,10 +471,10 @@ def test_a_traced_engine_records_the_kinds_and_the_window_rows(params, monkeypat
     assert any(a["window_rows"] < a["lanes"] * 8 for a in records)
     assert len(tl["routed"]) > 0 and all(len(row[4]) == 8 for row in tl["routed"])
     assert srv.metrics.snapshot()["window_pool_blocks"] == 1 + LANES * RING_BLOCKS
-    # where Pallas kernels run, the record of an engine built there says the full kind is walked
+    # where Pallas kernels run, the record of an engine built there says both kinds are walked
     monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
     reads = {name: kind["decode_read"] for name, kind in srv._kind_facts()["cache_kinds"].items()}
-    assert reads == {"full": "kernel", "window": "gather"}
+    assert reads == {"full": "kernel", "window": "kernel"}
 
 
 def test_the_dense_slot_cache_runs_every_layer_at_full_length(fam, params):

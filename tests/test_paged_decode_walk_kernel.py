@@ -9,7 +9,13 @@ size that does not divide the walk. The same against
 ``LlamaDecode._cache_attention`` over the rows ``_attend_paged`` gathers, at
 the head groupings of the families that decode through it (Mixtral's 32 on 8,
 OLMoE's 16 on 16), with two lanes sharing a prefix's blocks. Then which read a
-``pdecode`` holds in each kernel mode, for laguna and for the Llama family."""
+``pdecode`` holds in each kernel mode, for laguna and for the Llama family.
+And the walk under a window — a lower bound: against the gather of the lane's
+whole ring and ``masked_attention(visible(…, window))`` through permuted rings
+wrapped more than twice, at SmallThinker's and Laguna's head groupings.
+
+Scope: the kernel and the predicate. The engines that hold it are
+``tests/test_laguna_serving.py`` and ``tests/test_smallthinker_serving.py``'s."""
 
 import dataclasses
 
@@ -21,10 +27,10 @@ import pytest
 from neuronx_distributed_llama3_2_tpu.inference.model import CacheKind, LlamaDecode, decode_model_for
 from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
 from neuronx_distributed_llama3_2_tpu.kernels.paged_attention_pallas import (
-    paged_decode_walk, walk_fits, walk_group,
+    paged_decode_walk, walk_fits, walk_group, window_walk_group,
 )
 from neuronx_distributed_llama3_2_tpu.models.laguna import (
-    LAGUNA_CONFIGS, LagunaForCausalLM, masked_attention, visible,
+    LAGUNA_CONFIGS, LagunaForCausalLM, layer_runs, masked_attention, visible,
 )
 from neuronx_distributed_llama3_2_tpu.models.llama import LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_llama3_2_tpu.models.mixtral import MIXTRAL_CONFIGS, MixtralForCausalLM
@@ -220,6 +226,173 @@ def test_the_group_a_trip_follows_the_pools_shape():
 
 
 # ---------------------------------------------------------------------------
+# under a window: the walk has a lower bound, and the table is a ring
+# ---------------------------------------------------------------------------
+
+# a window of 22 rows over blocks of 4 spans at most 7 blocks; a ring of 8 blocks
+# (32 rows >= window - 1 + a block) is what the engine would give a lane
+WINDOW, RING = 22, 8
+RING_ROWS = RING * BS
+# shorter than the window; exactly the window (22 rows: the last is 21); one more;
+# then rings wrapped twice and three times with the row at a block's first row
+# (64, 96), its last (67) and inside (70, 109); lane 3 is a null lane
+RING_POSITIONS = (5, WINDOW - 1, WINDOW, 40, 64, 67, 70, 96, 109)
+RING_NULL = 3
+# (query heads, kv heads): SmallThinker's 7 a kv head on 4, Laguna's 6 on 8
+RING_HEADS = {"28on4": (28, 4), "48on8": (48, 8)}
+
+
+def make_rings(dtype, heads, seed=0):
+    """(q, k_pool, v_pool, rings, positions, null_lanes): a ring of ``RING``
+    scattered blocks a lane, never block 0 — a lane's row going to the null
+    block is said beside the table, as ``LagunaDecode.forward`` says it."""
+    n, nkv = heads
+    rng = np.random.default_rng(seed)
+    blocks = 1 + len(RING_POSITIONS) * RING
+    k_pool, v_pool = (jnp.asarray(rng.standard_normal((LAYERS, blocks, BS, nkv, D)), dtype) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((len(RING_POSITIONS), n, D)), dtype)
+    rings = rng.permutation(np.arange(1, blocks)).reshape(len(RING_POSITIONS), RING).astype(np.int32)
+    null = np.arange(len(RING_POSITIONS)) == RING_NULL
+    return q, k_pool, v_pool, jnp.asarray(rings), jnp.asarray(RING_POSITIONS, jnp.int32), jnp.asarray(null)
+
+
+@jax.jit
+def ring_twin(q, k_pool, v_pool, rings, positions, layer):
+    """``LagunaDecode._attend``'s read of a window layer at one row a lane: the
+    lane's ring gathered whole, the position a ring row holds, ``visible``."""
+    blocks = k_pool.shape[1]
+    at = layer * blocks + rings
+
+    def read(a):
+        got = a.reshape((LAYERS * blocks,) + a.shape[2:])[at]
+        return got.reshape((got.shape[0], RING_ROWS) + got.shape[3:])
+
+    pos = positions[:, None]
+    k_pos = pos[..., None] - (pos[..., None] - jnp.arange(RING_ROWS, dtype=jnp.int32)) % RING_ROWS
+    return masked_attention(q[:, None], read(k_pool), read(v_pool), visible(pos, k_pos, WINDOW))[:, 0]
+
+
+_RING_WALK = jax.jit(paged_decode_walk, static_argnames=("kv_limit", "group", "window"))
+
+
+def ring_walk(q, k_pool, v_pool, rings, positions, null, layer, group, window=WINDOW):
+    return _RING_WALK(q, k_pool, v_pool, rings, positions, jnp.int32(layer), group=group, window=window, null_lanes=null)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6), (jnp.bfloat16, 2e-2)], ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [3, 7, None], ids=lambda g: f"group{g}")
+@pytest.mark.parametrize("heads", RING_HEADS)
+def test_the_windowed_walk_is_the_ring_gather_and_masked_attention(heads, group, dtype, tol):
+    """Rings wrapped up to three times, each lane's blocks scattered; a group of
+    3 puts a 7-block walk's first and last block in different trips (3 + 3 + 1),
+    7 and the derived group (None) take it in one; layer 1's blocks. Contexts
+    shorter than the window, the window exactly, one more, rows at both edges
+    of a block. float32 to round-off, bfloat16 to the rounding of the scores
+    and of p."""
+    *operands, null = make_rings(dtype, RING_HEADS[heads])
+    got = ring_walk(*operands, null, 1, group)
+    want = ring_twin(*operands, jnp.int32(1))
+    assert got.dtype == dtype and got.shape == want.shape
+
+    def live(a):
+        return live_lanes(a, RING_NULL).astype(jnp.float32)
+
+    assert float(jnp.max(jnp.abs(live(got) - live(want)))) <= tol * float(jnp.max(jnp.abs(live(want))))
+    # neither the other layer's blocks nor a read with no lower bound
+    assert float(jnp.max(jnp.abs(live(got) - live(ring_twin(*operands, jnp.int32(0)))))) > 0.1
+    unbounded = ring_walk(*operands, null, 1, group, window=None)
+    assert float(jnp.max(jnp.abs(live(got)[2:] - live(unbounded)[2:]))) > 1e-3
+
+
+@pytest.mark.parametrize("group", [3, None], ids=lambda g: f"group{g}")
+def test_blocks_outside_a_lanes_window_are_never_read(group):
+    """Every block outside ``[first, first + live)`` of a lane's walk — the
+    rest of its ring, the null lane's whole ring — holds NaN: the live lanes'
+    output is the clean pool's bit for bit, and the null lane reads block 0."""
+    q, k_pool, v_pool, rings, positions, null = make_rings(jnp.float32, RING_HEADS["28on4"])
+    clean = ring_walk(q, k_pool, v_pool, rings, positions, null, 1, group)
+    reached = np.zeros(k_pool.shape[:2], bool)
+    reached[1, 0] = True
+    for lane, pos in enumerate(RING_POSITIONS):
+        if lane != RING_NULL:
+            first = max(0, pos - WINDOW + 1) // BS
+            reached[1, np.asarray(rings[lane])[np.arange(first, pos // BS + 1) % RING]] = True
+    assert reached[1].sum() < 1 + (len(RING_POSITIONS) - 1) * RING          # some ring blocks are outside
+    spoil = jnp.asarray(~reached)[:, :, None, None, None]
+    dirty = ring_walk(q, jnp.where(spoil, jnp.nan, k_pool), jnp.where(spoil, jnp.nan, v_pool), rings, positions, null, 1, group)
+    assert bool((live_lanes(dirty, RING_NULL) == live_lanes(clean, RING_NULL)).all())
+    assert bool(jnp.isfinite(dirty).all())
+
+
+@pytest.mark.parametrize("said", ["beside_the_table", "by_the_table"])
+def test_a_null_lane_under_a_window_walks_one_block_whatever_its_position(said):
+    """A lane whose row goes to the null block — said beside the table where
+    the lane has a ring of its own, by the table's first entry otherwise —
+    walks block 0 alone, all of its rows visible from a far position, and the
+    live lanes read what they read without it."""
+    q, k_pool, v_pool, rings, positions, null = make_rings(jnp.float32, RING_HEADS["48on8"])
+    if said == "by_the_table":
+        rings, null = rings.at[RING_NULL].set(0), None
+    a = ring_walk(q, k_pool, v_pool, rings, positions, null, 0, 3)
+    b = ring_walk(q, k_pool, v_pool, rings, positions.at[RING_NULL].set(1000), null, 0, 3)
+    assert bool((live_lanes(a, RING_NULL) == live_lanes(b, RING_NULL)).all())
+    nkv = RING_HEADS["48on8"][1]
+    only = masked_attention(
+        q[RING_NULL:RING_NULL + 1, None], k_pool[0, :1].reshape(1, BS, nkv, D), v_pool[0, :1].reshape(1, BS, nkv, D),
+        jnp.ones((1, 1, BS), bool))[0, 0]
+    np.testing.assert_allclose(b[RING_NULL], only, rtol=2e-6, atol=2e-6)
+
+
+def test_a_table_as_wide_as_the_context_takes_the_same_rule_under_a_rung():
+    """``benchmarks/check.py``'s call: one table, never wrapped, ``kv_limit``
+    rows of it — the window's blocks are columns ``first …`` of it, no modulo
+    reached, and the walk is the gather of the rung under ``visible``."""
+    q, k_pool, v_pool, tables, positions = make(jnp.float32, positions=(0, 15, 16, RUNG - 2, 9, 3, 25), null_lane=NULL_LANE)
+    window = 10
+    got = _RING_WALK(q, k_pool, v_pool, tables, positions, jnp.int32(1), kv_limit=RUNG, group=2, window=window)
+    at = BLOCKS + tables
+
+    def read(a):
+        got = a.reshape((LAYERS * BLOCKS,) + a.shape[2:])[at]
+        return got.reshape((got.shape[0], RUNG) + got.shape[3:])
+
+    k_pos = jnp.arange(RUNG, dtype=jnp.int32)[None, None, :]
+    want = masked_attention(q[:, None], read(k_pool), read(v_pool), visible(positions[:, None], k_pos, window))[:, 0]
+    np.testing.assert_allclose(live_lanes(got), live_lanes(want), rtol=2e-5, atol=2e-6)
+
+
+def scalar_operands(window):
+    """(scalar-prefetch operand shapes, every primitive of the wrapper) of a
+    ``paged_decode_walk`` call over 3 lanes and a table 8 blocks wide."""
+    q, pool = jnp.zeros((3, NKV * GROUPS, D)), jnp.zeros((LAYERS, BLOCKS, BS, NKV, D))
+    jaxpr = jax.make_jaxpr(lambda *a: paged_decode_walk(*a, 1, window=window))(
+        q, pool, pool, jnp.ones((3, WIDTH), jnp.int32), jnp.zeros((3,), jnp.int32)).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    count = call.params["grid_mapping"].num_index_operands
+    return [v.aval.shape for v in call.invars[:count]], {e.primitive.name for e in jaxpr.eqns}
+
+
+def test_without_a_window_the_kernel_has_the_three_scalar_operands_it_had():
+    """Table, live blocks, position and no lower-bound term, nor any of the
+    arithmetic that makes one ahead of the call; a window adds two a lane: the
+    walk's first column of the ring and the first row seen."""
+    shapes, wrapper = scalar_operands(None)
+    assert shapes == [(3, WIDTH), (3,), (3,)]
+    assert not wrapper & {"max", "sub", "mul", "rem", "gather"}
+    shapes, wrapper = scalar_operands(10)
+    assert shapes == [(3, WIDTH), (3,), (3,), (3,), (3,)] and "gather" not in wrapper
+
+
+def test_a_windows_group_is_its_blocks_over_the_nearest_whole_trips():
+    """SmallThinker's 257 blocks of 16 rows at 4 kv heads go 65 a trip in 4
+    trips and Laguna's 33 at 8 in one, where ``walk_group`` (64, 32) would spend
+    a whole score tile on the odd block; a short window is one trip."""
+    assert window_walk_group(257, 16, 4) == 65 and window_walk_group(33, 16, 8) == 33
+    assert window_walk_group(7, 4, 4) == 7 and window_walk_group(1, 16, 8) == 1
+    assert window_walk_group(96, 16, 8) == 32 and window_walk_group(100, 16, 8) == 34
+
+
+# ---------------------------------------------------------------------------
 # which read a decode program holds
 # ---------------------------------------------------------------------------
 
@@ -228,20 +401,22 @@ TINY = dataclasses.replace(LAGUNA_CONFIGS["tiny-laguna"], max_seq_len=128)
 
 @pytest.mark.parametrize("mode", ["reference", "interpret"])
 def test_the_kernel_mode_decides_which_read_a_decode_program_holds(mode, monkeypatch):
-    """``interpret``: a ``pdecode`` holds one ``pallas_call`` a full layer and no
-    (lanes, rung, kv heads, head) array of the full kind's rows; ``reference``
-    keeps the gather (the CPU tier's twin). A block of rows (``psfx``), a window
-    layer and an int8 pool keep it in either mode."""
+    """``interpret``: a ``pdecode`` holds one ``pallas_call`` a layer, full or
+    window, and neither a (lanes, rung, kv heads, head) array of the full kind's
+    rows nor a (lanes, ring rows, …) one of the window kind's; ``reference``
+    keeps both gathers (the CPU tier's twin). A block of rows (``psfx``) and an
+    int8 pool keep them in either mode."""
     monkeypatch.setenv(KERNEL_MODE_ENV, mode)
     model = decode_model_for(TINY)
     full, window = model.cache_kinds
     walks = mode == "interpret"
-    assert model.decode_read(full) == ("kernel" if walks else "gather")
-    assert model.decode_read(window) == "gather" and model.decode_read(full, quantized=True) == "gather"
+    assert model.decode_read(full) == model.decode_read(window) == ("kernel" if walks else "gather")
+    assert model.decode_read(window, quantized=True) == model.decode_read(full, quantized=True) == "gather"
     assert model.decode_read(CacheKind("rows", 5, None)) == model.decode_read(full)
     params = jax.eval_shape(LagunaForCausalLM(TINY).init, jax.random.key(0))
     lanes, rung, bs = 3, 64, 4
     gathered = f"[{lanes},{rung},{TINY.num_kv_heads},{TINY.head_dim}]"
+    ring = f"[{lanes},{6 * bs},{TINY.num_kv_heads},{TINY.head_dim}]"
 
     def programs(pool):
         tables = jnp.zeros((lanes, rung // bs), jnp.int32)
@@ -255,12 +430,13 @@ def test_the_kernel_mode_decides_which_read_a_decode_program_holds(mode, monkeyp
         return str(step), str(chunk)
 
     step, chunk = programs(jax.eval_shape(lambda: model.init_paged_cache(20, bs, window_blocks=19)))
-    # the window layers' ring is 24 rows, never the rung: the shape is the full kind's alone
-    assert step.count("pallas_call") == (TINY.layers_of("full") if walks else 0)
-    assert (gathered in step) == (not walks)
-    assert "pallas_call" not in chunk and gathered in chunk
+    # the window layers' ring is 24 rows, never the rung: each shape is one kind's alone;
+    # one call a run of layers (a scan's body holds its layers' one): full, 3 x window, full
+    assert step.count("pallas_call") == (len(layer_runs(TINY)) if walks else 0)
+    assert (gathered in step) == (ring in step) == (not walks)
+    assert "pallas_call" not in chunk and gathered in chunk and ring in chunk
     step, _ = programs(jax.eval_shape(lambda: model.init_paged_cache(20, bs, kv_cache_dtype="int8", window_blocks=19)))
-    assert "pallas_call" not in step and gathered in step
+    assert "pallas_call" not in step and gathered in step and ring in step
 
 
 FAMILIES = {
@@ -283,7 +459,8 @@ def test_the_kernel_mode_decides_which_read_a_llama_family_decode_program_holds(
     walks = mode == "interpret"
     assert model.decode_read(rows) == ("kernel" if walks else "gather")
     assert model.decode_read(rows, quantized=True) == "gather"
-    assert model.decode_read(CacheKind("ring", 1, 8)) == "gather"
+    # a kind with a window would be walked too (no such family decodes through LlamaDecode today)
+    assert model.decode_read(CacheKind("ring", 1, 8)) == model.decode_read(rows)
     asked = decode_model_for(dataclasses.replace(config, use_paged_kernel=True))
     assert asked.decode_read(rows) == asked.decode_read(rows, quantized=True) == "kernel"
     params = jax.eval_shape(net(config).init, jax.random.key(0))

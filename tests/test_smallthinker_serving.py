@@ -209,13 +209,14 @@ def test_a_second_request_through_used_blocks_and_a_used_ring(fam, params):
 
 def test_mixed_lengths_through_the_engine_give_the_references_tokens(fam, params, monkeypatch):
     """More requests than lanes, prompts under the window and several rings
-    long, the full layers' decode read the interpreted block walk (3 query
-    heads a kv head); the traced engine says which read each kind got."""
+    long, both kinds' decode read the interpreted block walk (3 query heads
+    a kv head) — the window kind's over its ring's visible blocks; the traced
+    engine says which read each kind got and what the window read passed."""
     monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
     prompts = prompts_of(np.random.default_rng(3), (37, 5, 90, 21, 60))
     srv = serving(params, new_tokens=6, trace_enabled=True)
     reads = {name: kind["decode_read"] for name, kind in srv._kind_facts()["cache_kinds"].items()}
-    assert reads == {"full": "kernel", "window": "gather"}
+    assert reads == {"full": "kernel", "window": "kernel"}
     rids = [srv.submit(p) for p in prompts]
     out = srv.run_to_completion()
     for rid, prompt in zip(rids, prompts):
@@ -224,6 +225,7 @@ def test_mixed_lengths_through_the_engine_give_the_references_tokens(fam, params
     records = [args for step in tl["steps"] for ph, name, _, _, args in step["events"]
                if ph == "X" and name == "dispatch"]
     assert records and all("window_rows" in a for a in records)
+    assert all(a["window_rows"] <= a["window_rows_passed"] < LANES * RING for a in records)
     # the routing tap commits a layer's counts: 8 experts wide, 3 a live token a layer
     assert len(tl["routed"]) > 0 and all(len(row[4]) == 8 for row in tl["routed"])
     clean(srv)

@@ -744,6 +744,38 @@ def test_a_mixed_stacks_block_gather_re_tiles_neither_pool(v5e, family, program,
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
+def ring_gathers(text, lanes, ring, nkv, bs=16):
+    """Results of the compiled text in the shape of every lane's whole ring as
+    ``LagunaDecode._attend``'s gather makes it: ``bf16[lanes · ring blocks,
+    bs · NKV, 128]``, or the same with the lanes apart."""
+    shapes = (f"bf16[{lanes * ring},{bs * nkv},128]", f"bf16[{lanes},{ring},{bs * nkv},128]")
+    return [line.strip()[:120] for line in text.splitlines() if any(f" = {s}" in line for s in shapes)]
+
+
+@pytest.mark.parametrize("family", sorted(MIXED_STACKS))
+def test_a_mixed_stacks_decode_step_gathers_no_ring(v5e, family, monkeypatch):
+    """``pdecode`` at the published attention widths, 16 lanes: a
+    ``paged_decode_walk`` a run of layers, window runs among them, and no
+    result of the shape of the lanes' whole rings (SmallThinker's
+    ``bf16[16 · 288, 64, 128]`` was the cell's largest op) nor a bulk move of
+    either pool's; the ``reference`` mode's program is the control that holds
+    that gather, and a 512-row ``psfx`` keeps its own (one lane's)."""
+    from neuronx_distributed_llama3_2_tpu.kernels.mode import KERNEL_MODE_ENV
+    from neuronx_distributed_llama3_2_tpu.models.laguna import layer_runs
+
+    cfg, train, ring = MIXED_STACKS[family]()
+    found = {}
+    for mode in ("compiled", "reference"):
+        monkeypatch.setenv(KERNEL_MODE_ENV, mode)
+        text = compile_paged(v5e, "pdecode", "rested", cfg=cfg, train=train, blocks=MIXED_BLOCKS).as_text()
+        walks = len(re.findall(r"custom_call_target=\"tpu_custom_call\".*paged_decode_walk", text))
+        found[mode] = (walks, bool(ring_gathers(text, 16, ring, cfg.num_kv_heads)))
+    assert found == {"compiled": (len(layer_runs(cfg)), False), "reference": (0, True)}, found
+    monkeypatch.setenv(KERNEL_MODE_ENV, "compiled")
+    text = compile_paged(v5e, "psfx", "rested", cfg=cfg, train=train, blocks=MIXED_BLOCKS).as_text()
+    assert "paged_decode_walk" not in text and ring_gathers(text, 1, ring, cfg.num_kv_heads)
+
+
 # ---------------------------------------------------------------------------
 # compiled for a described v5e: a state-space decode step visits the state
 # pool in place
